@@ -220,8 +220,6 @@ def run_save_binary(params: Dict[str, str]) -> None:
 
 
 def main(argv: List[str] = None) -> None:
-    from .utils.platform import pin_jax_platforms
-    pin_jax_platforms()
     params = parse_args(sys.argv[1:] if argv is None else argv)
     task = params.pop("task", "train")
     if task == "train":

@@ -390,7 +390,9 @@ _PARAMS: List[_Param] = [
        desc="directory for JAX's persistent XLA compilation cache: "
             "repeated runs (same shapes/params) skip recompiling the "
             "fused training step — applied to jax.config at booster "
-            "init, before the first trace"),
+            "init, before the first trace. Yields (with a log line) to "
+            "the JAX_COMPILATION_CACHE_DIR environment variable, which "
+            "JAX reads by itself"),
     # ---- Observability (docs/Observability.md) ----
     _p("telemetry_out", str, "", ("telemetry_output", "telemetry_file"),
        desc="path: stream structured JSONL telemetry (per-iteration "

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.histogram import build_histograms
-from ..ops.pallas_histogram import HAS_PALLAS, build_histograms_pallas_cm
+from ..ops.pallas_histogram import build_histograms_pallas_cm
 from ..ops.split import BestSplit, SplitParams, best_numerical_split_cm, \
     calculate_leaf_output
 from .learner import FeatureMeta, NEG_INF, _masked_gain, _masked_scatter

@@ -6,8 +6,8 @@ JAX reports compile phases through ``jax.monitoring`` duration events
 A single process-wide listener is installed on first attach and fans the
 events out to every live, enabled :class:`Telemetry` — so per-booster
 registries see the compiles their iterations trigger (a recompile
-mid-training is exactly the kind of cliff PROFILE.md says one-off timing
-scripts keep missing).  Whatever identity kwargs the monitoring API
+mid-training is exactly the kind of cliff one-off timing scripts keep
+missing).  Whatever identity kwargs the monitoring API
 passes (``fun_name`` on newer jax) ride along on the compile record.
 
 Memory accounting covers EVERY local device, not just device 0: a

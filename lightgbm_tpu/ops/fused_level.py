@@ -6,7 +6,8 @@ reference's hottest loops (ref: src/io/dense_bin.hpp ConstructHistogram,
 src/treelearner/serial_tree_learner.cpp:355-453, ocl/histogram256.cl) with
 a single streaming kernel per tree level.
 
-Design (all measured on the attached TPU, see PROFILE.md):
+Design (measured in rounds 2-3 on hardware that is gone; the figures
+ROADMAP.md queue A still leans on are marked there as not reproducible):
 
 - Layout is TRANSPOSED vs round 1: rows ride the 128-wide lane dimension,
   features/bins/slots ride sublanes. The bin one-hot build then uses only
@@ -46,20 +47,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .layout import PackedLayout, feature_layout  # noqa: F401  (shared
 # single-source layout contract — re-exported for existing callers)
 from . import quantize
-
-try:  # pragma: no cover - exotic backends fall back to interpret mode
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    # jax < 0.5 names it TPUCompilerParams (same kwargs)
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
 
 NCH_PRECISE = 5   # g_hi, g_lo, h_hi, h_lo, w
 NCH_FAST = 3      # g, h, w
@@ -88,12 +81,15 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     in round 3. The iota term is charged CONSERVATIVELY (advisor r4):
     Mosaic may fold the broadcasted_iota into the subtract, but that
     cannot be verified off-chip and an overflow is a hard compile/run
-    failure; the pending on-chip ablation (scripts/ablate_kernel.py
-    sweeps tile sizes) is the evidence either way.
+    failure. On a v5e (PR 21) every level of the Higgs layout (FB=1792,
+    nch=5, Sp 8..128 -> tiles 1024..512) compiled and ran at these
+    tiles under the default 16 MB scoped-VMEM limit, with no
+    vmem_limit_bytes; whether larger tiles also fit is for
+    scripts/ablate_kernel.py's tile sweep.
 
     Shallow levels (small Sp -> small accumulator) get LARGER tiles:
     their per-pass cost is floor-bound (oh-build + per-tile overheads,
-    PROFILE.md §5 — the Sp<=8 passes cost half the tree), so halving the
+    ROADMAP A2 — the Sp<=8 passes cost half the tree), so halving the
     tile count halves the fixed per-tile cost where the MXU is padded
     anyway."""
     acc = FB * nch * Sp * 4
@@ -445,19 +441,27 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
     P_i = (jnp.broadcast_to(leafb, (Sp, C))
            == leaf_of_slot).astype(jnp.int32)                  # [Sp, C] 0/1
     same_i = 1 - jnp.bitwise_xor(left_i, small_left_i)         # left==small
-    ch_dt = jnp.int8 if quant else jnp.bfloat16
-    in_small = (P_i * same_i).astype(ch_dt)                    # [Sp, C] 0/1
+    in_small = P_i * same_i                                    # [Sp, C] 0/1
+    if not quant:
+        in_small = in_small.astype(jnp.bfloat16)
 
     # ---- histogram: one wide-N dot, all channels packed. mask*g instead of
     # a select (i1 selects also hit the relayout bug); requires FINITE
     # grad/hess — a NaN/Inf row would leak 0*NaN into other slots' bins,
     # but non-finite gradients wreck training under any formulation.
     # Quantized mode: int8 channels, int32 accumulator — integer sums are
-    # EXACT and associative (ops/quantize.py), rescaled outside.
+    # EXACT and associative (ops/quantize.py), rescaled outside. The
+    # mask product runs in i32 and narrows afterwards: Mosaic on v5e
+    # refuses an i8 x i8 vector multiply ("failed to legalize operation
+    # 'arith.muli' ... vector<8x128x4xi8>").
     chans = []
     for ch in range(nch):
         g = gh_ref[ch:ch + 1, :]                               # [1, C]
-        chans.append(in_small * jnp.broadcast_to(g, (Sp, C)))
+        if quant:
+            chans.append((in_small * jnp.broadcast_to(
+                g.astype(jnp.int32), (Sp, C))).astype(jnp.int8))
+        else:
+            chans.append(in_small * jnp.broadcast_to(g, (Sp, C)))
     ghs = jnp.concatenate(chans, axis=0)                       # [nch*Sp, C]
     hist_ref[:] += jax.lax.dot_general(
         oh, ghs, (((1,), (1,)), ((), ())),
@@ -520,9 +524,6 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
         histograms, FB = packed.fb or f_oh*num_bins.
       new_leaf: [1, R] int32 updated assignment.
     """
-    if not HAS_PALLAS:
-        raise ImportError("jax.experimental.pallas is unavailable on this "
-                          "backend; use the XLA histogram path instead")
     Fp, R = bins_T.shape
     B = num_bins
     FB = _kernel_fb(f_oh, B, packed)
@@ -565,7 +566,7 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
             jax.ShapeDtypeStruct((1, R), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((FB, C), oh_dt)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
@@ -607,9 +608,6 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
                interpret: bool = False,
                packed: PackedLayout = None) -> jax.Array:
     """Row->leaf update only (same W/tbl contract as level_pass)."""
-    if not HAS_PALLAS:
-        raise ImportError("jax.experimental.pallas is unavailable on this "
-                          "backend; use the XLA histogram path instead")
     Fp, R = bins_T.shape
     B = num_bins
     FB = _kernel_fb(f_oh, B, packed)
@@ -631,7 +629,7 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
         out_specs=pl.BlockSpec((1, C), lambda t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((1, R), jnp.int32),
         scratch_shapes=[pltpu.VMEM((FB, C), jnp.bfloat16)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(bins_T, leaf_T, W, tbl)
@@ -649,7 +647,7 @@ def _epilogue_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, lv_ref, score_ref,
     Replaces four separate O(R) streams of the round-2 driver (the final
     route_pass, the table_lookup score update, the elementwise gradient/
     pack, and the next grow's root level_pass) — each of which paid the
-    full per-pass floor (oh-build + narrow-N dot, PROFILE.md §5).
+    full per-pass floor (oh-build + narrow-N dot, ROADMAP A2).
     The ref host loop being fused: gbdt.cpp:371 TrainOneIter's
     UpdateScore -> Boosting(GetGradients) -> next BeforeTrain root.
 
@@ -761,9 +759,6 @@ def epilogue_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
     new_score [1, R] f32, gh_T [8, R] bf16 pack_gh block for the next
     tree's level passes).
     """
-    if not HAS_PALLAS:
-        raise ImportError("jax.experimental.pallas is unavailable on this "
-                          "backend; use the XLA histogram path instead")
     Fp, R = bins_T.shape
     B = num_bins
     FB = f_oh * B
@@ -801,7 +796,7 @@ def epilogue_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
             jax.ShapeDtypeStruct((8, R), jnp.bfloat16),
         ],
         scratch_shapes=[pltpu.VMEM((FB, C), jnp.bfloat16)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(bins_T, leaf_T, W, tbl, lvp, score_T, ops_T, bag_T)
@@ -844,7 +839,7 @@ def table_lookup(idx_T: jax.Array, table: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, C), lambda t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((1, Rp), table.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(idx_T, tblp)

@@ -31,16 +31,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import quantize
 from .layout import feature_layout
-
-try:  # optional: exotic backends fall back to the XLA implementations
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
 
 NUM_CH = 3
 

@@ -13,45 +13,29 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils import log
+from ..utils import log, platform
 
 DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: ``jax.shard_map`` where it exists
-    (jax >= 0.6), else ``jax.experimental.shard_map.shard_map`` whose
-    replication check carries the older ``check_rep`` name. Every
-    shard_map in the tree learners routes through here so a jax upgrade
-    is a one-line change."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def buffer_donation_supported() -> bool:
-    """True where XLA actually honors ``donate_argnums`` (TPU/GPU).
-    The CPU backend copies anyway and warns per lowering, so callers
-    request donation only where it is real. Donation composes with
-    sharded operands too: a row-sharded score matrix under a multi-
-    process layout donates per-shard buffers, so the in-place update
-    holds on every rank."""
-    try:
-        return jax.default_backend() in ("tpu", "gpu")
-    except Exception:
-        return False
+    """``jax.shard_map`` with the replication check off by default (the
+    growers' psum'd outputs are replicated by construction). Every
+    shard_map in the tree learners routes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def donate_argnums(*argnums: int):
-    """``donate_argnums`` tuple for jax.jit, empty off-TPU/GPU — the
-    one-line idiom every driver jit that re-writes its score/gradient
-    carry buffers routes through (boosting/gbdt.py fast path, megastep,
-    epilogue, valid updates, parallel growers)."""
-    return tuple(argnums) if buffer_donation_supported() else ()
+    """``donate_argnums`` tuple for jax.jit, empty in the CPU test mode
+    (XLA:CPU copies anyway and warns per lowering) — the one-line idiom
+    every driver jit that re-writes its score/gradient carry buffers
+    routes through (boosting/gbdt.py fast path, megastep, epilogue,
+    valid updates, parallel growers; ingest/prefetch.py). Donation
+    composes with sharded operands: a row-sharded score matrix donates
+    per-shard buffers, so the in-place update holds on every device."""
+    return tuple(argnums) if platform.on_tpu() else ()
 
 
 def make_mesh(n_devices: Optional[int] = None,

@@ -13,7 +13,7 @@ amortize dispatch over batched requests.
 Layers (docs/Serving.md):
 
 - :class:`ServingEngine` (engine.py) — one packed model: bucketed,
-  donated, warmup-compiled device traversal with deterministic
+  warmup-compiled device traversal with deterministic
   compile/dispatch counters and graceful degradation to the host walk;
 - :class:`MicroBatcher` (batcher.py) — thread-safe request queue with
   ``max_batch_rows`` / ``max_delay_ms`` deadline coalescing, one device

@@ -3,8 +3,9 @@ import os
 # Multi-device testing on a virtual CPU mesh (SURVEY.md §4 implication):
 # replaces the reference's localhost-subprocess distributed mockup
 # (tests/distributed/_test_distributed.py).  XLA_FLAGS must be set before
-# jax initializes its backends; jax.config.update beats the JAX_PLATFORMS
-# env var, which the runtime environment may pin to a TPU platform.
+# jax initializes its backends.  Asking for the CPU BY NAME is what puts
+# the package in its test mode (utils/platform.on_tpu): XLA growers,
+# interpret-mode kernels.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
@@ -17,8 +18,9 @@ jax.config.update("jax_platforms", "cpu")
 # Persistent compilation cache: the learner jit varies with static shapes
 # (rows, features, num_leaves, max_bins), so repeat suite runs hit the disk
 # cache instead of re-tracing (~10-30 s per unique shape on CPU).
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgbm_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from lightgbm_tpu.utils.platform import compilation_cache_dir  # noqa: E402
+
+compilation_cache_dir()
 
 import pytest  # noqa: E402
 

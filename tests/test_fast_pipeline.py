@@ -2,8 +2,8 @@
 _train_one_iter_fast / drain_pending).
 
 The fast path defers HostTree materialisation: device trees queue up and
-drain in batches, removing the 2-3 blocking host syncs per tree that
-dominate remote-attached-TPU latency (ref behaviour being replaced:
+drain in batches, removing the 2-3 blocking host syncs per tree
+(ref behaviour being replaced:
 gbdt.cpp:371 TrainOneIter's synchronous bookkeeping).
 """
 import numpy as np

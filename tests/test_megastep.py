@@ -194,16 +194,25 @@ def test_trace_out_implies_section_granularity(tmp_path):
     assert (tmp_path / "trace.json").exists()
 
 
-def test_compilation_cache_dir_applied(tmp_path):
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compilation_cache_dir_applied(tmp_path, monkeypatch, env_set):
+    """The config key places the cache unless JAX_COMPILATION_CACHE_DIR
+    already did: then JAX reads the variable and the key yields."""
     import jax
     before = jax.config.jax_compilation_cache_dir
     cache = tmp_path / "xla_cache"
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "from_env"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
         X, y = _data(n=300)
         lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
                    "compilation_cache_dir": str(cache)},
                   lgb.Dataset(X, label=y), num_boost_round=2)
-        assert jax.config.jax_compilation_cache_dir == str(cache)
+        assert jax.config.jax_compilation_cache_dir == \
+            (before if env_set else str(cache))
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 

@@ -133,6 +133,44 @@ def test_fused_engine_data_parallel_fast_path_used(data):
     assert bst.num_trees() == BASE["num_iterations"]
 
 
+_BACKEND_COMPILES = []   # (time.time(), fun_name), process-wide listener
+
+
+def test_fused_data_parallel_second_dispatch_reuses_executable(data,
+                                                               tmp_path):
+    """Single-process data-parallel megastep: the score carries must
+    enter the FIRST dispatch in the sharding the step hands them back
+    in. Left on device 0 they changed sharding across the first call
+    and the second chunk recompiled the whole step (~100 s on four v5e
+    chips, PR 21) — a count the virtual mesh shows exactly."""
+    import json
+    import time
+
+    from jax import monitoring
+    if not _BACKEND_COMPILES:
+        monitoring.register_event_duration_secs_listener(
+            lambda event, secs, **kw: _BACKEND_COMPILES.append(
+                (time.time(), str(kw.get("fun_name"))))
+            if event.endswith("backend_compile_duration") else None)
+    X, y = data
+    tel = tmp_path / "t.jsonl"
+    ds = lgb.Dataset(X, label=y)
+    dv = lgb.Dataset(X[:512], label=y[:512], reference=ds)
+    curve = {}
+    bst = lgb.train(
+        dict(BASE, tpu_engine="fused", tree_learner="data", metric="auc",
+             tpu_megastep=True, tpu_megastep_iters=2, num_iterations=6,
+             telemetry_out=str(tel)),
+        ds, valid_sets=[dv], callbacks=[lgb.record_evaluation(curve)])
+    assert bst._gbdt.parallel_mode == "data" and bst.num_trees() == 6
+    drained = [json.loads(line)["ts"] for line in tel.read_text().splitlines()
+               if json.loads(line).get("event") == "megastep"]
+    assert len(drained) == 3
+    late = [name for ts, name in _BACKEND_COMPILES
+            if drained[0] < ts <= drained[-1]]
+    assert late == [], f"compiled after the first chunk: {late}"
+
+
 def test_data_parallel_categorical_and_monotone(data):
     """Categorical splits + monotone bounds must survive the psum path
     (none of the round-2 mesh tests exercised them — VERDICT weak #5)."""
