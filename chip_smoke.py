@@ -19,9 +19,12 @@ metric=auc (BASELINE.md GPU-benchmark row; data generated from a seed):
            requests of different sizes, flat compile count.
 
 Any failed check or exception ends the process non-zero. It never sets
-JAX_PLATFORMS and exits non-zero unless JAX finds a TPU. The last stdout
-line is one JSON object ({"ok": true, "device": {...}, ...}); timings in
-it are smoke timings, not benchmark numbers.
+JAX_PLATFORMS and exits non-zero, printing no result, unless JAX finds a
+TPU. On success stdout carries two JSON lines: first the summary (device,
+versions, wall time per phase, AUC, dispatches/iter, compile and cache
+counts, memory; also written to <out>/result.json; its timings are smoke
+timings, not benchmark numbers), and LAST the verdict the driver parses,
+exactly {"ok": true, "device": {"platform", "kind", "count"}}.
 
   python3 chip_smoke.py                    # the contract run, one chip
   python3 chip_smoke.py --data-parallel    # same configuration with
@@ -58,6 +61,13 @@ AUC_FLOOR = 0.955
 def require(cond, what: str) -> None:
     if not cond:
         raise RuntimeError(f"chip_smoke: FAILED: {what}")
+
+
+def verdict(device: dict) -> str:
+    """The line the driver parses: these keys and no others."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
 
 
 def say(msg: str) -> None:
@@ -455,6 +465,7 @@ def main() -> None:
     with open(os.path.join(args.out, "result.json"), "w") as fh:
         json.dump(result, fh, indent=1)
     print(json.dumps(result), flush=True)
+    print(verdict(device), flush=True)     # the last line of stdout
 
 
 if __name__ == "__main__":

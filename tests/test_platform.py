@@ -115,6 +115,26 @@ def test_chip_smoke_refuses_the_cpu():
     assert r.stdout.strip() == ""          # no result line
 
 
+def test_chip_smoke_verdict_is_the_last_thing_printed():
+    """The driver parses the last stdout line and refuses any key beyond
+    ok / device{platform, kind, count}."""
+    import ast
+    import importlib.util
+    path = os.path.join(REPO, "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    line = smoke.verdict({"platform": "tpu", "kind": "TPU v5 lite",
+                          "count": 1, "extra": "dropped"})
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    with open(path) as fh:
+        tree = ast.parse(fh.read())
+    main = next(n for n in tree.body
+                if isinstance(n, ast.FunctionDef) and n.name == "main")
+    assert ast.unparse(main.body[-1]) == "print(verdict(device), flush=True)"
+
+
 def test_bench_chip_mode_exits_nonzero_without_a_tpu(tmp_path, monkeypatch):
     monkeypatch.setenv("BENCH_TRAJECTORY", str(tmp_path / "traj.jsonl"))
     r = _run_script("bench.py")
