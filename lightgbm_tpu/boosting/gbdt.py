@@ -268,6 +268,7 @@ class GBDT:
         self._prof_start = 0
         self._prof_n = -1
         self._prof_active = False
+        self._prof_opened_at = 0
         self._prof_done = False
         # on-demand profiling control plane (POST /profile on the
         # metrics exporter): the armed-request handoff and the open
@@ -669,12 +670,22 @@ class GBDT:
         key away)."""
         self._profile_ctl_step()
         self._slo_step()
+        self._profiler_window(1)
+
+    def _profiler_window(self, ahead: int) -> None:
+        """The config-keyed window at a dispatch edge; ``ahead`` is the
+        number of iterations the coming dispatch covers. Under the
+        megastep the edges are chunk boundaries, so the window snaps
+        OUTWARD to them: it opens before the chunk that holds
+        profile_start_iteration and closes at the first boundary at or
+        after the last requested iteration. The start/stop events carry
+        the iterations actually covered."""
         if self._prof_done or not self._prof_dir \
                 or self._ctl_window is not None:
             return
         it = self.iter
         if not self._prof_active:
-            if it >= self._prof_start:
+            if it + ahead > self._prof_start:
                 try:
                     jax.block_until_ready(self.scores)
                     jax.profiler.start_trace(self._prof_dir)
@@ -683,6 +694,7 @@ class GBDT:
                     self._prof_done = True
                     return
                 self._prof_active = True
+                self._prof_opened_at = it
                 self.telemetry.event("profiler_trace_start", iteration=it,
                                      log_dir=self._prof_dir)
         elif 0 <= self._prof_n <= it - self._prof_start:
@@ -699,6 +711,8 @@ class GBDT:
         self._prof_active = False
         self._prof_done = True
         self.telemetry.event("profiler_trace_stop", iteration=self.iter,
+                             first_iteration=self._prof_opened_at,
+                             iterations=self.iter - self._prof_opened_at,
                              log_dir=self._prof_dir)
         self._roofline_capture(self._prof_dir)
 
@@ -3255,6 +3269,7 @@ class GBDT:
         meta = self.meta
         has_cat = self.has_cat
 
+        @jax.named_scope("lgbm.valid_apply")
         def apply_trees(vscore, vbins, trees):
             for tid in range(k):
                 new_row = add_tree_score(
@@ -3361,9 +3376,10 @@ class GBDT:
                     quant_bits=quant, packed=packed,
                     mask_onehot=mask_oh,
                     gh_scales=qrest[0] if quant else None)
-                delta = table_lookup(row_leaf[None, :],
-                                     tree.leaf_value * shrink,
-                                     interpret=interp)[0]
+                with jax.named_scope("lgbm.score_update"):
+                    delta = table_lookup(row_leaf[None, :],
+                                         tree.leaf_value * shrink,
+                                         interpret=interp)[0]
                 return tree, delta
             grow_one_sharded = _shard_map(
                 grow_one, mesh=self.mesh,
@@ -3384,25 +3400,24 @@ class GBDT:
             for tid in range(k):
                 fm_t = fm_pads[tid] & smask if screening \
                     else fm_pads[tid]
-                g_p = jnp.pad(grad[tid] * bag_weight, (0, pad))
-                h_p = jnp.pad(hess[tid] * bag_weight, (0, pad))
-                w_p = jnp.pad(bag_weight, (0, pad))
                 scales = None
-                if quant:
-                    gh_T, scales = pack_gh_quant(
-                        g_p, h_p, w_p, quant,
-                        seed + jnp.uint32(tid))
-                else:
-                    gh_T = pack_gh(g_p, h_p, w_p, self.fused_nch)
+                with jax.named_scope("lgbm.gh_pack"):
+                    g_p = jnp.pad(grad[tid] * bag_weight, (0, pad))
+                    h_p = jnp.pad(hess[tid] * bag_weight, (0, pad))
+                    w_p = jnp.pad(bag_weight, (0, pad))
+                    if quant:
+                        gh_T, scales = pack_gh_quant(
+                            g_p, h_p, w_p, quant,
+                            seed + jnp.uint32(tid))
+                    else:
+                        gh_T = pack_gh(g_p, h_p, w_p, self.fused_nch)
                 if par:
                     args = (bins_T, gh_T, fm_t) \
                         + ((scales,) if quant else ())
-                    tree, delta = grow_one_sharded(*args)
-                    # a dried-up class (no split found) contributes
-                    # NOTHING: the sync path appends a zero constant tree
-                    # for it (gbdt.cpp:421-437 beyond the first
-                    # iteration) and keeps boosting the other classes
-                    delta = jnp.where(tree.num_leaves > 1, delta[:n], 0.0)
+                    # (the shard_map boundary is the grower's; the lookup
+                    # inside names itself: the innermost lgbm. scope wins)
+                    with jax.named_scope("lgbm.grow"):
+                        tree, delta = grow_one_sharded(*args)
                 else:
                     tree, row_leaf = grow_tree_fused(
                         bins_T, gh_T, self.fused_meta, fm_t,
@@ -3418,9 +3433,19 @@ class GBDT:
                         mono_mode=getattr(self, "mono_mode", "basic"),
                         quant_bits=quant, packed=packed,
                         mask_onehot=mask_oh, gh_scales=scales)
-                    delta = tree_score_delta(tree, row_leaf, shrink,
-                                             num_rows=n, interpret=interp)
-                scores = scores.at[tid].add(delta)
+                with jax.named_scope("lgbm.score_update"):
+                    if par:
+                        # a dried-up class (no split found) contributes
+                        # NOTHING: the sync path appends a zero constant
+                        # tree for it (gbdt.cpp:421-437 beyond the first
+                        # iteration) and keeps boosting the other classes
+                        delta = jnp.where(tree.num_leaves > 1, delta[:n],
+                                          0.0)
+                    else:
+                        delta = tree_score_delta(tree, row_leaf, shrink,
+                                                 num_rows=n,
+                                                 interpret=interp)
+                    scores = scores.at[tid].add(delta)
                 trees.append(tree)
             stacked = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *trees)
@@ -3454,7 +3479,8 @@ class GBDT:
             def step(bins_T, scores, grad_in, hess_in, bag_weight,
                      fm_pads):
                 if in_jit_grads:
-                    grad, hess = obj.gradients_from(scores, grad_in)
+                    with jax.named_scope("lgbm.gradients"):
+                        grad, hess = obj.gradients_from(scores, grad_in)
                 else:
                     grad, hess = grad_in, hess_in
                 scores, stacked, _ = grow_k(bins_T, scores, grad, hess,
@@ -3465,7 +3491,8 @@ class GBDT:
         def step_ext(bins_T, scores, grad_in, hess_in, bag_weight,
                      fm_pads, ema, explore, seed):
             if in_jit_grads:
-                grad, hess = obj.gradients_from(scores, grad_in)
+                with jax.named_scope("lgbm.gradients"):
+                    grad, hess = obj.gradients_from(scores, grad_in)
             else:
                 grad, hess = grad_in, hess_in
             return grow_k(bins_T, scores, grad, hess, bag_weight,
@@ -3760,10 +3787,12 @@ class GBDT:
         es_state = (None if (self._eval_consumer is None
                              or self._es_carry is None)
                     else (self._es_carry[2], self._es_carry[3]))
-        trees_host, metrics_host, es_host = jax.device_get(
-            ([t for t, _, _, _ in pend],
-             [m for _, _, _, m in pend if m is not None],
-             es_state))
+        # (Fetch is the wait for the device, not host work)
+        with timer.section("GBDT::Drain::Fetch"):
+            trees_host, metrics_host, es_host = jax.device_get(
+                ([t for t, _, _, _ in pend],
+                 [m for _, _, _, m in pend if m is not None],
+                 es_state))
         # flatten megastep entries ([B, k, ...] stacked trees covering B
         # iterations) and per-iteration entries ([k, ...], batch == 1)
         # into one per-iteration sequence of host TreeArrays fields,
@@ -3821,14 +3850,18 @@ class GBDT:
                     iter_models.append((ht, None, False))
                     continue
                 any_grew = True
-                ht, sf_inner = self._to_host_tree(ta, self.shrinkage_rate)
-                # numerical guards stay live on the fast path: the host
-                # tree is already materialised here, so the non-finite
-                # checks cost numpy only (no extra device sync)
-                self._guard_tree(base_iter + i, tid, ht, gain_acc)
-                ht.apply_shrinkage(self.shrinkage_rate)
+                with timer.section("GBDT::Drain::HostTree"):
+                    ht, sf_inner = self._to_host_tree(ta,
+                                                      self.shrinkage_rate)
+                    # numerical guards stay live on the fast path: the
+                    # host tree is already materialised here, so the
+                    # non-finite checks cost numpy only (no extra device
+                    # sync)
+                    self._guard_tree(base_iter + i, tid, ht, gain_acc)
+                    ht.apply_shrinkage(self.shrinkage_rate)
                 cf, cm = self._last_cat or (None, None)
-                dt = _DeviceTree(ht, sf_inner, cat_flag=cf, cat_mask=cm)
+                with timer.section("GBDT::Drain::DeviceTree"):
+                    dt = _DeviceTree(ht, sf_inner, cat_flag=cf, cat_mask=cm)
                 if abs(init_scores[tid]) > K_EPSILON:
                     ht.add_bias(init_scores[tid])
                     dt.leaf_value = jnp.asarray(ht.leaf_value, jnp.float32)
@@ -3860,66 +3893,68 @@ class GBDT:
                 self.models.append(ht)
                 self.device_trees.append(dt)
         if stop_i is not None:
-            # the stopping iteration contributed nothing to the scores
-            # (every class's delta was zeroed in-jit); iterations after it
-            # must be discarded — subtract their contributions from the
-            # live scores (bin-space routing is training-identical, so
-            # each subtraction reverses the training add up to f32
-            # rounding)
-            self._epi_carry = None
-            scores = self.scores
-            # replay bins: the replicated copy single-process, the
-            # row-sharded global matrix under multi-process (the
-            # rank-local bins_dev cannot route the [k, Np] score carry)
-            replay_bins = self._train_bins_replay()
-            for conv_i in range(stop_i + 1, len(converted)):
-                if es_cut is not None and conv_i > es_cut:
-                    continue   # frozen tail: contributed nothing
-                iter_models = converted[conv_i]
-                for tid, (_, dt, grew) in enumerate(iter_models):
-                    if grew:
-                        scores = self._add_tree_to_score(
-                            scores, replay_bins, dt, tid, scale=-1.0,
-                            bundle=self._train_bundle())
+            with timer.section("GBDT::Drain::Rollback"):
+                # the stopping iteration contributed nothing to the scores
+                # (every class's delta was zeroed in-jit); iterations after it
+                # must be discarded — subtract their contributions from the
+                # live scores (bin-space routing is training-identical, so
+                # each subtraction reverses the training add up to f32
+                # rounding)
+                self._epi_carry = None
+                scores = self.scores
+                # replay bins: the replicated copy single-process, the
+                # row-sharded global matrix under multi-process (the
+                # rank-local bins_dev cannot route the [k, Np] score carry)
+                replay_bins = self._train_bins_replay()
+                for conv_i in range(stop_i + 1, len(converted)):
+                    if es_cut is not None and conv_i > es_cut:
+                        continue   # frozen tail: contributed nothing
+                    iter_models = converted[conv_i]
+                    for tid, (_, dt, grew) in enumerate(iter_models):
+                        if grew:
+                            scores = self._add_tree_to_score(
+                                scores, replay_bins, dt, tid, scale=-1.0,
+                                bundle=self._train_bundle())
+                            for vi in range(len(self.valid_scores)):
+                                self.valid_scores[vi] = \
+                                    self._add_tree_to_score(
+                                        self.valid_scores[vi],
+                                        self.valid_bins[vi], dt, tid,
+                                        scale=-1.0,
+                                        bundle=self._valid_bundle(vi))
+                if not self.models:
+                    # first-ever iteration stopped outright: the reference
+                    # keeps one constant tree per class carrying the init
+                    # score, updating the scorer a second time on top of
+                    # BoostFromAverage (gbdt.cpp:377,433 — 2x init total;
+                    # matched bug-for-bug by the synchronous path)
+                    init_scores = flat[stop_i][1]
+                    for tid in range(k):
+                        ht = HostTree(1)
+                        ht.leaf_value[0] = init_scores[tid]
+                        scores = scores.at[tid].add(float(init_scores[tid]))
                         for vi in range(len(self.valid_scores)):
-                            self.valid_scores[vi] = \
-                                self._add_tree_to_score(
-                                    self.valid_scores[vi],
-                                    self.valid_bins[vi], dt, tid,
-                                    scale=-1.0,
-                                    bundle=self._valid_bundle(vi))
-            if not self.models:
-                # first-ever iteration stopped outright: the reference
-                # keeps one constant tree per class carrying the init
-                # score, updating the scorer a second time on top of
-                # BoostFromAverage (gbdt.cpp:377,433 — 2x init total;
-                # matched bug-for-bug by the synchronous path)
-                init_scores = flat[stop_i][1]
-                for tid in range(k):
-                    ht = HostTree(1)
-                    ht.leaf_value[0] = init_scores[tid]
-                    scores = scores.at[tid].add(float(init_scores[tid]))
-                    for vi in range(len(self.valid_scores)):
-                        # the sync path's constant-tree branch updates the
-                        # valid scorers too (gbdt.cpp:422-441)
-                        self.valid_scores[vi] = self.valid_scores[vi] \
-                            .at[tid].add(float(init_scores[tid]))
-                    self.models.append(ht)
-                    self.device_trees.append(
-                        _DeviceTree(ht, np.zeros(0, np.int32)))
-            self.scores = scores
-            self.iter = base_iter + stop_i
-            self._stopped_early = True
-            log.warning("Stopped training because there are no more "
-                        "leaves that meet the split requirements")
-            # structured stop record (the sync path emits the same event
-            # inline). `discarded` lets iteration-granularity consumers
-            # reconcile: iteration records numbered >= this event's
-            # `iter` were rolled back and produced no trees
-            self.telemetry.event("stopped_no_splits", iteration=self.iter,
-                                 discarded=len(flat) - stop_i)
-        self._replay_drained_eval(flat_metrics, base_iter, len(flat),
-                                  stop_i, es_cut)
+                            # the sync path's constant-tree branch updates the
+                            # valid scorers too (gbdt.cpp:422-441)
+                            self.valid_scores[vi] = self.valid_scores[vi] \
+                                .at[tid].add(float(init_scores[tid]))
+                        self.models.append(ht)
+                        self.device_trees.append(
+                            _DeviceTree(ht, np.zeros(0, np.int32)))
+                self.scores = scores
+                self.iter = base_iter + stop_i
+                self._stopped_early = True
+                log.warning("Stopped training because there are no more "
+                            "leaves that meet the split requirements")
+                # structured stop record (the sync path emits the same event
+                # inline). `discarded` lets iteration-granularity consumers
+                # reconcile: iteration records numbered >= this event's
+                # `iter` were rolled back and produced no trees
+                self.telemetry.event("stopped_no_splits", iteration=self.iter,
+                                     discarded=len(flat) - stop_i)
+        with timer.section("GBDT::Drain::Replay"):
+            self._replay_drained_eval(flat_metrics, base_iter, len(flat),
+                                      stop_i, es_cut)
         tel = self.telemetry
         if tel.enabled and flat and self.parallel_mode != "serial":
             # measured in-trace collective traffic of the drained batch:
@@ -4223,7 +4258,7 @@ class GBDT:
 
     def _megastep_static_reason(self) -> Optional[str]:
         """Megastep blockers beyond fast-path eligibility that are fixed
-        for the run (config keys, objective protocol, profiler window)."""
+        for the run (config keys, objective protocol)."""
         obj = self.objective
         if not bool(getattr(self.config, "tpu_megastep", True)):
             return "config:tpu_megastep=false"
@@ -4239,13 +4274,6 @@ class GBDT:
         if self.telemetry.enabled \
                 and self._tel_granularity() == "iteration":
             return "config:telemetry_granularity=iteration"
-        # a bounded/offset jax.profiler window opens and closes at
-        # iteration edges _profiler_step only sees once per call —
-        # fusing would shift the captured window by up to a chunk
-        # (whole-run profiles, start 0 / no bound, are unaffected)
-        if self._prof_dir and not self._prof_done \
-                and (self._prof_start > 0 or self._prof_n >= 0):
-            return "config:profile_start_iteration/profile_num_iterations"
         return None
 
     def _megastep_ok(self) -> bool:
@@ -4312,6 +4340,7 @@ class GBDT:
 
     def _train_one_megastep(self, chunk: int) -> bool:
         tel = self.telemetry
+        self._profiler_window(chunk)
         t0 = time.perf_counter()
         with timer.section("GBDT::TrainMegastep"):
             self._megastep_body(chunk)
@@ -4480,7 +4509,8 @@ class GBDT:
             _make_fused_tree_loop for growth/score updates and
             _make_valid_apply per valid set — scanned, so the megastep
             is bit-identical to the pipelined path by construction."""
-            grad, hess = obj.gradients_from(scores, grad_ops)
+            with jax.named_scope("lgbm.gradients"):
+                grad, hess = obj.gradients_from(scores, grad_ops)
             scores, stacked, ema = grow_k(bins_T, scores, grad, hess,
                                           bag_weight, fm_pads, ema,
                                           explore, seed)
@@ -4552,6 +4582,7 @@ class GBDT:
         es_mask = jnp.asarray(np.asarray(mask_np, bool))
         es_rounds = jnp.int32(es_rounds)
 
+        @jax.named_scope("lgbm.early_stop")
         def es_update(es, mvals, it, active):
             best, bround, stopped, stop_it = es
             signed = mvals * sign
@@ -4581,10 +4612,11 @@ class GBDT:
                     # freeze past the stop latch: the tree still comes
                     # out of the scan (static shapes) but contributes
                     # nothing
-                    scores = jnp.where(active, new_scores, scores)
-                    vscores = tuple(jnp.where(active, nv, v)
-                                    for nv, v in zip(new_vscores,
-                                                     vscores))
+                    with jax.named_scope("lgbm.freeze"):
+                        scores = jnp.where(active, new_scores, scores)
+                        vscores = tuple(jnp.where(active, nv, v)
+                                        for nv, v in zip(new_vscores,
+                                                         vscores))
                     mvals = plan.eval_in_scan(scores, vscores, metric_ops)
                     es = es_update(es, mvals, it, active)
                     return (scores, vscores, es), (stacked, mvals)
@@ -4605,13 +4637,14 @@ class GBDT:
                  new_ema) = one_iteration(
                     bins_T, scores, vbins, vscores, grad_ops,
                     bag_weight, fm_pads, ema, explore, seed)
-                scores = jnp.where(active, new_scores, scores)
-                vscores = tuple(jnp.where(active, nv, v)
-                                for nv, v in zip(new_vscores, vscores))
-                if new_ema is not None:
-                    # frozen tail: the latched model stops realizing
-                    # gains, so the EMA freezes with it
-                    ema = jnp.where(active, new_ema, ema)
+                with jax.named_scope("lgbm.freeze"):
+                    scores = jnp.where(active, new_scores, scores)
+                    vscores = tuple(jnp.where(active, nv, v)
+                                    for nv, v in zip(new_vscores, vscores))
+                    if new_ema is not None:
+                        # frozen tail: the latched model stops realizing
+                        # gains, so the EMA freezes with it
+                        ema = jnp.where(active, new_ema, ema)
                 mvals = plan.eval_in_scan(scores, vscores, metric_ops)
                 es = es_update(es, mvals, it, active)
                 return (scores, vscores, es, ema), (stacked, mvals)

@@ -271,13 +271,16 @@ class TracedEvalPlan:
         """[n_slots] f32 metric vector for one iteration's updated score
         carries; runs inside the megastep scan trace."""
         vals = []
-        for (si, metrics), group_ops in zip(self._groups, metric_ops):
-            sc = scores if si < 0 else vscores[si]
-            for tm, ops in zip(metrics, group_ops):
-                vals.extend(tm.fn(sc, ops))
-        if not vals:
-            return jnp.zeros((0,), jnp.float32)
-        return jnp.stack([jnp.asarray(v, jnp.float32) for v in vals])
+        with jax.named_scope("lgbm.eval"):
+            for (si, metrics), group_ops in zip(self._groups, metric_ops):
+                sc = scores if si < 0 else vscores[si]
+                for tm, ops in zip(metrics, group_ops):
+                    # the metric's own name as the child scope: lgbm.eval/auc
+                    with jax.named_scope(tm.names[0]):
+                        vals.extend(tm.fn(sc, ops))
+            if not vals:
+                return jnp.zeros((0,), jnp.float32)
+            return jnp.stack([jnp.asarray(v, jnp.float32) for v in vals])
 
 
 def build_plan(gbdt, include_training: bool):

@@ -108,6 +108,9 @@ def _merge_best_many(best: BestSplit, idx: jax.Array, vals: BestSplit,
                        for a, v in zip(best, vals)])
 
 
+# the scope wraps the jit so that the call itself (and what the compiler
+# derives from it) is named too, not only the operations inside
+@jax.named_scope("lgbm.grow")
 @functools.partial(
     jax.jit,
     static_argnames=("params", "num_leaves", "max_bins", "f_oh", "num_rows",
@@ -241,90 +244,93 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     else:
         fmask2d = None
 
-    R = num_rows or Rp
-    # padding rows sit at leaf -1; inactive slots use leaf_of_slot = -2 so
-    # a -1 pad row never matches a slot
-    leaf_T = jnp.where(jnp.arange(Rp)[None, :] < R, 0, -1).astype(jnp.int32)
+    with jax.named_scope("root"):
+        R = num_rows or Rp
+        # padding rows sit at leaf -1; inactive slots use leaf_of_slot = -2 so
+        # a -1 pad row never matches a slot
+        leaf_T = jnp.where(jnp.arange(Rp)[None, :] < R, 0, -1) \
+            .astype(jnp.int32)
 
-    tree = empty_tree(L, B)
-    pool_g = jnp.zeros((L, f_oh, B), jnp.float32)
-    pool_h = jnp.zeros((L, f_oh, B), jnp.float32)
-    pool_c = jnp.zeros((L, f_oh, B), jnp.float32)
+        tree = empty_tree(L, B)
+        pool_g = jnp.zeros((L, f_oh, B), jnp.float32)
+        pool_h = jnp.zeros((L, f_oh, B), jnp.float32)
+        pool_c = jnp.zeros((L, f_oh, B), jnp.float32)
 
-    # ---------------- root pass: slot 0 collects the full-data histogram
-    # (W0[0, bins of column 0] = 1 sends every row "left" on slot 0 —
-    # each row's one-hot holds exactly one bin of column 0); skipped
-    # entirely when the previous iteration's epilogue already built it
-    Sp0 = 8
-    if root_hist is not None:
-        hist0 = root_hist
-    else:
-        # the root trick sends every row "left" over the FIRST kernel
-        # column's one-hot — that column's width is the first packed
-        # feature's slab under the adaptive layout
-        w0_span = packed.widths[0] if packed is not None else k_B
-        W0 = jnp.zeros((Sp0, kern_fb), jnp.bfloat16).at[0, :w0_span].set(1)
-        tbl0 = jnp.zeros((Sp0, 128), jnp.int32)
-        tbl0 = tbl0.at[:, 0].set(jnp.where(jnp.arange(Sp0) == 0, 0, -2))
-        tbl0 = tbl0.at[0, 2].set(1)
-        hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
-                              num_slots=Sp0,
-                              num_bins=k_B, f_oh=k_foh, nch=nch,
-                              interpret=interpret, quant_bits=quant_bits,
-                              packed=packed)
-        # feature mode: rows are replicated, the local histogram IS the
-        # global one (a psum would multiply by the shard count); voting:
-        # the root is always a full exchange like the XLA growers
-        if psum_axis is not None and parallel_mode != "feature":
-            hist0 = record_psum(hist0, psum_axis)
-    g0, h0, c0 = _decode(hist0, Sp0)
-    if use_bundles:
-        v = bundle_plane_views(jnp.stack([g0, h0, c0], axis=-1),
-                               bundle_cfg.flat_idx, bundle_cfg.valid,
-                               bundle_cfg.default_bin)
-        g0, h0, c0 = v[..., 0], v[..., 1], v[..., 2]
-    pool_g = pool_g.at[0].set(g0[0])
-    pool_h = pool_h.at[0].set(h0[0])
-    pool_c = pool_c.at[0].set(c0[0])
-    root_g = jnp.sum(g0[0, 0, :])
-    root_h = jnp.sum(h0[0, 0, :])
-    root_c = jnp.sum(c0[0, 0, :])
-    root_out = calculate_leaf_output(root_g, root_h, params, root_c, 0.0)
-    tree = tree._replace(
-        leaf_value=tree.leaf_value.at[0].set(root_out),
-        leaf_count=tree.leaf_count.at[0].set(root_c),
-        leaf_weight=tree.leaf_weight.at[0].set(root_h))
+        # ---------------- root pass: slot 0 collects the full-data histogram
+        # (W0[0, bins of column 0] = 1 sends every row "left" on slot 0 —
+        # each row's one-hot holds exactly one bin of column 0); skipped
+        # entirely when the previous iteration's epilogue already built it
+        Sp0 = 8
+        if root_hist is not None:
+            hist0 = root_hist
+        else:
+            # the root trick sends every row "left" over the FIRST kernel
+            # column's one-hot — that column's width is the first packed
+            # feature's slab under the adaptive layout
+            w0_span = packed.widths[0] if packed is not None else k_B
+            W0 = jnp.zeros((Sp0, kern_fb), jnp.bfloat16).at[0, :w0_span].set(1)
+            tbl0 = jnp.zeros((Sp0, 128), jnp.int32)
+            tbl0 = tbl0.at[:, 0].set(jnp.where(jnp.arange(Sp0) == 0, 0, -2))
+            tbl0 = tbl0.at[0, 2].set(1)
+            hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
+                                  num_slots=Sp0,
+                                  num_bins=k_B, f_oh=k_foh, nch=nch,
+                                  interpret=interpret, quant_bits=quant_bits,
+                                  packed=packed)
+            # feature mode: rows are replicated, the local histogram IS the
+            # global one (a psum would multiply by the shard count); voting:
+            # the root is always a full exchange like the XLA growers
+            if psum_axis is not None and parallel_mode != "feature":
+                hist0 = record_psum(hist0, psum_axis)
+        g0, h0, c0 = _decode(hist0, Sp0)
+        if use_bundles:
+            v = bundle_plane_views(jnp.stack([g0, h0, c0], axis=-1),
+                                   bundle_cfg.flat_idx, bundle_cfg.valid,
+                                   bundle_cfg.default_bin)
+            g0, h0, c0 = v[..., 0], v[..., 1], v[..., 2]
+        pool_g = pool_g.at[0].set(g0[0])
+        pool_h = pool_h.at[0].set(h0[0])
+        pool_c = pool_c.at[0].set(c0[0])
+        root_g = jnp.sum(g0[0, 0, :])
+        root_h = jnp.sum(h0[0, 0, :])
+        root_c = jnp.sum(c0[0, 0, :])
+        root_out = calculate_leaf_output(root_g, root_h, params, root_c, 0.0)
+        tree = tree._replace(
+            leaf_value=tree.leaf_value.at[0].set(root_out),
+            leaf_count=tree.leaf_count.at[0].set(root_c),
+            leaf_weight=tree.leaf_weight.at[0].set(root_h))
 
-    leaf_lo = jnp.full((L,), -jnp.inf, jnp.float32)
-    leaf_hi = jnp.full((L,), jnp.inf, jnp.float32)
-    leaf_groups = jnp.full((L,), -1, jnp.int32)
-    # intermediate monotone mode: per-leaf bin-space regions over the
-    # LOGICAL features. Padded features (num_bin=0) get a fake [0, 1)
-    # region so they always overlap — splits never touch them, and the
-    # adjacency test needs overlap on every feature but one.
-    reg_lo = jnp.zeros((L, f_oh), jnp.int32)
-    reg_hi = jnp.broadcast_to(jnp.maximum(meta.num_bin, 1)[None, :],
-                              (L, f_oh)).astype(jnp.int32)
-    feat_par = psum_axis is not None and parallel_mode == "feature"
-    root_mask = feature_mask[None, :]
-    if feat_par:
-        root_mask = root_mask & feature_shard_mask[None, :]
-    if use_node_masks:
-        root_mask = root_mask & node_feature_mask(
-            node_masks, leaf_groups[:1], jnp.zeros((1,), jnp.int32))
-    root_best = best_split_cm(
-        g0[:1], h0[:1], c0[:1], meta.num_bin, meta.missing_type,
-        meta.default_bin, root_mask, meta_is_cat(meta), meta.monotone,
-        params, tree.leaf_value[:1], has_cat=has_cat,
-        use_bounds=use_mono_bounds, bound_lo=leaf_lo[:1],
-        bound_hi=leaf_hi[:1], leaf_depth=tree.leaf_depth[:1])
-    if feat_par:
-        # global winner over the column shards (the fused layout is
-        # replicated, so local indices ARE global — offset 0)
-        root_best = merge_best_over_shards(root_best, psum_axis, 0)
-    best = BestSplit(*[jnp.zeros((L,) + a.shape[1:], a.dtype).at[0].set(a[0])
-                       for a in root_best])
-    best = best._replace(gain=best.gain.at[1:].set(NEG_INF))
+        leaf_lo = jnp.full((L,), -jnp.inf, jnp.float32)
+        leaf_hi = jnp.full((L,), jnp.inf, jnp.float32)
+        leaf_groups = jnp.full((L,), -1, jnp.int32)
+        # intermediate monotone mode: per-leaf bin-space regions over the
+        # LOGICAL features. Padded features (num_bin=0) get a fake [0, 1)
+        # region so they always overlap — splits never touch them, and the
+        # adjacency test needs overlap on every feature but one.
+        reg_lo = jnp.zeros((L, f_oh), jnp.int32)
+        reg_hi = jnp.broadcast_to(jnp.maximum(meta.num_bin, 1)[None, :],
+                                  (L, f_oh)).astype(jnp.int32)
+        feat_par = psum_axis is not None and parallel_mode == "feature"
+        root_mask = feature_mask[None, :]
+        if feat_par:
+            root_mask = root_mask & feature_shard_mask[None, :]
+        if use_node_masks:
+            root_mask = root_mask & node_feature_mask(
+                node_masks, leaf_groups[:1], jnp.zeros((1,), jnp.int32))
+        root_best = best_split_cm(
+            g0[:1], h0[:1], c0[:1], meta.num_bin, meta.missing_type,
+            meta.default_bin, root_mask, meta_is_cat(meta), meta.monotone,
+            params, tree.leaf_value[:1], has_cat=has_cat,
+            use_bounds=use_mono_bounds, bound_lo=leaf_lo[:1],
+            bound_hi=leaf_hi[:1], leaf_depth=tree.leaf_depth[:1])
+        if feat_par:
+            # global winner over the column shards (the fused layout is
+            # replicated, so local indices ARE global — offset 0)
+            root_best = merge_best_over_shards(root_best, psum_axis, 0)
+        best = BestSplit(*[
+            jnp.zeros((L,) + a.shape[1:], a.dtype).at[0].set(a[0])
+            for a in root_best])
+        best = best._replace(gain=best.gain.at[1:].set(NEG_INF))
 
     lpn = jnp.full((L,), -1, jnp.int32)   # leaf -> parent node
     lil = jnp.zeros((L,), bool)           # leaf is left child of its parent
@@ -364,6 +370,7 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     return tree, leaf_T[0]
 
 
+@jax.named_scope("level")
 def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                S_d, nch, max_depth, has_cat, use_mono_bounds,
                use_node_masks, node_masks, fold, is_last,
@@ -409,360 +416,365 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
         (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
          leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
          reg_lo, reg_hi, pool_valid) = op
-        sel_i32 = selected.astype(jnp.int32)
-        k_of_leaf = jnp.cumsum(sel_i32) - sel_i32
-        new_of_leaf = jnp.where(selected, tree.num_leaves + k_of_leaf, -1)
-        # node index base: a tree with N leaves has N-1 internal nodes
-        node_of_leaf = jnp.where(selected,
-                                 tree.num_leaves - 1 + k_of_leaf, -1)
+        with jax.named_scope("route"):
+            sel_i32 = selected.astype(jnp.int32)
+            k_of_leaf = jnp.cumsum(sel_i32) - sel_i32
+            new_of_leaf = jnp.where(selected, tree.num_leaves + k_of_leaf, -1)
+            # node index base: a tree with N leaves has N-1 internal nodes
+            node_of_leaf = jnp.where(selected,
+                                     tree.num_leaves - 1 + k_of_leaf, -1)
 
-        # ---- slot tables (leaf_of_slot = -2 marks inactive slots so they
-        # can never match the -1 of padding rows)
-        lof = _masked_scatter(
-            jnp.full((Sp,), -2, jnp.int32),
-            jnp.minimum(k_of_leaf, Sp - 1), slots,
-            selected & (k_of_leaf < Sp))
-        lof_on = lof >= 0
-        lof_safe = jnp.maximum(lof, 0)
-        feat_s = jnp.where(lof_on, best.feature[lof_safe], -1)
-        thr_s = best.threshold[lof_safe]
-        dl_s = best.default_left[lof_safe]
-        cf_s = best.cat_flag[lof_safe] & lof_on
-        cm_s = best.cat_mask[lof_safe]
-        small_left_s = (best.left_count[lof_safe]
-                        <= best.right_count[lof_safe])
-        new_s = jnp.where(lof_on, tree.num_leaves + jnp.arange(Sp), 0)
-        delta_s = jnp.where(lof_on, new_s - lof_safe, 0)
+            # ---- slot tables (leaf_of_slot = -2 marks inactive slots so they
+            # can never match the -1 of padding rows)
+            lof = _masked_scatter(
+                jnp.full((Sp,), -2, jnp.int32),
+                jnp.minimum(k_of_leaf, Sp - 1), slots,
+                selected & (k_of_leaf < Sp))
+            lof_on = lof >= 0
+            lof_safe = jnp.maximum(lof, 0)
+            feat_s = jnp.where(lof_on, best.feature[lof_safe], -1)
+            thr_s = best.threshold[lof_safe]
+            dl_s = best.default_left[lof_safe]
+            cf_s = best.cat_flag[lof_safe] & lof_on
+            cm_s = best.cat_mask[lof_safe]
+            small_left_s = (best.left_count[lof_safe]
+                            <= best.right_count[lof_safe])
+            new_s = jnp.where(lof_on, tree.num_leaves + jnp.arange(Sp), 0)
+            delta_s = jnp.where(lof_on, new_s - lof_safe, 0)
 
-        if use_bundles:
-            W = build_route_table_bundled(
-                feat_s, thr_s, dl_s, meta.num_bin, meta.missing_type,
-                meta.default_bin, bundle_cfg.default_bin,
-                bundle_cfg.col_of_feat, bundle_cfg.offset_of_feat,
-                bundle_cols, bundle_col_bins,
-                cat_flag=cf_s if has_cat else None,
-                cat_mask=cm_s if has_cat else None)
-        else:
-            W = build_route_table(feat_s, thr_s, dl_s, meta.num_bin,
-                                  meta.missing_type, meta.default_bin,
-                                  Sp, f_oh, B,
-                                  cat_flag=cf_s if has_cat else None,
-                                  cat_mask=cm_s if has_cat else None)
-            if packed is not None:
-                # route tables are built on the logical padded layout and
-                # re-indexed onto the packed flat axis (exact 0/1 gather)
-                W = pack_route_table(W, packed)
-        tbl = jnp.zeros((Sp, 128), jnp.int32)
-        tbl = tbl.at[:, 0].set(lof)
-        tbl = tbl.at[:, 1].set(delta_s)
-        tbl = tbl.at[:, 2].set(small_left_s.astype(jnp.int32))
+            if use_bundles:
+                W = build_route_table_bundled(
+                    feat_s, thr_s, dl_s, meta.num_bin, meta.missing_type,
+                    meta.default_bin, bundle_cfg.default_bin,
+                    bundle_cfg.col_of_feat, bundle_cfg.offset_of_feat,
+                    bundle_cols, bundle_col_bins,
+                    cat_flag=cf_s if has_cat else None,
+                    cat_mask=cm_s if has_cat else None)
+            else:
+                W = build_route_table(feat_s, thr_s, dl_s, meta.num_bin,
+                                      meta.missing_type, meta.default_bin,
+                                      Sp, f_oh, B,
+                                      cat_flag=cf_s if has_cat else None,
+                                      cat_mask=cm_s if has_cat else None)
+                if packed is not None:
+                    # route tables are built on the logical padded layout and
+                    # re-indexed onto the packed flat axis (exact 0/1 gather)
+                    W = pack_route_table(W, packed)
+            tbl = jnp.zeros((Sp, 128), jnp.int32)
+            tbl = tbl.at[:, 0].set(lof)
+            tbl = tbl.at[:, 1].set(delta_s)
+            tbl = tbl.at[:, 2].set(small_left_s.astype(jnp.int32))
 
         k_foh = bundle_cols if use_bundles else f_oh
         k_B = bundle_col_bins if use_bundles else B
-        # ---- THE level pass: route (+ smaller-child histograms)
-        def_W2, def_tbl2 = def_W, def_tbl
-        if route_only and defer_final_route:
-            # the epilogue kernel applies this pass's routing; hand it the
-            # (width-padded) tables and keep leaf_T at the pre-terminal
-            # assignment. Only one route-only pass can ever fire, so the
-            # single write is never clobbered.
-            leaf_T2 = leaf_T
-            def_W2 = jnp.zeros_like(def_W).at[:Sp].set(W)
-            def_tbl2 = jnp.zeros_like(def_tbl).at[:, 0].set(-2) \
-                .at[:Sp].set(tbl)
-            pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
-            pool_valid2 = pool_valid
-        elif route_only:
-            leaf_T2 = route_pass(bins_T, leaf_T, W, tbl, num_slots=Sp,
-                                 num_bins=k_B, f_oh=k_foh,
-                                 interpret=interpret, packed=packed)
-            pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
-            pool_valid2 = pool_valid
-        else:
-            hist, leaf_T2 = level_pass(
-                bins_T, leaf_T, gh_T, W, tbl, fmask2d, num_slots=Sp,
-                num_bins=k_B, f_oh=k_foh, nch=nch, interpret=interpret,
-                quant_bits=quant_bits, packed=packed)
-            if psum_axis is not None and not vote_live and not feat_par:
-                hist = record_psum(hist, psum_axis)
-
-            # ---- voting exchange: rank local per-feature gains on the
-            # smaller-child planes, psum the votes, and sum only the
-            # top-W winners' columns over the mesh; everything else is
-            # zeroed and marked invalid for later scans
-            # (ref: voting_parallel_tree_learner.cpp:151-184; same vote
-            # rule as the XLA growers' _exchange)
-            if vote_live:
-                # local decode just for the vote ranking
-                lg, lh, lc = decode(hist, Sp)
-                if use_bundles:
-                    v = bundle_plane_views(
-                        jnp.stack([lg, lh, lc], axis=-1),
-                        bundle_cfg.flat_idx, bundle_cfg.valid,
-                        bundle_cfg.default_bin)
-                    lg, lh, lc = v[..., 0], v[..., 1], v[..., 2]
-                # the smaller child's own post-split output is its
-                # path-smoothing parent (matches the child-scan call)
-                sm_out = jnp.where(
-                    small_left_s,
-                    jnp.where(lof_on, best.left_output[lof_safe], 0.0),
-                    jnp.where(lof_on, best.right_output[lof_safe], 0.0))
-                vote_mask = jnp.broadcast_to(feature_mask[None, :],
-                                             (Sp, f_oh)) & lof_on[:, None]
-                gains_loc = per_feature_gains_cm(
-                    lg, lh, lc, meta.num_bin, meta.missing_type,
-                    meta.default_bin, vote_mask, meta_is_cat(meta),
-                    meta.monotone, params, sm_out, has_cat=has_cat)
-                k_v = min(top_k, f_oh)
-                W_vote = min(f_oh, 2 * top_k)
-                kth = jnp.sort(gains_loc, axis=1)[:, f_oh - k_v][:, None]
-                votes = (gains_loc >= kth) & jnp.isfinite(gains_loc)
-                votes = record_psum(votes.astype(jnp.int32), psum_axis)
-                score_f = jnp.sum(votes, axis=0)
-                _, w_idx = jax.lax.top_k(score_f, W_vote)
-                lvl_valid = jnp.zeros((f_oh,), bool).at[w_idx].set(True)
-                if use_bundles:
-                    # logical features interleave inside bundle columns;
-                    # exchange the DECODED logical planes (divergence vs
-                    # the unbundled path: decode-then-psum rounds
-                    # differently than psum-then-decode — documented,
-                    # bundles+voting only)
-                    stack = jnp.stack([lg, lh, lc], axis=-1)
-                    sub = record_psum(jnp.take(stack, w_idx, axis=1),
-                                       psum_axis)
-                    stack = jnp.zeros_like(stack).at[:, w_idx].set(sub)
-                    sm_g, sm_h, sm_c = (stack[..., 0], stack[..., 1],
-                                        stack[..., 2])
-                else:
-                    # exchange the PACKED hi/lo channels of the winning
-                    # columns so the decode happens AFTER the global sum
-                    # — bit-identical to the data-parallel path when
-                    # every column wins (top_k >= F)
-                    hr = hist.reshape(k_foh, k_B, -1)
-                    sub = record_psum(jnp.take(hr, w_idx, axis=0),
-                                       psum_axis)
-                    hr = jnp.zeros_like(hr).at[w_idx].set(sub)
-                    hist = hr.reshape(k_foh * k_B, -1)
-                    sm_g, sm_h, sm_c = decode(hist, Sp)
-            else:
-                lvl_valid = jnp.ones((f_oh,), bool)
-                sm_g, sm_h, sm_c = decode(hist, Sp)
-                if use_bundles:
-                    v = bundle_plane_views(
-                        jnp.stack([sm_g, sm_h, sm_c], axis=-1),
-                        bundle_cfg.flat_idx, bundle_cfg.valid,
-                        bundle_cfg.default_bin)
-                    sm_g, sm_h, sm_c = v[..., 0], v[..., 1], v[..., 2]
-
-            # ---- sibling by subtraction from the parent pool
-            par_g = _pool_read(pool_g, lof_safe, Sp)
-            par_h = _pool_read(pool_h, lof_safe, Sp)
-            par_c = _pool_read(pool_c, lof_safe, Sp)
-            sb_g, sb_h, sb_c = par_g - sm_g, par_h - sm_h, par_c - sm_c
-            sl = small_left_s[:, None, None]
-            left_g = jnp.where(sl, sm_g, sb_g)
-            left_h = jnp.where(sl, sm_h, sb_h)
-            left_c = jnp.where(sl, sm_c, sb_c)
-            right_g = jnp.where(sl, sb_g, sm_g)
-            right_h = jnp.where(sl, sb_h, sm_h)
-            right_c = jnp.where(sl, sb_c, sm_c)
-
-            pool_g2 = _pool_write(pool_g, lof_safe, left_g, lof_on)
-            pool_g2 = _pool_write(pool_g2, new_s, right_g, lof_on)
-            pool_h2 = _pool_write(pool_h, lof_safe, left_h, lof_on)
-            pool_h2 = _pool_write(pool_h2, new_s, right_h, lof_on)
-            pool_c2 = _pool_write(pool_c, lof_safe, left_c, lof_on)
-            pool_c2 = _pool_write(pool_c2, new_s, right_c, lof_on)
-            # validity: the exchanged (smaller) side is valid where the
-            # vote summed it; the subtracted side additionally needs a
-            # globally-valid parent (root is fully valid, so data/
-            # feature modes stay all-true)
-            if vote_live:
-                par_v = pool_valid[lof_safe]          # [Sp, f_oh]
-                sm_v = jnp.broadcast_to(lvl_valid[None, :], (Sp, f_oh))
-                sb_v = par_v & sm_v
-                sl2 = small_left_s[:, None]
-                left_v = jnp.where(sl2, sm_v, sb_v)
-                right_v = jnp.where(sl2, sb_v, sm_v)
-                pool_valid2 = _masked_scatter(pool_valid, lof_safe,
-                                              left_v, lof_on)
-                pool_valid2 = _masked_scatter(pool_valid2, new_s,
-                                              right_v, lof_on)
-            else:
+        with jax.named_scope("route" if route_only else "hist"):
+            # ---- THE level pass: route (+ smaller-child histograms)
+            def_W2, def_tbl2 = def_W, def_tbl
+            if route_only and defer_final_route:
+                # the epilogue kernel applies this pass's routing; hand it the
+                # (width-padded) tables and keep leaf_T at the pre-terminal
+                # assignment. Only one route-only pass can ever fire, so the
+                # single write is never clobbered.
+                leaf_T2 = leaf_T
+                def_W2 = jnp.zeros_like(def_W).at[:Sp].set(W)
+                def_tbl2 = jnp.zeros_like(def_tbl).at[:, 0].set(-2) \
+                    .at[:Sp].set(tbl)
+                pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
                 pool_valid2 = pool_valid
+            elif route_only:
+                leaf_T2 = route_pass(bins_T, leaf_T, W, tbl, num_slots=Sp,
+                                     num_bins=k_B, f_oh=k_foh,
+                                     interpret=interpret, packed=packed)
+                pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
+                pool_valid2 = pool_valid
+            else:
+                hist, leaf_T2 = level_pass(
+                    bins_T, leaf_T, gh_T, W, tbl, fmask2d, num_slots=Sp,
+                    num_bins=k_B, f_oh=k_foh, nch=nch, interpret=interpret,
+                    quant_bits=quant_bits, packed=packed)
+                if psum_axis is not None and not vote_live and not feat_par:
+                    hist = record_psum(hist, psum_axis)
 
-        # ---- tree bookkeeping (ref: tree.h:62 Tree::Split; same node
-        # array conventions as models/frontier.py round 1)
-        f_l = best.feature
-        new_depth = tree.leaf_depth + 1
+                # ---- voting exchange: rank local per-feature gains on the
+                # smaller-child planes, psum the votes, and sum only the
+                # top-W winners' columns over the mesh; everything else is
+                # zeroed and marked invalid for later scans
+                # (ref: voting_parallel_tree_learner.cpp:151-184; same vote
+                # rule as the XLA growers' _exchange)
+                if vote_live:
+                    # local decode just for the vote ranking
+                    lg, lh, lc = decode(hist, Sp)
+                    if use_bundles:
+                        v = bundle_plane_views(
+                            jnp.stack([lg, lh, lc], axis=-1),
+                            bundle_cfg.flat_idx, bundle_cfg.valid,
+                            bundle_cfg.default_bin)
+                        lg, lh, lc = v[..., 0], v[..., 1], v[..., 2]
+                    # the smaller child's own post-split output is its
+                    # path-smoothing parent (matches the child-scan call)
+                    sm_out = jnp.where(
+                        small_left_s,
+                        jnp.where(lof_on, best.left_output[lof_safe], 0.0),
+                        jnp.where(lof_on, best.right_output[lof_safe], 0.0))
+                    vote_mask = jnp.broadcast_to(feature_mask[None, :],
+                                                 (Sp, f_oh)) & lof_on[:, None]
+                    gains_loc = per_feature_gains_cm(
+                        lg, lh, lc, meta.num_bin, meta.missing_type,
+                        meta.default_bin, vote_mask, meta_is_cat(meta),
+                        meta.monotone, params, sm_out, has_cat=has_cat)
+                    k_v = min(top_k, f_oh)
+                    W_vote = min(f_oh, 2 * top_k)
+                    kth = jnp.sort(gains_loc, axis=1)[:, f_oh - k_v][:, None]
+                    votes = (gains_loc >= kth) & jnp.isfinite(gains_loc)
+                    votes = record_psum(votes.astype(jnp.int32), psum_axis)
+                    score_f = jnp.sum(votes, axis=0)
+                    _, w_idx = jax.lax.top_k(score_f, W_vote)
+                    lvl_valid = jnp.zeros((f_oh,), bool).at[w_idx].set(True)
+                    if use_bundles:
+                        # logical features interleave inside bundle columns;
+                        # exchange the DECODED logical planes (divergence vs
+                        # the unbundled path: decode-then-psum rounds
+                        # differently than psum-then-decode — documented,
+                        # bundles+voting only)
+                        stack = jnp.stack([lg, lh, lc], axis=-1)
+                        sub = record_psum(jnp.take(stack, w_idx, axis=1),
+                                           psum_axis)
+                        stack = jnp.zeros_like(stack).at[:, w_idx].set(sub)
+                        sm_g, sm_h, sm_c = (stack[..., 0], stack[..., 1],
+                                            stack[..., 2])
+                    else:
+                        # exchange the PACKED hi/lo channels of the winning
+                        # columns so the decode happens AFTER the global sum
+                        # — bit-identical to the data-parallel path when
+                        # every column wins (top_k >= F)
+                        hr = hist.reshape(k_foh, k_B, -1)
+                        sub = record_psum(jnp.take(hr, w_idx, axis=0),
+                                           psum_axis)
+                        hr = jnp.zeros_like(hr).at[w_idx].set(sub)
+                        hist = hr.reshape(k_foh * k_B, -1)
+                        sm_g, sm_h, sm_c = decode(hist, Sp)
+                else:
+                    lvl_valid = jnp.ones((f_oh,), bool)
+                    sm_g, sm_h, sm_c = decode(hist, Sp)
+                    if use_bundles:
+                        v = bundle_plane_views(
+                            jnp.stack([sm_g, sm_h, sm_c], axis=-1),
+                            bundle_cfg.flat_idx, bundle_cfg.valid,
+                            bundle_cfg.default_bin)
+                        sm_g, sm_h, sm_c = v[..., 0], v[..., 1], v[..., 2]
 
-        def w(arr, vals):
-            return _masked_scatter(arr, node_of_leaf, vals, selected)
-        sf = w(tree.split_feature, f_l)
-        tb = w(tree.threshold_bin, best.threshold)
-        dfl = w(tree.default_left, best.default_left)
-        cfw = w(tree.cat_flag, best.cat_flag)
-        cmw = w(tree.cat_mask, best.cat_mask)
-        sg = w(tree.split_gain, best.gain)
-        iv = w(tree.internal_value, tree.leaf_value)
-        ic = w(tree.internal_count, tree.leaf_count)
-        iw = w(tree.internal_weight, tree.leaf_weight)
-        lc = w(tree.left_child, -slots - 1)
-        rc = w(tree.right_child, -new_of_leaf - 1)
-        wl = selected & (lpn >= 0) & lil
-        wr = selected & (lpn >= 0) & ~lil
-        lc = _masked_scatter(lc, lpn, node_of_leaf, wl)
-        rc = _masked_scatter(rc, lpn, node_of_leaf, wr)
-        lpn2 = jnp.where(selected, node_of_leaf, lpn)
-        lil2 = jnp.where(selected, True, lil)
-        lpn2 = _masked_scatter(lpn2, new_of_leaf, node_of_leaf, selected)
-        lil2 = _masked_scatter(lil2, new_of_leaf, jnp.zeros((L,), bool),
-                               selected)
+                # ---- sibling by subtraction from the parent pool
+                par_g = _pool_read(pool_g, lof_safe, Sp)
+                par_h = _pool_read(pool_h, lof_safe, Sp)
+                par_c = _pool_read(pool_c, lof_safe, Sp)
+                sb_g, sb_h, sb_c = par_g - sm_g, par_h - sm_h, par_c - sm_c
+                sl = small_left_s[:, None, None]
+                left_g = jnp.where(sl, sm_g, sb_g)
+                left_h = jnp.where(sl, sm_h, sb_h)
+                left_c = jnp.where(sl, sm_c, sb_c)
+                right_g = jnp.where(sl, sb_g, sm_g)
+                right_h = jnp.where(sl, sb_h, sm_h)
+                right_c = jnp.where(sl, sb_c, sm_c)
 
-        def upd2(arr, lv, rv):
-            arr = _masked_scatter(arr, slots, lv, selected)
-            return _masked_scatter(arr, new_of_leaf, rv, selected)
-        if inter:
-            # intermediate monotone: sequential per-split clipping/fences
-            # over [L]-state (models/learner.mono_inter_level_update);
-            # clipped child outputs replace the raw scan outputs
-            (lv_inter, leaf_lo2, leaf_hi2, reg_lo2, reg_hi2,
-             mono_changed) = mono_inter_level_update(
-                tree.leaf_value, leaf_lo, leaf_hi, reg_lo, reg_hi,
-                selected, k_of_leaf, best.feature, best.threshold,
-                best.cat_flag, best.left_output, best.right_output,
-                meta.monotone, tree.num_leaves, Sp)
-            new_leaf_value = lv_inter
-        else:
-            new_leaf_value = upd2(tree.leaf_value, best.left_output,
-                                  best.right_output)
-            reg_lo2, reg_hi2 = reg_lo, reg_hi
-            mono_changed = None
-        tree2 = tree._replace(
-            num_leaves=tree.num_leaves + n_sel,
-            split_feature=sf, threshold_bin=tb, default_left=dfl,
-            cat_flag=cfw, cat_mask=cmw,
-            split_gain=sg, internal_value=iv, internal_count=ic,
-            internal_weight=iw, left_child=lc, right_child=rc,
-            leaf_value=new_leaf_value,
-            leaf_count=upd2(tree.leaf_count, best.left_count,
-                            best.right_count),
-            leaf_weight=upd2(tree.leaf_weight, best.left_sum_hess,
-                             best.right_sum_hess),
-            leaf_depth=upd2(tree.leaf_depth, new_depth, new_depth),
-        )
+                pool_g2 = _pool_write(pool_g, lof_safe, left_g, lof_on)
+                pool_g2 = _pool_write(pool_g2, new_s, right_g, lof_on)
+                pool_h2 = _pool_write(pool_h, lof_safe, left_h, lof_on)
+                pool_h2 = _pool_write(pool_h2, new_s, right_h, lof_on)
+                pool_c2 = _pool_write(pool_c, lof_safe, left_c, lof_on)
+                pool_c2 = _pool_write(pool_c2, new_s, right_c, lof_on)
+                # validity: the exchanged (smaller) side is valid where the
+                # vote summed it; the subtracted side additionally needs a
+                # globally-valid parent (root is fully valid, so data/
+                # feature modes stay all-true)
+                if vote_live:
+                    par_v = pool_valid[lof_safe]          # [Sp, f_oh]
+                    sm_v = jnp.broadcast_to(lvl_valid[None, :], (Sp, f_oh))
+                    sb_v = par_v & sm_v
+                    sl2 = small_left_s[:, None]
+                    left_v = jnp.where(sl2, sm_v, sb_v)
+                    right_v = jnp.where(sl2, sb_v, sm_v)
+                    pool_valid2 = _masked_scatter(pool_valid, lof_safe,
+                                                  left_v, lof_on)
+                    pool_valid2 = _masked_scatter(pool_valid2, new_s,
+                                                  right_v, lof_on)
+                else:
+                    pool_valid2 = pool_valid
 
-        # ---- bound/group propagation (cheap [L]-sized state upkeep,
-        # shared by both variants)
-        if use_mono_bounds and not inter:
-            mono_dir = jnp.where(best.feature >= 0,
-                                 meta.monotone[jnp.maximum(best.feature, 0)],
-                                 0)
-            # reference gates constraint updates on is_numerical_split
-            mono_dir = jnp.where(best.cat_flag, 0, mono_dir)
-            leaf_lo2, leaf_hi2 = mono_child_bounds(
-                leaf_lo, leaf_hi, leaf_lo, leaf_hi, selected, mono_dir,
-                best.left_output, best.right_output,
-                jnp.arange(L, dtype=jnp.int32), new_of_leaf)
-        elif not use_mono_bounds:
-            leaf_lo2, leaf_hi2 = leaf_lo, leaf_hi
-        if use_node_masks:
-            leaf_groups2 = update_leaf_groups(
-                node_masks, leaf_groups, best.feature, selected,
-                jnp.arange(L, dtype=jnp.int32), new_of_leaf)
-        else:
-            leaf_groups2 = leaf_groups
+        with jax.named_scope("book"):
+            # ---- tree bookkeeping (ref: tree.h:62 Tree::Split; same node
+            # array conventions as models/frontier.py round 1)
+            f_l = best.feature
+            new_depth = tree.leaf_depth + 1
 
-        if route_only:
-            # no split search will ever run again; just bar the fresh
-            # leaves (and the reused parent slots) from re-selection
-            neg = jnp.full((L,), NEG_INF, jnp.float32)
-            g2 = _masked_scatter(best.gain, slots, neg, selected)
-            g2 = _masked_scatter(g2, new_of_leaf, neg, selected)
-            best2 = best._replace(gain=g2)
-            return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2,
-                    lpn2, lil2, leaf_lo2, leaf_hi2, leaf_groups2,
-                    def_W2, def_tbl2, reg_lo2, reg_hi2, pool_valid2)
+            def w(arr, vals):
+                return _masked_scatter(arr, node_of_leaf, vals, selected)
+            sf = w(tree.split_feature, f_l)
+            tb = w(tree.threshold_bin, best.threshold)
+            dfl = w(tree.default_left, best.default_left)
+            cfw = w(tree.cat_flag, best.cat_flag)
+            cmw = w(tree.cat_mask, best.cat_mask)
+            sg = w(tree.split_gain, best.gain)
+            iv = w(tree.internal_value, tree.leaf_value)
+            ic = w(tree.internal_count, tree.leaf_count)
+            iw = w(tree.internal_weight, tree.leaf_weight)
+            lc = w(tree.left_child, -slots - 1)
+            rc = w(tree.right_child, -new_of_leaf - 1)
+            wl = selected & (lpn >= 0) & lil
+            wr = selected & (lpn >= 0) & ~lil
+            lc = _masked_scatter(lc, lpn, node_of_leaf, wl)
+            rc = _masked_scatter(rc, lpn, node_of_leaf, wr)
+            lpn2 = jnp.where(selected, node_of_leaf, lpn)
+            lil2 = jnp.where(selected, True, lil)
+            lpn2 = _masked_scatter(lpn2, new_of_leaf, node_of_leaf, selected)
+            lil2 = _masked_scatter(lil2, new_of_leaf, jnp.zeros((L,), bool),
+                                   selected)
 
-        # ---- best splits for the 2*Sp fresh children only; each child's
-        # own post-split output is the parent_output for path smoothing of
-        # its prospective grandchildren (matches learner.py:208 and ref
-        # feature_histogram.hpp FindBestThreshold parent_output usage).
-        # Intermediate mode reads the CLIPPED outputs from the tree.
-        if inter:
-            left_out = jnp.where(lof_on, tree2.leaf_value[lof_safe], 0.0)
-            right_out = jnp.where(lof_on, tree2.leaf_value[new_s], 0.0)
-        else:
-            left_out = jnp.where(lof_on, best.left_output[lof_safe], 0.0)
-            right_out = jnp.where(lof_on, best.right_output[lof_safe], 0.0)
-        ch_g = jnp.concatenate([left_g, right_g], axis=0)
-        ch_h = jnp.concatenate([left_h, right_h], axis=0)
-        ch_c = jnp.concatenate([left_c, right_c], axis=0)
-        if use_mono_bounds:
-            ch_lo = jnp.concatenate([leaf_lo2[lof_safe], leaf_lo2[new_s]])
-            ch_hi = jnp.concatenate([leaf_hi2[lof_safe], leaf_hi2[new_s]])
-        else:
-            ch_lo = ch_hi = None
-        ch_mask = feature_mask[None, :]
-        if vote_live:
-            # scans must not read local-only (unexchanged) columns
-            ch_mask = ch_mask & jnp.concatenate([left_v, right_v], axis=0)
-        if feat_par:
-            ch_mask = ch_mask & feature_shard_mask[None, :]
-        if use_node_masks:
-            ch_groups = jnp.concatenate([leaf_groups2[lof_safe],
-                                         leaf_groups2[new_s]])
-            # per-node sampling identity: creating node id + side bit
-            ch_ids = jnp.concatenate([2 * (node_of_leaf[lof_safe] + 1) + 1,
-                                      2 * (node_of_leaf[lof_safe] + 1)])
-            ch_mask = ch_mask & node_feature_mask(node_masks, ch_groups,
-                                                  ch_ids)
-        ch_depth = jnp.concatenate([tree2.leaf_depth[lof_safe],
-                                    tree2.leaf_depth[new_s]])
-        bs = best_split_cm(
-            ch_g, ch_h, ch_c, meta.num_bin, meta.missing_type,
-            meta.default_bin, ch_mask, meta_is_cat(meta), meta.monotone,
-            params, jnp.concatenate([left_out, right_out]),
-            has_cat=has_cat, use_bounds=use_mono_bounds, bound_lo=ch_lo,
-            bound_hi=ch_hi, leaf_depth=ch_depth)
-        if feat_par:
-            # per-level SyncUpGlobalBestSplit over the column shards
-            # (ref: parallel_tree_learner.h:191); offset 0 — the fused
-            # layout is replicated, local indices are global
-            bs = merge_best_over_shards(bs, psum_axis, 0)
-        left_bs = BestSplit(*[a[:Sp] for a in bs])
-        right_bs = BestSplit(*[a[Sp:] for a in bs])
-        best2 = _merge_best_many(best, lof_safe, left_bs, lof_on)
-        best2 = _merge_best_many(best2, new_s, right_bs, lof_on)
+            def upd2(arr, lv, rv):
+                arr = _masked_scatter(arr, slots, lv, selected)
+                return _masked_scatter(arr, new_of_leaf, rv, selected)
+            if inter:
+                # intermediate monotone: sequential per-split clipping/fences
+                # over [L]-state (models/learner.mono_inter_level_update);
+                # clipped child outputs replace the raw scan outputs
+                (lv_inter, leaf_lo2, leaf_hi2, reg_lo2, reg_hi2,
+                 mono_changed) = mono_inter_level_update(
+                    tree.leaf_value, leaf_lo, leaf_hi, reg_lo, reg_hi,
+                    selected, k_of_leaf, best.feature, best.threshold,
+                    best.cat_flag, best.left_output, best.right_output,
+                    meta.monotone, tree.num_leaves, Sp)
+                new_leaf_value = lv_inter
+            else:
+                new_leaf_value = upd2(tree.leaf_value, best.left_output,
+                                      best.right_output)
+                reg_lo2, reg_hi2 = reg_lo, reg_hi
+                mono_changed = None
+            tree2 = tree._replace(
+                num_leaves=tree.num_leaves + n_sel,
+                split_feature=sf, threshold_bin=tb, default_left=dfl,
+                cat_flag=cfw, cat_mask=cmw,
+                split_gain=sg, internal_value=iv, internal_count=ic,
+                internal_weight=iw, left_child=lc, right_child=rc,
+                leaf_value=new_leaf_value,
+                leaf_count=upd2(tree.leaf_count, best.left_count,
+                                best.right_count),
+                leaf_weight=upd2(tree.leaf_weight, best.left_sum_hess,
+                                 best.right_sum_hess),
+                leaf_depth=upd2(tree.leaf_depth, new_depth, new_depth),
+            )
 
-        if inter:
-            # stale-leaf recompute: pre-existing leaves whose bounds the
-            # cross-tightening touched re-derive their cached best split
-            # from the pool with the new bounds (ref:
-            # serial_tree_learner.cpp:706-714 recompute of leaves_to_update)
-            def _rescan(b):
-                node_ids = 2 * (lpn2 + 1) + lil2.astype(jnp.int32)
-                m = feature_mask[None, :]
-                if use_node_masks:
-                    m = m & node_feature_mask(node_masks, leaf_groups2,
-                                              node_ids)
-                bs_all = best_split_cm(
-                    pool_g2, pool_h2, pool_c2, meta.num_bin,
-                    meta.missing_type, meta.default_bin,
-                    jnp.broadcast_to(m, (L, f_oh)) & pool_valid2,
-                    meta_is_cat(meta),
-                    meta.monotone, params, tree2.leaf_value,
-                    has_cat=has_cat, use_bounds=True, bound_lo=leaf_lo2,
-                    bound_hi=leaf_hi2, leaf_depth=tree2.leaf_depth)
+            # ---- bound/group propagation (cheap [L]-sized state upkeep,
+            # shared by both variants)
+            if use_mono_bounds and not inter:
+                mono_dir = jnp.where(
+                    best.feature >= 0,
+                    meta.monotone[jnp.maximum(best.feature, 0)], 0)
+                # reference gates constraint updates on is_numerical_split
+                mono_dir = jnp.where(best.cat_flag, 0, mono_dir)
+                leaf_lo2, leaf_hi2 = mono_child_bounds(
+                    leaf_lo, leaf_hi, leaf_lo, leaf_hi, selected, mono_dir,
+                    best.left_output, best.right_output,
+                    jnp.arange(L, dtype=jnp.int32), new_of_leaf)
+            elif not use_mono_bounds:
+                leaf_lo2, leaf_hi2 = leaf_lo, leaf_hi
+            if use_node_masks:
+                leaf_groups2 = update_leaf_groups(
+                    node_masks, leaf_groups, best.feature, selected,
+                    jnp.arange(L, dtype=jnp.int32), new_of_leaf)
+            else:
+                leaf_groups2 = leaf_groups
 
-                def merge(old, newv):
-                    mm = (mono_changed if old.ndim == 1
-                          else mono_changed[:, None])
-                    return jnp.where(mm, newv, old)
-                return BestSplit(*[merge(o, n) for o, n in zip(b, bs_all)])
+            if route_only:
+                # no split search will ever run again; just bar the fresh
+                # leaves (and the reused parent slots) from re-selection
+                neg = jnp.full((L,), NEG_INF, jnp.float32)
+                g2 = _masked_scatter(best.gain, slots, neg, selected)
+                g2 = _masked_scatter(g2, new_of_leaf, neg, selected)
+                best2 = best._replace(gain=g2)
+                return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2,
+                        lpn2, lil2, leaf_lo2, leaf_hi2, leaf_groups2,
+                        def_W2, def_tbl2, reg_lo2, reg_hi2, pool_valid2)
 
-            best2 = jax.lax.cond(jnp.any(mono_changed), _rescan,
-                                 lambda b: b, best2)
+        with jax.named_scope("split"):
+            # ---- best splits for the 2*Sp fresh children only; each child's
+            # own post-split output is the parent_output for path smoothing of
+            # its prospective grandchildren (matches learner.py:208 and ref
+            # feature_histogram.hpp FindBestThreshold parent_output usage).
+            # Intermediate mode reads the CLIPPED outputs from the tree.
+            if inter:
+                left_out = jnp.where(lof_on, tree2.leaf_value[lof_safe], 0.0)
+                right_out = jnp.where(lof_on, tree2.leaf_value[new_s], 0.0)
+            else:
+                left_out = jnp.where(lof_on, best.left_output[lof_safe], 0.0)
+                right_out = jnp.where(lof_on, best.right_output[lof_safe], 0.0)
+            ch_g = jnp.concatenate([left_g, right_g], axis=0)
+            ch_h = jnp.concatenate([left_h, right_h], axis=0)
+            ch_c = jnp.concatenate([left_c, right_c], axis=0)
+            if use_mono_bounds:
+                ch_lo = jnp.concatenate([leaf_lo2[lof_safe], leaf_lo2[new_s]])
+                ch_hi = jnp.concatenate([leaf_hi2[lof_safe], leaf_hi2[new_s]])
+            else:
+                ch_lo = ch_hi = None
+            ch_mask = feature_mask[None, :]
+            if vote_live:
+                # scans must not read local-only (unexchanged) columns
+                ch_mask = ch_mask & jnp.concatenate([left_v, right_v], axis=0)
+            if feat_par:
+                ch_mask = ch_mask & feature_shard_mask[None, :]
+            if use_node_masks:
+                ch_groups = jnp.concatenate([leaf_groups2[lof_safe],
+                                             leaf_groups2[new_s]])
+                # per-node sampling identity: creating node id + side bit
+                ch_ids = jnp.concatenate([2 * (node_of_leaf[lof_safe] + 1) + 1,
+                                          2 * (node_of_leaf[lof_safe] + 1)])
+                ch_mask = ch_mask & node_feature_mask(node_masks, ch_groups,
+                                                      ch_ids)
+            ch_depth = jnp.concatenate([tree2.leaf_depth[lof_safe],
+                                        tree2.leaf_depth[new_s]])
+            bs = best_split_cm(
+                ch_g, ch_h, ch_c, meta.num_bin, meta.missing_type,
+                meta.default_bin, ch_mask, meta_is_cat(meta), meta.monotone,
+                params, jnp.concatenate([left_out, right_out]),
+                has_cat=has_cat, use_bounds=use_mono_bounds, bound_lo=ch_lo,
+                bound_hi=ch_hi, leaf_depth=ch_depth)
+            if feat_par:
+                # per-level SyncUpGlobalBestSplit over the column shards
+                # (ref: parallel_tree_learner.h:191); offset 0 — the fused
+                # layout is replicated, local indices are global
+                bs = merge_best_over_shards(bs, psum_axis, 0)
+            left_bs = BestSplit(*[a[:Sp] for a in bs])
+            right_bs = BestSplit(*[a[Sp:] for a in bs])
+            best2 = _merge_best_many(best, lof_safe, left_bs, lof_on)
+            best2 = _merge_best_many(best2, new_s, right_bs, lof_on)
+
+            if inter:
+                # stale-leaf recompute: pre-existing leaves whose bounds the
+                # cross-tightening touched re-derive their cached best split
+                # from the pool with the new bounds (ref:
+                # serial_tree_learner.cpp:706-714 recompute of
+                # leaves_to_update)
+                def _rescan(b):
+                    node_ids = 2 * (lpn2 + 1) + lil2.astype(jnp.int32)
+                    m = feature_mask[None, :]
+                    if use_node_masks:
+                        m = m & node_feature_mask(node_masks, leaf_groups2,
+                                                  node_ids)
+                    bs_all = best_split_cm(
+                        pool_g2, pool_h2, pool_c2, meta.num_bin,
+                        meta.missing_type, meta.default_bin,
+                        jnp.broadcast_to(m, (L, f_oh)) & pool_valid2,
+                        meta_is_cat(meta),
+                        meta.monotone, params, tree2.leaf_value,
+                        has_cat=has_cat, use_bounds=True, bound_lo=leaf_lo2,
+                        bound_hi=leaf_hi2, leaf_depth=tree2.leaf_depth)
+
+                    def merge(old, newv):
+                        mm = (mono_changed if old.ndim == 1
+                              else mono_changed[:, None])
+                        return jnp.where(mm, newv, old)
+                    return BestSplit(*[merge(o, n) for o, n in zip(b, bs_all)])
+
+                best2 = jax.lax.cond(jnp.any(mono_changed), _rescan,
+                                     lambda b: b, best2)
 
         return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2, lpn2,
                 lil2, leaf_lo2, leaf_hi2, leaf_groups2, def_W2, def_tbl2,

@@ -57,15 +57,23 @@ def compilation_cache_dir(requested: str = "") -> str:
     else ``<checkout>/.jax_cache`` — a fixed path derived from the
     package's own location, because a cache directory that moves between
     runs never hits. Must run before the process's first compile (JAX
-    decides once whether the cache is in use)."""
+    decides once whether the cache is in use).
+
+    Either way the cache key covers the instructions' metadata: JAX
+    strips it by default, and a cached executable then keeps the
+    ``op_name``s of whichever program was compiled first — a profiler
+    trace would attribute device time by ``lgbm.*`` phase scopes
+    (docs/Observability.md section 3) that the running code no longer
+    has, or lack the ones it has."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(_CACHE_ENV)
     if env:
         if requested and requested != env:
             log.info("compilation_cache_dir=%s yields to %s=%s",
                      requested, _CACHE_ENV, env)
         return env
-    import jax
-
     path = requested or _DEFAULT_CACHE_DIR
     jax.config.update("jax_compilation_cache_dir", path)
     return path

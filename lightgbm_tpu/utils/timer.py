@@ -12,10 +12,11 @@ asynchronous under JAX, so sections measure DISPATCH time unless
 ``sync=True`` is passed, which blocks on the given arrays first — the
 honest way to attribute device time to a section.
 
-``profiler_trace`` wraps ``jax.profiler.trace`` for XLA-level traces
-viewable in TensorBoard/Perfetto — the deep-dive path the reference
-lacks (SURVEY §5: profiling gap). The training loop exposes the same
-trace via the ``profile_dir`` config key (docs/Observability.md).
+Every section is also a ``jax.profiler.TraceAnnotation``, whether the
+timer is enabled or not: in a profiler trace (``profile_dir``,
+docs/Observability.md) the sections lie on the host plane, on the same
+clock as the device's operations. Outside a profiler session an
+annotation costs one atomic load.
 """
 from __future__ import annotations
 
@@ -25,6 +26,8 @@ import os
 import threading
 import time
 from typing import Dict, NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 from . import log
 
@@ -103,14 +106,15 @@ class Timer:
     def section(self, name: str, sync=None):
         """Time a block. ``sync`` = array/pytree to block on before
         closing the section (attributes asynchronous device work here)."""
-        self.start(name)
-        try:
-            yield
-        finally:
-            if self._enabled and sync is not None:
-                import jax
-                jax.block_until_ready(sync)
-            self.stop(name)
+        with TraceAnnotation(name):
+            self.start(name)
+            try:
+                yield
+            finally:
+                if self._enabled and sync is not None:
+                    import jax
+                    jax.block_until_ready(sync)
+                self.stop(name)
 
     # ------------------------------------------------------------------
     def stats(self) -> Dict[str, SectionStat]:
@@ -138,11 +142,3 @@ global_timer = Timer(enabled=bool(int(
 def _print_at_exit() -> None:  # ref: common.h:988 ~Timer() { Print(); }
     if global_timer.enabled:
         global_timer.print()
-
-
-@contextlib.contextmanager
-def profiler_trace(log_dir: str):
-    """XLA-level trace via jax.profiler (TensorBoard/Perfetto viewable)."""
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield

@@ -1,0 +1,146 @@
+"""The `lgbm.*` phase scopes of the traced training step
+(docs/Observability.md section 3a): every operation of the megastep that
+`lgb.train` builds which touches a row-length array carries one, so a
+device trace can say whose each fusion is.
+
+The scopes are read from the lowered module's debug locations, i.e. from
+the program as JAX wrote it, not from what one backend's compiler made of
+it. A location names an operation relative to the function it is in
+(``root/broadcast_in_dim`` inside ``@grow_tree_fused``); the call sites
+give the rest (``lgbm.grow/jit(grow_tree_fused)``).
+"""
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.boosting.gbdt import GBDT
+
+# the benchmark's own parser of the scopes (``phase_of``): what the program
+# names has to be what the readers of a device trace understand
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark"))
+from harness.trace_phases import UNSCOPED, phase_of  # noqa: E402
+
+ROWS, VALID_ROWS = 2000, 500
+TOP_LEVEL = {"gradients", "gh_pack", "grow", "score_update", "valid_apply"}
+EVAL_ONLY = {"freeze", "eval", "early_stop"}
+GROW_CHILDREN = {"root", "level", "level/route", "level/hist",
+                 "level/split", "level/book"}
+# carriers of whole carries, not operations on them
+STRUCTURAL = {"while", "cond", "closed_call", "shard_map", "body"}
+
+
+def _lowered_step(monkeypatch, with_eval: bool, learner: str) -> str:
+    """Debug text of the megastep `lgb.train` built for a small binary
+    job, lowered again from the shapes it was called with."""
+    seen = {}
+    make = GBDT._make_megastep
+
+    def recording(self, chunk):
+        fn = make(self, chunk)
+
+        def call(*args):
+            seen["fn"] = fn
+            seen["avals"] = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype,
+                    sharding=x.sharding if getattr(x, "committed", False)
+                    else None), args)
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(GBDT, "_make_megastep", recording)
+    rng = np.random.RandomState(0)
+    X = rng.rand(ROWS, 8).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] > 1).astype(np.float32)
+    Xv = rng.rand(VALID_ROWS, 8).astype(np.float32)
+    yv = (Xv[:, 0] + Xv[:, 1] > 1).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "max_bin": 63,
+              "verbose": -1, "min_data_in_leaf": 5, "tpu_engine": "fused",
+              "tpu_megastep": True, "tpu_megastep_iters": 2,
+              "metric": "auc", "tree_learner": learner}
+    ds = lgb.Dataset(X, label=y)
+    # with callbacks the scan evaluates the metric itself and carries the
+    # early-stop latch; without them it only keeps the validation scores
+    lgb.train(params, ds, num_boost_round=2,
+              valid_sets=[lgb.Dataset(Xv, label=yv, reference=ds)],
+              callbacks=[lgb.record_evaluation({})] if with_eval else None)
+    return seen["fn"].lower(*seen["avals"]).as_text(debug_info=True), \
+        seen["avals"]
+
+
+_LOC_DEF = re.compile(r"^(#loc\d+) = loc\((.*)\)$", re.M)
+_FUNC = re.compile(r"^\s*func\.func \w+ @(\w+)\(")
+_CALL = re.compile(r"\bcall @(\w+)\(")
+_LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+_DIMS = re.compile(r"tensor<((?:\d+x)+)")
+
+
+def _scoped_ops(text: str):
+    """[(function, op name relative to it, dims it touches)] for every
+    one-line operation, and {function: [names of its call sites]}."""
+    named = {}
+    for key, body in _LOC_DEF.findall(text):
+        m = re.match(r'"([^"]*)"', body)
+        named[key] = m.group(1) if m else ""
+    ops, calls, func = [], {}, None
+    for line in text.splitlines():
+        m = _FUNC.match(line)
+        if m:
+            func = m.group(1)
+            continue
+        use = _LOC_USE.search(line)
+        if not use or func is None or "return" in line.split("loc(")[0]:
+            continue
+        name = named.get(use.group(1), "")
+        callee = _CALL.search(line)
+        if callee:
+            calls.setdefault(callee.group(1), []).append((func, name))
+        dims = {int(d) for run in _DIMS.findall(line)
+                for d in run.split("x") if d}
+        ops.append((func, name, dims))
+    return ops, calls
+
+
+def _full_names(func: str, name: str, calls: dict, depth: int = 0):
+    """Every name the operation can have, one per chain of call sites."""
+    if func == "main" or depth > 8:
+        return [name]
+    return [full for caller, site in calls.get(func, [])
+            for full in _full_names(caller, f"{site}/{name}", calls,
+                                    depth + 1)]
+
+
+@pytest.mark.parametrize("learner", ["serial", "data"])
+@pytest.mark.parametrize("with_eval", [True, False], ids=["eval", "noeval"])
+def test_every_row_length_operation_is_scoped(monkeypatch, with_eval,
+                                              learner):
+    text, avals = _lowered_step(monkeypatch, with_eval, learner)
+    bins_T = avals[0]
+    shards = len(bins_T.sharding.device_set) if bins_T.sharding else 1
+    row_lengths = {ROWS, bins_T.shape[1], bins_T.shape[1] // shards,
+                   ROWS // shards, VALID_ROWS}
+    ops, calls = _scoped_ops(text)
+    phases, unscoped = set(), []
+    for func, name, dims in ops:
+        for full in _full_names(func, name, calls):
+            phase = phase_of(full)
+            if phase != UNSCOPED:
+                phases.add(phase)
+            elif dims & row_lengths \
+                    and full.split("/")[-1] not in STRUCTURAL:
+                unscoped.append(full)
+    assert not unscoped, f"row-length operations outside any lgbm. " \
+        f"scope: {sorted(set(unscoped))[:10]}"
+    top = {p.split("/")[0] for p in phases}
+    assert top == TOP_LEVEL | (EVAL_ONLY if with_eval else set())
+    assert GROW_CHILDREN <= {p[len("grow/"):] for p in phases
+                             if p.startswith("grow/")}
+    if with_eval:
+        assert any("lgbm.eval/auc/" in full for func, name, _ in ops
+                   for full in _full_names(func, name, calls))
