@@ -145,6 +145,21 @@ def _round_up_pow2(n: int) -> int:
     return 1 << max(1, (n - 1).bit_length())
 
 
+def _fused_layout_T(rows_dev: jax.Array, Fp: int, Rp: int, dtype,
+                    feat_order=None) -> jax.Array:
+    """[R, n_cols] device bin matrix -> the level kernels' [Fp, Rp]
+    layout: transposed, cast, the feature rows permuted into width-class
+    order under the adaptive layout (the logical order is recovered at
+    plane decode), zero-padded. The ONE builder of the training matrix
+    and of every validation set's passenger matrix (GBDT._valid_route),
+    so the route tables written over the one read the other."""
+    src = rows_dev.T.astype(dtype)
+    if feat_order is not None:
+        src = jnp.take(src, jnp.asarray(feat_order, jnp.int32), axis=0)
+    n_cols, R = src.shape
+    return jnp.zeros((Fp, Rp), dtype).at[:n_cols, :R].set(src)
+
+
 def _screening_mask_fn(ema: jax.Array, explore, F: int,
                        keep_k: int) -> jax.Array:
     """EMA-FS screening mask [F_oh]: keep the top ``keep_k`` REAL
@@ -373,6 +388,8 @@ class GBDT:
 
         self.valid_data: List[TpuDataset] = []
         self.valid_bins: List = []
+        self._valid_routes: Dict[int, Tuple] = {}   # see _valid_route
+        self._valid_route_said: set = set()
         self.valid_scores: List = []
         self.valid_metrics: List[List] = []
         self.valid_names: List[str] = []
@@ -1960,6 +1977,7 @@ class GBDT:
         self._epi_fm_pad = None
         self._epi_bag_ones = None
         self._valid_upd_fns = None    # close over shrinkage/depth bound
+        self._valid_routes = {}       # path and layout are the engine's
         self._coll_per_iter = None    # re-measured on the fresh traces
         self._coll_per_grow = None
         engine = config.tpu_engine
@@ -2227,10 +2245,8 @@ class GBDT:
                 self.fused_bins_T = self._mp_fused_bins_T(
                     np.asarray(self.bundle_bins_host), Fp, Rp, Bc_p)
             else:
-                self.fused_bins_T = (
-                    jnp.zeros((Fp, Rp), dtype)
-                    .at[:n_cols, :R].set(
-                        self.bundle_bins_dev.T.astype(dtype)))
+                self.fused_bins_T = _fused_layout_T(self.bundle_bins_dev,
+                                                    Fp, Rp, dtype)
             self.fused_bundle_cols = C_oh
             self.fused_bundle_col_bins = Bc_p
             # decode tables padded to the logical f_oh (padding features:
@@ -2279,15 +2295,8 @@ class GBDT:
             # Three full copies are live on device 0 while this runs
             # (the [R, F] source, the zeros target, the .at[].set
             # result); data-parallel runs reshard only afterwards.
-            src = self.bins_dev.T.astype(dtype)
-            if feat_order is not None:
-                # width-class permutation of the feature rows (adaptive
-                # layout; the logical order is recovered at plane decode)
-                src = jnp.take(src, jnp.asarray(feat_order, jnp.int32),
-                               axis=0)
-            self.fused_bins_T = (
-                jnp.zeros((Fp, Rp), dtype)
-                .at[:F, :R].set(src))
+            self.fused_bins_T = _fused_layout_T(self.bins_dev, Fp, Rp,
+                                                dtype, feat_order)
             self.fused_bundle_cols = 0
             self.fused_bundle_col_bins = 0
             self.fused_bundle_cfg = None
@@ -2379,6 +2388,8 @@ class GBDT:
         self._megastep_fns = {}       # valid-set count is baked into the
         self._epi_ok_cache = None     # megastep signature
         self._epi_carry = None
+        self._fast_step_fn = None     # the per-iteration steps keep the
+        self._epi_fns = None          # route log a new set may ask for
         if self._eval_consumer is not None:
             # the traced eval plan enumerated the old valid-set list; a
             # new set mid-run invalidates it (cannot happen through
@@ -2403,6 +2414,9 @@ class GBDT:
             self.valid_scores.append(jnp.zeros((k, n), jnp.float32))
         self.valid_metrics.append(list(metrics))
         self.valid_names.append(name)
+        # the passenger matrix, where the training kernels can route this
+        # set, is part of the set's upload
+        self._valid_route(len(self.valid_data) - 1)
         # replay existing model onto the new valid set (continued training)
         for i, dt in enumerate(self.device_trees):
             tree_id = i % self.num_tree_per_iteration
@@ -3258,30 +3272,166 @@ class GBDT:
                           slot_cap=max_slot_cap(fb, self.fused_nch))
         return len(caps) + 1
 
-    def _make_valid_apply(self, bundle):
-        """Traced valid-score update for one iteration's stacked [k, ...]
-        TreeArrays: the ONE body both the per-iteration fast path
-        (_update_valid_from_trees jits it per valid set) and the megastep
-        scan inline — shared so the two paths cannot drift apart."""
+    def _valid_route_reason(self, vi: int) -> Optional[str]:
+        """Why validation set ``vi`` keeps the gather walk, or None when
+        the training kernels can route it: the fast paths replay a route
+        log only from the fused grower, and the log's tables are written
+        over the TRAINING matrix's columns, so the set must be stored in
+        the same ones — logical bins beside an unbundled training
+        matrix, or the training set's own sparse-built bundle columns.
+        Dense EFB (bundles made from a logical-bin training set) leaves
+        the validation set in logical bins: the walk stays for it."""
+        reason = self._fast_path_reason()
+        if reason is not None:
+            return "no_route_log:" + reason
+        valid_pre = self.valid_data[vi].prebundled is not None
+        if self.fused_bundle_cols:
+            train = ("prebundled" if self.train_data.prebundled is not None
+                     else "efb")
+            same = train == "prebundled" and valid_pre
+        else:
+            train, same = "logical", not valid_pre
+        if same:
+            return None
+        return "layout:train=%s,valid=%s" % (
+            train, "prebundled" if valid_pre else "logical")
+
+    def _valid_route(self, vi: int) -> Tuple[Optional[jax.Array],
+                                             Optional[str]]:
+        """(passenger matrix, None) when validation set ``vi`` is routed
+        by the training kernels, (None, reason) when it keeps the gather
+        walk. The passenger is the set's second device layout, [Fp, Rvp]
+        in the training matrix's dtype, feature order and 2048-row
+        padding, built once per fused layout by the training matrix's own
+        builder; the row-major ``valid_bins`` stay for what replays host
+        trees (rollback, a continued model, recovery). Says which path
+        the set took once per run: the ``valid.route_*_sets`` counters
+        and a ``valid_route`` event (not a ``degrade``: nothing that was
+        asked for is lost, both paths give the same bits)."""
+        route = self._valid_routes.get(vi)
+        if route is None:
+            reason = self._valid_route_reason(vi)
+            mat = None
+            if reason is None:
+                Rv = int(self.valid_bins[vi].shape[0])
+                mat = _fused_layout_T(
+                    self.valid_bins[vi], self.fused_bins_T.shape[0],
+                    -(-Rv // 2048) * 2048, self.fused_bins_T.dtype,
+                    self.fused_packed.feat_order
+                    if self.fused_packed is not None else None)
+            route = self._valid_routes[vi] = (mat, reason)
+        tel = self.telemetry
+        if tel.enabled and vi not in self._valid_route_said:
+            self._valid_route_said.add(vi)
+            path = "gather" if route[1] else "kernel"
+            tel.inc("valid.route_%s_sets" % path)
+            tel.event("valid_route", iteration=self.iter,
+                      valid_set=self.valid_names[vi], path=path,
+                      **({"reason": route[1]} if route[1] else {}))
+        return route
+
+    def _wants_route_log(self) -> bool:
+        """Some validation set is routed by the kernels: the steps that
+        grow trees keep their route logs."""
+        return any(self._valid_route(vi)[1] is None
+                   for vi in range(len(self.valid_bins)))
+
+    def _valid_operands(self) -> Tuple:
+        """Per validation set, the matrix its traced score update reads:
+        the passenger on the kernel path, the row-major bins on the
+        gather path."""
+        mats = [self._valid_route(vi)[0]
+                for vi in range(len(self.valid_bins))]
+        return tuple(vb if m is None else m
+                     for vb, m in zip(self.valid_bins, mats))
+
+    def _make_valid_apply(self, vi: int):
+        """Traced valid-score update of validation set ``vi`` for one
+        iteration's stacked [k, ...] TreeArrays: the ONE body both the
+        per-iteration fast path (_update_valid_from_trees jits it per
+        valid set) and the megastep scan inline — shared so the two
+        paths cannot drift apart.
+
+        ``apply_trees(vscore, vmat, trees, logs)`` finds each row's leaf
+        one of two ways and ends both the same way, so leaves and scores
+        are bit-identical between them:
+
+        - kernel path (``logs``: the k route logs of
+          ``grow_tree_fused(route_log=True)``; ``vmat``: the set's
+          passenger matrix): the tables that routed the training rows
+          route the validation rows, one ``route_pass`` per level the
+          tree actually grew (models/frontier2.replay_route_log);
+        - gather path (``logs`` unused; ``vmat``: the row-major bins):
+          ops/predict.route_rows_to_leaves walks the tree node by node,
+          a static ``_fast_tree_depth_bound()`` levels of row-length
+          gathers. Serves the sets whose storage the tables do not
+          describe (``_valid_route_reason``);
+        - then one ``table_lookup`` of the shrunk leaf values and the
+          add, the training scores' own formula (tree_score_delta). The
+          product leaf_value * shrink is rounded before the lookup on
+          both paths: with the add next to it a backend that contracts
+          the two into one fused multiply-add rounds differently (XLA's
+          CPU backend did, in ops/predict.add_tree_score's fusion)."""
+        from ..models.frontier2 import replay_route_log
+        from ..ops.fused_level import table_lookup
+        from ..ops.predict import route_rows_to_leaves
         k = self.num_tree_per_iteration
         shrink = jnp.float32(self.shrinkage_rate)
         steps = self._fast_tree_depth_bound()
         meta = self.meta
         has_cat = self.has_cat
+        kernel = self._valid_route(vi)[1] is None
+        bundle = self._valid_bundle(vi)
+        n_valid = int(self.valid_bins[vi].shape[0])
+        interp = self.fused_interpret
 
-        @jax.named_scope("lgbm.valid_apply")
-        def apply_trees(vscore, vbins, trees):
+        # (scoped themselves: a shard_map region does not inherit the
+        # scope it is called under)
+        scope = jax.named_scope("lgbm.valid_apply")
+
+        @scope
+        def replay(vmat, log):
+            return replay_route_log(
+                vmat, log, n_valid,
+                num_bins=(self.fused_bundle_col_bins
+                          if self.fused_bundle_cols else self.fused_Bp),
+                f_oh=self.fused_bundle_cols or self.fused_f_oh,
+                interpret=interp, packed=self.fused_packed)
+
+        @scope
+        def lookup(leaf_T, leaf_value):
+            return table_lookup(leaf_T, leaf_value,
+                                interpret=interp)[0, :n_valid]
+        if self.parallel_mode in ("data", "voting"):
+            # the set, the log and the leaves are replicated over the
+            # mesh: every chip routes all of the set's rows, in regions
+            # of the kernels' own so the partitioner has nothing to
+            # decide about them
+            from jax.sharding import PartitionSpec as P
+            replay = _shard_map(replay, mesh=self.mesh,
+                                in_specs=(P(), P()), out_specs=P(),
+                                check_vma=False)
+            lookup = _shard_map(lookup, mesh=self.mesh,
+                                in_specs=(P(), P()), out_specs=P(),
+                                check_vma=False)
+
+        @scope
+        def apply_trees(vscore, vmat, trees, logs=None):
             for tid in range(k):
-                new_row = add_tree_score(
-                    vscore[tid], vbins, trees.leaf_value[tid] * shrink,
-                    trees.split_feature[tid], trees.threshold_bin[tid],
-                    trees.default_left[tid], trees.left_child[tid],
-                    trees.right_child[tid], meta.num_bin,
-                    meta.missing_type, meta.default_bin,
-                    max_steps=steps,
-                    cat_flag=trees.cat_flag[tid] if has_cat else None,
-                    cat_mask=trees.cat_mask[tid] if has_cat else None,
-                    bundle=bundle)
+                if kernel:
+                    leaf_T = replay(vmat, logs[tid])
+                else:
+                    leaf_T = route_rows_to_leaves(
+                        vmat, trees.split_feature[tid],
+                        trees.threshold_bin[tid], trees.default_left[tid],
+                        trees.left_child[tid], trees.right_child[tid],
+                        meta.num_bin, meta.missing_type, meta.default_bin,
+                        max_steps=steps,
+                        cat_flag=trees.cat_flag[tid] if has_cat else None,
+                        cat_mask=trees.cat_mask[tid] if has_cat else None,
+                        bundle=bundle)[None, :]
+                new_row = vscore[tid] + lookup(
+                    leaf_T, trees.leaf_value[tid] * shrink)
                 # dried class: zero contribution (matches the training
                 # score handling)
                 new_row = jnp.where(trees.num_leaves[tid] > 1, new_row,
@@ -3290,35 +3440,38 @@ class GBDT:
             return vscore
         return apply_trees
 
-    def _update_valid_from_trees(self, trees) -> None:
+    def _update_valid_from_trees(self, trees, logs=None) -> None:
         """In-jit valid-score updates straight from the stacked device
         TreeArrays — no HostTree materialisation, no per-iteration sync
-        (ref: gbdt.cpp:493 UpdateScore over valid ScoreUpdaters)."""
+        (ref: gbdt.cpp:493 UpdateScore over valid ScoreUpdaters).
+        ``logs``: the trees' route logs, which the pipelined fast step
+        and the epilogue step keep when some set is routed by the
+        kernels (_wants_route_log)."""
         if not self.valid_scores:
             return
         if not getattr(self, "_valid_upd_fns", None):
             self._valid_upd_fns = {}
+        operands = self._valid_operands()
         for vi in range(len(self.valid_scores)):
-            bundled = self.valid_data[vi].prebundled is not None
-            if bundled not in self._valid_upd_fns:
+            if vi not in self._valid_upd_fns:
                 # the old valid-score buffer is dead the moment the
                 # update returns — donate it so XLA writes in place
                 # instead of allocating a fresh [k, n_valid] f32 every
                 # iteration
-                self._valid_upd_fns[bundled] = jax.jit(
-                    self._make_valid_apply(
-                        self._valid_bundle(vi) if bundled else None),
-                    donate_argnums=_donate(0))
+                self._valid_upd_fns[vi] = jax.jit(
+                    self._make_valid_apply(vi), donate_argnums=_donate(0))
             self.telemetry.inc("train.dispatches")
-            self.valid_scores[vi] = self._valid_upd_fns[bundled](
-                self.valid_scores[vi], self.valid_bins[vi], trees)
+            self.valid_scores[vi] = self._valid_upd_fns[vi](
+                self.valid_scores[vi], operands[vi], trees, logs)
 
     def _make_fused_tree_loop(self):
         """Traced per-iteration tree-growing core: gh pack -> fused
         growth -> score delta for each of the k class trees, returning
-        the updated scores and the stacked [k, ...] TreeArrays. The ONE
-        body the per-iteration fast step and the megastep scan share, so
-        the megastep stays bit-identical to the fast path by
+        the updated scores, the stacked [k, ...] TreeArrays, the gain
+        EMA and the k trees' route logs (None unless a validation set is
+        routed by the kernels: _valid_route). The ONE body the
+        per-iteration fast step and the megastep scan share, so the
+        megastep stays bit-identical to the fast path by
         construction."""
         from ..models.frontier2 import grow_tree_fused, tree_score_delta
         from ..ops.fused_level import pack_gh, table_lookup
@@ -3347,6 +3500,7 @@ class GBDT:
         screening = self.use_screening
         mask_oh = self._mask_onehot()
         packed = self.fused_packed
+        route_log = self._wants_route_log()
         if quant:
             from ..ops.fused_level import pack_gh_quant
         if screening:
@@ -3360,7 +3514,7 @@ class GBDT:
             top_k = int(self.config.top_k) if mode == "voting" else 0
 
             def grow_one(bins_T, gh_T, fm_pad, *qrest):
-                tree, row_leaf = grow_tree_fused(
+                tree, row_leaf, *log = grow_tree_fused(
                     bins_T, gh_T, self.fused_meta, fm_pad,
                     self.params, self.max_leaves, self.fused_Bp,
                     self.fused_f_oh, num_rows=0, nch=self.fused_nch,
@@ -3375,17 +3529,21 @@ class GBDT:
                     parallel_mode=mode, top_k=top_k,
                     quant_bits=quant, packed=packed,
                     mask_onehot=mask_oh,
-                    gh_scales=qrest[0] if quant else None)
+                    gh_scales=qrest[0] if quant else None,
+                    route_log=route_log)
                 with jax.named_scope("lgbm.score_update"):
                     delta = table_lookup(row_leaf[None, :],
                                          tree.leaf_value * shrink,
                                          interpret=interp)[0]
-                return tree, delta
+                return (tree, delta, *log)
+            # (the log is made from the global splits, like the tree:
+            # it leaves the region replicated)
             grow_one_sharded = _shard_map(
                 grow_one, mesh=self.mesh,
                 in_specs=(P(None, axis), P(None, axis), P())
                 + ((P(),) if quant else ()),
-                out_specs=(P(), P(axis)), check_vma=False)
+                out_specs=(P(), P(axis)) + ((P(),) if route_log else ()),
+                check_vma=False)
 
         def grow_k_trees(bins_T, scores, grad, hess, bag_weight, fm_pads,
                          ema=None, explore=None, seed=None):
@@ -3396,7 +3554,7 @@ class GBDT:
                 # composed with the feature_fraction masks; exploration
                 # rounds keep the mask fully open
                 smask = _screening_mask_fn(ema, explore, F_real, keep_k)
-            trees = []
+            trees, logs = [], []
             for tid in range(k):
                 fm_t = fm_pads[tid] & smask if screening \
                     else fm_pads[tid]
@@ -3417,9 +3575,9 @@ class GBDT:
                     # (the shard_map boundary is the grower's; the lookup
                     # inside names itself: the innermost lgbm. scope wins)
                     with jax.named_scope("lgbm.grow"):
-                        tree, delta = grow_one_sharded(*args)
+                        tree, delta, *log = grow_one_sharded(*args)
                 else:
-                    tree, row_leaf = grow_tree_fused(
+                    tree, row_leaf, *log = grow_tree_fused(
                         bins_T, gh_T, self.fused_meta, fm_t,
                         self.params, self.max_leaves, self.fused_Bp,
                         self.fused_f_oh, num_rows=n, nch=self.fused_nch,
@@ -3432,7 +3590,8 @@ class GBDT:
                         interpret=interp,
                         mono_mode=getattr(self, "mono_mode", "basic"),
                         quant_bits=quant, packed=packed,
-                        mask_onehot=mask_oh, gh_scales=scales)
+                        mask_onehot=mask_oh, gh_scales=scales,
+                        route_log=route_log)
                 with jax.named_scope("lgbm.score_update"):
                     if par:
                         # a dried-up class (no split found) contributes
@@ -3447,6 +3606,7 @@ class GBDT:
                                                  interpret=interp)
                     scores = scores.at[tid].add(delta)
                 trees.append(tree)
+                logs.extend(log)
             stacked = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *trees)
             if screening:
@@ -3456,7 +3616,7 @@ class GBDT:
                 gvec = _tree_gain_vec(stacked.split_feature,
                                       stacked.split_gain, F_oh)
                 ema = alpha * ema + (1.0 - alpha) * gvec
-            return scores, stacked, ema
+            return scores, stacked, ema, tuple(logs) or None
         return grow_k_trees
 
     def _make_fast_step(self):
@@ -3483,9 +3643,9 @@ class GBDT:
                         grad, hess = obj.gradients_from(scores, grad_in)
                 else:
                     grad, hess = grad_in, hess_in
-                scores, stacked, _ = grow_k(bins_T, scores, grad, hess,
-                                            bag_weight, fm_pads)
-                return scores, stacked
+                scores, stacked, _, logs = grow_k(
+                    bins_T, scores, grad, hess, bag_weight, fm_pads)
+                return scores, stacked, logs
             return jax.jit(step, donate_argnums=_donate(1))
 
         def step_ext(bins_T, scores, grad_in, hess_in, bag_weight,
@@ -3495,8 +3655,10 @@ class GBDT:
                     grad, hess = obj.gradients_from(scores, grad_in)
             else:
                 grad, hess = grad_in, hess_in
-            return grow_k(bins_T, scores, grad, hess, bag_weight,
-                          fm_pads, ema, explore, seed)
+            scores, stacked, ema, logs = grow_k(
+                bins_T, scores, grad, hess, bag_weight, fm_pads, ema,
+                explore, seed)
+            return scores, stacked, logs, ema
         return jax.jit(step_ext, donate_argnums=_donate(1))
 
     # ------------------------------------------------------------------
@@ -3544,6 +3706,7 @@ class GBDT:
         # gradients vanish under both closed forms
         self._epi_ops = jnp.zeros((8, Rp), jnp.float32) \
             .at[0, :n].set(op0).at[1, :n].set(op1)
+        route_log = self._wants_route_log()
 
         def in_jit_grads(score_pad, ops_T):
             # the objective's own traced closed form; padded rows carry
@@ -3565,7 +3728,8 @@ class GBDT:
                 bundle_col_bins=self.fused_bundle_col_bins,
                 bundle_cfg=self.fused_bundle_cfg, interpret=interp,
                 root_hist=hist0, defer_final_route=True,
-                mono_mode=getattr(self, "mono_mode", "basic"))
+                mono_mode=getattr(self, "mono_mode", "basic"),
+                route_log=route_log)
 
         def epilogue(bins_T, leafT, W_l, tbl_l, tree, score_pad, ops_T,
                      bag_next):
@@ -3581,17 +3745,18 @@ class GBDT:
         def prime(bins_T, score_pad, ops_T, bag_cur, bag_next, fm_pad):
             g, h = in_jit_grads(score_pad, ops_T)
             gh_T = pack_gh(g * bag_cur, h * bag_cur, bag_cur, nch)
-            tree, leafT, W_l, tbl_l = grow(bins_T, gh_T, fm_pad, None)
+            tree, leafT, W_l, tbl_l, *log = grow(bins_T, gh_T, fm_pad, None)
             score2, hist0, ghT = epilogue(bins_T, leafT, W_l, tbl_l, tree,
                                           score_pad, ops_T, bag_next)
-            return score2, hist0, ghT, tree
+            return score2, hist0, ghT, tree, tuple(log) or None
 
         def cont(bins_T, score_pad, hist0, gh_T, ops_T, bag_next, fm_pad):
-            tree, leafT, W_l, tbl_l = grow(bins_T, gh_T, fm_pad, hist0)
+            tree, leafT, W_l, tbl_l, *log = grow(bins_T, gh_T, fm_pad,
+                                                 hist0)
             score2, hist0n, ghT_n = epilogue(bins_T, leafT, W_l, tbl_l,
                                              tree, score_pad, ops_T,
                                              bag_next)
-            return score2, hist0n, ghT_n, tree
+            return score2, hist0n, ghT_n, tree, tuple(log) or None
         # the (score, root-hist, packed-gh) carry buffers die at each
         # call — donate them so the iteration carry updates in place
         # (self.scores is a separate sliced buffer, never the donated
@@ -3635,11 +3800,11 @@ class GBDT:
             score_pad, hist0, gh_T = self._epi_carry
             out = cont(self.fused_bins_T, score_pad, hist0, gh_T,
                        self._epi_ops, bag_next, fm_pad)
-        score2, hist0n, ghT_n, tree = out
+        score2, hist0n, ghT_n, tree, logs = out
         self._epi_carry = (score2, hist0n, ghT_n)
         self.scores = score2[None, :n]
         trees = jax.tree_util.tree_map(lambda x: jnp.stack([x]), tree)
-        return self._finish_fast_iter(trees, init_scores)
+        return self._finish_fast_iter(trees, init_scores, logs)
 
     def _train_one_iter_fast(self) -> bool:
         tel = self.telemetry
@@ -3718,13 +3883,14 @@ class GBDT:
                 call_args = (self.fused_bins_T, self.scores, grad_in,
                              hess_in, self.bag_weight, fm_pads, ema,
                              explore, seed)
-                self.scores, trees, ema2 = self._fast_step_fn(*call_args)
+                self.scores, trees, logs, ema2 = \
+                    self._fast_step_fn(*call_args)
                 if self.use_screening:
                     self._gain_ema_dev = ema2
             else:
                 call_args = (self.fused_bins_T, self.scores, grad_in,
                              hess_in, self.bag_weight, fm_pads)
-                self.scores, trees = self._fast_step_fn(*call_args)
+                self.scores, trees, logs = self._fast_step_fn(*call_args)
         if rec is not None:
             self._coll_per_iter = rec.profile
         if fresh_step and self.telemetry.enabled:
@@ -3743,16 +3909,16 @@ class GBDT:
                                 kind="fast_step", scale=1,
                                 operand_bytes=op_bytes,
                                 iteration=self.iter)
-        return self._finish_fast_iter(trees, init_scores)
+        return self._finish_fast_iter(trees, init_scores, logs)
 
-    def _finish_fast_iter(self, trees, init_scores):
+    def _finish_fast_iter(self, trees, init_scores, logs=None):
         """Pipelining tail shared by the fast and epilogue iteration
         bodies: async host copies, in-jit valid updates, pending append,
         batch-drain signalling."""
         for leaf in jax.tree_util.tree_leaves(trees):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
-        self._update_valid_from_trees(trees)
+        self._update_valid_from_trees(trees, logs)
         if not self._pending:
             self._batch_w0 = self.telemetry.wall_now()
             self._batch_t0 = time.perf_counter()
@@ -4335,6 +4501,11 @@ class GBDT:
         self.scores = jax.device_put(self.scores, rows)
         self.valid_scores = [jax.device_put(v, rep)
                              for v in self.valid_scores]
+        # (the passenger matrices never come back from a step; placed
+        # here they are not broadcast from device 0 at every dispatch)
+        self._valid_routes = {
+            vi: (m if m is None else jax.device_put(m, rep), reason)
+            for vi, (m, reason) in self._valid_routes.items()}
         if self._es_carry is not None:
             self._es_carry = jax.device_put(self._es_carry, rep)
 
@@ -4404,7 +4575,7 @@ class GBDT:
                 self._maybe_record_collectives(fresh_fn) as coll_rec:
             ext = bool(self.use_screening or self.quant_bits)
             base_args = (self.fused_bins_T, self.scores,
-                         tuple(self.valid_bins),
+                         self._valid_operands(),
                          tuple(self.valid_scores),
                          operands, self.bag_weight, fm_pads)
             if plan is None:
@@ -4449,7 +4620,7 @@ class GBDT:
             op_bytes = sum(
                 int(getattr(a, "nbytes", 0)) for a in
                 [self.fused_bins_T, self.scores, self.bag_weight,
-                 fm_pads, *self.valid_bins, *self.valid_scores])
+                 fm_pads, *base_args[2], *self.valid_scores])
             sig = f"megastep[chunk={chunk},k={k},eval={plan is not None}]"
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
@@ -4494,11 +4665,8 @@ class GBDT:
     def _make_megastep(self, chunk: int):
         obj = self.objective
         grow_k = self._make_fused_tree_loop()
-        valid_appliers = [
-            self._make_valid_apply(self._valid_bundle(vi)
-                                   if self.valid_data[vi].prebundled
-                                   is not None else None)
-            for vi in range(len(self.valid_scores))]
+        valid_appliers = [self._make_valid_apply(vi)
+                          for vi in range(len(self.valid_scores))]
 
         ext = bool(self.use_screening or self.quant_bits)
 
@@ -4511,11 +4679,11 @@ class GBDT:
             is bit-identical to the pipelined path by construction."""
             with jax.named_scope("lgbm.gradients"):
                 grad, hess = obj.gradients_from(scores, grad_ops)
-            scores, stacked, ema = grow_k(bins_T, scores, grad, hess,
-                                          bag_weight, fm_pads, ema,
-                                          explore, seed)
+            scores, stacked, ema, logs = grow_k(
+                bins_T, scores, grad, hess, bag_weight, fm_pads, ema,
+                explore, seed)
             vscores = tuple(
-                apply_v(vscore, vb, stacked)
+                apply_v(vscore, vb, stacked, logs)
                 for apply_v, vscore, vb in zip(valid_appliers, vscores,
                                                vbins))
             return scores, vscores, stacked, ema

@@ -118,7 +118,8 @@ def _merge_best_many(best: BestSplit, idx: jax.Array, vals: BestSplit,
                      "use_mono_bounds", "use_node_masks", "interpret",
                      "bundle_cols", "bundle_col_bins", "psum_axis",
                      "defer_final_route", "mono_mode", "parallel_mode",
-                     "top_k", "quant_bits", "packed", "mask_onehot"))
+                     "top_k", "quant_bits", "packed", "mask_onehot",
+                     "route_log"))
 def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                     feature_mask: jax.Array, params: SplitParams,
                     num_leaves: int, max_bins: int, f_oh: int,
@@ -135,7 +136,7 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                     feature_shard_mask: jax.Array = None,
                     quant_bits: int = 0, packed=None,
                     mask_onehot: bool = False, gh_scales: jax.Array = None,
-                    ):
+                    route_log: bool = False):
     """Grow one tree with fused level passes.
 
     Args:
@@ -203,10 +204,19 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
         its splits in the tree but does NOT route rows; the pass's route
         tables are returned for the epilogue kernel to apply. The returned
         row_leaf is then the PRE-final-route assignment.
+      route_log: also return the tree's per-level route tables, the ones
+        the training rows were routed with: (log_W [n_levels, Sp_max,
+        kern_fb] bf16, log_tbl [n_levels, Sp_max, 128] int32), padded to
+        the widest level like the deferred tables; a level the runtime
+        ``cond`` skipped keeps its all-(-2) table. The deferred terminal
+        level is logged like any other, so :func:`replay_route_log` over
+        any matrix in the training layout finds each row's leaf in the
+        FINISHED tree (the validation sets' path to their leaves).
 
     Returns (TreeArrays, row_leaf [Rp] int32 — caller slices to R; padding
     rows stay at -1). With defer_final_route:
-    (tree, row_leaf, W_last, tbl_last).
+    (tree, row_leaf, W_last, tbl_last). With route_log the pair
+    (log_W, log_tbl) is appended as one last element.
     """
     Fp, Rp = bins_T.shape
     L = num_leaves
@@ -350,9 +360,15 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     # later scans must not touch local-only columns (the XLA leaf-wise
     # voting keeps the same plane)
     pool_valid = jnp.ones((L, f_oh), bool)
+    # the route log rides the state as its last element (None when the
+    # caller does not ask: the grower's trace is then what it always was)
+    log = None
+    if route_log:
+        log = (jnp.zeros((len(caps),) + def_W.shape, def_W.dtype),
+               jnp.broadcast_to(def_tbl, (len(caps),) + def_tbl.shape))
     state = (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
              leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-             reg_lo, reg_hi, pool_valid)
+             reg_lo, reg_hi, pool_valid, log)
     for li, S_d in enumerate(caps):
         state = _one_level(state, bins_T, gh_T, meta, feature_mask, params,
                            L, B, f_oh, S_d, nch, max_depth, has_cat,
@@ -365,9 +381,12 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                            quant_bits=quant_bits, packed=packed,
                            decode=_decode, fmask2d=fmask2d)
     tree, leaf_T = state[0], state[1]
+    out = (tree, leaf_T[0])
     if defer_final_route:
-        return tree, leaf_T[0], state[11], state[12]
-    return tree, leaf_T[0]
+        out += (state[11], state[12])
+    if route_log:
+        out += (state[16],)
+    return out
 
 
 @jax.named_scope("level")
@@ -381,7 +400,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                decode=None, fmask2d=None):
     (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
      leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-     reg_lo, reg_hi, pool_valid) = state
+     reg_lo, reg_hi, pool_valid, log) = state
     use_bundles = bundle_cols > 0
     inter = use_mono_bounds and mono_mode == "intermediate"
     voting = psum_axis is not None and parallel_mode == "voting"
@@ -415,7 +434,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
     def _apply_level(op, route_only):
         (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
          leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-         reg_lo, reg_hi, pool_valid) = op
+         reg_lo, reg_hi, pool_valid, log) = op
         with jax.named_scope("route"):
             sel_i32 = selected.astype(jnp.int32)
             k_of_leaf = jnp.cumsum(sel_i32) - sel_i32
@@ -464,6 +483,11 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
             tbl = tbl.at[:, 0].set(lof)
             tbl = tbl.at[:, 1].set(delta_s)
             tbl = tbl.at[:, 2].set(small_left_s.astype(jnp.int32))
+            log2 = log
+            if log is not None:
+                # ``fold`` is this level's 1-based position in the schedule
+                log2 = (log[0].at[fold - 1, :Sp].set(W),
+                        log[1].at[fold - 1, :Sp].set(tbl))
 
         k_foh = bundle_cols if use_bundles else f_oh
         k_B = bundle_col_bins if use_bundles else B
@@ -692,7 +716,8 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                 best2 = best._replace(gain=g2)
                 return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2,
                         lpn2, lil2, leaf_lo2, leaf_hi2, leaf_groups2,
-                        def_W2, def_tbl2, reg_lo2, reg_hi2, pool_valid2)
+                        def_W2, def_tbl2, reg_lo2, reg_hi2, pool_valid2,
+                        log2)
 
         with jax.named_scope("split"):
             # ---- best splits for the 2*Sp fresh children only; each child's
@@ -778,11 +803,11 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
 
         return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2, lpn2,
                 lil2, leaf_lo2, leaf_hi2, leaf_groups2, def_W2, def_tbl2,
-                reg_lo2, reg_hi2, pool_valid2)
+                reg_lo2, reg_hi2, pool_valid2, log2)
 
     op0 = (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
            leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl, reg_lo, reg_hi,
-           pool_valid)
+           pool_valid, log)
 
     def dispatch(op):
         if is_last:
@@ -794,6 +819,37 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
         return jax.lax.cond(budget_after > 0, do_level, do_level_route, op)
 
     return jax.lax.cond(n_sel > 0, dispatch, lambda op: op, op0)
+
+
+def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
+                     num_bins: int, f_oh: int, interpret: bool = False,
+                     packed=None) -> jax.Array:
+    """Leaf of every row of ``bins_T`` in the tree whose route log
+    (``grow_tree_fused(route_log=True)``) is ``log``: start the
+    ``num_rows`` real rows at leaf 0 (padding columns at -1) and run one
+    ``route_pass`` per logged level that has an active slot. ``bins_T``
+    is any [Fp, Rp] matrix in the layout the tables were written over
+    (the grower's ``num_bins`` / ``f_oh`` / ``packed`` kernel layout).
+    The decisions are the training rows' own (``W @ one_hot > 0.5``, exact
+    0/1 arithmetic), so over the training matrix this returns the
+    grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
+    log_W, log_tbl = log
+    Rp = bins_T.shape[1]
+    leaf_T = jnp.where(jnp.arange(Rp)[None, :] < num_rows, 0, -1) \
+        .astype(jnp.int32)
+
+    def level(leaf_T, tables):
+        W, tbl = tables
+        return jax.lax.cond(
+            jnp.any(tbl[:, 0] >= 0),
+            lambda lt: route_pass(bins_T, lt, W, tbl,
+                                  num_slots=tbl.shape[0], num_bins=num_bins,
+                                  f_oh=f_oh, interpret=interpret,
+                                  packed=packed),
+            lambda lt: lt, leaf_T), None
+
+    leaf_T, _ = jax.lax.scan(level, leaf_T, (log_W, log_tbl))
+    return leaf_T
 
 
 def tree_score_delta(tree: TreeArrays, row_leaf: jax.Array, shrinkage,

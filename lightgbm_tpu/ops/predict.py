@@ -1,9 +1,26 @@
-"""On-device tree routing over binned features.
+"""On-device tree routing over binned features: the gather walk.
 
-Used for validation-score updates during training (the reference walks
-pointer trees per row on the host, gbdt.cpp UpdateScore /
-score_updater.hpp:88; here the whole valid set advances one tree level per
-fused pass — no host round trips).
+The whole row set advances one tree level per step, each step a handful
+of row-length gathers (the reference walks pointer trees per row on the
+host, gbdt.cpp UpdateScore / score_updater.hpp:88). On a TPU a
+row-length gather costs about 8 ns per gathered element, so this is the
+path for what has only a finished tree to go by:
+
+- ``add_tree_score`` / ``route_rows_to_leaves`` over binned rows: the
+  synchronous driver's validation and training score updates, rollback,
+  DART / RF replays, the replay of a continued model onto a new
+  validation set, recovery (``GBDT._add_tree_to_score``); and, on the
+  fast paths, the validation sets whose storage the training kernels'
+  route tables do not describe (``GBDT._valid_route``:
+  ``route_rows_to_leaves``, then the kernels' ``table_lookup``);
+- ``route_raw_rows_to_leaves`` over raw values: ``Booster.predict`` and
+  ``serve/``.
+
+A validation set of a fused-engine fast-path run in the training
+matrix's own columns is NOT routed here: the grower hands out the route
+tables it routed the training rows with and
+``models/frontier2.replay_route_log`` runs them over the set with the
+training kernels, to the same leaves bit for bit.
 """
 from __future__ import annotations
 
