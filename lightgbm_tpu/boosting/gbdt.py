@@ -424,7 +424,32 @@ class GBDT:
         self.best_iter: Dict[Tuple[int, str], int] = {}
         self.early_stopping_round = int(config.early_stopping_round)
         self.es_first_metric_only = bool(config.first_metric_only)
+        self._rank_layout_said = False
+        self._publish_rank_layout()
 
+    def _publish_rank_layout(self) -> None:
+        """A ranking objective's query layout, once per run: the exact
+        ``rank.*`` counters (from shapes) and a ``rank_layout`` event with
+        the bucket table the gradient's planes are built from."""
+        layout = getattr(self.objective, "planes", None)
+        tel = self.telemetry
+        if layout is None or self._rank_layout_said or not tel.enabled:
+            return
+        self._rank_layout_said = True
+        tel.inc("rank.queries", layout.num_queries)
+        tel.inc("rank.max_docs", layout.max_docs)
+        pairs = getattr(self.objective, "pairs_per_iter", None)
+        if pairs is not None:
+            tel.inc("rank.pairs_per_iter", pairs)
+        tel.event("rank_layout", iteration=self.iter,
+                  objective=self.objective.name, queries=layout.num_queries,
+                  max_docs=layout.max_docs, rows=layout.rows,
+                  padded_rows=layout.padded_rows,
+                  buckets=[list(b) for b in zip(layout.widths,
+                                                layout.queries,
+                                                layout.capacity)],
+                  **({"pairs_per_iter": pairs} if pairs is not None
+                     else {}))
 
 
     @property
@@ -4534,6 +4559,7 @@ class GBDT:
         k = self.num_tree_per_iteration
         init0 = [self._boost_from_average(tid, True) for tid in range(k)]
         operands = self.objective.gradient_operands()
+        self._publish_rank_layout()
         self._bagging(self.iter, None, None)   # chunk-aligned: a round
         # can fire only at the chunk's first iteration
         fn = self._megastep_fns.get(chunk)

@@ -236,8 +236,16 @@ _PARAMS: List[_Param] = [
     _p("fair_c", float, 1.0, check=(">", 0.0)),
     _p("poisson_max_delta_step", float, 0.7, check=(">", 0.0)),
     _p("tweedie_variance_power", float, 1.5, check=(">=", 1.0), check2=("<", 2.0)),
-    _p("lambdarank_truncation_level", int, 30, check=(">", 0)),
-    _p("lambdarank_norm", bool, True),
+    _p("lambdarank_truncation_level", int, 30, check=(">", 0),
+       desc="lambdarank: pairs are formed only against each query's top-"
+            "this-many documents in score order; honoured on every path, "
+            "the megastep's traced gradient included (work and memory grow "
+            "with it times the rows)"),
+    _p("lambdarank_norm", bool, True,
+       desc="lambdarank: divide a pair's delta NDCG by 0.01 + its score "
+            "gap and scale each query's lambdas by log2(1 + S) / S; "
+            "honoured on every path, the megastep's traced gradient "
+            "included"),
     _p("label_gain", list, []),
     # ---- Metric parameters ----
     _p("metric", list, [], ("metrics", "metric_types")),

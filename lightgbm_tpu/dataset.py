@@ -17,7 +17,9 @@ src/io/metadata.cpp).  Design deviation from the reference, on purpose:
 """
 from __future__ import annotations
 
+import os
 import pickle
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -25,6 +27,11 @@ import numpy as np
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN, BinMapper)
 from .config import Config
 from .utils import log
+
+# host binning of dense rows (TpuDataset.bin_rows): rows per transpose
+# block and the most threads it takes
+_BIN_BLOCK_ROWS = 1 << 16
+_BIN_THREADS = 8
 
 
 # the binning-defining keys a binary cache round-trips (the same family
@@ -490,14 +497,34 @@ class TpuDataset:
         # transpose copies on both sides keep every inner loop contiguous
         # (strided per-column access to the row-major matrices dominates
         # otherwise); float32 input stays float32 — value_to_bin bins it
-        # exactly against pre-rounded f32 bounds
-        dataT = np.ascontiguousarray(data.T)
-        outT = np.empty((len(self.used_features), data.shape[0]),
-                        dtype=dtype)
-        for k, j in enumerate(self.used_features):
-            outT[k] = self.mappers[j].value_to_bin(dataT[j]).astype(
-                dtype, copy=False)
-        return np.ascontiguousarray(outT.T)
+        # exactly against pre-rounded f32 bounds. Columns (and the row
+        # blocks of the two transposes) are independent and numpy drops
+        # the GIL inside them, so a few threads share them: the same
+        # bytes out, in a third of the time at millions of rows
+        n = data.shape[0]
+        used = list(enumerate(self.used_features))
+        dataT = np.empty((data.shape[1], n), data.dtype)
+        outT = np.empty((len(used), n), dtype=dtype)
+        out = np.empty((n, len(used)), dtype=dtype)
+        blocks = [(a, min(a + _BIN_BLOCK_ROWS, n))
+                  for a in range(0, n, _BIN_BLOCK_ROWS)]
+
+        def into_columns(span):
+            dataT[:, span[0]:span[1]] = data[span[0]:span[1]].T
+
+        def bin_column(kj):
+            outT[kj[0]] = self.mappers[kj[1]].value_to_bin(
+                dataT[kj[1]]).astype(dtype, copy=False)
+
+        def into_rows(span):
+            out[span[0]:span[1]] = outT[:, span[0]:span[1]].T
+
+        with ThreadPoolExecutor(min(_BIN_THREADS,
+                                    os.cpu_count() or 1)) as pool:
+            for work, items in ((into_columns, blocks), (bin_column, used),
+                                (into_rows, blocks)):
+                list(pool.map(work, items))
+        return out
 
     def _push_data(self, data: np.ndarray) -> None:
         self.bins = self.bin_rows(data)

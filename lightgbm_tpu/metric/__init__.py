@@ -625,30 +625,34 @@ class NDCGMetric(Metric):
         if self.query_boundaries is None:
             log.fatal("The NDCG metric requires query information")
         dcg.check_label(self.label, len(self.label_gain))
-        qb = self.query_boundaries
+        qb = np.asarray(self.query_boundaries, np.int64)
         self.num_queries = len(qb) - 1
-        # per-query ideal DCGs
-        self.inv_max_dcgs = np.zeros((self.num_queries, len(self.eval_at)))
-        for q in range(self.num_queries):
-            lab = np.asarray(self.label)[self._query_rows(q)]
-            for ki, k in enumerate(self.eval_at):
-                m = dcg.max_dcg_at_k(k, lab, self.label_gain)
-                self.inv_max_dcgs[q, ki] = 1.0 / m if m > 0 else -1.0
+        # per-query ideal DCGs, every query and cutoff at once
+        label = np.asarray(self.label)
+        if self.query_row_map is not None:
+            label = label[np.asarray(self.query_row_map)]
+        m = dcg.max_dcg_table(self.eval_at, label, qb, self.label_gain)
+        self.inv_max_dcgs = np.where(m > 0, 1.0 / np.where(m > 0, m, 1.0),
+                                     -1.0)
 
     def eval(self, score, objective):
-        qb = self.query_boundaries
-        result = np.zeros(len(self.eval_at))
-        for q in range(self.num_queries):
-            lab = self.label[qb[q]:qb[q + 1]]
-            sc = score[0][qb[q]:qb[q + 1]]
-            for ki, k in enumerate(self.eval_at):
-                if self.inv_max_dcgs[q, ki] <= 0:
-                    # all-zero-label query counts as perfect (ref: :88-92)
-                    result[ki] += 1.0
-                else:
-                    d = dcg.dcg_at_k([k], lab, sc, self.label_gain)[0]
-                    result[ki] += d * self.inv_max_dcgs[q, ki]
-        return list(result / self.num_queries)
+        qb = np.asarray(self.query_boundaries, np.int64)
+        sc = np.asarray(score[0], np.float64)[:qb[-1]]
+        qid = np.repeat(np.arange(self.num_queries), np.diff(qb))
+        # stable within (query, -score): ties in original row order
+        order = np.lexsort((-sc, qid))
+        gains = self.label_gain[np.asarray(self.label)[order]
+                                .astype(np.int64)]
+        top = int(max(1, min(max(self.eval_at), np.diff(qb).max())))
+        at, disc = dcg.top_slots(qb, top)
+        cum = np.cumsum(gains[at] * disc, axis=1)
+        result = []
+        for ki, k in enumerate(self.eval_at):
+            inv = self.inv_max_dcgs[:, ki]
+            d = cum[:, min(k, cum.shape[1]) - 1]
+            # all-zero-label query counts as perfect (ref: :88-92)
+            result.append(float(np.mean(np.where(inv <= 0, 1.0, d * inv))))
+        return result
 
     def eval_mp(self, score_dev, objective, mp):
         if self.query_row_map is None:

@@ -52,6 +52,7 @@ from . import (AUCMetric, BinaryErrorMetric, BinaryLoglossMetric,
                MultiErrorMetric, MultiSoftmaxLoglossMetric, NDCGMetric,
                QuantileMetric, RMSEMetric, K_EPSILON, _weighted_auc_jnp)
 from ..utils import dcg
+from ..utils.query_planes import QueryPlanes
 
 
 class TracedMetric(NamedTuple):
@@ -167,53 +168,59 @@ def _multi_error_builder(metric, objective) -> Optional[TracedMetric]:
 
 
 def _ndcg_builder(metric, objective) -> Optional[TracedMetric]:
-    """NDCG@k from the shared utils/dcg gain/discount tables as a
-    sort-then-segment-sum reduction: one global stable lexsort by
-    (query, -score) groups every query's rows into its static slot
-    range, so the per-slot discount*[pos<k] factor and the per-query
-    ideal-DCG normalizers are host-precomputed constants and only the
-    score ordering is data-dependent."""
+    """NDCG@k over the queries' length buckets (``utils/query_planes.py``):
+    the scores go into one ``[queries_b, width_b]`` plane per bucket and
+    ``lax.top_k`` along the short axis names each query's first
+    max(eval_at) documents (equal scores: the lower row first, as the
+    reference's std::stable_sort ranks them); their gains times the
+    discounts, summed cumulatively, are the DCGs. Operands are O(rows)
+    (the gains' planes) and O(queries x cutoffs). A query of one document
+    is ranked perfectly whatever its score and is in no plane.
+
+    One global two-key sort by (query, -score) does the same in 2.9 ms at
+    753,611 rows, and takes the TPU compiler 64 s (PERF.md section 6, PR
+    27); the planes' top_k compile in a tenth of that."""
     qb = np.asarray(metric.query_boundaries, np.int64)
     if qb is None or len(qb) < 2:
         return None
-    n = int(qb[-1])
     if getattr(metric, "query_row_map", None) is not None:
         return None        # multi-process compacted layout: host path
     num_q = len(qb) - 1
-    label = np.asarray(metric.label)
+    planes = QueryPlanes(qb, min_docs=2)
     gains = np.asarray(metric.label_gain, np.float64)
-    row_gain = gains[label.astype(np.int64)].astype(np.float32)
-    qid = np.repeat(np.arange(num_q, dtype=np.int32), np.diff(qb))
-    pos = np.arange(n, dtype=np.int64) - qb[qid]       # rank within query
-    disc = dcg.discounts(int(np.diff(qb).max()))
-    ks = list(metric.eval_at)
-    # [n_k, n]: discount at the slot's rank, zeroed past each cutoff
-    factor = np.stack([np.where(pos < k, disc[pos], 0.0) for k in ks]) \
-        .astype(np.float32)
+    row_gain = gains[np.asarray(metric.label).astype(np.int64)]
+    ks = [int(k) for k in metric.eval_at]
     inv_max = np.asarray(metric.inv_max_dcgs, np.float64)   # [num_q, n_k]
     degenerate = inv_max <= 0
+    unranked = num_q - sum(planes.queries)
 
-    ops = (jnp.asarray(row_gain), jnp.asarray(qid),
-           jnp.asarray(factor),
-           jnp.asarray(np.where(degenerate, 0.0, inv_max)
-                       .astype(np.float32).T),             # [n_k, num_q]
-           jnp.asarray(degenerate.T))
+    ops = (planes.operands(),
+           tuple(jnp.asarray(g.astype(np.float32))
+                 for g in planes.pad_host(row_gain)),
+           tuple(jnp.asarray(x.astype(np.float32)) for x in
+                 planes.of_queries(np.where(degenerate, 0.0, inv_max))),
+           tuple(jnp.asarray(x) for x in planes.of_queries(degenerate)))
 
     def fn(score, ops):
-        row_gain, qid, factor, inv_max_t, degen_t = ops
-        s = score[0]
-        order = jnp.argsort(-s, stable=True)
-        order = order[jnp.argsort(qid[order], stable=True)]
-        g_sorted = row_gain[order]
-        # slot -> query mapping is static after the lexsort (query sizes
-        # are fixed), so the original ascending qid vector is reused
-        out = []
-        for ki in range(len(ks)):
-            dcg_q = jax.ops.segment_sum(g_sorted * factor[ki], qid,
-                                        num_segments=num_q)
-            ndcg_q = jnp.where(degen_t[ki], 1.0, dcg_q * inv_max_t[ki])
-            out.append(jnp.sum(ndcg_q) / jnp.float32(num_q))
-        return out
+        layout, gain_planes, inv_maxes, degens = ops
+        totals = [jnp.float32(unranked)] * len(ks)
+        for s_pad, gain, count, inv, degen in zip(
+                planes.to_planes(score[0], layout), gain_planes, layout[1],
+                inv_maxes, degens):
+            width = s_pad.shape[1]
+            top = min(max(ks), width)
+            lane = jnp.arange(width, dtype=jnp.int32)[None, :]
+            _, at = jax.lax.top_k(
+                jnp.where(lane < count[:, None], s_pad, -jnp.inf), top)
+            disc = jnp.asarray(dcg.discounts(top).astype(np.float32))
+            cum = jnp.cumsum(
+                jnp.where(lane[:, :top] < count[:, None],
+                          jnp.take_along_axis(gain, at, axis=1) * disc, 0.0),
+                axis=1)
+            for ki, k in enumerate(ks):
+                totals[ki] = totals[ki] + jnp.sum(jnp.where(
+                    degen[:, ki], 1.0, cum[:, min(k, top) - 1] * inv[:, ki]))
+        return [t / jnp.float32(num_q) for t in totals]
     return TracedMetric(tuple(metric.names), ops, fn)
 
 
@@ -275,8 +282,9 @@ class TracedEvalPlan:
             for (si, metrics), group_ops in zip(self._groups, metric_ops):
                 sc = scores if si < 0 else vscores[si]
                 for tm, ops in zip(metrics, group_ops):
-                    # the metric's own name as the child scope: lgbm.eval/auc
-                    with jax.named_scope(tm.names[0]):
+                    # the metric's own name as the child scope:
+                    # lgbm.eval/auc, lgbm.eval/ndcg (one for its cutoffs)
+                    with jax.named_scope(tm.names[0].split("@")[0]):
                         vals.extend(tm.fn(sc, ops))
             if not vals:
                 return jnp.zeros((0,), jnp.float32)

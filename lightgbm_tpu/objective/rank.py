@@ -1,68 +1,67 @@
 """Learning-to-rank objectives.
 
 TPU-native analog of ref: src/objective/rank_objective.hpp (LambdarankNDCG,
-RankXENDCG).  The reference iterates pairs per query on the host with OpenMP;
-here queries are padded into a ``[num_queries, max_docs]`` matrix and the
-pairwise lambda accumulation is one batched ``[Q, D, D]`` tensor program,
-chunked over queries to bound memory.  The reference's sigmoid lookup table
-(a CPU speed hack, rank_objective.hpp:240) is replaced by the exact sigmoid —
-fused on the VPU it costs nothing.
+RankXENDCG).  The reference iterates pairs per query on the host with OpenMP.
+Here the queries are grouped into a few LENGTH BUCKETS (power-of-two widths
+from 128 lanes up, ``utils/query_planes.py``): a bucket is a ``[queries_b,
+width_b]`` plane, filled from the flat score vector by whole-row window
+gathers, sorted along its short axis, and the pairs of a query are formed
+against its own top
+``lambdarank_truncation_level`` documents only, one truncation position at a
+time (``lax.scan`` over i, a ``[queries_b, width_b]`` plane per step).  Work
+and memory follow sum_q min(T, n_q) * n_q and the rows; nothing is padded to
+the longest query and no ``[Q, D, D]`` plane exists.  The reference's sigmoid
+lookup table (a CPU speed hack, rank_objective.hpp:240) is replaced by the
+exact sigmoid.
+
+Everything O(rows) or O(queries) the gradient reads is a jit OPERAND
+(``gradient_operands``), so the same traced ``gradients_from`` serves the
+eager entry point, the pipelined fast step and the megastep scan.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..utils import dcg, log
+from ..utils.query_planes import QueryPlanes
 from .base import K_EPSILON, ObjectiveFunction
 
 
 class RankingObjective(ObjectiveFunction):
-    """Shared query handling (ref: rank_objective.hpp:25-93)."""
+    """Shared query handling (ref: rank_objective.hpp:25-93): the queries'
+    length buckets (``utils/query_planes.py``) and the row weights."""
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             log.fatal("Ranking tasks require query information")
         self.query_boundaries = metadata.query_boundaries
-        self.num_queries = len(self.query_boundaries) - 1
-        qb = self.query_boundaries.astype(np.int64)
-        sizes = np.diff(qb)
-        self.max_docs = int(sizes.max())
-        Q, D = self.num_queries, self.max_docs
-        # padded [Q, D] gather indices + validity mask. Multi-process:
-        # boundaries are over COMPACTED real rows; query_row_map carries
-        # each compacted row's PADDED global row index (rank blocks leave
-        # gaps — parallel/multiproc.GlobalMetadata) so gathers/scatters
-        # land on the true score rows.
+        # Multi-process: boundaries are over COMPACTED real rows;
+        # query_row_map carries each compacted row's PADDED global row
+        # index (rank blocks leave gaps — parallel/multiproc
+        # .GlobalMetadata), so the planes read and the result lands on
+        # the true score rows.
         row_map = getattr(metadata, "query_row_map", None)
-        idx = np.zeros((Q, D), dtype=np.int64)
-        valid = np.zeros((Q, D), dtype=bool)
-        for q in range(Q):
-            c = sizes[q]
-            rows = np.arange(qb[q], qb[q + 1])
-            idx[q, :c] = rows if row_map is None else row_map[rows]
-            valid[q, :c] = True
-        self._pad_idx = idx
-        self._valid = valid
-        # scatter target covers every PADDED row when mapped
-        self._out_rows = int(num_data) if row_map is None \
-            else int(len(metadata.label))
-        self._label_padded = np.where(valid, self.label[idx], 0.0) \
-            .astype(np.float32)
-        self._qsizes = sizes
+        # a query of one document has no pair and no listwise gradient:
+        # it is in no bucket and its rows read zero
+        self.planes = QueryPlanes(
+            self.query_boundaries, row_map, min_docs=2)
+        self.num_queries = self.planes.num_queries
+        self.max_docs = self.planes.max_docs
+        label = np.asarray(self.label)
+        self._label_compact = label if row_map is None \
+            else label[np.asarray(row_map)]
+        self._weight_j = (jnp.asarray(self.weight)
+                          if self.weight is not None else None)
 
-    def _unpad(self, padded: jnp.ndarray) -> jnp.ndarray:
-        """Scatter padded [Q, D] values back to flat [n] row order."""
-        flat_idx = jnp.asarray(self._pad_idx.reshape(-1))
-        vals = padded.reshape(-1)
-        mask = jnp.asarray(self._valid.reshape(-1))
-        out = jnp.zeros((self._out_rows,), jnp.float32)
-        safe_idx = jnp.where(mask, flat_idx, 0)
-        return out.at[safe_idx].add(jnp.where(mask, vals, 0.0))
+    def to_string(self):
+        return self.name
+
+    @property
+    def need_accurate_prediction(self):
+        return False
 
 
 class LambdarankNDCG(RankingObjective):
@@ -83,123 +82,150 @@ class LambdarankNDCG(RankingObjective):
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
-        dcg.check_label(self.label, len(self.label_gain))
+        dcg.check_label(self._label_compact, len(self.label_gain))
+        qb = np.asarray(self.query_boundaries, np.int64)
         # inverse max DCG per query (ref: rank_objective.hpp:124-135)
-        inv = np.zeros(self.num_queries)
-        for q in range(self.num_queries):
-            s, e = self.query_boundaries[q], self.query_boundaries[q + 1]
-            m = dcg.max_dcg_at_k(self.truncation_level, self.label[s:e],
-                                 self.label_gain)
-            inv[q] = 1.0 / m if m > 0 else 0.0
-        self._inv_max_dcg = jnp.asarray(inv.astype(np.float32))
-        self._labels_j = jnp.asarray(self._label_padded)
-        self._valid_j = jnp.asarray(self._valid)
-        self._gain_table = jnp.asarray(self.label_gain.astype(np.float32))
-        self._disc = jnp.asarray(
-            dcg.discounts(self.max_docs).astype(np.float32))
-        self._weight_j = (jnp.asarray(self.weight)
-                          if self.weight is not None else None)
-        self._grad_fn = self._build_grad_fn()
+        max_dcg = dcg.max_dcg_table([self.truncation_level],
+                                    self._label_compact, qb,
+                                    self.label_gain)[:, 0]
+        inv = np.where(max_dcg > 0, 1.0 / np.where(max_dcg > 0, max_dcg, 1.0),
+                       0.0)
+        self._inv_max_dcg = [jnp.asarray(x.astype(np.float32))
+                             for x in self.planes.of_queries(inv)]
+        labels = self._label_compact.astype(np.int64)
+        self._num_labels = int(labels.max()) + 1 if labels.size else 1
+        self._labels_pad = [jnp.asarray(p.astype(np.int8))
+                            for p in self.planes.pad_host(labels)]
+        self.pairs_per_iter = self.planes.pairs(self.truncation_level)
 
-    def _build_grad_fn(self):
-        D = self.max_docs
-        trunc = self.truncation_level
-        sig = self.sigmoid
-        norm = self.norm
-        gain_table = self._gain_table
-        disc = self._disc
+    # -- in-jit gradient protocol -----------------------------------------
+    def _operands(self):
+        return (self.planes.operands(), tuple(self._labels_pad),
+                tuple(self._inv_max_dcg), self._weight_j)
 
-        def per_query(y, s, valid, inv_max_dcg):
-            """Lambdas/hessians for one padded query
-            (ref: rank_objective.hpp:139-230 GetGradientsForOneQuery)."""
-            neg_inf = jnp.float32(-jnp.inf)
-            s_masked = jnp.where(valid, s, neg_inf)
-            order = jnp.argsort(-s_masked, stable=True)  # positions -> doc
-            ys = y[order]
-            ss = s_masked[order]
-            ok = valid[order] & jnp.isfinite(ss)
-            n_ok = jnp.sum(ok.astype(jnp.int32))
-            # best/worst scores (ref: :158-166 — worst skips one kMinScore)
-            best = ss[0]
-            worst_i = jnp.maximum(n_ok - 1, 0)
-            worst = ss[worst_i]
-
-            gains = gain_table[ys.astype(jnp.int32)]
-            pos = jnp.arange(D)
-            # pair mask: i < j, i under truncation, both valid, labels differ
-            mi = pos[:, None]
-            mj = pos[None, :]
-            pair = ((mi < mj) & (mi < trunc)
-                    & ok[:, None] & ok[None, :]
-                    & (ys[:, None] != ys[None, :]))
-
-            hi_is_i = ys[:, None] > ys[None, :]
-            ds = jnp.where(hi_is_i, ss[:, None] - ss[None, :],
-                           ss[None, :] - ss[:, None])
-            dcg_gap = jnp.abs(gains[:, None] - gains[None, :])
-            paired_disc = jnp.abs(disc[:, None] - disc[None, :])
-            delta = dcg_gap * paired_disc * inv_max_dcg
-            if norm:
-                delta = jnp.where(best != worst,
-                                  delta / (0.01 + jnp.abs(ds)), delta)
-            p = 1.0 / (1.0 + jnp.exp(sig * ds))      # GetSigmoid(ds)
-            p_hess = p * (1.0 - p) * (sig * sig) * delta
-            p_lambda = -sig * delta * p              # (ref: :207-210)
-            p_lambda = jnp.where(pair, p_lambda, 0.0)
-            p_hess = jnp.where(pair, p_hess, 0.0)
-
-            # high gets +p_lambda, low gets -p_lambda; hess adds to both
-            # (pair (i,j) stored once at [i,j]; role decided by hi_is_i)
-            contrib_i = jnp.where(hi_is_i, p_lambda, -p_lambda)
-            contrib_j = jnp.where(hi_is_i, -p_lambda, p_lambda)
-            lam_sorted = (jnp.sum(contrib_i, axis=1)
-                          + jnp.sum(contrib_j, axis=0))
-            hess_sorted = jnp.sum(p_hess, axis=1) + jnp.sum(p_hess, axis=0)
-            sum_lambdas = -2.0 * jnp.sum(p_lambda)
-            if norm:
-                factor = jnp.where(
-                    sum_lambdas > 0,
-                    jnp.log2(1.0 + sum_lambdas) / jnp.maximum(sum_lambdas,
-                                                              K_EPSILON),
-                    1.0)
-                lam_sorted = lam_sorted * factor
-                hess_sorted = hess_sorted * factor
-            # unsort back to doc positions
-            lam = jnp.zeros((D,), jnp.float32).at[order].set(lam_sorted)
-            hes = jnp.zeros((D,), jnp.float32).at[order].set(hess_sorted)
-            return lam, hes
-
-        vq = jax.vmap(per_query)
-
-        @jax.jit
-        def grad_fn(score_padded, labels, valid, inv_max_dcg):
-            return vq(labels, score_padded, valid, inv_max_dcg)
-
-        return grad_fn
+    def gradient_operands(self):
+        # multi-process: the compacted layout's row map is rank-global
+        # host state of the sync driver; that path keeps its eviction
+        if self.planes.row_map is not None:
+            return None
+        return self._operands()
 
     def get_gradients(self, score):
-        s = score[0]  # [n]
-        s_padded = s[jnp.asarray(self._pad_idx)]
-        lam, hes = self._grad_fn(s_padded, self._labels_j, self._valid_j,
-                                 self._inv_max_dcg)
-        g = self._unpad(lam)[None, :]
-        h = self._unpad(hes)[None, :]
-        if self._weight_j is not None:
-            w = self._weight_j[None, :]
-            g, h = g * w, h * w
-        return g, h
+        return self.gradients_from(score, self._operands())
 
-    def to_string(self):
-        return self.name
+    def _bucket_lambdas(self, s_pad, labels, count, inv_max_dcg):
+        """Lambdas and hessians of one bucket, ``[queries_b, width_b]``
+        in the plane's own (unsorted) lane order
+        (ref: rank_objective.hpp:139-230 GetGradientsForOneQuery)."""
+        Qb, D = s_pad.shape
+        T = min(self.truncation_level, D)
+        sig = jnp.float32(self.sigmoid)
+        neg_inf = jnp.float32(-jnp.inf)
+        lane = jnp.arange(D, dtype=jnp.int32)[None, :]
+        inside = lane < count[:, None]
+        # label and lane ride the sort as ONE integer passenger (a second
+        # gather per plane costs more than the sort does), and as its
+        # second KEY: lanes are distinct, so the order is the stable one
+        # (ties in original row order) without the iota operand a stable
+        # sort adds, which the TPU compiler takes a third longer over
+        shift = max(1, int(self._num_labels - 1).bit_length())
+        with jax.named_scope("rank_sort"):
+            key = jnp.where(inside, -s_pad, jnp.float32(jnp.inf))
+            passenger = (lane << shift) | labels.astype(jnp.int32)
+            key, passenger = jax.lax.sort((key, passenger), dimension=1,
+                                          num_keys=2, is_stable=False)
+        with jax.named_scope("rank_pairs"):
+            # padded lanes are keyed +inf and sit in the highest lanes:
+            # slot j < count holds a document, -inf scores last among them
+            S = jnp.where(inside, -key, neg_inf)
+            lab = passenger & ((1 << shift) - 1)
+            perm = passenger >> shift
+            gain = jnp.zeros_like(S)
+            for k in range(1, self._num_labels):
+                gain = jnp.where(lab == k, jnp.float32(self.label_gain[k]),
+                                 gain)
+            if self.label_gain[0] != 0.0:
+                gain = jnp.where(lab == 0, jnp.float32(self.label_gain[0]),
+                                 gain)
+            ok = inside & (S > neg_inf)
+            disc = jnp.asarray(dcg.discounts(D).astype(np.float32))[None, :]
+            # best/worst scores (ref: :158-166 — worst skips one kMinScore)
+            best = S[:, 0]
+            last = jnp.max(jnp.where(lane == count[:, None] - 1, S, neg_inf),
+                           axis=1)
+            prev = jnp.max(jnp.where(lane == count[:, None] - 2, S, neg_inf),
+                           axis=1)
+            worst = jnp.where((count > 1) & (last == neg_inf), prev, last)
+            scale_by_gap = (best != worst)[:, None]
+            inv = inv_max_dcg[:, None]
 
-    @property
-    def need_accurate_prediction(self):
-        return False
+            def one_position(carry, t):
+                lam_j, hes_j = carry
+                pick = lambda a: jax.lax.dynamic_slice_in_dim(a, t, 1, 1)
+                s_i, lab_i, g_i, ok_i = pick(S), pick(lab), pick(gain), \
+                    pick(ok)
+                pair = (lane > t) & ok_i & ok & (lab != lab_i)
+                i_high = lab_i > lab
+                ds = jnp.where(i_high, s_i - S, S - s_i)
+                delta = jnp.abs(g_i - gain) \
+                    * jnp.abs(pick(disc) - disc) * inv
+                if self.norm:
+                    delta = jnp.where(scale_by_gap,
+                                      delta / (0.01 + jnp.abs(ds)), delta)
+                rho = 1.0 / (1.0 + jnp.exp(sig * ds))     # GetSigmoid(ds)
+                lam = jnp.where(pair, sig * delta * rho, 0.0)
+                hes = jnp.where(pair, sig * sig * delta * rho * (1.0 - rho),
+                                0.0)
+                # the higher label is pushed up (-), the lower down (+)
+                to_i = jnp.where(i_high, -lam, lam)
+                return (lam_j - to_i, hes_j + hes), \
+                    (jnp.sum(to_i, axis=1), jnp.sum(hes, axis=1),
+                     jnp.sum(lam, axis=1))
+
+            zeros = jnp.zeros_like(S)
+            (lam_j, hes_j), (lam_i, hes_i, lam_abs) = jax.lax.scan(
+                one_position, (zeros, zeros), jnp.arange(T, dtype=jnp.int32))
+            top = ((0, 0), (0, D - T))
+            lam_s = lam_j + jnp.pad(lam_i.T, top)
+            hes_s = hes_j + jnp.pad(hes_i.T, top)
+            if self.norm:
+                sum_lambdas = 2.0 * jnp.sum(lam_abs, axis=0)
+                factor = jnp.where(
+                    sum_lambdas > 0,
+                    jnp.log2(1.0 + sum_lambdas)
+                    / jnp.maximum(sum_lambdas, K_EPSILON), 1.0)[:, None]
+                lam_s, hes_s = lam_s * factor, hes_s * factor
+        with jax.named_scope("rank_scatter"):
+            # back to the plane's lane order: the sort's own permutation
+            # is the key of a second sort
+            _, lam_s, hes_s = jax.lax.sort((perm, lam_s, hes_s),
+                                           dimension=1, num_keys=1)
+        return lam_s, hes_s
+
+    def gradients_from(self, score, operands):
+        layout, labels, inv_max_dcg, weight = operands
+        n_out = score.shape[-1]
+        with jax.named_scope("rank_sort"):
+            planes = self.planes.to_planes(score[0], layout)
+        lams, hess = [], []
+        for s_pad, lab, cnt, inv in zip(planes, labels, layout[1],
+                                        inv_max_dcg):
+            lam, hes = self._bucket_lambdas(s_pad, lab, cnt, inv)
+            lams.append(lam)
+            hess.append(hes)
+        with jax.named_scope("rank_scatter"):
+            g = self.planes.to_rows(lams, n_out, layout)
+            h = self.planes.to_rows(hess, n_out, layout)
+            if weight is not None:
+                g, h = g * weight, h * weight
+        return g[None, :], h[None, :]
 
 
 class RankXENDCG(RankingObjective):
     """XE_NDCG listwise objective [arxiv.org/abs/1911.09798]
-    (ref: rank_objective.hpp:284-363)."""
+    (ref: rank_objective.hpp:284-363). Its per-iteration uniform draws
+    come from host-held RNG state, so it offers no ``gradient_operands``
+    and stays off the megastep (``objective_untraced_gradients``)."""
 
     name = "rank_xendcg"
 
@@ -209,66 +235,58 @@ class RankXENDCG(RankingObjective):
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
-        self._labels_j = jnp.asarray(self._label_padded)
-        self._valid_j = jnp.asarray(self._valid)
-        self._weight_j = (jnp.asarray(self.weight)
-                          if self.weight is not None else None)
+        self._labels_pad = [
+            jnp.asarray(p.astype(np.float32))
+            for p in self.planes.pad_host(
+                self._label_compact.astype(np.float64))]
         self._rng_key = jax.random.PRNGKey(self.seed)
-        self._grad_fn = self._build_grad_fn()
+        self._grad_fn = jax.jit(self._lambdas)
 
-    def _build_grad_fn(self):
-        def per_query(y, s, valid, gumbel_u):
-            neg_inf = jnp.float32(-jnp.inf)
-            sm = jnp.where(valid, s, neg_inf)
-            # softmax over valid docs (ref: :315 Common::Softmax)
-            rho = jax.nn.softmax(sm)
-            rho = jnp.where(valid, rho, 0.0)
-            # Phi(l, u) = 2^l - u (ref: :355-357)
-            params = jnp.where(valid, jnp.exp2(y) - gumbel_u, 0.0)
-            inv_denom = 1.0 / jnp.maximum(K_EPSILON, jnp.sum(params))
-            # first order (ref: :332-339)
-            term1 = -params * inv_denom + rho
-            lam = term1
-            one_m_rho = jnp.maximum(1.0 - rho, K_EPSILON)
-            params1 = jnp.where(valid, term1 / one_m_rho, 0.0)
-            sum_l1 = jnp.sum(params1)
-            # second order (ref: :341-348)
-            term2 = rho * (sum_l1 - params1)
-            lam = lam + term2
-            params2 = jnp.where(valid, term2 / one_m_rho, 0.0)
-            sum_l2 = jnp.sum(params2)
-            # third order (ref: :349-352)
-            lam = lam + rho * (sum_l2 - params2)
-            hes = rho * (1.0 - rho)
-            n_ok = jnp.sum(valid.astype(jnp.int32))
-            lam = jnp.where((n_ok > 1) & valid, lam, 0.0)
-            hes = jnp.where((n_ok > 1) & valid, hes, 0.0)
-            return lam, hes
+    @staticmethod
+    def _bucket_lambdas(s, y, valid, gumbel_u):
+        neg_inf = jnp.float32(-jnp.inf)
+        sm = jnp.where(valid, s, neg_inf)
+        # softmax over valid docs (ref: :315 Common::Softmax)
+        rho = jax.nn.softmax(sm, axis=1)
+        rho = jnp.where(valid, rho, 0.0)
+        # Phi(l, u) = 2^l - u (ref: :355-357)
+        params = jnp.where(valid, jnp.exp2(y) - gumbel_u, 0.0)
+        inv_denom = 1.0 / jnp.maximum(
+            K_EPSILON, jnp.sum(params, axis=1, keepdims=True))
+        # first order (ref: :332-339)
+        term1 = -params * inv_denom + rho
+        lam = term1
+        one_m_rho = jnp.maximum(1.0 - rho, K_EPSILON)
+        params1 = jnp.where(valid, term1 / one_m_rho, 0.0)
+        sum_l1 = jnp.sum(params1, axis=1, keepdims=True)
+        # second order (ref: :341-348)
+        term2 = rho * (sum_l1 - params1)
+        lam = lam + term2
+        params2 = jnp.where(valid, term2 / one_m_rho, 0.0)
+        sum_l2 = jnp.sum(params2, axis=1, keepdims=True)
+        # third order (ref: :349-352)
+        lam = lam + rho * (sum_l2 - params2)
+        hes = rho * (1.0 - rho)
+        return jnp.where(valid, lam, 0.0), jnp.where(valid, hes, 0.0)
 
-        vq = jax.vmap(per_query)
-
-        @jax.jit
-        def grad_fn(score_padded, labels, valid, u):
-            return vq(labels, score_padded, valid, u)
-
-        return grad_fn
+    def _lambdas(self, s, u, layout, labels):
+        lams, hess = [], []
+        for s_pad, u_pad, y, cnt in zip(self.planes.to_planes(s, layout),
+                                        self.planes.to_planes(u, layout),
+                                        labels, layout[1]):
+            valid = jnp.arange(s_pad.shape[1])[None, :] < cnt[:, None]
+            lam, hes = self._bucket_lambdas(s_pad, y, valid, u_pad)
+            lams.append(lam)
+            hess.append(hes)
+        return (self.planes.to_rows(lams, s.shape[0], layout),
+                self.planes.to_rows(hess, s.shape[0], layout))
 
     def get_gradients(self, score):
         s = score[0]
-        s_padded = s[jnp.asarray(self._pad_idx)]
         self._rng_key, sub = jax.random.split(self._rng_key)
-        u = jax.random.uniform(sub, self._labels_j.shape)
-        lam, hes = self._grad_fn(s_padded, self._labels_j, self._valid_j, u)
-        g = self._unpad(lam)[None, :]
-        h = self._unpad(hes)[None, :]
+        u = jax.random.uniform(sub, s.shape)
+        g, h = self._grad_fn(s, u, self.planes.operands(),
+                             tuple(self._labels_pad))
         if self._weight_j is not None:
-            w = self._weight_j[None, :]
-            g, h = g * w, h * w
-        return g, h
-
-    def to_string(self):
-        return self.name
-
-    @property
-    def need_accurate_prediction(self):
-        return False
+            g, h = g * self._weight_j, h * self._weight_j
+        return g[None, :], h[None, :]

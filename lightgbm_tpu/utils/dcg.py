@@ -45,6 +45,44 @@ def max_dcg_at_k(k: int, label: np.ndarray,
     return float(np.sum(sorted_gain[:k] * discounts(k)))
 
 
+def top_slots(query_boundaries: np.ndarray, top: int):
+    """(at, disc): every query's first ``top`` slots of rows that are
+    sorted within their query: ``[num_queries, top]`` row positions
+    (clipped into the rows) and each slot's DCG discount, 0 past the
+    query's length. Static given the boundaries: only which document
+    sits in a slot depends on the sort."""
+    qb = np.asarray(query_boundaries, np.int64)
+    slot = np.arange(top, dtype=np.int64)[None, :]
+    at = np.minimum(qb[:-1, None] + slot, max(int(qb[-1]) - 1, 0))
+    disc = np.where(slot < np.diff(qb)[:, None], discounts(top)[None, :],
+                    0.0)
+    return at, disc
+
+
+def max_dcg_table(ks: Sequence[int], label: np.ndarray,
+                  query_boundaries: np.ndarray,
+                  label_gain: np.ndarray) -> np.ndarray:
+    """``max_dcg_at_k`` of every query at every k, ``[num_queries, len(ks)]``
+    float64, by array operations: the gains are sorted within their query
+    once, each query's top max(ks) are laid side by side and summed
+    cumulatively along that short axis (the order ``max_dcg_at_k`` sums
+    in)."""
+    qb = np.asarray(query_boundaries, np.int64)
+    sizes = np.diff(qb)
+    out = np.zeros((sizes.size, len(ks)), np.float64)
+    if sizes.size == 0 or qb[-1] == 0:
+        return out
+    top = int(min(max(ks), sizes.max()))
+    gains = np.asarray(label_gain, np.float64)[
+        np.asarray(label[:qb[-1]]).astype(np.int64)]
+    qid = np.repeat(np.arange(sizes.size), sizes)
+    at, disc = top_slots(qb, top)
+    cum = np.cumsum(gains[np.lexsort((-gains, qid))][at] * disc, axis=1)
+    for ki, k in enumerate(ks):
+        out[:, ki] = cum[:, min(int(k), top) - 1]
+    return out
+
+
 def dcg_at_k(ks: Sequence[int], label: np.ndarray, score: np.ndarray,
              label_gain: np.ndarray) -> List[float]:
     """DCG at each k for one query, docs ranked by score descending

@@ -123,3 +123,27 @@ def test_max_bin_by_feature():
     assert ds.mappers[0].num_bin <= 8
     assert ds.mappers[1].num_bin > 100
     assert ds.mappers[2].num_bin <= 16
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_threaded_row_binning_gives_the_same_bytes(monkeypatch, dtype):
+    """``TpuDataset.bin_rows`` shares the columns and the row blocks of
+    the two transposes among a few threads; the packed matrix is the one a
+    single thread gives, NaNs and a categorical column included."""
+    import lightgbm_tpu.dataset as dataset
+    from lightgbm_tpu.config import Config
+    rng = np.random.RandomState(5)
+    n = 3 * 4096 + 17
+    X = rng.randn(n, 9).astype(dtype)
+    X[rng.rand(n, 9) < 0.03] = np.nan
+    X[:, 4] = rng.randint(0, 12, n)
+    cfg = Config({"max_bin": 63, "verbose": -1,
+                  "categorical_feature": [4]})
+    monkeypatch.setattr(dataset, "_BIN_BLOCK_ROWS", 4096)
+    threaded = dataset.TpuDataset.from_data(X, cfg, categorical_feature=[4])
+    monkeypatch.setattr(dataset, "_BIN_THREADS", 1)
+    serial = dataset.TpuDataset.from_data(X, cfg, categorical_feature=[4])
+    assert threaded.bins.dtype == serial.bins.dtype == np.uint8
+    assert threaded.bins.flags.c_contiguous
+    np.testing.assert_array_equal(threaded.bins, serial.bins)
+    assert threaded.bins.shape == (n, 9)

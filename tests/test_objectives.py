@@ -122,6 +122,33 @@ def test_lambdarank_zero_gradient_when_perfect_separation_saturates():
     assert abs(g.sum()) < 1e-5
 
 
+@pytest.mark.parametrize("name", ["lambdarank", "rank_xendcg"])
+@pytest.mark.parametrize("params", [
+    {}, {"lambdarank_norm": False}, {"lambdarank_truncation_level": 3}],
+    ids=["default", "nonorm", "trunc3"])
+def test_lambdarank_lambdas_balance_within_each_query(name, params):
+    """No finite differences (the lambdas are no gradient of a loss that
+    could be written down): within every query they sum to zero, every
+    hessian is >= 0, and a query of one document or of equal labels
+    (lambdarank: no pair differs) gets zeros."""
+    rng = np.random.RandomState(4)
+    sizes = [1, 6, 40, 150, 9, 2]
+    qb = np.r_[0, np.cumsum(sizes)]
+    label = rng.randint(0, 4, qb[-1]).astype(np.float32)
+    label[qb[1]:qb[2]] = 3.0                      # equal labels
+    obj = setup_obj(name, label, params, group=sizes)
+    g, h = obj.get_gradients(jnp.asarray(rng.randn(1, qb[-1]), jnp.float32))
+    g, h = np.asarray(g)[0], np.asarray(h)[0]
+    assert np.isfinite(g).all() and (h >= 0).all()
+    for a, b in zip(qb[:-1], qb[1:]):
+        # float32 sums of up to 150 terms of size <= 4
+        assert abs(g[a:b].sum()) < 2e-5 * max(1.0, np.abs(g[a:b]).sum())
+    assert g[0] == 0 and h[0] == 0
+    if name == "lambdarank":
+        assert (g[qb[1]:qb[2]] == 0).all() and (h[qb[1]:qb[2]] == 0).all()
+        assert np.abs(g[qb[2]:]).max() > 0
+
+
 def test_rank_xendcg_gradients_finite():
     rng = np.random.RandomState(2)
     label = rng.randint(0, 4, 20).astype(np.float32)

@@ -31,13 +31,19 @@ TOP_LEVEL = {"gradients", "gh_pack", "grow", "score_update", "valid_apply"}
 EVAL_ONLY = {"freeze", "eval", "early_stop"}
 GROW_CHILDREN = {"root", "level", "level/route", "level/hist",
                  "level/split", "level/book"}
+# queries of 1 to 300 documents that sum to ROWS and to VALID_ROWS: two
+# length buckets (128 and 512 lanes) in training, one in validation
+RANK_GROUPS = [1, 300, 99] + [50] * 32
+RANK_GROUPS_VALID = [100, 1, 99] + [50] * 6
 # carriers of whole carries, not operations on them
 STRUCTURAL = {"while", "cond", "closed_call", "shard_map", "body"}
 
 
-def _lowered_step(monkeypatch, with_eval: bool, learner: str) -> str:
+def _lowered_step(monkeypatch, with_eval: bool, learner: str,
+                  ranking: bool = False) -> str:
     """Debug text of the megastep `lgb.train` built for a small binary
-    job, lowered again from the shapes it was called with."""
+    (or, ``ranking``, lambdarank + NDCG) job, lowered again from the
+    shapes it was called with."""
     seen = {}
     make = GBDT._make_megastep
 
@@ -64,11 +70,18 @@ def _lowered_step(monkeypatch, with_eval: bool, learner: str) -> str:
               "verbose": -1, "min_data_in_leaf": 5, "tpu_engine": "fused",
               "tpu_megastep": True, "tpu_megastep_iters": 2,
               "metric": "auc", "tree_learner": learner}
-    ds = lgb.Dataset(X, label=y)
+    group = group_v = None
+    if ranking:
+        params.update(objective="lambdarank", metric="ndcg", eval_at=[1, 10])
+        y = np.floor(3 * X[:, 0] + X[:, 1]).astype(np.float32)
+        yv = np.floor(3 * Xv[:, 0] + Xv[:, 1]).astype(np.float32)
+        group, group_v = RANK_GROUPS, RANK_GROUPS_VALID
+    ds = lgb.Dataset(X, label=y, group=group)
     # with callbacks the scan evaluates the metric itself and carries the
     # early-stop latch; without them it only keeps the validation scores
     lgb.train(params, ds, num_boost_round=2,
-              valid_sets=[lgb.Dataset(Xv, label=yv, reference=ds)],
+              valid_sets=[lgb.Dataset(Xv, label=yv, group=group_v,
+                                      reference=ds)],
               callbacks=[lgb.record_evaluation({})] if with_eval else None)
     return seen["fn"].lower(*seen["avals"]).as_text(debug_info=True), \
         seen["avals"]
@@ -144,3 +157,35 @@ def test_every_row_length_operation_is_scoped(monkeypatch, with_eval,
     if with_eval:
         assert any("lgbm.eval/auc/" in full for func, name, _ in ops
                    for full in _full_names(func, name, calls))
+
+
+def test_every_row_length_operation_of_the_lambdarank_step_is_scoped(
+        monkeypatch):
+    """The ranking objective's gradient is no per-row closed form: window
+    gathers into length buckets, per-query sorts, pair planes and the way
+    back, each under its own child of `lgbm.gradients`; NDCG's planes and
+    their top_k under `lgbm.eval/ndcg`."""
+    text, avals = _lowered_step(monkeypatch, True, "serial", ranking=True)
+    bins_T = avals[0]
+    # the rows, and every size the gradient lays them out in: the padded
+    # vectors its tiles are cut from and the planes' slot counts
+    slots = [128 * 33, 512 * 1]
+    tiles = {(-(-ROWS // w) + 1) * w for w in (128, 512)}
+    row_lengths = {ROWS, bins_T.shape[1], VALID_ROWS, sum(slots)} | tiles
+    ops, calls = _scoped_ops(text)
+    names, unscoped = set(), []
+    for func, name, dims in ops:
+        for full in _full_names(func, name, calls):
+            names.add(full)
+            if phase_of(full) == UNSCOPED and dims & row_lengths \
+                    and full.split("/")[-1] not in STRUCTURAL:
+                unscoped.append(full)
+    assert not unscoped, f"row-length operations outside any lgbm. " \
+        f"scope: {sorted(set(unscoped))[:10]}"
+    assert {phase_of(n).split("/")[0] for n in names} - {UNSCOPED} \
+        == TOP_LEVEL | EVAL_ONLY
+    for stage in ("rank_sort", "rank_pairs", "rank_scatter"):
+        assert any(f"lgbm.gradients/{stage}/" in n for n in names), stage
+    assert any(n.endswith("lgbm.gradients/rank_sort/sort") for n in names)
+    assert any("lgbm.eval/ndcg/" in n and n.endswith("top_k") for n in names)
+    assert not any("lgbm.eval/ndcg@" in n for n in names)
