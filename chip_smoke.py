@@ -128,7 +128,8 @@ def kernel_case(Sp: int, rows: int = 4096, seed: int = 0) -> dict:
     import jax.numpy as jnp
 
     from lightgbm_tpu.ops.fused_level import (build_route_table,
-                                              feature_layout)
+                                              feature_layout,
+                                              route_table_columns)
     F = FEATURES
     F_oh, Bp = feature_layout(F, PARAMS["max_bin"])
     require((F_oh, Bp) == (28, 64), f"Higgs layout moved: {(F_oh, Bp)}")
@@ -153,19 +154,24 @@ def kernel_case(Sp: int, rows: int = 4096, seed: int = 0) -> dict:
 
     bins_T = np.zeros((max(F_oh, 8), rows), np.int8)
     bins_T[:F] = bins.T
-    W = build_route_table(
-        jnp.asarray([s[1] if s[0] >= 0 else -1 for s in slots], jnp.int32),
-        jnp.asarray([s[2] for s in slots], jnp.int32),
-        jnp.asarray([s[3] for s in slots]), jnp.asarray(nb),
-        jnp.asarray(mt), jnp.asarray(db), Sp, F_oh, Bp)
+    split = (jnp.asarray([s[1] if s[0] >= 0 else -1 for s in slots],
+                         jnp.int32),
+             jnp.asarray([s[2] for s in slots], jnp.int32),
+             jnp.asarray([s[3] for s in slots]), jnp.asarray(nb),
+             jnp.asarray(mt), jnp.asarray(db))
+    W = build_route_table(*split, Sp, F_oh, Bp)
     tbl = np.zeros((Sp, 128), np.int32)
     for k, (lf, _, _, _, delta, small_left) in enumerate(slots):
         tbl[k, :3] = lf, delta, small_left
+    tbl = jnp.asarray(tbl)
+    # the same splits in the kernels' two routing forms: (W, tbl) by
+    # table, (None, tbl with the split columns) by the bin values
     return dict(F=F, F_oh=F_oh, Bp=Bp, Sp=Sp, rows=rows, bins=bins,
                 leaf=leaf, grad=grad, hess=hess, w=w, slots=slots,
                 meta=(nb, mt, db), bins_T=jnp.asarray(bins_T),
-                leaf_T=jnp.asarray(leaf[None, :]), W=W,
-                tbl=jnp.asarray(tbl))
+                leaf_T=jnp.asarray(leaf[None, :]), W=W, tbl=tbl,
+                forms={"table": (W, tbl),
+                       "bins": (None, route_table_columns(tbl, *split))})
 
 
 def _planes(hist, c, Sp):
@@ -197,18 +203,21 @@ def check_kernels(interpret: bool = False) -> None:
                        jnp.asarray(c["w"]), NCH_PRECISE)
         kw = dict(num_slots=Sp, num_bins=c["Bp"], f_oh=c["F_oh"],
                   interpret=interpret)
-        hist, new_leaf = level_pass(c["bins_T"], c["leaf_T"], gh_T, c["W"],
-                                    c["tbl"], nch=NCH_PRECISE, **kw)
-        np.testing.assert_allclose(_planes(hist, c, Sp), want, rtol=1e-4,
-                                   atol=1e-4,
-                                   err_msg=f"level_pass Sp={Sp}")
-        np.testing.assert_array_equal(np.asarray(new_leaf)[0], want_leaf,
-                                      err_msg=f"level_pass leaf Sp={Sp}")
-        routed = route_pass(c["bins_T"], c["leaf_T"], c["W"], c["tbl"],
-                            **kw)
-        np.testing.assert_array_equal(np.asarray(routed)[0], want_leaf,
-                                      err_msg=f"route_pass Sp={Sp}")
-        say(f"kernels: level_pass + route_pass Sp={Sp} match numpy")
+        for form, (W, tbl) in c["forms"].items():
+            what = f"Sp={Sp} {form} form"
+            hist, new_leaf = level_pass(c["bins_T"], c["leaf_T"], gh_T, W,
+                                        tbl, nch=NCH_PRECISE, **kw)
+            np.testing.assert_allclose(_planes(hist, c, Sp), want,
+                                       rtol=1e-4, atol=1e-4,
+                                       err_msg=f"level_pass {what}")
+            np.testing.assert_array_equal(
+                np.asarray(new_leaf)[0], want_leaf,
+                err_msg=f"level_pass leaf {what}")
+            routed = route_pass(c["bins_T"], c["leaf_T"], W, tbl, **kw)
+            np.testing.assert_array_equal(np.asarray(routed)[0], want_leaf,
+                                          err_msg=f"route_pass {what}")
+        say(f"kernels: level_pass + route_pass Sp={Sp} match numpy in "
+            "both routing forms")
 
     # epilogue on the Sp=128 tables: final route -> score update ->
     # binary gradients -> hi/lo pack -> next tree's root histogram

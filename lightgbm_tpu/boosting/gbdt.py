@@ -390,6 +390,7 @@ class GBDT:
         self.valid_bins: List = []
         self._valid_routes: Dict[int, Tuple] = {}   # see _valid_route
         self._valid_route_said: set = set()
+        self._route_form_said: set = set()          # see _route_form
         self.valid_scores: List = []
         self.valid_metrics: List[List] = []
         self.valid_names: List[str] = []
@@ -1752,6 +1753,7 @@ class GBDT:
         md = int(self.config.max_depth)
         if kind == "fused_sync":
             from ..models.frontier2 import grow_tree_fused
+            self._route_form()
             interp = self.fused_interpret
             use_nm = self.use_node_masks
             mode = self.parallel_mode
@@ -2627,6 +2629,7 @@ class GBDT:
         if self.use_fused:
             from ..models.frontier2 import grow_tree_fused
             from ..ops.fused_level import pack_gh, pack_gh_quant
+            self._route_form()
             n = self.num_data
             pad = self.fused_Rp - n
             g_p = jnp.pad(gh[:, 0], (0, pad))
@@ -3355,6 +3358,26 @@ class GBDT:
                       **({"reason": route[1]} if route[1] else {}))
         return route
 
+    def _route_form(self, defer_final_route: bool = False) -> None:
+        """Say the routing form of a step that grows trees on the fused
+        engine (models/frontier2.route_form decides; this only tells):
+        counter ``route.form_<form>`` and a ``route_form`` event, once
+        per run and (form, reason). Called where each such step is
+        built, since one of the reasons is the step's own (the epilogue
+        step defers its final route)."""
+        from ..models.frontier2 import route_form
+        said = route_form(
+            self.has_cat, self.fused_bundle_cols, defer_final_route,
+            self.fused_bundle_col_bins if self.fused_bundle_cols
+            else self.fused_Bp)
+        tel = self.telemetry
+        if tel.enabled and said not in self._route_form_said:
+            self._route_form_said.add(said)
+            form, reason = said
+            tel.inc("route.form_%s" % form)
+            tel.event("route_form", iteration=self.iter, form=form,
+                      **({"reason": reason} if reason else {}))
+
     def _wants_route_log(self) -> bool:
         """Some validation set is routed by the kernels: the steps that
         grow trees keep their route logs."""
@@ -3385,7 +3408,9 @@ class GBDT:
           ``grow_tree_fused(route_log=True)``; ``vmat``: the set's
           passenger matrix): the tables that routed the training rows
           route the validation rows, one ``route_pass`` per level the
-          tree actually grew (models/frontier2.replay_route_log);
+          tree actually grew (models/frontier2.replay_route_log), in
+          the grower's own routing form (frontier2.route_form: a log
+          of slot tables alone in the bins form);
         - gather path (``logs`` unused; ``vmat``: the row-major bins):
           ops/predict.route_rows_to_leaves walks the tree node by node,
           a static ``_fast_tree_depth_bound()`` levels of row-length
@@ -3526,6 +3551,7 @@ class GBDT:
         mask_oh = self._mask_onehot()
         packed = self.fused_packed
         route_log = self._wants_route_log()
+        self._route_form()
         if quant:
             from ..ops.fused_level import pack_gh_quant
         if screening:
@@ -3732,6 +3758,7 @@ class GBDT:
         self._epi_ops = jnp.zeros((8, Rp), jnp.float32) \
             .at[0, :n].set(op0).at[1, :n].set(op1)
         route_log = self._wants_route_log()
+        self._route_form(defer_final_route=True)
 
         def in_jit_grads(score_pad, ops_T):
             # the objective's own traced closed form; padded rows carry

@@ -36,7 +36,9 @@ from ..ops.fused_level import (NCH_PRECISE, build_route_table,
                                build_route_table_bundled,
                                bundle_plane_views, expand_feature_mask,
                                hist_planes, level_pass, max_slot_cap,
-                               pack_route_table, route_pass, table_lookup)
+                               pack_route_table, root_route_tables,
+                               route_pass, route_table_columns,
+                               table_lookup)
 from ..ops.split import (BestSplit, SplitParams, best_split_cm,
                          calculate_leaf_output, per_feature_gains_cm)
 from ..ops.collectives import record_psum
@@ -67,6 +69,36 @@ def level_caps(num_leaves: int, max_depth: int, extra_levels: int,
         d += 1
     caps.extend([min(64, slot_cap, num_leaves - 1)] * extra_levels)
     return tuple(caps)
+
+
+def route_form(has_cat: bool, bundle_cols: int, defer_final_route: bool,
+               num_bins: int) -> Tuple[str, str]:
+    """(form, reason) of a grower's routing, from what is static about the
+    job. THE place where the form is chosen: the grower asks here, and so
+    does the driver for its ``route_form`` event.
+
+    ``bins``: a numerical split routes by the bin VALUE of its feature
+    (ops/fused_level._left_from_bins): the slot table carries threshold,
+    missing bin, default_left and the feature's row, no [Sp, FB] table is
+    built, logged or multiplied. ``table``: ``W @ one_hot``
+    (build_route_table*), kept where a split is not one comparison of one
+    stored value, or where another kernel consumes the table:
+
+    - ``categorical``: "left" is membership in a bin set;
+    - ``bundled``: the stored value is an EFB bundle bin that decodes
+      to the split feature's bin by its window (and may pass 256);
+    - ``deferred_final_route``: the epilogue kernel applies the last
+      level's tables (bare ``Booster.update``), and it routes by table;
+    - ``wide_bins``: bin values over 255 are not exact in bfloat16."""
+    if has_cat:
+        return "table", "categorical"
+    if bundle_cols > 0:
+        return "table", "bundled"
+    if defer_final_route:
+        return "table", "deferred_final_route"
+    if num_bins > 256:
+        return "table", "wide_bins"
+    return "bins", None
 
 
 def _onehot_dot(sel: jax.Array, mat: jax.Array) -> jax.Array:
@@ -208,7 +240,9 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
         the training rows were routed with: (log_W [n_levels, Sp_max,
         kern_fb] bf16, log_tbl [n_levels, Sp_max, 128] int32), padded to
         the widest level like the deferred tables; a level the runtime
-        ``cond`` skipped keeps its all-(-2) table. The deferred terminal
+        ``cond`` skipped keeps its all-(-2) table. In the bins form
+        (:func:`route_form`) ``log_tbl`` holds the splits themselves and
+        ``log_W`` is None. The deferred terminal
         level is logged like any other, so :func:`replay_route_log` over
         any matrix in the training layout finds each row's leaf in the
         FINISHED tree (the validation sets' path to their leaves).
@@ -232,6 +266,8 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     caps = level_caps(L, max_depth, extra_levels,
                       slot_cap=max_slot_cap(k_foh * k_B, nch))
     kern_fb = packed.fb if packed is not None else k_foh * k_B
+    bins_form = route_form(has_cat, bundle_cols, defer_final_route,
+                           k_B)[0] == "bins"
 
     def _decode(hist, Sp_):
         """Kernel accumulator -> (g, h, c) f32 planes on the logical
@@ -267,21 +303,17 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
         pool_c = jnp.zeros((L, f_oh, B), jnp.float32)
 
         # ---------------- root pass: slot 0 collects the full-data histogram
-        # (W0[0, bins of column 0] = 1 sends every row "left" on slot 0 —
-        # each row's one-hot holds exactly one bin of column 0); skipped
+        # (every row goes "left" on slot 0: root_route_tables); skipped
         # entirely when the previous iteration's epilogue already built it
         Sp0 = 8
         if root_hist is not None:
             hist0 = root_hist
         else:
-            # the root trick sends every row "left" over the FIRST kernel
-            # column's one-hot — that column's width is the first packed
-            # feature's slab under the adaptive layout
-            w0_span = packed.widths[0] if packed is not None else k_B
-            W0 = jnp.zeros((Sp0, kern_fb), jnp.bfloat16).at[0, :w0_span].set(1)
-            tbl0 = jnp.zeros((Sp0, 128), jnp.int32)
-            tbl0 = tbl0.at[:, 0].set(jnp.where(jnp.arange(Sp0) == 0, 0, -2))
-            tbl0 = tbl0.at[0, 2].set(1)
+            # (the first kernel column's width is the first packed
+            # feature's slab under the adaptive layout)
+            W0, tbl0 = root_route_tables(
+                k_B, kern_fb, packed.widths[0] if packed is not None else k_B,
+                bins_form, Sp0)
             hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
                                   num_slots=Sp0,
                                   num_bins=k_B, f_oh=k_foh, nch=nch,
@@ -351,7 +383,8 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     # so its routing can safely ride the epilogue kernel instead. Tables
     # are padded to the widest level (an all-(-2) table routes nothing).
     Sp_max = max([8] + [max(8, c) for c in caps])
-    def_W = jnp.zeros((Sp_max, kern_fb), jnp.bfloat16)
+    def_W = None if bins_form \
+        else jnp.zeros((Sp_max, kern_fb), jnp.bfloat16)
     def_tbl = jnp.zeros((Sp_max, 128), jnp.int32) \
         .at[:, 0].set(-2)
 
@@ -364,7 +397,8 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     # caller does not ask: the grower's trace is then what it always was)
     log = None
     if route_log:
-        log = (jnp.zeros((len(caps),) + def_W.shape, def_W.dtype),
+        log = (None if bins_form
+               else jnp.zeros((len(caps),) + def_W.shape, def_W.dtype),
                jnp.broadcast_to(def_tbl, (len(caps),) + def_tbl.shape))
     state = (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
              leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
@@ -379,7 +413,8 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                            mono_mode, parallel_mode, top_k,
                            feature_shard_mask,
                            quant_bits=quant_bits, packed=packed,
-                           decode=_decode, fmask2d=fmask2d)
+                           decode=_decode, fmask2d=fmask2d,
+                           bins_form=bins_form)
     tree, leaf_T = state[0], state[1]
     out = (tree, leaf_T[0])
     if defer_final_route:
@@ -397,7 +432,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                psum_axis=None, defer_final_route=False,
                mono_mode="basic", parallel_mode="data", top_k=0,
                feature_shard_mask=None, quant_bits=0, packed=None,
-               decode=None, fmask2d=None):
+               decode=None, fmask2d=None, bins_form=False):
     (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
      leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
      reg_lo, reg_hi, pool_valid, log) = state
@@ -461,7 +496,17 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
             new_s = jnp.where(lof_on, tree.num_leaves + jnp.arange(Sp), 0)
             delta_s = jnp.where(lof_on, new_s - lof_safe, 0)
 
-            if use_bundles:
+            tbl = jnp.zeros((Sp, 128), jnp.int32).at[:, :3].set(
+                jnp.stack([lof, delta_s, small_left_s.astype(jnp.int32)],
+                          axis=1))
+            if bins_form:
+                # (route_form) the splits ride the slot table; no [Sp, FB]
+                # table is built
+                W = None
+                tbl = route_table_columns(
+                    tbl, feat_s, thr_s, dl_s, meta.num_bin,
+                    meta.missing_type, meta.default_bin, packed)
+            elif use_bundles:
                 W = build_route_table_bundled(
                     feat_s, thr_s, dl_s, meta.num_bin, meta.missing_type,
                     meta.default_bin, bundle_cfg.default_bin,
@@ -479,14 +524,11 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                     # route tables are built on the logical padded layout and
                     # re-indexed onto the packed flat axis (exact 0/1 gather)
                     W = pack_route_table(W, packed)
-            tbl = jnp.zeros((Sp, 128), jnp.int32)
-            tbl = tbl.at[:, 0].set(lof)
-            tbl = tbl.at[:, 1].set(delta_s)
-            tbl = tbl.at[:, 2].set(small_left_s.astype(jnp.int32))
             log2 = log
             if log is not None:
                 # ``fold`` is this level's 1-based position in the schedule
-                log2 = (log[0].at[fold - 1, :Sp].set(W),
+                log2 = (None if W is None
+                        else log[0].at[fold - 1, :Sp].set(W),
                         log[1].at[fold - 1, :Sp].set(tbl))
 
         k_foh = bundle_cols if use_bundles else f_oh
@@ -830,9 +872,10 @@ def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
     ``route_pass`` per logged level that has an active slot. ``bins_T``
     is any [Fp, Rp] matrix in the layout the tables were written over
     (the grower's ``num_bins`` / ``f_oh`` / ``packed`` kernel layout).
-    The decisions are the training rows' own (``W @ one_hot > 0.5``, exact
-    0/1 arithmetic), so over the training matrix this returns the
-    grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
+    The decisions are the training rows' own in either form (``W @ one_hot
+    > 0.5``, or the bin value against the slot's threshold where the log
+    holds no ``W``: exact arithmetic both), so over the training matrix
+    this returns the grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
     log_W, log_tbl = log
     Rp = bins_T.shape[1]
     leaf_T = jnp.where(jnp.arange(Rp)[None, :] < num_rows, 0, -1) \

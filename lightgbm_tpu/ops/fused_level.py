@@ -1,28 +1,47 @@
 """Fused per-level Pallas kernel: route + histogram in ONE pass over rows.
 
-This is the round-2 hot path, replacing ops/pallas_histogram.py +
-the per-slot routing loop of models/frontier.py. It replaces the
-reference's hottest loops (ref: src/io/dense_bin.hpp ConstructHistogram,
-src/treelearner/serial_tree_learner.cpp:355-453, ocl/histogram256.cl) with
-a single streaming kernel per tree level.
+This is the hot path of the fused engine, replacing
+ops/pallas_histogram.py + the per-slot routing loop of models/frontier.py.
+It replaces the reference's hottest loops (ref: src/io/dense_bin.hpp
+ConstructHistogram, src/treelearner/serial_tree_learner.cpp:355-453,
+ocl/histogram256.cl) with a single streaming kernel per tree level.
 
-Design (measured in rounds 2-3 on hardware that is gone; the figures
-ROADMAP.md queue A still leans on are marked there as not reproducible):
+Design (timings: TPU v5e, PERF.md section 6, PR 28's step 0):
 
 - Layout is TRANSPOSED vs round 1: rows ride the 128-wide lane dimension,
   features/bins/slots ride sublanes. The bin one-hot build then uses only
   native sublane broadcasts (no per-feature lane broadcast / int8 sublane
   extraction, which cost 2-3x in round 1's kernel).
 - The one-hot ``oh[f*B+b, r] = (bins[f, r] == b)`` is built ONCE per row
-  tile with a bulk int8->int32 convert + ``jnp.repeat`` + one compare, then
-  feeds BOTH matmuls:
-    * routing:   ``D = W @ oh``            -> [S, C]  (W encodes this
-      level's split thresholds + missing routing per slot)
-    * histogram: ``hist += oh @ ghs^T``    -> [FB, nch*S]
-  so routing costs one extra MXU pass instead of a separate O(S*R)
-  column-load loop over HBM (round 1's dominant cost).
-- All gh channels are packed into ONE dot (N = nch*S): measured MXU
-  efficiency rises sharply with N (45 TF/s at N=192 -> 83 TF/s at N=384).
+  tile of ``level_pass`` and feeds the histogram dot,
+  ``hist += oh @ ghs^T -> [FB, nch*S]``: the MXU streams the FB one-hot
+  rows through one latched [128, 128] tile of ``ghs`` per 128 data rows
+  and N-tile (70 ms per N-tile of 128 columns at 28M rows x FB 1,792).
+- ROUTING (which rows of slot k's leaf go left) has two forms, chosen
+  once per grower from what is static about the job
+  (models/frontier2.route_form):
+    * BINS form, every dense numerical job: a split compares ONE stored
+      value with ONE threshold, so slot k's feature row is picked out of
+      the [Fp, C] bin tile with a K = Fp dot (``sel[Sp, Fp] @ bins``,
+      exact: one non-zero term, values <= 255) and compared with the
+      threshold and missing bin the slot table carries
+      (``route_table_columns``, ``_left_from_bins``). ``route_pass`` in
+      this form builds no one-hot and has no FB-sized scratch: 5.3 ms
+      over 28M rows x 28 features at 64 slots, 2.4 ms over 6.8M x 137.
+    * TABLE form: ``D = W @ oh -> [S, C]`` with W [S, FB] encoding the
+      level's left-going bins per slot (``build_route_table*``). It
+      contracts over K = FB to learn one bit per row and slot, latching
+      FB/128 x C/128 one-hot tiles and streaming only S <= 128 rows
+      through each: 58-70 ms of every ``level_pass`` and, with the
+      one-hot build it needs (28-60 ms), all 103 / 131 ms of a
+      ``route_pass`` at the two widths above. Kept where "left" is not
+      one comparison of one stored value (categorical bin sets, EFB
+      bundle columns whose bins decode by window, bins over 255) and for
+      the epilogue kernel, which applies the deferred last level's
+      tables. ``level_pass`` / ``route_pass`` take the form from their
+      ``W`` argument (None = bins form).
+- All gh channels are packed into ONE dot (N = nch*S): MXU efficiency
+  rises with N.
 - Channels (``nch=5``, default): g_hi, g_lo, h_hi, h_lo, w — grad/hess are
   split into two bfloat16 halves (hi + exact residual) so the accumulated
   histogram carries ~fp32 input precision, matching the reference GPU
@@ -32,9 +51,9 @@ ROADMAP.md queue A still leans on are marked there as not reproducible):
 - The grid is sequential on a TPU core, so the [FB, nch*S] output block
   accumulates across row tiles race-free; the updated row->leaf vector is
   emitted per-tile alongside.
-- The ROOT pass needs no special kernel: tables with leaf_of_slot=[0],
-  W[0, 0:B] = 1 (every row "goes left" on feature 0) and small_is_left=1
-  make slot 0 collect the full-data histogram.
+- The ROOT pass needs no special kernel: slot 0 holds leaf 0, sends every
+  row "left" (``root_route_tables``) and is its own smaller child, so it
+  collects the full-data histogram.
 
 The smaller child of each split is histogrammed (caller puts the smaller
 side in the slot tables); the sibling is reconstructed outside by
@@ -56,6 +75,12 @@ from . import quantize
 
 NCH_PRECISE = 5   # g_hi, g_lo, h_hi, h_lo, w
 NCH_FAST = 3      # g, h, w
+
+# columns of the per-level slot table ``tbl`` [Sp, 128] int32. 0-2 are read
+# by both routing forms; 3-6 carry the split itself in the bins form
+# (route_table_columns) and stay zero in the table form.
+TBL_LEAF, TBL_RIGHT_DELTA, TBL_SMALL_LEFT = 0, 1, 2
+TBL_THRESHOLD, TBL_MISSING_BIN, TBL_DEFAULT_LEFT, TBL_FEATURE_ROW = 3, 4, 5, 6
 
 
 def _round_up(x: int, m: int) -> int:
@@ -316,6 +341,54 @@ def build_route_table(feature: jax.Array, threshold: jax.Array,
     return w.reshape(Sp, F_oh * B).astype(jnp.bfloat16)
 
 
+def route_table_columns(tbl: jax.Array, feature: jax.Array,
+                        threshold: jax.Array, default_left: jax.Array,
+                        num_bin: jax.Array, missing_type: jax.Array,
+                        default_bin: jax.Array,
+                        packed: PackedLayout = None) -> jax.Array:
+    """The BINS form of a level's numerical splits: ``tbl`` with columns
+    3-6 filled per slot (threshold bin; the bin that rides default_left,
+    -1 for none — build_route_table's ``is_missing``; default_left; the
+    split feature's ROW of the kernel's bin matrix, its position in
+    ``packed.feat_order`` under the adaptive layout). An inactive slot
+    (feature -1) gets threshold -1, no missing bin and row -1: it reads
+    "not left" for every row, as its all-zero W row does. The kernels
+    decide ``left = where(bin == missing, default_left, bin <= threshold)``
+    from these (_left_from_bins): the same 0/1 plane as ``W @ one_hot``.
+    Args as build_route_table ([Sp] per slot, [F] per feature)."""
+    on = feature >= 0
+    f = jnp.maximum(feature, 0)
+    mt = missing_type[f]
+    miss = jnp.where(mt == 1, default_bin[f],
+                     jnp.where(mt == 2, num_bin[f] - 1, -1))
+    row = f if packed is None else jnp.asarray(packed.row_of_feat)[f]
+    cols = jnp.stack([threshold, miss, default_left.astype(jnp.int32), row],
+                     axis=1)
+    cols = jnp.where(on[:, None], cols, jnp.array([-1, -1, 0, -1]))
+    return tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(
+        cols.astype(jnp.int32))
+
+
+def root_route_tables(num_bins: int, kern_fb: int, first_width: int,
+                      bins_form: bool, Sp: int = 8):
+    """(W, tbl) of the ROOT pass: slot 0 holds leaf 0, is its own
+    "smaller child" and sends every row left, so it collects the
+    full-data histogram; the other slots are inactive. Table form: W[0]
+    is 1 over the FIRST kernel column's ``first_width`` bins (each row's
+    one-hot holds exactly one of them). Bins form (W None): whatever bin
+    the first kernel row holds is <= num_bins - 1, and no bin is the
+    missing one (the root sends missing rows left too)."""
+    tbl = jnp.zeros((Sp, 128), jnp.int32) \
+        .at[:, TBL_LEAF].set(jnp.where(jnp.arange(Sp) == 0, 0, -2)) \
+        .at[0, TBL_SMALL_LEFT].set(1)
+    if not bins_form:
+        W = jnp.zeros((Sp, kern_fb), jnp.bfloat16).at[0, :first_width].set(1)
+        return W, tbl
+    cols = jnp.array([[num_bins - 1, -1, 0, 0]] + [[-1, -1, 0, -1]] * (Sp - 1),
+                     jnp.int32)
+    return None, tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(cols)
+
+
 def build_route_table_bundled(feature: jax.Array, threshold: jax.Array,
                               default_left: jax.Array, num_bin: jax.Array,
                               missing_type: jax.Array,
@@ -395,16 +468,55 @@ def bundle_plane_views(plane: jax.Array, flat_idx: jax.Array,
     return out[..., 0] if squeeze else out
 
 
+def _left_from_bins(bins_ref, tbl_ref):
+    """left_i [Sp, C] int32 0/1 from the bin VALUES (bins form): slot
+    k's feature row is picked with a K = Fp dot, ``v[k, r] = sum_f
+    sel[k, f] * bins[f, r]`` (one non-zero term, bin values <= 255 are
+    exact in bf16, so v is exact in f32), then compared with the slot's
+    threshold and missing bin from ``tbl``. An inactive slot (feature
+    row -1: sel row all zero, threshold -1) reads 0. Mask algebra stays
+    in i32 (the i1 relayout bug noted in _level_kernel)."""
+    Fp = bins_ref.shape[0]
+    Sp = tbl_ref.shape[0]
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (Sp, Fp), 1)
+           == tbl_ref[:, TBL_FEATURE_ROW:TBL_FEATURE_ROW + 1]) \
+        .astype(jnp.bfloat16)                                  # [Sp, Fp]
+    v = jax.lax.dot_general(sel, bins_ref[:].astype(jnp.bfloat16),
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [Sp, C]
+    thr = tbl_ref[:, TBL_THRESHOLD:TBL_THRESHOLD + 1].astype(jnp.float32)
+    miss = tbl_ref[:, TBL_MISSING_BIN:TBL_MISSING_BIN + 1] \
+        .astype(jnp.float32)
+    dl = tbl_ref[:, TBL_DEFAULT_LEFT:TBL_DEFAULT_LEFT + 1]     # [Sp, 1]
+    le = (v <= thr).astype(jnp.int32)
+    is_miss = (v == miss).astype(jnp.int32)
+    return le + is_miss * (dl - le)        # where(is_miss, dl, v <= thr)
+
+
+def _route_rows(leafb, left_i, tbl_ref):
+    """(new leaf [1, C], P_i [Sp, C]): rows of slot k's leaf that do not
+    go left move to leaf + right_delta[k]."""
+    Sp, C = left_i.shape
+    leaf_of_slot = tbl_ref[:, TBL_LEAF:TBL_LEAF + 1]           # [Sp, 1]
+    right_delta = tbl_ref[:, TBL_RIGHT_DELTA:TBL_RIGHT_DELTA + 1]
+    P_i = (jnp.broadcast_to(leafb, (Sp, C))
+           == leaf_of_slot).astype(jnp.int32)                  # [Sp, C] 0/1
+    go_right = P_i * (1 - left_i)                              # [Sp, C] 0/1
+    delta = jnp.sum(go_right * jnp.broadcast_to(right_delta, (Sp, C)),
+                    axis=0, keepdims=True)                     # [1, C] i32
+    return leafb + delta, P_i
+
+
 def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
                   quant: bool = False, packed: PackedLayout = None,
-                  has_fm: bool = False):
-    if has_fm:
-        (bins_ref, leaf_ref, gh_ref, w_ref, tbl_ref, fm_ref,
-         hist_ref, newleaf_ref, oh_ref) = refs
-    else:
-        (bins_ref, leaf_ref, gh_ref, w_ref, tbl_ref,
-         hist_ref, newleaf_ref, oh_ref) = refs
-        fm_ref = None
+                  has_fm: bool = False, has_w: bool = True):
+    refs = list(refs)
+    bins_ref, leaf_ref, gh_ref = refs[:3]
+    hist_ref, newleaf_ref, oh_ref = refs[-3:]
+    rest = refs[3:-3]
+    w_ref = rest.pop(0) if has_w else None
+    tbl_ref = rest.pop(0)
+    fm_ref = rest.pop(0) if has_fm else None
     t = pl.program_id(0)
 
     @pl.when(t == 0)
@@ -417,11 +529,15 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
 
     leafb = leaf_ref[:]                                        # [1, C] i32
 
-    # ---- routing: D[k, r] = 1 iff row r goes left under slot k's split.
-    # Quantized mode routes on the same int8 one-hot through the MXU's
-    # native s8 x s8 -> s32 path (W is 0/1-valued either way).
+    # ---- routing: left_i[k, r] = 1 iff row r goes left under slot k's
+    # split. Bins form: from the bin values (no W operand, nothing read
+    # from the one-hot). Table form: D = W @ one_hot; quantized mode
+    # routes on the same int8 one-hot through the MXU's native s8 x s8
+    # -> s32 path (W is 0/1-valued either way).
     oh = oh_ref[:]
-    if quant:
+    if not has_w:
+        left_i = _left_from_bins(bins_ref, tbl_ref)            # [Sp, C] 0/1
+    elif quant:
         D = jax.lax.dot_general(w_ref[:], oh, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.int32)
         left_i = (D > 0).astype(jnp.int32)                     # [Sp, C] 0/1
@@ -434,12 +550,11 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
         # int select lowers to the same VPU ops anyway.
         left_i = (D > 0.5).astype(jnp.int32)                   # [Sp, C] 0/1
 
-    # ---- slot membership
-    leaf_of_slot = tbl_ref[:, 0:1]                             # [Sp, 1]
-    right_delta = tbl_ref[:, 1:2]
-    small_left_i = (tbl_ref[:, 2:3] > 0).astype(jnp.int32)     # [Sp, 1] 0/1
-    P_i = (jnp.broadcast_to(leafb, (Sp, C))
-           == leaf_of_slot).astype(jnp.int32)                  # [Sp, C] 0/1
+    # ---- slot membership + row->leaf update: right-child rows move to
+    # their new leaf id
+    newleaf_ref[:], P_i = _route_rows(leafb, left_i, tbl_ref)
+    small_left_i = (tbl_ref[:, TBL_SMALL_LEFT:TBL_SMALL_LEFT + 1]
+                    > 0).astype(jnp.int32)                     # [Sp, 1] 0/1
     same_i = 1 - jnp.bitwise_xor(left_i, small_left_i)         # left==small
     in_small = P_i * same_i                                    # [Sp, C] 0/1
     if not quant:
@@ -466,12 +581,6 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
     hist_ref[:] += jax.lax.dot_general(
         oh, ghs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32 if quant else jnp.float32)
-
-    # ---- row->leaf update: right-child rows move to their new leaf id
-    go_right = P_i * (1 - left_i)                              # [Sp, C] 0/1
-    delta = jnp.sum(go_right * jnp.broadcast_to(right_delta, (Sp, C)),
-                    axis=0, keepdims=True)                     # [1, C] i32
-    newleaf_ref[:] = leafb + delta
 
 
 def _kernel_fb(f_oh: int, num_bins: int, packed: PackedLayout) -> int:
@@ -536,20 +645,22 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     quant = quant_bits > 0
     oh_dt = jnp.int8 if quant else jnp.bfloat16
     acc_dt = jnp.int32 if quant else jnp.float32
-    if quant:
-        W = W.astype(jnp.int8)
 
     kernel = functools.partial(_level_kernel, B=B, F_oh=f_oh, Sp=Sp,
                                nch=nch, quant=quant, packed=packed,
-                               has_fm=fmask is not None)
+                               has_fm=fmask is not None,
+                               has_w=W is not None)
     in_specs = [
         pl.BlockSpec((Fp, C), lambda t: (0, t)),
         pl.BlockSpec((1, C), lambda t: (0, t)),
         pl.BlockSpec((8, C), lambda t: (0, t)),
-        pl.BlockSpec((Sp, FB), lambda t: (0, 0)),
-        pl.BlockSpec((Sp, 128), lambda t: (0, 0)),
     ]
-    operands = [bins_T, leaf_T, gh_T, W, tbl]
+    operands = [bins_T, leaf_T, gh_T]
+    if W is not None:
+        in_specs.append(pl.BlockSpec((Sp, FB), lambda t: (0, 0)))
+        operands.append(W.astype(jnp.int8) if quant else W)
+    in_specs.append(pl.BlockSpec((Sp, 128), lambda t: (0, 0)))
+    operands.append(tbl)
     if fmask is not None:
         in_specs.append(pl.BlockSpec((FB, 128), lambda t: (0, 0)))
         operands.append(fmask.astype(oh_dt))
@@ -576,26 +687,39 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
 def _route_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, newleaf_ref,
                   oh_ref, *, B: int, F_oh: int, Sp: int,
                   packed: PackedLayout = None):
-    """Routing-only sibling of _level_kernel: updates row->leaf without
-    accumulating histograms. Used for passes whose histograms can never be
-    consumed (the leaf budget is exhausted, or no further pass follows) —
-    the histogram dot is ~60% of a deep pass's cost. Routing keeps the
-    bf16 formulation under quantization (no precision at stake); only
-    the ``packed`` layout matters here (the bin rows are permuted)."""
-    C = bins_ref.shape[1]
+    """Routing-only sibling of _level_kernel, TABLE form: updates
+    row->leaf without accumulating histograms. Used for passes whose
+    histograms can never be consumed (the leaf budget is exhausted, or no
+    further pass follows). Routing keeps the bf16 formulation under
+    quantization (no precision at stake); only the ``packed`` layout
+    matters here (the bin rows are permuted)."""
     _write_onehot(bins_ref, oh_ref, F_oh, B, packed=packed)
-    leafb = leaf_ref[:]
     D = jax.lax.dot_general(w_ref[:], oh_ref[:], (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     left_i = (D > 0.5).astype(jnp.int32)
-    leaf_of_slot = tbl_ref[:, 0:1]
-    right_delta = tbl_ref[:, 1:2]
-    P_i = (jnp.broadcast_to(leafb, (Sp, C))
-           == leaf_of_slot).astype(jnp.int32)
-    go_right = P_i * (1 - left_i)
-    delta = jnp.sum(go_right * jnp.broadcast_to(right_delta, (Sp, C)),
-                    axis=0, keepdims=True)
-    newleaf_ref[:] = leafb + delta
+    newleaf_ref[:], _ = _route_rows(leaf_ref[:], left_i, tbl_ref)
+
+
+def _route_bins_kernel(bins_ref, leaf_ref, tbl_ref, newleaf_ref):
+    """_route_kernel in the BINS form: no one-hot, no [FB, C] scratch.
+    Per tile: [Fp, C] int8 -> bf16, one K = Fp dot, a handful of [Sp, C]
+    VPU ops."""
+    left_i = _left_from_bins(bins_ref, tbl_ref)
+    newleaf_ref[:], _ = _route_rows(leaf_ref[:], left_i, tbl_ref)
+
+
+def route_tile_rows(Sp: int, Fp: int) -> int:
+    """Row-tile width of the bins-form route kernel. Nothing in it is
+    FB-sized: what the compiler puts on the scoped-VMEM stack is the bf16
+    copy of the [Fp, C] bin tile (2 B an element: 31.4 MB refused at Fp
+    2,000 x 8,192 rows, 16.3 MB at 512 x 16,384, compiled for a described
+    v5e), beside the int8 tile's double buffer; the [Sp, C] planes are
+    charged 16 B a row and slot. A power of two from 512 to 8,192: a grid
+    step costs ~0.35 us, and on a v5e (PR 28) the 28M-row Higgs pass took
+    7.8 / 6.0 / 5.3 ms at 2,048 / 4,096 / 8,192 rows and 64 slots."""
+    c = VMEM_BUDGET // (4 * Fp + 16 * Sp)
+    c = 1 << (int(c).bit_length() - 1)
+    return int(max(512, min(8192, c)))
 
 
 @functools.partial(
@@ -607,33 +731,43 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
                f_oh: int, tile_rows: int = 0,
                interpret: bool = False,
                packed: PackedLayout = None) -> jax.Array:
-    """Row->leaf update only (same W/tbl contract as level_pass)."""
+    """Row->leaf update only (same W/tbl contract as level_pass; ``W``
+    None = bins form, whose tile is sized from Fp and Sp, not from FB)."""
     Fp, R = bins_T.shape
     B = num_bins
-    FB = _kernel_fb(f_oh, B, packed)
     Sp = tbl.shape[0]
+    row_spec = lambda rows, C: pl.BlockSpec((rows, C), lambda t: (0, t))
+    tbl_spec = pl.BlockSpec((Sp, 128), lambda t: (0, 0))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    if W is None:
+        C = _fit_tile(tile_rows or route_tile_rows(Sp, Fp), R)
+        assert R % C == 0, f"rows {R} not padded to tile {C}"
+        return pl.pallas_call(
+            _route_bins_kernel,
+            grid=(R // C,),
+            in_specs=[row_spec(Fp, C), row_spec(1, C), tbl_spec],
+            out_specs=row_spec(1, C),
+            out_shape=jax.ShapeDtypeStruct((1, R), jnp.int32),
+            compiler_params=params,
+            interpret=interpret,
+        )(bins_T, leaf_T, tbl)
+    FB = _kernel_fb(f_oh, B, packed)
     C = _fit_tile(tile_rows or default_tile_rows(Sp, f_oh * B, NCH_FAST,
                                                  wide_bins=B > 256), R)
     assert R % C == 0, f"rows {R} not padded to tile {C}"
     kernel = functools.partial(_route_kernel, B=B, F_oh=f_oh, Sp=Sp,
                                packed=packed)
-    new_leaf = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(R // C,),
-        in_specs=[
-            pl.BlockSpec((Fp, C), lambda t: (0, t)),
-            pl.BlockSpec((1, C), lambda t: (0, t)),
-            pl.BlockSpec((Sp, FB), lambda t: (0, 0)),
-            pl.BlockSpec((Sp, 128), lambda t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda t: (0, t)),
+        in_specs=[row_spec(Fp, C), row_spec(1, C),
+                  pl.BlockSpec((Sp, FB), lambda t: (0, 0)), tbl_spec],
+        out_specs=row_spec(1, C),
         out_shape=jax.ShapeDtypeStruct((1, R), jnp.int32),
         scratch_shapes=[pltpu.VMEM((FB, C), jnp.bfloat16)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=params,
         interpret=interpret,
     )(bins_T, leaf_T, W, tbl)
-    return new_leaf
 
 
 def _epilogue_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, lv_ref, score_ref,
@@ -671,13 +805,7 @@ def _epilogue_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, lv_ref, score_ref,
     D = jax.lax.dot_general(w_ref[:], oh, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     left_i = (D > 0.5).astype(jnp.int32)
-    leaf_of_slot = tbl_ref[:, 0:1]
-    right_delta = tbl_ref[:, 1:2]
-    P_i = (jnp.broadcast_to(leafb, (Sp, C)) == leaf_of_slot).astype(jnp.int32)
-    go_right = P_i * (1 - left_i)
-    delta_l = jnp.sum(go_right * jnp.broadcast_to(right_delta, (Sp, C)),
-                      axis=0, keepdims=True)
-    leaf2 = leafb + delta_l                                    # [1, C]
+    leaf2, _ = _route_rows(leafb, left_i, tbl_ref)             # [1, C]
 
     # ---- leaf-value score update (sublane one-hot, as _lookup_kernel;
     # padding rows at leaf -1 match nothing -> delta 0)

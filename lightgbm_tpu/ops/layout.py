@@ -115,6 +115,13 @@ class PackedLayout:
         return out
 
     @functools.cached_property
+    def row_of_feat(self) -> np.ndarray:
+        """[f_oh] bin-matrix row of each logical feature (-1: dropped)."""
+        pos = np.full(self.f_oh, -1, np.int32)
+        pos[list(self.feat_order)] = np.arange(len(self.feat_order))
+        return pos
+
+    @functools.cached_property
     def padded_to_packed(self) -> np.ndarray:
         """[f_oh * bp] -> packed flat index (0 where invalid)."""
         idx = np.zeros(self.f_oh * self.bp, np.int32)

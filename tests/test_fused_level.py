@@ -210,3 +210,113 @@ def test_table_lookup():
                        tile_rows=1024, interpret=True)
     want = np.where(idx >= 0, table[np.clip(idx, 0, L - 1)], 0.0)
     np.testing.assert_allclose(np.asarray(out)[0], want, rtol=1e-6)
+
+
+# ---- the two routing forms (PR 28): W @ one_hot against the bin values
+FORM_NUM_BIN = np.array([32, 32, 9, 9, 32, 5], np.int32)
+FORM_MT = np.array([0, 1, 2, 0, 2, 1], np.int32)      # none, zero, NaN
+FORM_DB = np.array([0, 3, 0, 0, 0, 1], np.int32)
+FORM_CASES = (
+    [f"missing_{name}_default_{'left' if dl else 'right'}"
+     for name in ("none", "zero", "nan") for dl in (0, 1)]
+    + ["threshold_at_last_bin", "root", "inactive_beside_padding", "packed"])
+
+
+def _form_case(Sp, case):
+    """One level's operands in both forms: (bins_T, leaf_T, W, tbl of the
+    table form, tbl of the bins form, packed, the leaves numpy expects)."""
+    from lightgbm_tpu.ops.fused_level import (pack_route_table,
+                                              root_route_tables,
+                                              route_table_columns)
+    from lightgbm_tpu.ops.layout import packed_feature_layout
+    rng = np.random.RandomState(Sp + len(case))
+    F, B, R, Rp = len(FORM_NUM_BIN), 32, 1500, 2048
+    F_oh, Bp = feature_layout(F, B)
+    assert Bp == B
+    bins = np.stack([rng.randint(0, nb, R) for nb in FORM_NUM_BIN], 1)
+    pad_f = lambda a: jnp.asarray(np.pad(a, (0, F_oh - F)))
+    meta = (pad_f(FORM_NUM_BIN), pad_f(FORM_MT), pad_f(FORM_DB))
+    leaf = rng.randint(0, Sp, R).astype(np.int32)
+    feat = rng.randint(0, F, Sp).astype(np.int32)
+    thr = np.array([rng.randint(0, FORM_NUM_BIN[f]) for f in feat], np.int32)
+    dl = rng.randint(0, 2, Sp).astype(bool)
+    packed = None
+    order = np.arange(F)
+    if case.startswith("missing_"):
+        _, name, _, side = case.split("_")
+        mt_want = {"none": 0, "zero": 1, "nan": 2}[name]
+        feat = rng.choice(np.nonzero(FORM_MT == mt_want)[0], Sp) \
+            .astype(np.int32)
+        thr = np.array([rng.randint(0, FORM_NUM_BIN[f]) for f in feat],
+                       np.int32)
+        dl[:] = side == "left"
+    elif case == "threshold_at_last_bin":
+        thr = FORM_NUM_BIN[feat] - 1
+    elif case == "inactive_beside_padding":
+        feat[1::2] = -1                   # every other slot is off
+        leaf[rng.rand(R) < 0.2] = Sp + 3  # a leaf no slot holds
+    elif case == "packed":
+        packed = packed_feature_layout(FORM_NUM_BIN, B, f_oh=F_oh)
+        order = np.asarray(packed.feat_order)
+        assert not np.array_equal(order, np.arange(F))
+    lof = np.where(feat >= 0, np.arange(Sp), -2).astype(np.int32)
+    bins_T = np.zeros((max(F_oh, 8), Rp), np.int8)
+    bins_T[:F, :R] = bins[:, order].T
+    leaf_T = np.full((1, Rp), -1, np.int32)
+    if case == "root":
+        leaf[:] = 0
+        kern_fb = F_oh * B
+        W, tbl = root_route_tables(B, kern_fb, B, False, Sp)
+        _, tbl_b = root_route_tables(B, kern_fb, B, True, Sp)
+        want = leaf.copy()
+    else:
+        tbl = np.zeros((Sp, 128), np.int32)
+        tbl[:, 0] = lof
+        tbl[:, 1] = np.where(feat >= 0, Sp + np.arange(Sp) - lof, 0)
+        tbl[:, 2] = rng.randint(0, 2, Sp)
+        tbl = jnp.asarray(tbl)
+        split = (jnp.asarray(feat), jnp.asarray(thr), jnp.asarray(dl))
+        W = build_route_table(*split, *meta, Sp, F_oh, B)
+        if packed is not None:
+            W = pack_route_table(W, packed)
+        tbl_b = route_table_columns(tbl, *split, *meta, packed)
+        want = leaf.copy()
+        for k in np.nonzero(feat >= 0)[0]:
+            f = feat[k]
+            left = _np_route_left(bins[:, f], thr[k], dl[k], FORM_NUM_BIN[f],
+                                  FORM_MT[f], FORM_DB[f])
+            want[(leaf == lof[k]) & ~left] += Sp + k - lof[k]
+    leaf_T[0, :R] = leaf
+    return (jnp.asarray(bins_T), jnp.asarray(leaf_T), W, tbl, tbl_b, packed,
+            np.pad(want, (0, Rp - R), constant_values=-1))
+
+
+@pytest.mark.parametrize("case", FORM_CASES)
+@pytest.mark.parametrize("Sp", [8, 64])
+def test_bins_form_routes_and_histograms_like_the_table_form(Sp, case):
+    """The same splits as a [Sp, FB] table and as the slot table's columns
+    3-6: identical leaves from both kernels, identical histogram."""
+    from lightgbm_tpu.ops.fused_level import route_pass
+    bins_T, leaf_T, W, tbl, tbl_b, packed, want = _form_case(Sp, case)
+    rng = np.random.RandomState(7)
+    Rp = bins_T.shape[1]
+    gh_T = pack_gh(jnp.asarray(rng.randn(Rp).astype(np.float32)),
+                   jnp.asarray(rng.rand(Rp).astype(np.float32) + 0.1),
+                   jnp.ones((Rp,), jnp.float32), NCH_PRECISE)
+    F_oh, B = feature_layout(len(FORM_NUM_BIN), 32)
+    kw = dict(num_slots=Sp, num_bins=B, f_oh=F_oh, tile_rows=512,
+              interpret=True, packed=packed)
+    hist_t, leaf_t = level_pass(bins_T, leaf_T, gh_T, W, tbl, **kw)
+    hist_b, leaf_b = level_pass(bins_T, leaf_T, gh_T, None, tbl_b, **kw)
+    assert np.array_equal(np.asarray(leaf_t)[0], want)
+    assert np.array_equal(np.asarray(leaf_b), np.asarray(leaf_t))
+    assert np.array_equal(np.asarray(hist_b), np.asarray(hist_t))
+    assert np.abs(np.asarray(hist_t)).sum() > 0
+    for w, t in ((W, tbl), (None, tbl_b)):
+        routed = route_pass(bins_T, leaf_T, w, t, **kw)
+        assert np.array_equal(np.asarray(routed), np.asarray(leaf_t))
+    moved = (want != np.asarray(leaf_T)[0]).sum()
+    if case == "threshold_at_last_bin":
+        assert moved < 100        # only missing rows that default right
+    elif case != "root":
+        assert moved > 100

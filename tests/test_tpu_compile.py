@@ -1,0 +1,91 @@
+"""The level and route kernels COMPILED for a described TPU v5e, no chip
+attached (the on-chip-measurement guide's third rehearsal), at the
+benchmark's widths and in both routing forms. Interpret mode cannot see
+what the TPU's compiler refuses: a slice off the tiling, a matmul shape,
+more scoped VMEM than a kernel may use. A compile that passes is not a
+chip run: nothing here says anything about results or times.
+
+The topology is described inside a fixture (one process at a time may
+load the TPU's library; every xdist worker imports this file), and all
+such tests live in this one file.
+"""
+import functools
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from lightgbm_tpu.ops.fused_level import (NCH_PRECISE, feature_layout,
+                                          level_pass, max_slot_cap,
+                                          route_pass, route_tile_rows)
+
+ROWS = 65_536       # the grid's length only; tiles are per shape
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _operands(chip, features, max_bin, sp):
+    f_oh, bp = feature_layout(features, max_bin)
+    fp = max(f_oh, 8)
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=chip)
+    return dict(
+        bins=shape((fp, ROWS), jnp.int8 if bp <= 128 else jnp.int16),
+        leaf=shape((1, ROWS), jnp.int32), gh=shape((8, ROWS), jnp.bfloat16),
+        W=shape((sp, f_oh * bp), jnp.bfloat16),
+        tbl=shape((sp, 128), jnp.int32),
+        kw=dict(num_slots=sp, num_bins=bp, f_oh=f_oh), fp=fp, fb=f_oh * bp)
+
+
+def _compiles(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+# name, features, max_bin: the benchmark's two widths, and Higgs at 255 bins
+WIDTHS = [("higgs63", 28, 63), ("msltr63", 137, 63), ("higgs255", 28, 255)]
+
+
+@pytest.mark.parametrize("form", ["bins", "table"])
+@pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
+@pytest.mark.parametrize("name,features,max_bin", WIDTHS,
+                         ids=[w[0] for w in WIDTHS])
+def test_level_and_route_kernels_compile(one_chip, name, features, max_bin,
+                                         deep, form):
+    fb = feature_layout(features, max_bin)
+    sp = min(128, max_slot_cap(fb[0] * fb[1], NCH_PRECISE)) if deep else 8
+    o = _operands(one_chip, features, max_bin, sp)
+    W = o["W"] if form == "table" else None
+    _compiles(functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"]),
+              o["bins"], o["leaf"], o["gh"], W, o["tbl"])
+    _compiles(functools.partial(route_pass, **o["kw"]),
+              o["bins"], o["leaf"], W, o["tbl"])
+
+
+def test_bins_form_route_kernel_has_no_fb_sized_scratch(one_chip):
+    """Epsilon's width (2,000 features, FB 128,000): the table form needs
+    31 MB of scoped VMEM for its one-hot and is refused; the bins form
+    holds the [Fp, C] bin tile alone and compiles (ROADMAP B-I.3)."""
+    o = _operands(one_chip, 2000, 63, 8)
+    assert route_tile_rows(8, o["fp"]) == 1024
+    _compiles(functools.partial(route_pass, **o["kw"]),
+              o["bins"], o["leaf"], None, o["tbl"])
+    with pytest.raises(Exception, match="vmem"):
+        _compiles(functools.partial(route_pass, **o["kw"]),
+                  o["bins"], o["leaf"], o["W"], o["tbl"])
