@@ -183,8 +183,8 @@ def _run(X, y, n_iters: int):
             g.drain_pending()
         jax.block_until_ready(g.scores)
 
-    booster.update()  # warmup: compile + first tree
-    booster.update()  # second iter compiles the epilogue CONT step
+    booster.update()  # warmup: compile + first tree (the one fast step;
+    # later iterations compile nothing)
     settle()
     t0 = time.perf_counter()
     for _ in range(n_iters):

@@ -7,9 +7,9 @@ hardware — 10.5M rows x 28 features, max_bin=63, num_leaves=255,
 learning_rate=0.1, binary objective, plus a 500K-row validation set with
 metric=auc (BASELINE.md GPU-benchmark row; data generated from a seed):
 
-  kernels  the four Pallas kernels (level_pass, route_pass,
-           epilogue_pass, table_lookup) COMPILED at the Higgs layout and
-           compared with the numpy oracle of tests/test_fused_level.py;
+  kernels  the three Pallas kernels (level_pass, route_pass,
+           table_lookup) COMPILED at the Higgs layout and compared with
+           the numpy oracle of tests/test_fused_level.py;
   train    lgb.train, 64 iterations = two megastep dispatches of 32,
            every key but telemetry_out at its default (tpu_engine=auto
            must resolve to the compiled fused engine by itself);
@@ -182,15 +182,15 @@ def _planes(hist, c, Sp):
 
 
 def check_kernels(interpret: bool = False) -> None:
-    """Each of the four kernels, compiled, against numpy at the
+    """Each of the three kernels, compiled, against numpy at the
     tolerances tests/test_fused_level.py uses. A kernel that compiles
     and computes something else is the failure interpret mode cannot
     show."""
     import jax.numpy as jnp
 
-    from lightgbm_tpu.ops.fused_level import (NCH_PRECISE, epilogue_pass,
-                                              level_pass, pack_gh,
-                                              route_pass, table_lookup)
+    from lightgbm_tpu.ops.fused_level import (NCH_PRECISE, level_pass,
+                                              pack_gh, route_pass,
+                                              table_lookup)
     sys.path.insert(0, os.path.join(HERE, "tests"))
     from test_fused_level import _oracle
 
@@ -219,44 +219,10 @@ def check_kernels(interpret: bool = False) -> None:
         say(f"kernels: level_pass + route_pass Sp={Sp} match numpy in "
             "both routing forms")
 
-    # epilogue on the Sp=128 tables: final route -> score update ->
-    # binary gradients -> hi/lo pack -> next tree's root histogram
     rng = np.random.RandomState(1)
     L = PARAMS["num_leaves"]
     rows = c["rows"]
     lv = (0.1 * rng.randn(L)).astype(np.float32)
-    score = rng.randn(rows).astype(np.float32)
-    label = np.where(rng.rand(rows) < 0.5, 1.0, -1.0).astype(np.float32)
-    lw = np.ones(rows, np.float32)
-    bag = (rng.rand(rows) < 0.8).astype(np.float32)
-    ops_T = np.zeros((8, rows), np.float32)
-    ops_T[0], ops_T[1] = label, lw
-    hist, new_score, gh_out = epilogue_pass(
-        c["bins_T"], c["leaf_T"], c["W"], c["tbl"], jnp.asarray(lv),
-        jnp.asarray(score[None, :]), jnp.asarray(ops_T),
-        jnp.asarray(bag[None, :]), num_bins=c["Bp"], f_oh=c["F_oh"],
-        nch=NCH_PRECISE, kind="binary", sigmoid=1.0, interpret=interpret)
-    score2 = score + lv[want_leaf]
-    resp = -label / (1.0 + np.exp(label * score2))
-    g = (resp * lw * bag).astype(np.float32)
-    h = (np.abs(resp) * (1.0 - np.abs(resp)) * lw * bag).astype(np.float32)
-    root = [(0, 0, c["Bp"] - 1, True, 0, 1)] + [(-2, 0, 0, False, 0, 0)] * 7
-    want_root, _ = _oracle(c["bins"], np.zeros(rows, np.int32), g, h, bag,
-                           root, c["meta"], c["F"], c["Bp"])
-    np.testing.assert_allclose(np.asarray(new_score)[0], score2, rtol=1e-6,
-                               atol=1e-6, err_msg="epilogue score")
-    gh_out = np.asarray(gh_out.astype(jnp.float32))
-    np.testing.assert_allclose(gh_out[0] + gh_out[1], g, rtol=1e-4,
-                               atol=1e-6, err_msg="epilogue grad pack")
-    np.testing.assert_allclose(gh_out[2] + gh_out[3], h, rtol=1e-4,
-                               atol=1e-6, err_msg="epilogue hess pack")
-    np.testing.assert_array_equal(gh_out[4], bag,
-                                  err_msg="epilogue bag channel")
-    np.testing.assert_allclose(_planes(hist, c, 8)[0], want_root[0],
-                               rtol=1e-4, atol=1e-4,
-                               err_msg="epilogue root histogram")
-    say("kernels: epilogue_pass matches numpy")
-
     idx = rng.randint(-1, L, size=rows).astype(np.int32)
     out = table_lookup(jnp.asarray(idx[None, :]), jnp.asarray(lv),
                        interpret=interpret)

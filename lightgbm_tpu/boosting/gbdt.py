@@ -241,11 +241,6 @@ class GBDT:
         self._batch_t0 = None
         self._batch_w0 = None
         self._batch_fused = 0
-        # fused-epilogue state (see _use_epilogue)
-        self._epi_ok_cache = None
-        self._epi_fns = None
-        self._epi_carry = None
-        self._epi_ops = None
         # histogram-plane cuts (ROADMAP item 4): quantized gradient
         # histograms, adaptive per-feature bins, EMA-FS gain screening
         self.quant_bits = 0
@@ -344,8 +339,7 @@ class GBDT:
         self._setup_cegb(config)
         self._setup_forced_splits(config, train_data)
         self._setup_bundles(config, train_data)
-        # NOTE: computed before _setup_engine so the frontier-v1 fallback
-        # sees them
+        # NOTE: computed before _setup_engine, which reads them
         ic = config.interaction_constraints
         bynode = float(config.feature_fraction_bynode)
         self.use_node_masks = bool(ic) or (0.0 < bynode < 1.0)
@@ -1820,7 +1814,7 @@ class GBDT:
             grow = (grow_tree_leafwise if self.grow_policy == "leafwise"
                     and mode in ("data", "voting")
                     else grow_tree_depthwise)
-            hist_impl = self._xla_hist_impl()
+            hist_impl = self.config.tpu_histogram_impl
             use_nm = self.use_node_masks
             use_cegb = self.use_cegb
             ub = getattr(self, "use_bundles", False)
@@ -1998,11 +1992,6 @@ class GBDT:
         self._megastep_fm = {}
         self._fast_fm_pads = None
         self._par_fns = {}            # parallel growers close over params
-        self._epi_ok_cache = None     # epilogue closes over params too
-        self._epi_fns = None
-        self._epi_carry = None
-        self._epi_fm_pad = None
-        self._epi_bag_ones = None
         self._valid_upd_fns = None    # close over shrinkage/depth bound
         self._valid_routes = {}       # path and layout are the engine's
         self._coll_per_iter = None    # re-measured on the fresh traces
@@ -2010,37 +1999,9 @@ class GBDT:
         engine = config.tpu_engine
         if engine == "auto":
             engine = "fused" if self.on_tpu else "xla"
-        # the fused engine composes with every distribution mode since
-        # round 5 (ref: tree_learner.cpp:17-49 — the reference
-        # instantiates its device learner under data/voting/feature
-        # distribution too); only the frontier-v1 engine lacks a
-        # multi-chip path
-        if getattr(self, "mp", None) is not None \
-                and engine not in ("xla", "fused"):
-            # the mp row layout was aligned for fused only when the
-            # CONFIG requested fused/auto-tpu; a late engine swap to
-            # fused would trip the Rp/Np alignment guard
-            log.info("multi-process training runs on the XLA or fused "
-                     "engines; using xla")
-            self.telemetry.degrade("engine_multiproc_needs_xla_or_fused",
-                                   requested=config.tpu_engine, to="xla")
-            self._report_eviction("engine:multiproc_needs_xla_or_fused",
-                                  requested=str(config.tpu_engine))
-            engine = "xla"
-        if self.parallel_mode in ("voting", "feature") \
-                and engine not in ("xla", "fused"):
-            log.info("tree_learner=%s runs on the XLA or fused engines",
-                     self.parallel_mode)
-            self.telemetry.degrade("engine_parallel_needs_xla_or_fused",
-                                   requested=config.tpu_engine, to="xla",
-                                   mode=self.parallel_mode)
-            engine = "xla"
-        if self.parallel_mode == "data" and engine == "frontier":
-            log.info("the frontier-v1 engine has no multi-chip path; "
-                     "using the fused engine")
-            self.telemetry.degrade("frontier_no_multichip",
-                                   requested="frontier", to="fused")
-            engine = "fused"
+        # both engines compose with every distribution mode (ref:
+        # tree_learner.cpp:17-49 — the reference instantiates its device
+        # learner under data/voting/feature distribution too)
         # intermediate/advanced monotone modes need the stale-leaf
         # recompute, implemented on the leaf-wise grower (the reference
         # implements them in SerialTreeLearner too,
@@ -2058,12 +2019,6 @@ class GBDT:
             self.telemetry.degrade("forced_splits_need_xla",
                                    requested=engine, to="xla")
             engine = "xla"
-        if getattr(self, "use_bundles", False) and engine == "frontier":
-            log.info("feature bundling is not wired into the frontier-v1 "
-                     "engine; using the fused engine")
-            self.telemetry.degrade("frontier_no_bundling",
-                                   requested="frontier", to="fused")
-            engine = "fused"
         if getattr(self, "use_cegb", False) and engine != "xla":
             # CEGB gain deltas are wired into the depthwise XLA grower;
             # must override BEFORE the engine flags are derived
@@ -2074,21 +2029,7 @@ class GBDT:
             engine = "xla"
         self.use_fused = engine == "fused"
         self.fused_interpret = self.use_fused and not self.on_tpu
-        self.use_frontier = (engine == "frontier" and self.on_tpu
-                             and config.tpu_histogram_impl
-                             in ("auto", "pallas"))
-        needs_v2 = (self.has_cat or getattr(self, "use_mono_bounds", False)
-                    or getattr(self, "use_node_masks", False))
-        if self.use_frontier and needs_v2:
-            log.warning("tpu_engine=frontier supports neither categorical "
-                        "features, monotone bounds, nor interaction/bynode "
-                        "constraints; using the fused engine")
-            self.telemetry.degrade("frontier_missing_features",
-                                   requested="frontier", to="fused")
-            self.use_frontier = False
-            self.use_fused = True
-            self.fused_interpret = not self.on_tpu
-        default_policy = ("depthwise" if (self.use_fused or self.use_frontier
+        default_policy = ("depthwise" if (self.use_fused
                                           or getattr(self, "use_cegb", False))
                           else "leafwise")
         self.grow_policy = {"auto": default_policy}.get(config.grow_policy,
@@ -2156,7 +2097,7 @@ class GBDT:
             self.telemetry.degrade("forced_splits_disable_cegb")
             self.use_cegb = False
         if self.grow_policy != "depthwise":
-            self.use_fused = self.use_frontier = False
+            self.use_fused = False
         # ---- histogram-plane cuts (ROADMAP item 4). Each gates
         # independently; all three are fused-engine features — other
         # engines degrade with a structured event and train unchanged.
@@ -2212,8 +2153,6 @@ class GBDT:
                 from ..ops.quantize import QNCH
                 self.fused_nch = QNCH[self.quant_bits]
             self._publish_hist_gauges()
-        elif self.use_frontier and not hasattr(self, "bins_i32_dev"):
-            self._init_frontier(self.train_data)
 
     # ------------------------------------------------------------------
     def _mp_fused_bins_T(self, local_rows_np: np.ndarray, Fp: int,
@@ -2371,32 +2310,6 @@ class GBDT:
                                       jnp.asarray(ic))
 
     # ------------------------------------------------------------------
-    def _init_frontier(self, train_data: TpuDataset) -> None:
-        """Feature-padded int32 row-major + transposed bin matrices for the
-        Pallas kernel and column-load routing (models/frontier.py)."""
-        from ..ops.pallas_histogram import pad_feature_layout
-        F = train_data.num_features
-        Fp, Bp = pad_feature_layout(F, self.max_bins)
-        self.frontier_Fp = Fp
-        self.frontier_Bp = Bp
-        bins = np.asarray(train_data.bins)
-        bins_i32 = np.zeros((self.num_data, Fp), np.int32)
-        bins_i32[:, :F] = bins
-        self.bins_i32_dev = jnp.asarray(bins_i32)
-        self.bins_T_dev = jnp.asarray(bins_i32.T.copy())
-        # padded feature meta: pad features are trivial and never selected
-        nb = np.full(Fp, 2, np.int32)
-        nb[:F] = np.asarray(self.meta.num_bin)
-        mt = np.zeros(Fp, np.int32)
-        mt[:F] = np.asarray(self.meta.missing_type)
-        db = np.zeros(Fp, np.int32)
-        db[:F] = np.asarray(self.meta.default_bin)
-        mono = np.zeros(Fp, np.int32)
-        mono[:F] = np.asarray(self.meta.monotone)
-        self.frontier_meta = FeatureMeta(jnp.asarray(nb), jnp.asarray(mt),
-                                         jnp.asarray(db), jnp.asarray(mono))
-
-    # ------------------------------------------------------------------
     def add_valid_data(self, valid_data: TpuDataset, name: str,
                        metrics: Sequence) -> None:
         """(ref: gbdt.cpp AddValidDataset)"""
@@ -2413,10 +2326,9 @@ class GBDT:
         self.drain_pending()          # replay below needs the full model
         self._fast_ok_cache = None    # (valid sets ride the fast path now)
         self._megastep_fns = {}       # valid-set count is baked into the
-        self._epi_ok_cache = None     # megastep signature
-        self._epi_carry = None
-        self._fast_step_fn = None     # the per-iteration steps keep the
-        self._epi_fns = None          # route log a new set may ask for
+        # megastep signature; the per-iteration step keeps the route log
+        # a new set may ask for
+        self._fast_step_fn = None
         if self._eval_consumer is not None:
             # the traced eval plan enumerated the old valid-set list; a
             # new set mid-run invalidates it (cannot happen through
@@ -2495,11 +2407,10 @@ class GBDT:
     def _bag_mask_for(self, it: int):
         """In-bag mask effective at iteration ``it``. Rounds fire at
         iterations where it % bagging_freq == 0 and are drawn strictly in
-        stream order, cached by firing iteration (two most recent kept) —
-        the fused-epilogue fast path legitimately asks ONE round ahead
-        (the epilogue computes the NEXT iteration's gradients and root
-        histogram, so it needs the next round's weights early; the draw
-        order, and hence reference parity, is unchanged)."""
+        stream order, cached by firing iteration. The two most recent
+        rounds are kept because the checkpoint format stores them
+        (resilience/state.py; rollback_one_iter re-applies the previous
+        round's mask)."""
         cfg = self.config
         fire = (it // cfg.bagging_freq) * cfg.bagging_freq
         cache = getattr(self, "_bag_round_cache", None)
@@ -2552,36 +2463,11 @@ class GBDT:
         log.debug("Re-bagging, using %d data to train", self.bag_cnt)
         return grad, hess
 
-    def _bag_weight_for_iter(self, it: int):
-        """[n] f32 in-bag weights effective at iteration ``it`` (lookahead
-        helper for the fused epilogue; does not touch the live
-        bag_weight/bag_cnt bookkeeping)."""
-        cfg = self.config
-        if not self.is_bagging or cfg.bagging_freq <= 0:
-            return jnp.ones((self.num_data,), jnp.float32)
-        mask = self._bag_mask_for(it)
-        return jnp.asarray(mask.astype(np.float32))
-
     # ------------------------------------------------------------------
     def _make_fused_step(self):
         """One jit-compiled dispatch per tree: bagging fold-in + growth.
         Eager per-op dispatch latency dominates otherwise (each jnp op is a
         separate dispatch)."""
-        if self.use_frontier:
-            from ..models.frontier import grow_tree_frontier
-            Fp = self.frontier_Fp
-
-            @jax.jit
-            def step(grad_row, hess_row, bag_weight, fm_pad):
-                gh = jnp.stack([grad_row * bag_weight,
-                                hess_row * bag_weight, bag_weight], axis=1)
-                return grow_tree_frontier(
-                    self.bins_i32_dev, self.bins_T_dev, gh,
-                    self.frontier_meta, fm_pad, self.params,
-                    self.max_leaves, self.frontier_Bp,
-                    int(self.config.max_depth), hist_impl="pallas")
-            return step
-
         grow = (grow_tree_depthwise if self.grow_policy == "depthwise"
                 else grow_tree_leafwise)
 
@@ -2592,30 +2478,17 @@ class GBDT:
             return grow(self.bins_dev, gh, self.meta, fm, self.params,
                         self.max_leaves, self.max_bins,
                         int(self.config.max_depth),
-                        hist_impl=self._xla_hist_impl())
+                        hist_impl=self.config.tpu_histogram_impl)
         return step
 
     def _fused_step(self, grad_row, hess_row):
         if getattr(self, "_fused_step_fn", None) is None:
             self._fused_step_fn = self._make_fused_step()
             self._score_add_fn = self._make_score_add()
-        fm = self._feature_mask()
-        if self.use_frontier:
-            Fp = self.frontier_Fp
-            fm = jnp.zeros((Fp,), bool).at[:fm.shape[0]].set(fm)
-        return self._fused_step_fn(grad_row, hess_row, self.bag_weight, fm)
+        return self._fused_step_fn(grad_row, hess_row, self.bag_weight,
+                                   self._feature_mask())
 
     def _make_score_add(self):
-        L = self.max_leaves
-        if self.use_frontier:
-            from ..models.frontier import leaf_value_lookup
-
-            @jax.jit
-            def add(scores, tid, leaf_value, row_leaf):
-                return scores.at[tid].add(
-                    leaf_value_lookup(leaf_value, row_leaf, L))
-            return add
-
         @jax.jit
         def add(scores, tid, leaf_value, row_leaf):
             return scores.at[tid].add(leaf_value[row_leaf])
@@ -2666,15 +2539,6 @@ class GBDT:
                 mask_onehot=self._mask_onehot(), gh_scales=scales)
             self._note_tree_gains(tree)
             return tree, row_leaf[:n]
-        if self.use_frontier:
-            from ..models.frontier import grow_tree_frontier
-            Fp = self.frontier_Fp
-            fm_pad = jnp.zeros((Fp,), bool).at[:fm.shape[0]].set(fm)
-            return grow_tree_frontier(
-                self.bins_i32_dev, self.bins_T_dev, gh,
-                self.frontier_meta, fm_pad, self.params,
-                self.max_leaves, self.frontier_Bp,
-                int(self.config.max_depth), hist_impl="pallas")
         if self.grow_policy == "depthwise":
             ub = getattr(self, "use_bundles", False)
             lazy = getattr(self, "use_cegb_lazy", False)
@@ -2683,7 +2547,8 @@ class GBDT:
                 self.meta, fm, self.params,
                 self.max_leaves, self.max_bins,
                 int(self.config.max_depth),
-                hist_impl=self._xla_hist_impl(), has_cat=self.has_cat,
+                hist_impl=self.config.tpu_histogram_impl,
+                has_cat=self.has_cat,
                 use_mono_bounds=self.use_mono_bounds,
                 use_node_masks=self.use_node_masks,
                 node_masks=self._node_masks_for_iter(),
@@ -2708,7 +2573,8 @@ class GBDT:
             self.bundle_bins_dev if ub else self.bins_dev, gh,
             self.meta, fm, self.params,
             self.max_leaves, self.max_bins, int(self.config.max_depth),
-            hist_impl=self._xla_hist_impl(), has_cat=self.has_cat,
+            hist_impl=self.config.tpu_histogram_impl,
+            has_cat=self.has_cat,
             use_mono_bounds=self.use_mono_bounds,
             use_node_masks=self.use_node_masks,
             node_masks=self._node_masks_for_iter(),
@@ -2745,10 +2611,6 @@ class GBDT:
             .at[:, :F].set(nm.group_feat)
         gwf = jnp.zeros((F_oh,), jnp.int32).at[:F].set(nm.groups_with_f)
         return NodeMaskCfg(gf, gwf, nm.bynode_k, nm.key)
-
-    def _xla_hist_impl(self) -> str:
-        impl = self.config.tpu_histogram_impl
-        return "auto" if impl in ("auto", "pallas") else impl
 
     def _feature_mask(self):
         """Per-tree column sampling (ref: col_sampler.hpp:20)."""
@@ -3358,16 +3220,15 @@ class GBDT:
                       **({"reason": route[1]} if route[1] else {}))
         return route
 
-    def _route_form(self, defer_final_route: bool = False) -> None:
+    def _route_form(self) -> None:
         """Say the routing form of a step that grows trees on the fused
         engine (models/frontier2.route_form decides; this only tells):
         counter ``route.form_<form>`` and a ``route_form`` event, once
         per run and (form, reason). Called where each such step is
-        built, since one of the reasons is the step's own (the epilogue
-        step defers its final route)."""
+        built."""
         from ..models.frontier2 import route_form
         said = route_form(
-            self.has_cat, self.fused_bundle_cols, defer_final_route,
+            self.has_cat, self.fused_bundle_cols,
             self.fused_bundle_col_bins if self.fused_bundle_cols
             else self.fused_Bp)
         tel = self.telemetry
@@ -3495,8 +3356,8 @@ class GBDT:
         TreeArrays — no HostTree materialisation, no per-iteration sync
         (ref: gbdt.cpp:493 UpdateScore over valid ScoreUpdaters).
         ``logs``: the trees' route logs, which the pipelined fast step
-        and the epilogue step keep when some set is routed by the
-        kernels (_wants_route_log)."""
+        keeps when some set is routed by the kernels
+        (_wants_route_log)."""
         if not self.valid_scores:
             return
         if not getattr(self, "_valid_upd_fns", None):
@@ -3712,152 +3573,6 @@ class GBDT:
             return scores, stacked, logs, ema
         return jax.jit(step_ext, donate_argnums=_donate(1))
 
-    # ------------------------------------------------------------------
-    # Fused boosting epilogue (ops/fused_level.epilogue_pass): the final
-    # route + score update + gradients + next ROOT histogram run as ONE
-    # streaming kernel, removing two full level passes plus the lookup and
-    # gradient streams from every iteration (the host loop being fused:
-    # ref gbdt.cpp:371 TrainOneIter's UpdateScore -> GetGradients -> next
-    # BeforeTrain). State carried on device between iterations:
-    # (padded score row, next root histogram, next packed gh block).
-    def _use_epilogue(self) -> bool:
-        if self._epi_ok_cache is None:
-            spec = (self.objective.epilogue_spec()
-                    if self.objective is not None else None)
-            # the histogram-plane cuts bypass the fused epilogue: its
-            # kernel computes gradients/root histogram on the padded f32
-            # layout, and screening's per-tree mask must reach the NEXT
-            # tree's root build (docs/Performance.md eligibility matrix)
-            self._epi_ok_cache = bool(
-                spec is not None
-                and bool(self.config.tpu_fused_epilogue)
-                and self.num_tree_per_iteration == 1
-                and self.parallel_mode == "serial"
-                and not self.quant_bits
-                and not self.use_adaptive_bins
-                and not self.use_screening)
-        return self._epi_ok_cache
-
-    def _make_epi_fns(self):
-        from ..models.frontier2 import grow_tree_fused
-        from ..ops.fused_level import epilogue_pass, pack_gh
-        kind, (op0, op1), sig = self.objective.epilogue_spec()
-        n = self.num_data
-        Rp = self.fused_Rp
-        pad = Rp - n
-        nch = self.fused_nch
-        shrink = jnp.float32(self.shrinkage_rate)
-        max_depth = int(self.config.max_depth)
-        extra = int(self.config.tpu_extra_levels)
-        interp = self.fused_interpret
-        kF = self.fused_bundle_cols or self.fused_f_oh
-        kB = (self.fused_bundle_col_bins if self.fused_bundle_cols
-              else self.fused_Bp)
-        # operand rows padded once; zero padding makes padded-row
-        # gradients vanish under both closed forms
-        self._epi_ops = jnp.zeros((8, Rp), jnp.float32) \
-            .at[0, :n].set(op0).at[1, :n].set(op1)
-        route_log = self._wants_route_log()
-        self._route_form(defer_final_route=True)
-
-        def in_jit_grads(score_pad, ops_T):
-            # the objective's own traced closed form; padded rows carry
-            # zero operands and so produce zero gradients under both
-            # kinds (the Pallas kernel copy in _epilogue_kernel is the
-            # only unavoidable duplicate of these formulas)
-            g, h = self.objective.gradients_from(
-                score_pad[None, :], (ops_T[0], ops_T[1]))
-            return g[0], h[0]
-
-        def grow(bins_T, gh_T, fm_pad, hist0):
-            return grow_tree_fused(
-                bins_T, gh_T, self.fused_meta, fm_pad, self.params,
-                self.max_leaves, self.fused_Bp, self.fused_f_oh,
-                num_rows=n, nch=nch, max_depth=max_depth,
-                extra_levels=extra, has_cat=self.has_cat,
-                use_mono_bounds=self.use_mono_bounds,
-                bundle_cols=self.fused_bundle_cols,
-                bundle_col_bins=self.fused_bundle_col_bins,
-                bundle_cfg=self.fused_bundle_cfg, interpret=interp,
-                root_hist=hist0, defer_final_route=True,
-                mono_mode=getattr(self, "mono_mode", "basic"),
-                route_log=route_log)
-
-        def epilogue(bins_T, leafT, W_l, tbl_l, tree, score_pad, ops_T,
-                     bag_next):
-            lv = jnp.where(tree.num_leaves > 1,
-                           tree.leaf_value * shrink, 0.0)
-            hist0, score2, ghT = epilogue_pass(
-                bins_T, leafT[None, :], W_l, tbl_l, lv,
-                score_pad[None, :], ops_T, bag_next[None, :],
-                num_bins=kB, f_oh=kF, nch=nch, kind=kind,
-                sigmoid=float(sig), interpret=interp)
-            return score2[0], hist0, ghT
-
-        def prime(bins_T, score_pad, ops_T, bag_cur, bag_next, fm_pad):
-            g, h = in_jit_grads(score_pad, ops_T)
-            gh_T = pack_gh(g * bag_cur, h * bag_cur, bag_cur, nch)
-            tree, leafT, W_l, tbl_l, *log = grow(bins_T, gh_T, fm_pad, None)
-            score2, hist0, ghT = epilogue(bins_T, leafT, W_l, tbl_l, tree,
-                                          score_pad, ops_T, bag_next)
-            return score2, hist0, ghT, tree, tuple(log) or None
-
-        def cont(bins_T, score_pad, hist0, gh_T, ops_T, bag_next, fm_pad):
-            tree, leafT, W_l, tbl_l, *log = grow(bins_T, gh_T, fm_pad,
-                                                 hist0)
-            score2, hist0n, ghT_n = epilogue(bins_T, leafT, W_l, tbl_l,
-                                             tree, score_pad, ops_T,
-                                             bag_next)
-            return score2, hist0n, ghT_n, tree, tuple(log) or None
-        # the (score, root-hist, packed-gh) carry buffers die at each
-        # call — donate them so the iteration carry updates in place
-        # (self.scores is a separate sliced buffer, never the donated
-        # operand; _epi_ops persists across iterations and is NOT donated)
-        return (jax.jit(prime, donate_argnums=_donate(1)),
-                jax.jit(cont, donate_argnums=_donate(1, 2, 3)))
-
-    def _epi_iter_body(self):
-        n = self.num_data
-        Rp = self.fused_Rp
-        init_scores = [self._boost_from_average(0, True)]
-        self._bagging(self.iter, None, None)   # live bookkeeping, iter t
-        if self._epi_fns is None:
-            self._epi_fns = self._make_epi_fns()
-        prime, cont = self._epi_fns
-        F_oh = self.fused_f_oh
-        if float(self.config.feature_fraction) >= 1.0:
-            # cached: no per-iteration eager dispatches
-            if getattr(self, "_epi_fm_pad", None) is None:
-                self._epi_fm_pad = jnp.ones((F_oh,), bool) \
-                    .at[self.train_data.num_features:].set(False)
-            fm_pad = self._epi_fm_pad
-        else:
-            fm_pad = jnp.zeros((F_oh,), bool) \
-                .at[:self.train_data.num_features].set(self._feature_mask())
-        if not self.is_bagging:
-            if getattr(self, "_epi_bag_ones", None) is None:
-                self._epi_bag_ones = jnp.zeros((Rp,), jnp.float32) \
-                    .at[:n].set(1.0)
-            bag_next = self._epi_bag_ones
-        else:
-            bag_next = jnp.pad(self._bag_weight_for_iter(self.iter + 1),
-                               (0, Rp - n))
-        self.telemetry.inc("train.dispatches")
-        if self._epi_carry is None:
-            score_pad = jnp.pad(self.scores[0], (0, Rp - n))
-            bag_cur = jnp.pad(self.bag_weight, (0, Rp - n))
-            out = prime(self.fused_bins_T, score_pad, self._epi_ops,
-                        bag_cur, bag_next, fm_pad)
-        else:
-            score_pad, hist0, gh_T = self._epi_carry
-            out = cont(self.fused_bins_T, score_pad, hist0, gh_T,
-                       self._epi_ops, bag_next, fm_pad)
-        score2, hist0n, ghT_n, tree, logs = out
-        self._epi_carry = (score2, hist0n, ghT_n)
-        self.scores = score2[None, :n]
-        trees = jax.tree_util.tree_map(lambda x: jnp.stack([x]), tree)
-        return self._finish_fast_iter(trees, init_scores, logs)
-
     def _train_one_iter_fast(self) -> bool:
         tel = self.telemetry
         # iteration granularity: the fast path stays (one jit dispatch),
@@ -3869,10 +3584,7 @@ class GBDT:
             w0 = tel.wall_now()
             t0 = time.perf_counter()
         with timer.section("GBDT::TrainOneIterFast"):
-            if self._use_epilogue():
-                stop = self._epi_iter_body()
-            else:
-                stop = self._fast_iter_body()
+            stop = self._fast_iter_body()
         if per_iter:
             jax.block_until_ready(self.scores)
             dt = time.perf_counter() - t0
@@ -3964,9 +3676,8 @@ class GBDT:
         return self._finish_fast_iter(trees, init_scores, logs)
 
     def _finish_fast_iter(self, trees, init_scores, logs=None):
-        """Pipelining tail shared by the fast and epilogue iteration
-        bodies: async host copies, in-jit valid updates, pending append,
-        batch-drain signalling."""
+        """Pipelining tail of the fast iteration body: async host copies,
+        in-jit valid updates, pending append, batch-drain signalling."""
         for leaf in jax.tree_util.tree_leaves(trees):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
@@ -4118,7 +3829,6 @@ class GBDT:
                 # live scores (bin-space routing is training-identical, so
                 # each subtraction reverses the training add up to f32
                 # rounding)
-                self._epi_carry = None
                 scores = self.scores
                 # replay bins: the replicated copy single-process, the
                 # row-sharded global matrix under multi-process (the
@@ -4688,10 +4398,6 @@ class GBDT:
                                 iteration=self.iter)
         self.scores = scores
         self.valid_scores = list(vscores)
-        # the fused-epilogue carry (score_pad, hist0, gh_T) captured
-        # score state from before this chunk; a later epilogue iteration
-        # must re-prime from the advanced scores, not resume stale state
-        self._epi_carry = None
         for leaf in jax.tree_util.tree_leaves(trees_B):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
@@ -4921,7 +4627,6 @@ class GBDT:
             return self._sync_iter_body(gradients, hessians)
 
     def _sync_iter_body(self, gradients, hessians) -> bool:
-        self._epi_carry = None   # sync iterations mutate scores directly
         k, n = self.num_tree_per_iteration, self.num_data
         tel = self.telemetry
         it = self.iter
@@ -5055,11 +4760,6 @@ class GBDT:
                         delta = table_lookup(
                             row_leaf[None, :], lv_dev,
                             interpret=self.fused_interpret)[0]
-                    elif self.use_frontier:
-                        # per-row gathers are slow on TPU; where-chain
-                        from ..models.frontier import leaf_value_lookup
-                        delta = leaf_value_lookup(lv_dev, row_leaf,
-                                                  self.max_leaves)
                     else:
                         delta = lv_dev[row_leaf]
                     self.scores = self.scores.at[tid].add(delta)
@@ -5206,8 +4906,7 @@ class GBDT:
                                        cnt, nbytes)
         extra = {"num_leaves": nl_per_class,
                  "bag_cnt": int(self.bag_cnt),
-                 "engine": ("fused" if self.use_fused else
-                            "frontier" if self.use_frontier else "xla"),
+                 "engine": "fused" if self.use_fused else "xla",
                  "mode": self.parallel_mode}
         if gain_acc is not None:
             # the key is always present so count == 0 (no finite gains
@@ -5285,11 +4984,9 @@ class GBDT:
         bin matrix (bins_par) — per-row routing partitions cleanly over
         the mesh, so the same in-jit replay works rank-sharded."""
         self.drain_pending()
-        self._epi_carry = None   # score subtraction invalidates the carry
         # _bag_round_cache is RETAINED: entries are keyed by firing
         # iteration and stay valid, so a rollback within the cache's
-        # two-round window replays the exact round it used before —
-        # covering the fused epilogue's one-round lookahead (ADVICE r3).
+        # two-round window replays the exact round it used before.
         # Deeper rollbacks fall off the eviction window and draw the
         # next stream round on retrain, which is also what the reference
         # does at ANY depth (gbdt.cpp:456+230 never rewinds the RNG) —
@@ -5599,7 +5296,6 @@ class GBDT:
         # live device scores must match the refitted model for subsequent
         # training/eval
         self.scores = jnp.asarray(scores, jnp.float32)
-        self._epi_carry = None
 
 
 class DART(GBDT):

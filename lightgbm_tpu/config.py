@@ -277,11 +277,12 @@ _PARAMS: List[_Param] = [
        desc="auto, leafwise (exact LightGBM semantics), depthwise "
             "(frontier-batched, fastest on TPU)"),
     _p("tpu_histogram_impl", str, "auto",
-       desc="auto, segment (XLA segment-sum), onehot (one-hot matmul), "
-            "pallas (Pallas kernel)"),
+       desc="histogram build of the XLA growers: auto, segment (XLA "
+            "segment-sum), onehot (one-hot matmul)"),
     _p("tpu_engine", str, "auto",
-       desc="auto, fused (fused route+histogram level kernel, fastest), "
-            "frontier (round-1 Pallas path), xla (no Pallas)"),
+       desc="auto (fused on a TPU, xla elsewhere), fused (fused "
+            "route+histogram level kernels), xla (no Pallas: the "
+            "reference growers)"),
     _p("tpu_hist_precision", str, "bf16x2",
        desc="histogram input precision: bf16x2 (hi/lo split, fp32-grade, "
             "default) or bf16 (fastest)"),
@@ -351,10 +352,6 @@ _PARAMS: List[_Param] = [
             "batches); off = synchronous per-iteration host bookkeeping "
             "— bit-comparable across engines/modes, used by debugging "
             "and A/B tests"),
-    _p("tpu_fused_epilogue", bool, True,
-       desc="fuse final-level routing + score update + gradients + next "
-            "root histogram into one kernel pass on the pipelined fast "
-            "path (objectives with a kernel closed form: binary, l2)"),
     _p("tpu_megastep", bool, True,
        desc="chain up to tpu_megastep_iters boosting iterations inside "
             "ONE jit (lax.scan over the fused tree-growing step; "
@@ -816,6 +813,12 @@ class Config:
                     "data_parallel": "data", "voting": "voting",
                     "voting_parallel": "voting"}
         self._values["tree_learner"] = tl_alias.get(tl, tl)
+        for key, accepted in (("tpu_engine", ("auto", "fused", "xla")),
+                              ("tpu_histogram_impl",
+                               ("auto", "segment", "onehot"))):
+            if self._values[key] not in accepted:
+                log.fatal("%s=%s is not supported; accepted values: %s",
+                          key, self._values[key], ", ".join(accepted))
         self.is_parallel = self._values["tree_learner"] != "serial"
         self.is_data_based_parallel = self._values["tree_learner"] in ("data", "voting")
         if self._values["verbosity"] < 0:

@@ -1,12 +1,12 @@
-"""Frontier grower v2 — fused route+histogram level passes.
+"""Frontier grower — fused route+histogram level passes.
 
-Round-2 replacement for models/frontier.py on the TPU path. One
+The fused engine's tree grower. One
 ``ops/fused_level.level_pass`` kernel invocation per tree level does the
 routing AND the smaller-child histograms in a single streaming pass over
 the binned matrix; everything else per level is small-tensor XLA glue:
 
-- per-level slot counts are EXACT (1, 2, 4, ... capped at 128) instead of
-  round 1's uniform 64 — histogram flops track the real frontier width;
+- per-level slot counts are EXACT (1, 2, 4, ... capped at 128), so
+  histogram flops track the real frontier width;
 - split finding runs on the 2*S new children only, updating a cached
   per-leaf best-split table, instead of rescanning all ``num_leaves``
   slots every level (ref: serial_tree_learner.cpp:379-453 only scans the
@@ -17,8 +17,8 @@ the binned matrix; everything else per level is small-tensor XLA glue:
   ~100 us of MXU time instead;
 - after the capped-pow2 main levels, ``extra_levels`` additional passes
   (64 slots each) let skewed trees keep splitting until the leaf budget
-  is spent — addressing the round-1 divergence from leaf-wise growth on
-  skewed data (trees stopped near depth log2(num_leaves)+1).
+  is spent (a level-capped schedule alone stops skewed trees near depth
+  log2(num_leaves)+1, short of leaf-wise growth).
 
 Reference semantics preserved: smaller-child histogramming + sibling
 subtraction (serial_tree_learner.cpp:283-323,423-425), leaf budget,
@@ -71,7 +71,7 @@ def level_caps(num_leaves: int, max_depth: int, extra_levels: int,
     return tuple(caps)
 
 
-def route_form(has_cat: bool, bundle_cols: int, defer_final_route: bool,
+def route_form(has_cat: bool, bundle_cols: int,
                num_bins: int) -> Tuple[str, str]:
     """(form, reason) of a grower's routing, from what is static about the
     job. THE place where the form is chosen: the grower asks here, and so
@@ -82,20 +82,16 @@ def route_form(has_cat: bool, bundle_cols: int, defer_final_route: bool,
     missing bin, default_left and the feature's row, no [Sp, FB] table is
     built, logged or multiplied. ``table``: ``W @ one_hot``
     (build_route_table*), kept where a split is not one comparison of one
-    stored value, or where another kernel consumes the table:
+    stored value:
 
     - ``categorical``: "left" is membership in a bin set;
     - ``bundled``: the stored value is an EFB bundle bin that decodes
       to the split feature's bin by its window (and may pass 256);
-    - ``deferred_final_route``: the epilogue kernel applies the last
-      level's tables (bare ``Booster.update``), and it routes by table;
     - ``wide_bins``: bin values over 255 are not exact in bfloat16."""
     if has_cat:
         return "table", "categorical"
     if bundle_cols > 0:
         return "table", "bundled"
-    if defer_final_route:
-        return "table", "deferred_final_route"
     if num_bins > 256:
         return "table", "wide_bins"
     return "bins", None
@@ -149,7 +145,7 @@ def _merge_best_many(best: BestSplit, idx: jax.Array, vals: BestSplit,
                      "nch", "max_depth", "extra_levels", "has_cat",
                      "use_mono_bounds", "use_node_masks", "interpret",
                      "bundle_cols", "bundle_col_bins", "psum_axis",
-                     "defer_final_route", "mono_mode", "parallel_mode",
+                     "mono_mode", "parallel_mode",
                      "top_k", "quant_bits", "packed", "mask_onehot",
                      "route_log"))
 def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
@@ -161,9 +157,7 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                     use_node_masks: bool = False, node_masks=None,
                     bundle_cols: int = 0, bundle_col_bins: int = 0,
                     bundle_cfg=None, interpret: bool = False,
-                    psum_axis: str = None, root_hist: jax.Array = None,
-                    defer_final_route: bool = False,
-                    mono_mode: str = "basic",
+                    psum_axis: str = None, mono_mode: str = "basic",
                     parallel_mode: str = "data", top_k: int = 0,
                     feature_shard_mask: jax.Array = None,
                     quant_bits: int = 0, packed=None,
@@ -228,29 +222,19 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
       top_k: voting-parallel vote width (2*top_k columns exchanged).
       feature_shard_mask: [f_oh] bool, this shard's owned columns
         (feature mode only).
-      root_hist: optional precomputed root histogram [FB, nch*8] in the
-        root-pass layout (slot 0 live) — produced by the previous
-        iteration's fused boosting epilogue (ops/fused_level.epilogue_pass)
-        so the root level_pass is skipped entirely.
-      defer_final_route: when True, the statically-last level pass records
-        its splits in the tree but does NOT route rows; the pass's route
-        tables are returned for the epilogue kernel to apply. The returned
-        row_leaf is then the PRE-final-route assignment.
       route_log: also return the tree's per-level route tables, the ones
         the training rows were routed with: (log_W [n_levels, Sp_max,
         kern_fb] bf16, log_tbl [n_levels, Sp_max, 128] int32), padded to
-        the widest level like the deferred tables; a level the runtime
-        ``cond`` skipped keeps its all-(-2) table. In the bins form
+        the widest level (an all-(-2) table routes nothing); a level the
+        runtime ``cond`` skipped keeps its all-(-2) table. In the bins form
         (:func:`route_form`) ``log_tbl`` holds the splits themselves and
-        ``log_W`` is None. The deferred terminal
-        level is logged like any other, so :func:`replay_route_log` over
+        ``log_W`` is None. :func:`replay_route_log` over
         any matrix in the training layout finds each row's leaf in the
         FINISHED tree (the validation sets' path to their leaves).
 
     Returns (TreeArrays, row_leaf [Rp] int32 — caller slices to R; padding
-    rows stay at -1). With defer_final_route:
-    (tree, row_leaf, W_last, tbl_last). With route_log the pair
-    (log_W, log_tbl) is appended as one last element.
+    rows stay at -1). With route_log the pair (log_W, log_tbl) is appended
+    as one last element.
     """
     Fp, Rp = bins_T.shape
     L = num_leaves
@@ -266,8 +250,7 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     caps = level_caps(L, max_depth, extra_levels,
                       slot_cap=max_slot_cap(k_foh * k_B, nch))
     kern_fb = packed.fb if packed is not None else k_foh * k_B
-    bins_form = route_form(has_cat, bundle_cols, defer_final_route,
-                           k_B)[0] == "bins"
+    bins_form = route_form(has_cat, bundle_cols, k_B)[0] == "bins"
 
     def _decode(hist, Sp_):
         """Kernel accumulator -> (g, h, c) f32 planes on the logical
@@ -303,27 +286,23 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
         pool_c = jnp.zeros((L, f_oh, B), jnp.float32)
 
         # ---------------- root pass: slot 0 collects the full-data histogram
-        # (every row goes "left" on slot 0: root_route_tables); skipped
-        # entirely when the previous iteration's epilogue already built it
+        # (every row goes "left" on slot 0: root_route_tables)
         Sp0 = 8
-        if root_hist is not None:
-            hist0 = root_hist
-        else:
-            # (the first kernel column's width is the first packed
-            # feature's slab under the adaptive layout)
-            W0, tbl0 = root_route_tables(
-                k_B, kern_fb, packed.widths[0] if packed is not None else k_B,
-                bins_form, Sp0)
-            hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
-                                  num_slots=Sp0,
-                                  num_bins=k_B, f_oh=k_foh, nch=nch,
-                                  interpret=interpret, quant_bits=quant_bits,
-                                  packed=packed)
-            # feature mode: rows are replicated, the local histogram IS the
-            # global one (a psum would multiply by the shard count); voting:
-            # the root is always a full exchange like the XLA growers
-            if psum_axis is not None and parallel_mode != "feature":
-                hist0 = record_psum(hist0, psum_axis)
+        # (the first kernel column's width is the first packed
+        # feature's slab under the adaptive layout)
+        W0, tbl0 = root_route_tables(
+            k_B, kern_fb, packed.widths[0] if packed is not None else k_B,
+            bins_form, Sp0)
+        hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
+                              num_slots=Sp0,
+                              num_bins=k_B, f_oh=k_foh, nch=nch,
+                              interpret=interpret, quant_bits=quant_bits,
+                              packed=packed)
+        # feature mode: rows are replicated, the local histogram IS the
+        # global one (a psum would multiply by the shard count); voting:
+        # the root is always a full exchange like the XLA growers
+        if psum_axis is not None and parallel_mode != "feature":
+            hist0 = record_psum(hist0, psum_axis)
         g0, h0, c0 = _decode(hist0, Sp0)
         if use_bundles:
             v = bundle_plane_views(jnp.stack([g0, h0, c0], axis=-1),
@@ -377,17 +356,6 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     lpn = jnp.full((L,), -1, jnp.int32)   # leaf -> parent node
     lil = jnp.zeros((L,), bool)           # leaf is left child of its parent
 
-    # deferred terminal-route tables. At most ONE route-only pass ever
-    # fires per tree (the pass that exhausts the leaf budget, or the
-    # statically-last pass): after it, no level can select splits again,
-    # so its routing can safely ride the epilogue kernel instead. Tables
-    # are padded to the widest level (an all-(-2) table routes nothing).
-    Sp_max = max([8] + [max(8, c) for c in caps])
-    def_W = None if bins_form \
-        else jnp.zeros((Sp_max, kern_fb), jnp.bfloat16)
-    def_tbl = jnp.zeros((Sp_max, 128), jnp.int32) \
-        .at[:, 0].set(-2)
-
     # per-(leaf, feature) global-validity pool: under voting only the
     # vote winners' columns hold GLOBAL sums; sibling subtraction and
     # later scans must not touch local-only columns (the XLA leaf-wise
@@ -397,19 +365,21 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
     # caller does not ask: the grower's trace is then what it always was)
     log = None
     if route_log:
+        # padded to the widest level; an all-(-2) table routes nothing
+        Sp_max = max([8, *caps])
         log = (None if bins_form
-               else jnp.zeros((len(caps),) + def_W.shape, def_W.dtype),
-               jnp.broadcast_to(def_tbl, (len(caps),) + def_tbl.shape))
+               else jnp.zeros((len(caps), Sp_max, kern_fb), jnp.bfloat16),
+               jnp.zeros((len(caps), Sp_max, 128), jnp.int32)
+               .at[:, :, 0].set(-2))
     state = (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
-             leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-             reg_lo, reg_hi, pool_valid, log)
+             leaf_lo, leaf_hi, leaf_groups, reg_lo, reg_hi, pool_valid, log)
     for li, S_d in enumerate(caps):
         state = _one_level(state, bins_T, gh_T, meta, feature_mask, params,
                            L, B, f_oh, S_d, nch, max_depth, has_cat,
                            use_mono_bounds, use_node_masks, node_masks,
                            li + 1, li == len(caps) - 1,
                            bundle_cols, bundle_col_bins, bundle_cfg,
-                           interpret, psum_axis, defer_final_route,
+                           interpret, psum_axis,
                            mono_mode, parallel_mode, top_k,
                            feature_shard_mask,
                            quant_bits=quant_bits, packed=packed,
@@ -417,10 +387,8 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                            bins_form=bins_form)
     tree, leaf_T = state[0], state[1]
     out = (tree, leaf_T[0])
-    if defer_final_route:
-        out += (state[11], state[12])
     if route_log:
-        out += (state[16],)
+        out += (state[-1],)
     return out
 
 
@@ -429,13 +397,12 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                S_d, nch, max_depth, has_cat, use_mono_bounds,
                use_node_masks, node_masks, fold, is_last,
                bundle_cols, bundle_col_bins, bundle_cfg, interpret,
-               psum_axis=None, defer_final_route=False,
-               mono_mode="basic", parallel_mode="data", top_k=0,
+               psum_axis=None, mono_mode="basic", parallel_mode="data", top_k=0,
                feature_shard_mask=None, quant_bits=0, packed=None,
                decode=None, fmask2d=None, bins_form=False):
     (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
-     leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-     reg_lo, reg_hi, pool_valid, log) = state
+     leaf_lo, leaf_hi, leaf_groups, reg_lo, reg_hi, pool_valid,
+     log) = state
     use_bundles = bundle_cols > 0
     inter = use_mono_bounds and mono_mode == "intermediate"
     voting = psum_axis is not None and parallel_mode == "voting"
@@ -468,8 +435,8 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
 
     def _apply_level(op, route_only):
         (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
-         leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl,
-         reg_lo, reg_hi, pool_valid, log) = op
+         leaf_lo, leaf_hi, leaf_groups, reg_lo, reg_hi, pool_valid,
+         log) = op
         with jax.named_scope("route"):
             sel_i32 = selected.astype(jnp.int32)
             k_of_leaf = jnp.cumsum(sel_i32) - sel_i32
@@ -535,19 +502,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
         k_B = bundle_col_bins if use_bundles else B
         with jax.named_scope("route" if route_only else "hist"):
             # ---- THE level pass: route (+ smaller-child histograms)
-            def_W2, def_tbl2 = def_W, def_tbl
-            if route_only and defer_final_route:
-                # the epilogue kernel applies this pass's routing; hand it the
-                # (width-padded) tables and keep leaf_T at the pre-terminal
-                # assignment. Only one route-only pass can ever fire, so the
-                # single write is never clobbered.
-                leaf_T2 = leaf_T
-                def_W2 = jnp.zeros_like(def_W).at[:Sp].set(W)
-                def_tbl2 = jnp.zeros_like(def_tbl).at[:, 0].set(-2) \
-                    .at[:Sp].set(tbl)
-                pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
-                pool_valid2 = pool_valid
-            elif route_only:
+            if route_only:
                 leaf_T2 = route_pass(bins_T, leaf_T, W, tbl, num_slots=Sp,
                                      num_bins=k_B, f_oh=k_foh,
                                      interpret=interpret, packed=packed)
@@ -668,7 +623,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
 
         with jax.named_scope("book"):
             # ---- tree bookkeeping (ref: tree.h:62 Tree::Split; same node
-            # array conventions as models/frontier.py round 1)
+            # array conventions as models/learner.py's growers)
             f_l = best.feature
             new_depth = tree.leaf_depth + 1
 
@@ -758,8 +713,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                 best2 = best._replace(gain=g2)
                 return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2,
                         lpn2, lil2, leaf_lo2, leaf_hi2, leaf_groups2,
-                        def_W2, def_tbl2, reg_lo2, reg_hi2, pool_valid2,
-                        log2)
+                        reg_lo2, reg_hi2, pool_valid2, log2)
 
         with jax.named_scope("split"):
             # ---- best splits for the 2*Sp fresh children only; each child's
@@ -844,12 +798,11 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                                      lambda b: b, best2)
 
         return (tree2, leaf_T2, pool_g2, pool_h2, pool_c2, best2, lpn2,
-                lil2, leaf_lo2, leaf_hi2, leaf_groups2, def_W2, def_tbl2,
+                lil2, leaf_lo2, leaf_hi2, leaf_groups2,
                 reg_lo2, reg_hi2, pool_valid2, log2)
 
     op0 = (tree, leaf_T, pool_g, pool_h, pool_c, best, lpn, lil,
-           leaf_lo, leaf_hi, leaf_groups, def_W, def_tbl, reg_lo, reg_hi,
-           pool_valid, log)
+           leaf_lo, leaf_hi, leaf_groups, reg_lo, reg_hi, pool_valid, log)
 
     def dispatch(op):
         if is_last:

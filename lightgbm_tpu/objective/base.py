@@ -105,16 +105,6 @@ class ObjectiveFunction:
         fall back to the host numpy path)."""
         return None
 
-    def epilogue_spec(self):
-        """(kind, (row0, row1), sigmoid) for the fused boosting-epilogue
-        kernel (ops/fused_level.epilogue_pass), which re-derives the
-        gradients INSIDE the route+score+root-histogram pass, or None when
-        this objective has no per-row closed form the kernel implements.
-        ``kind`` selects the formula ('binary' | 'l2'); row0/row1 are [R]
-        f32 device arrays (binary: ±1 label and label weight; l2: label
-        and row weight)."""
-        return None
-
     def supports_traced_gradients(self) -> bool:
         """True only when the class providing the most-derived
         get_gradients ALSO provides its own gradients_from — a subclass

@@ -79,12 +79,6 @@ class BinaryLogloss(ObjectiveFunction):
         hess = abs_resp * (self.sigmoid - abs_resp) * lw
         return grad, hess
 
-    def epilogue_spec(self):
-        if not self.need_train:
-            return None
-        return ("binary", (self._label_val, self._label_weight),
-                self.sigmoid)
-
     def boost_from_score(self, class_id):
         # ref: binary_objective.hpp:139-163
         if self.weight is not None:

@@ -52,15 +52,6 @@ class RegressionL2Loss(ObjectiveFunction):
         w = weight[None, :]
         return diff * w, jnp.broadcast_to(w, diff.shape)
 
-    def epilogue_spec(self):
-        # exact-class guard: huber/fair/poisson/quantile subclass this and
-        # override get_gradients — they must not inherit the L2 closed form
-        if type(self) is not RegressionL2Loss:
-            return None
-        w = (self._weight_j if self._weight_j is not None
-             else jnp.ones_like(self._label_j))
-        return ("l2", (self._label_j, w), 1.0)
-
     def boost_from_score(self, class_id):
         # ref: regression_objective.hpp:173 — weighted label mean
         if self.weight is not None:

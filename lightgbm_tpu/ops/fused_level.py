@@ -1,17 +1,16 @@
 """Fused per-level Pallas kernel: route + histogram in ONE pass over rows.
 
-This is the hot path of the fused engine, replacing
-ops/pallas_histogram.py + the per-slot routing loop of models/frontier.py.
-It replaces the reference's hottest loops (ref: src/io/dense_bin.hpp
+This is the hot path of the fused engine. It replaces the reference's
+hottest loops (ref: src/io/dense_bin.hpp
 ConstructHistogram, src/treelearner/serial_tree_learner.cpp:355-453,
 ocl/histogram256.cl) with a single streaming kernel per tree level.
 
 Design (timings: TPU v5e, PERF.md section 6, PR 28's step 0):
 
-- Layout is TRANSPOSED vs round 1: rows ride the 128-wide lane dimension,
+- Layout is TRANSPOSED: rows ride the 128-wide lane dimension,
   features/bins/slots ride sublanes. The bin one-hot build then uses only
   native sublane broadcasts (no per-feature lane broadcast / int8 sublane
-  extraction, which cost 2-3x in round 1's kernel).
+  extraction, which cost 2-3x in a row-major kernel).
 - The one-hot ``oh[f*B+b, r] = (bins[f, r] == b)`` is built ONCE per row
   tile of ``level_pass`` and feeds the histogram dot,
   ``hist += oh @ ghs^T -> [FB, nch*S]``: the MXU streams the FB one-hot
@@ -36,17 +35,16 @@ Design (timings: TPU v5e, PERF.md section 6, PR 28's step 0):
       one-hot build it needs (28-60 ms), all 103 / 131 ms of a
       ``route_pass`` at the two widths above. Kept where "left" is not
       one comparison of one stored value (categorical bin sets, EFB
-      bundle columns whose bins decode by window, bins over 255) and for
-      the epilogue kernel, which applies the deferred last level's
-      tables. ``level_pass`` / ``route_pass`` take the form from their
-      ``W`` argument (None = bins form).
+      bundle columns whose bins decode by window, bins over 255).
+      ``level_pass`` / ``route_pass`` take the form from their ``W``
+      argument (None = bins form).
 - All gh channels are packed into ONE dot (N = nch*S): MXU efficiency
   rises with N.
 - Channels (``nch=5``, default): g_hi, g_lo, h_hi, h_lo, w — grad/hess are
   split into two bfloat16 halves (hi + exact residual) so the accumulated
   histogram carries ~fp32 input precision, matching the reference GPU
   precision contract (ref: docs/GPU-Performance.rst:130-160) instead of
-  round 1's raw-bf16 rounding. ``nch=3`` (g, h, w single-bf16) is the fast
+  raw-bf16 rounding. ``nch=3`` (g, h, w single-bf16) is the fast
   mode.
 - The grid is sequential on a TPU core, so the [FB, nch*S] output block
   accumulates across row tiles race-free; the updated row->leaf vector is
@@ -768,167 +766,6 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
         compiler_params=params,
         interpret=interpret,
     )(bins_T, leaf_T, W, tbl)
-
-
-def _epilogue_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, lv_ref, score_ref,
-                     op_ref, bag_ref, hist_ref, newscore_ref, gh_ref,
-                     oh_ref, *, B: int, F_oh: int, Sp: int, Lp: int,
-                     nch: int, kind: str, sigmoid: float):
-    """Fused boosting epilogue: final-level routing + leaf-value score
-    update + objective gradients + bf16 hi/lo channel pack + next tree's
-    ROOT histogram, in ONE streaming pass over the rows.
-
-    Replaces four separate O(R) streams of the round-2 driver (the final
-    route_pass, the table_lookup score update, the elementwise gradient/
-    pack, and the next grow's root level_pass) — each of which paid the
-    full per-pass floor (oh-build + narrow-N dot, ROADMAP A2).
-    The ref host loop being fused: gbdt.cpp:371 TrainOneIter's
-    UpdateScore -> Boosting(GetGradients) -> next BeforeTrain root.
-
-    Output hist layout matches the root pass ([FB, nch*8], slot 0 live)
-    so grow_tree_fused can consume it as ``root_hist`` directly.
-    """
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        hist_ref[:] = jnp.zeros_like(hist_ref)
-
-    C = bins_ref.shape[1]
-    FB = F_oh * B
-    _write_onehot(bins_ref, oh_ref, F_oh, B)
-    oh = oh_ref[:]
-
-    # ---- final-level routing (same contract as _route_kernel; an
-    # all-inactive table — leaf_of_slot=-2 — routes nothing)
-    leafb = leaf_ref[:]
-    D = jax.lax.dot_general(w_ref[:], oh, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    left_i = (D > 0.5).astype(jnp.int32)
-    leaf2, _ = _route_rows(leafb, left_i, tbl_ref)             # [1, C]
-
-    # ---- leaf-value score update (sublane one-hot, as _lookup_kernel;
-    # padding rows at leaf -1 match nothing -> delta 0)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (Lp, C), 0)
-    Pl = jnp.broadcast_to(leaf2, (Lp, C)) == iota_l
-    lvals = jnp.broadcast_to(lv_ref[:, 0:1], (Lp, C))
-    delta = jnp.sum(jnp.where(Pl, lvals, 0.0), axis=0, keepdims=True)
-    score2 = score_ref[:] + delta                              # [1, C] f32
-    newscore_ref[:] = score2
-
-    # ---- objective gradients from the UPDATED score (closed forms of the
-    # epilogue_spec protocol; ref: binary_objective.hpp:107-136,
-    # regression_objective.hpp:127-141)
-    if kind == "binary":
-        lv = op_ref[0:1, :]
-        lw = op_ref[1:2, :]
-        resp = -lv * sigmoid / (1.0 + jnp.exp(lv * sigmoid * score2))
-        ar = jnp.abs(resp)
-        g = resp * lw
-        h = ar * (sigmoid - ar) * lw
-    else:  # "l2"
-        label = op_ref[0:1, :]
-        w_row = op_ref[1:2, :]
-        g = (score2 - label) * w_row
-        h = w_row
-    bag = bag_ref[:]                                           # [1, C]
-    g = g * bag
-    h = h * bag
-
-    # ---- bf16 channel pack (pack_gh layout) + root histogram: slot 0 of
-    # an 8-slot block carries every row, slots 1-7 stay zero so the
-    # output matches the root level_pass layout bit-for-bit
-    zero7 = jnp.zeros((7, C), jnp.bfloat16)
-    if nch == NCH_PRECISE:
-        g_hi = g.astype(jnp.bfloat16)
-        g_lo = (g - g_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        h_hi = h.astype(jnp.bfloat16)
-        h_lo = (h - h_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        w_ch = bag.astype(jnp.bfloat16)
-        rows = [g_hi, g_lo, h_hi, h_lo, w_ch]
-    else:
-        rows = [g.astype(jnp.bfloat16), h.astype(jnp.bfloat16),
-                bag.astype(jnp.bfloat16)]
-    gh_ref[:] = jnp.concatenate(
-        rows + [jnp.zeros((8 - nch, C), jnp.bfloat16)], axis=0)
-    ghs = jnp.concatenate([jnp.concatenate([r, zero7], axis=0)
-                           for r in rows], axis=0)             # [nch*8, C]
-    hist_ref[:] += jax.lax.dot_general(
-        oh, ghs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                    # [FB, nch*8]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_bins", "f_oh", "nch", "kind", "sigmoid",
-                     "tile_rows", "interpret"))
-def epilogue_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
-                  tbl: jax.Array, leaf_values: jax.Array,
-                  score_T: jax.Array, ops_T: jax.Array, bag_T: jax.Array,
-                  *, num_bins: int, f_oh: int, nch: int = NCH_PRECISE,
-                  kind: str = "binary", sigmoid: float = 1.0,
-                  tile_rows: int = 0, interpret: bool = False):
-    """One fused epilogue pass (see _epilogue_kernel).
-
-    Args:
-      bins_T/leaf_T: as level_pass (leaf_T is the PRE-final-route
-        assignment; padding rows carry -1).
-      W/tbl: the deferred final level's route tables (grow_tree_fused with
-        defer_final_route=True); an all-inactive tbl routes nothing.
-      leaf_values: [L] f32 — shrinkage-scaled leaf outputs of the tree
-        just grown (zeroed by the caller when the tree grew no splits).
-      score_T: [1, R] f32 current scores.
-      ops_T: [8, R] f32 objective operand rows (binary: label_val,
-        label_weight; l2: label, weight).
-      bag_T: [1, R] f32 NEXT iteration's bagging weights (0 for padding
-        rows — they zero the histogram and gh channels).
-
-    Returns (hist [FB, nch*8] f32 root histogram for the next tree,
-    new_score [1, R] f32, gh_T [8, R] bf16 pack_gh block for the next
-    tree's level passes).
-    """
-    Fp, R = bins_T.shape
-    B = num_bins
-    FB = f_oh * B
-    Sp = tbl.shape[0]
-    L = leaf_values.shape[0]
-    Lp = _round_up(max(L, 8), 8)
-    C = _fit_tile(tile_rows or default_tile_rows(8, FB, nch,
-                                                 wide_bins=B > 256), R)
-    assert R % C == 0, f"rows {R} not padded to tile {C}"
-    lvp = jnp.zeros((Lp, 128), jnp.float32).at[:L, 0].set(leaf_values)
-    kernel = functools.partial(_epilogue_kernel, B=B, F_oh=f_oh, Sp=Sp,
-                               Lp=Lp, nch=nch, kind=kind,
-                               sigmoid=float(sigmoid))
-    hist, new_score, gh_T = pl.pallas_call(
-        kernel,
-        grid=(R // C,),
-        in_specs=[
-            pl.BlockSpec((Fp, C), lambda t: (0, t)),
-            pl.BlockSpec((1, C), lambda t: (0, t)),
-            pl.BlockSpec((Sp, FB), lambda t: (0, 0)),
-            pl.BlockSpec((Sp, 128), lambda t: (0, 0)),
-            pl.BlockSpec((Lp, 128), lambda t: (0, 0)),
-            pl.BlockSpec((1, C), lambda t: (0, t)),
-            pl.BlockSpec((8, C), lambda t: (0, t)),
-            pl.BlockSpec((1, C), lambda t: (0, t)),
-        ],
-        out_specs=[
-            pl.BlockSpec((FB, nch * 8), lambda t: (0, 0)),
-            pl.BlockSpec((1, C), lambda t: (0, t)),
-            pl.BlockSpec((8, C), lambda t: (0, t)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((FB, nch * 8), jnp.float32),
-            jax.ShapeDtypeStruct((1, R), jnp.float32),
-            jax.ShapeDtypeStruct((8, R), jnp.bfloat16),
-        ],
-        scratch_shapes=[pltpu.VMEM((FB, C), jnp.bfloat16)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(bins_T, leaf_T, W, tbl, lvp, score_T, ops_T, bag_T)
-    return hist, new_score, gh_T
 
 
 def _lookup_kernel(idx_ref, tbl_ref, out_ref, *, Lp: int):

@@ -14,8 +14,9 @@ are dense-array programs XLA can tile:
   contracts it with (grad, hess, count) on the MXU.  Fastest when targeting a
   single leaf (leaf-wise growth; the smaller-child + subtraction trick,
   ref: serial_tree_learner.cpp:423-425).
-- a Pallas kernel (ops/pallas_histogram.py) specializes the onehot formulation
-  with VMEM-resident accumulators to avoid materializing the one-hot in HBM.
+
+These serve the XLA growers (models/learner.py); the fused engine builds
+its histograms inside ops/fused_level.level_pass.
 
 Histograms are ``float32 [num_slots, F, B, 3]`` with channels (sum_grad,
 sum_hess, count); the reference accumulates float64 on CPU and float32 on GPU

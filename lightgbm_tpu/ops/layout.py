@@ -5,10 +5,8 @@ Two layouts:
 
 - **padded** (`feature_layout`): every feature widened to the global pow2
   bin count ``Bp`` and the feature count rounded so ``(Fp * Bp) % 128 ==
-  0``.  This is the round-2 contract both kernels used to compute
-  independently (``ops/fused_level.feature_layout`` and
-  ``ops/pallas_histogram.pad_feature_layout``) — consolidated here so a
-  layout change cannot drift between the standalone and fused kernels.
+  0``.  The fused kernels and the driver's matrix layout both take it
+  from here (``ops/fused_level`` re-exports it).
 - **packed** (`packed_feature_layout`): adaptive per-feature bin widths
   (arxiv 2603.00326).  Each feature gets its own pow2 width ``>= its
   effective bin count`` and features are grouped by width class, each

@@ -31,8 +31,8 @@ def donate_argnums(*argnums: int):
     """``donate_argnums`` tuple for jax.jit, empty in the CPU test mode
     (XLA:CPU copies anyway and warns per lowering) — the one-line idiom
     every driver jit that re-writes its score/gradient carry buffers
-    routes through (boosting/gbdt.py fast path, megastep, epilogue,
-    valid updates, parallel growers; ingest/prefetch.py). Donation
+    routes through (boosting/gbdt.py fast path, megastep, valid
+    updates, parallel growers; ingest/prefetch.py). Donation
     composes with sharded operands: a row-sharded score matrix donates
     per-shard buffers, so the in-place update holds on every device."""
     return tuple(argnums) if platform.on_tpu() else ()

@@ -273,7 +273,6 @@ def restore(gbdt, payload: Dict[str, Any], arrays) -> int:
     gbdt._stopped_early = False
     gbdt._es_finished = False
     gbdt._es_carry = None
-    gbdt._epi_carry = None
     gbdt._last_ckpt_iter = gbdt.iter
     # lineage: the resumed run descends from this checkpoint — chain the
     # parent hash into the (freshly built) provenance record

@@ -29,7 +29,6 @@ at its next drain boundary without restarting the job
 """
 from __future__ import annotations
 
-import functools
 import os
 import sys
 import time
@@ -146,12 +145,10 @@ def main_micro() -> None:
 
     R = 2_000_000
     Fp = 32
-    B = 64
     N = 10
     rng = np.random.RandomState(0)
     bins = jnp.asarray(rng.randint(0, 63, size=(R, Fp)).astype(np.int32))
     bins_u8 = jnp.asarray(np.asarray(bins).astype(np.uint8))
-    gh = jnp.asarray(rng.randn(R, 3).astype(np.float32))
     perm = jnp.asarray(rng.permutation(R).astype(np.int32))
     slot = jnp.asarray(rng.randint(0, 64, size=R).astype(np.int32))
 
@@ -197,21 +194,6 @@ def main_micro() -> None:
     f = _chain(lambda i, x: jnp.cumsum(x) % 1000, N)
     t = _timeit(f, slot) / N
     results["cumsum_2M_ms"] = t * 1e3
-
-    # 5. current pallas histogram, jit-compiled, per-pass
-    from lightgbm_tpu.ops.pallas_histogram import \
-        build_histograms_pallas_cm
-
-    for S in (8, 64):
-        @functools.partial(jax.jit, static_argnames=())
-        def hist_loop(bins, gh, slot, _S=S):
-            def step(i, acc):
-                g, h, c = build_histograms_pallas_cm(
-                    bins, gh, (slot + i) % _S, num_slots=_S, num_bins=B)
-                return acc + g[0, 0, 0]
-            return jax.lax.fori_loop(0, N, step, 0.0)
-        t = _timeit(hist_loop, bins, gh, slot) / N
-        results[f"pallas_hist_S{S}_ms"] = t * 1e3
 
     for k, v in results.items():
         print(f"{k:36s} {v if isinstance(v, str) else round(v, 3)}")

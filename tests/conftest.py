@@ -39,12 +39,6 @@ _INTERPRET_HEAVY = {
     ("test_efb.py", "test_bundled_categorical_matches_unbundled"),
     ("test_efb.py", "test_fused_bundles_with_missing_values"),
     ("test_efb.py", "test_fused_engine_with_bundles_matches_unbundled"),
-    ("test_epilogue.py", "test_binary_epilogue_identical"),
-    ("test_epilogue.py", "test_binary_epilogue_deep_tree_terminal_route"),
-    ("test_epilogue.py", "test_epilogue_early_stop_semantics"),
-    ("test_epilogue.py", "test_epilogue_with_bagging_lookahead"),
-    ("test_epilogue.py", "test_epilogue_feature_fraction"),
-    ("test_epilogue.py", "test_l2_epilogue_identical"),
     ("test_fast_pipeline.py", "test_fast_matches_sync_path"),
     ("test_megastep.py", "test_megastep_bit_identical_to_fast_path"),
     ("test_megastep.py", "test_megastep_early_stop_across_boundary"),
@@ -71,7 +65,6 @@ _INTERPRET_HEAVY = {
     ("test_fast_pipeline.py", "test_multiclass_rare_class_keeps_init_score"),
     ("test_fast_pipeline.py",
      "test_subclassed_objective_not_trained_with_base_gradients"),
-    ("test_fast_valid.py", "test_valid_traces_match_unfused_path"),
     ("test_fast_valid.py", "test_fast_path_stays_on_with_valid"),
     ("test_fast_valid.py", "test_device_metrics_match_host_metrics"),
     ("test_fast_valid.py", "test_early_stopping_fires_on_fast_path"),

@@ -242,3 +242,33 @@ def test_engine_train_uses_fast_path():
     assert bst.num_trees() == 12
     from sklearn.metrics import roc_auc_score
     assert roc_auc_score(y, bst.predict(X)) > 0.95
+
+
+def test_huber_trains_on_fast_path():
+    # huber subclasses L2 but overrides get_gradients
+    X, y = _data()
+    b = lgb.train(dict(FUSED, objective="huber"), lgb.Dataset(X, label=y),
+                  num_boost_round=6)
+    assert b._gbdt._fast_path_ok()
+    assert b.num_trees() == 6
+
+
+def test_multiclass_trains_on_fast_path():
+    X, _ = _data()
+    y3 = (np.random.RandomState(5).rand(X.shape[0]) * 3).astype(int)
+    b = lgb.train(dict(FUSED, objective="multiclass", num_class=3),
+                  lgb.Dataset(X, label=y3), num_boost_round=6)
+    assert b._gbdt._fast_path_ok()
+    assert b.num_trees() == 3 * 6
+
+
+def test_rollback_then_update_on_fast_path():
+    X, y = _data()
+    bst = lgb.Booster(params=dict(FUSED), train_set=lgb.Dataset(X, label=y))
+    for _ in range(4):
+        bst.update()
+    bst.rollback_one_iter()
+    for _ in range(2):
+        bst.update()   # continues from the rolled-back scores
+    assert bst.num_trees() == 5
+    assert np.isfinite(bst.predict(X)).all()

@@ -1,6 +1,7 @@
 """End-to-end GBDT training through the fused engine (tpu_engine=fused,
 interpret mode on CPU) vs the default XLA engine."""
 import numpy as np
+import pytest
 
 import lightgbm_tpu as lgb
 
@@ -132,3 +133,22 @@ def test_reset_parameter_callback_with_fused_engine():
                         learning_rate=lambda i: 0.2 * (0.9 ** i))])
     from sklearn.metrics import roc_auc_score
     assert roc_auc_score(y, bst.predict(X)) > 0.95
+
+
+@pytest.mark.parametrize("params,refused", [
+    ({"tpu_engine": "frontier"}, "tpu_engine"),
+    ({"tpu_histogram_impl": "pallas"}, "tpu_histogram_impl"),
+    ({"tpu_engine": "fused", "tpu_histogram_impl": "onehot"}, None),
+], ids=["engine_frontier", "hist_impl_pallas", "fused_onehot"])
+def test_a_stale_engine_value_is_refused_by_name(params, refused):
+    """A value that went with a deleted engine is an error that names
+    the accepted ones, not a silent fall-through to the XLA engine."""
+    from lightgbm_tpu.config import Config
+    if refused is None:
+        cfg = Config(params)
+        assert (cfg.tpu_engine, cfg.tpu_histogram_impl) == ("fused", "onehot")
+        return
+    with pytest.raises(lgb.basic.LightGBMError) as err:
+        Config(params)
+    assert refused in str(err.value) and "accepted values: auto, " \
+        in str(err.value)

@@ -102,7 +102,7 @@ def _run_level(bins, leaf, grad, hess, w, slots, meta, F, B, nch):
     return got, np.asarray(new_leaf)[0, :R]
 
 
-def test_root_histogram():
+def test_root_pass_histogram():
     bins, grad, hess, w, meta = _setup()
     F, B = 5, 16
     leaf = np.zeros(bins.shape[0], np.int32)

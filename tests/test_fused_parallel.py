@@ -47,7 +47,7 @@ def test_fused_voting_full_topk_matches_data(data):
     data-parallel full-exchange path — the tree must equal the
     data-parallel fused tree BIT-FOR-BIT. Both runs pin the synchronous
     driver (tpu_fast_path=false): voting always runs sync, and the
-    pipelined fast path's fused epilogue is numerically equivalent but
+    pipelined fast path's f32 shrinkage is numerically equivalent but
     not bit-identical to it."""
     X, y = data
     _, m_data = _model(X, y, dict(BASE, tree_learner="data",

@@ -2,10 +2,10 @@
 histograms, EMA-FS gain screening, adaptive per-feature bins.
 
 Contracts under test:
-- shared layout source of truth (pad_feature_layout == feature_layout)
-  and the packed-layout index maps;
-- masked (slot == -1) rows with NONZERO gh contribute nothing in the
-  XLA and Pallas formulations (the pallas_histogram docstring fix);
+- the one layout rule (ops/layout.feature_layout) and the packed-layout
+  index maps;
+- masked (slot == -1 / leaf == -1) rows with NONZERO gh contribute
+  nothing in the XLA formulations and in the fused level kernel;
 - quantization: stochastic rounding determinism + integer exactness,
   int16/int8 channel encode/decode roundtrip, kernel-level parity
   (exact on an integer grid, bounded error on random grads),
@@ -35,9 +35,6 @@ from lightgbm_tpu.ops import quantize
 from lightgbm_tpu.ops.histogram import _choose_chunk, build_histograms
 from lightgbm_tpu.ops.layout import (feature_layout, hist_plane_bytes,
                                      packed_feature_layout)
-from lightgbm_tpu.ops.pallas_histogram import (build_histograms_pallas_cm,
-                                               build_histograms_pallas_quant,
-                                               pad_feature_layout)
 
 KNOBS = {"tpu_quantized_grad": 16, "tpu_gain_screening": True,
          "tpu_screening_warmup": 2, "tpu_screening_explore_period": 4,
@@ -74,8 +71,8 @@ def _trees(bst):
 def test_shared_layout_contract():
     for F in (1, 3, 8, 28, 130):
         for mb in (2, 15, 63, 255, 300):
-            assert pad_feature_layout(F, mb) == feature_layout(F, mb)
             Fp, Bp = feature_layout(F, mb)
+            assert fl.feature_layout(F, mb) == (Fp, Bp)
             assert (Fp * Bp) % 128 == 0 and Fp >= F and Bp >= mb
 
 
@@ -203,22 +200,31 @@ def test_masked_rows_contribute_nothing_xla(impl):
     assert h_masked.sum() != 0.0
 
 
-def test_masked_rows_contribute_nothing_pallas():
-    bins, gh, slot, slot_m, masked, (R, F, B, S) = _masked_row_inputs()
-    Fp, Bp = pad_feature_layout(F, B)
-    bp = np.zeros((R, Fp), np.int32)
-    bp[:, :F] = bins
-    g1, h1, c1 = build_histograms_pallas_cm(
-        jnp.asarray(bp), jnp.asarray(gh), jnp.asarray(slot_m),
-        num_slots=S, num_bins=Bp, interpret=True)
-    gh0 = gh.copy()
-    gh0[masked] = 0.0
-    g2, h2, c2 = build_histograms_pallas_cm(
-        jnp.asarray(bp), jnp.asarray(gh0), jnp.asarray(slot_m),
-        num_slots=S, num_bins=Bp, interpret=True)
-    for a, b in ((g1, g2), (h1, h2), (c1, c2)):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    assert float(jnp.sum(jnp.abs(g1))) > 0.0
+def test_masked_rows_contribute_nothing_level_pass():
+    """Rows at leaf -1 (the padding rows' leaf) with NONZERO gh leave the
+    fused kernel's histogram untouched: bit-equal to the same pass with
+    their gh zeroed."""
+    rng = np.random.RandomState(1)
+    F, R, Sp = 4, 2048, 8
+    F_oh, Bp = feature_layout(F, 16)
+    bT = np.zeros((max(F_oh, 8), R), np.int8)
+    bT[:F] = rng.randint(0, 16, (F, R))
+    masked = rng.rand(R) < 0.3
+    leaf = jnp.asarray(np.where(masked, -1, 0).astype(np.int32)[None, :])
+    g = rng.randn(R).astype(np.float32)
+    h = np.abs(rng.randn(R)).astype(np.float32)
+    ones = np.ones(R, np.float32)
+    _, tbl = fl.root_route_tables(Bp, F_oh * Bp, Bp, True, Sp)
+
+    def hist(keep):
+        gh_T = fl.pack_gh(jnp.asarray(g * keep), jnp.asarray(h * keep),
+                          jnp.asarray(ones * keep), 5)
+        return np.asarray(fl.level_pass(
+            jnp.asarray(bT), leaf, gh_T, None, tbl, num_slots=Sp,
+            num_bins=Bp, f_oh=F_oh, nch=5, interpret=True)[0])
+    h_masked = hist(ones)
+    assert np.array_equal(h_masked, hist((~masked).astype(np.float32)))
+    assert np.abs(h_masked).sum() > 0.0
 
 
 # -------------------------------------------------- quantized histograms
@@ -243,34 +249,6 @@ def test_xla_quantized_exact_on_integer_grid():
         jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot),
         num_slots=S, num_bins=B, impl="segment"))
     assert np.array_equal(hq, hf)
-
-
-def test_pallas_quant_matches_xla_quant_grid():
-    """The fused int8-channel kernel formulation and the XLA int32
-    segment formulation accumulate the SAME integer grid — on a
-    scale-1 integer grid both equal the exact sums."""
-    rng = np.random.RandomState(4)
-    R, F, B, S = 512, 4, 16, 2
-    bins = rng.randint(0, B, (R, F)).astype(np.int32)
-    qmax = quantize.QMAX[16]
-    g = rng.randint(-qmax, qmax + 1, R).astype(np.float32)
-    g[np.argmax(np.abs(g))] = qmax
-    h = np.abs(rng.randint(0, qmax + 1, R)).astype(np.float32)
-    h[np.argmax(h)] = qmax
-    gh = np.stack([g, h, np.ones(R, np.float32)], axis=1)
-    slot = rng.randint(0, S, R).astype(np.int32)
-    Fp, Bp = pad_feature_layout(F, B)
-    bp = np.zeros((R, Fp), np.int32)
-    bp[:, :F] = bins
-    gq, hq, cq = build_histograms_pallas_quant(
-        jnp.asarray(bp), jnp.asarray(gh), jnp.asarray(slot),
-        num_slots=S, num_bins=Bp, quant_bits=16, interpret=True)
-    ref = np.asarray(build_histograms(
-        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(slot),
-        num_slots=S, num_bins=B, impl="segment"))
-    assert np.array_equal(np.asarray(gq)[:, :F, :B], ref[..., 0])
-    assert np.array_equal(np.asarray(hq)[:, :F, :B], ref[..., 1])
-    assert np.array_equal(np.asarray(cq)[:, :F, :B], ref[..., 2])
 
 
 def test_level_pass_quant_error_bound():

@@ -71,8 +71,7 @@ def test_megastep_bit_identical_to_fast_path():
     X, y = _data()
     b1 = lgb.train(dict(FUSED, tpu_megastep=True),
                    lgb.Dataset(X, label=y), num_boost_round=8)
-    b2 = lgb.train(dict(FUSED, tpu_megastep=False,
-                        tpu_fused_epilogue=False),
+    b2 = lgb.train(dict(FUSED, tpu_megastep=False),
                    lgb.Dataset(X, label=y), num_boost_round=8)
     _trees_equal(b1, b2)
     # live training scores too, not just the serialized model
@@ -88,8 +87,7 @@ def test_megastep_early_stop_across_boundary():
     params = dict(FUSED, min_sum_hessian_in_leaf=20.0, learning_rate=0.9)
     b1 = lgb.train(dict(params, tpu_megastep=True),
                    lgb.Dataset(X, label=y), num_boost_round=30)
-    b2 = lgb.train(dict(params, tpu_megastep=False,
-                        tpu_fused_epilogue=False),
+    b2 = lgb.train(dict(params, tpu_megastep=False),
                    lgb.Dataset(X, label=y), num_boost_round=30)
     b2._gbdt.drain_pending()   # the pipeline detects the stop at drain
     assert b1._gbdt._stopped_early and b2._gbdt._stopped_early
@@ -111,7 +109,7 @@ def test_megastep_valid_and_bagging():
                          valid_sets=[lgb.Dataset(Xv, label=yv,
                                                  reference=d)])
     b1 = run({"tpu_megastep": True})
-    b2 = run({"tpu_megastep": False, "tpu_fused_epilogue": False})
+    b2 = run({"tpu_megastep": False})
     _trees_equal(b1, b2)
     np.testing.assert_array_equal(np.asarray(b1._gbdt.valid_scores[0]),
                                   np.asarray(b2._gbdt.valid_scores[0]))

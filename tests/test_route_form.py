@@ -3,12 +3,12 @@
 `models/frontier2.route_form` picks, from what is static about a job,
 between routing by the bin values (`bins`: the slot table carries the
 splits, no `[Sp, FB]` table exists) and `W @ one_hot` (`table`: categorical
-splits, EFB bundle columns, the epilogue step's deferred final route, bins
-over 255). Here: (a) the choice itself; (b) the grower in both forms over
-the same numerical data: the same tree, the same leaves, the same replay;
+splits, EFB bundle columns, bins over 255). Here: (a) the choice itself;
+(b) the grower in both forms over the same numerical data: the same tree,
+the same leaves, the same replay;
 (c) whole jobs say their form once, with the reason (`route_form` event,
-`route.form_*` counters), and the bare `Booster.update` job (table form)
-grows the trees of the same job on the pipelined step (bins form).
+`route.form_*` counters); the bare `Booster.update` job takes the bins
+form like `lgb.train`'s.
 """
 import json
 
@@ -30,13 +30,12 @@ from test_valid_route import BINARY, _binary_data
 
 # ---------------------------------------------------------------- (a)
 @pytest.mark.parametrize("static,want", [
-    ((False, 0, False, 64), ("bins", None)),
-    ((True, 0, False, 64), ("table", "categorical")),
-    ((False, 3, False, 512), ("table", "bundled")),
-    ((False, 0, True, 64), ("table", "deferred_final_route")),
-    ((False, 0, False, 512), ("table", "wide_bins")),
-    ((False, 0, False, 256), ("bins", None)),
-    ((True, 3, True, 512), ("table", "categorical")),
+    ((False, 0, 64), ("bins", None)),
+    ((True, 0, 64), ("table", "categorical")),
+    ((False, 3, 512), ("table", "bundled")),
+    ((False, 0, 512), ("table", "wide_bins")),
+    ((False, 0, 256), ("bins", None)),
+    ((True, 3, 512), ("table", "categorical")),
 ], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else None)
 def test_route_form_is_chosen_from_what_is_static(static, want):
     assert route_form(*static) == want
@@ -140,16 +139,13 @@ def _wide_bins_job(out):
     return bst
 
 
-def _bare_update_job(out, epilogue=True):
+def _bare_update_job(out):
     X, y = _binary_data()
     ds = lgb.Dataset(X[:1200], label=y[:1200])
-    params = dict(BINARY, tpu_megastep=False, tpu_fused_epilogue=epilogue)
-    if out:
-        params["telemetry_out"] = out
-    bst = lgb.Booster(params, ds)
+    bst = lgb.Booster(dict(BINARY, tpu_megastep=False, telemetry_out=out),
+                      ds)
     for _ in range(2):
         bst.update()
-    assert bst._gbdt._use_epilogue() == epilogue
     return bst
 
 
@@ -158,7 +154,7 @@ def _bare_update_job(out, epilogue=True):
     (_categorical_job, "table", "categorical"),
     (_dense_efb_job, "table", "bundled"),
     (_wide_bins_job, "table", "wide_bins"),
-    (_bare_update_job, "table", "deferred_final_route"),
+    (_bare_update_job, "bins", None),
 ], ids=["dense", "categorical", "dense_efb", "wide_bins", "bare_update"])
 def test_a_job_says_its_form_once_with_the_reason(tmp_path, job, form, reason):
     out = tmp_path / "t.jsonl"
@@ -172,8 +168,3 @@ def test_a_job_says_its_form_once_with_the_reason(tmp_path, job, form, reason):
     assert counters.get("route.form_%s" % other, 0) == 0
     assert counters["events.route_form"] == 1
     assert bst.num_trees() == 2
-    if job is _bare_update_job:
-        # the same job on the pipelined step takes the bins form: the
-        # same trees
-        assert bst.dump_model()["tree_info"] == \
-            _bare_update_job(None, epilogue=False).dump_model()["tree_info"]

@@ -37,10 +37,12 @@ def _bins():
         .astype(np.int8), rng
 
 
-def _grow_and_replay(signal: str, defer: bool = False):
+def _grow_and_replay(signal: str, bins_form: bool = False):
     """(tree, the grower's own row_leaf, the replayed leaves, the log) of
     one tree grown on ``signal``; every case shares one set of static
-    arguments but ``defer``, so the grower compiles twice in this file."""
+    arguments but ``has_cat`` (off for ``bins_form``: no column is
+    categorical then and the log holds no ``W``), so the grower compiles
+    twice in this file."""
     bins, rng = _bins()
     y = {"numeric": (bins[:, 0] > 12) + 0.5 * (bins[:, 1] > 20)
          + 0.3 * (bins[:, 4] > 7),
@@ -58,26 +60,18 @@ def _grow_and_replay(signal: str, defer: bool = False):
     nb = np.zeros(F_oh, np.int32)
     nb[:F] = NUM_BIN
     is_cat = np.zeros(F_oh, bool)
-    is_cat[2] = True
+    is_cat[2] = not bins_form
     z = jnp.zeros(F_oh, jnp.int32)
     meta = FeatureMeta(jnp.asarray(nb), z, z, z, jnp.asarray(is_cat))
-    kw = dict(nch=5, extra_levels=1, interpret=True, has_cat=True,
+    kw = dict(nch=5, extra_levels=1, interpret=True, has_cat=not bins_form,
               num_rows=R, route_log=True)
     # (the gain floor is what lets a tree run dry before the schedule ends)
     args = (jnp.asarray(bins_T), gh_T, meta, jnp.asarray(np.arange(F_oh) < F),
             SplitParams(min_data_in_leaf=5, min_gain_to_split=2.0,
                         cat_smooth=1.0, min_data_per_group=5),
             4, B, F_oh)
-    tree, row_leaf, *rest = grow_tree_fused(
-        *args, defer_final_route=defer, **kw)
-    log = rest[-1]
-    if defer:
-        # the grower kept the PRE-terminal assignment and handed the
-        # terminal level's tables out; the log holds that level too
-        assert np.any(np.asarray(rest[1])[:, 0] >= 0)
-        final = grow_tree_fused(*args, **kw)[1]
-        assert not np.array_equal(np.asarray(row_leaf), np.asarray(final))
-        row_leaf = final
+    tree, row_leaf, log = grow_tree_fused(*args, **kw)
+    assert (log[0] is None) == bins_form
     leaves = replay_route_log(args[0], log, R, num_bins=Bp, f_oh=F_oh,
                               interpret=True)
     return jax.device_get(tree), np.asarray(row_leaf), \
@@ -86,10 +80,10 @@ def _grow_and_replay(signal: str, defer: bool = False):
 
 @pytest.mark.parametrize("case,signal", [
     ("plain", "numeric"), ("categorical", "categorical"),
-    ("stops_early", "one_split"), ("defer_final_route", "numeric")])
+    ("stops_early", "one_split"), ("bins_form", "numeric")])
 def test_replay_over_training_matrix_is_row_leaf(case, signal):
     tree, row_leaf, leaves, (log_W, log_tbl) = _grow_and_replay(
-        signal, defer=case == "defer_final_route")
+        signal, bins_form=case == "bins_form")
     nl = int(tree.num_leaves)
     assert np.array_equal(leaves, row_leaf)     # padding rows: -1 in both
     assert set(np.unique(leaves[:R])) == set(range(nl))
@@ -335,14 +329,11 @@ def test_data_parallel_same_bits_and_no_recompile(monkeypatch, tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("step", ["fast_step", "epilogue"])
-def test_per_iteration_fast_paths_same_bits(monkeypatch, step):
-    """Bare `Booster.update` (no megastep): the pipelined fast step and
-    the fused-epilogue step hand their trees' logs to
-    `_update_valid_from_trees` too."""
+def test_per_iteration_fast_path_same_bits(monkeypatch):
+    """Bare `Booster.update` (no megastep): the pipelined fast step hands
+    its trees' logs to `_update_valid_from_trees` too."""
     X, y = _binary_data()
-    params = dict(BINARY, tpu_megastep=False,
-                  tpu_fused_epilogue=step == "epilogue")
+    params = dict(BINARY, tpu_megastep=False)
 
     def scores():
         ds = lgb.Dataset(X[:1200], label=y[:1200])
@@ -352,7 +343,6 @@ def test_per_iteration_fast_paths_same_bits(monkeypatch, step):
         for _ in range(3):
             bst.update()
         g = bst._gbdt
-        assert g._use_epilogue() == (step == "epilogue")
         return np.asarray(g.valid_scores[0]), g._wants_route_log()
     kernel, logged = scores()
     assert logged
