@@ -2730,23 +2730,14 @@ class GBDT:
         CURRENT layout/quantization (ops/layout.hist_plane_bytes): what
         the bench records as hist_bytes_per_iter and the exporter
         scrapes as hist.bytes_per_level."""
-        from ..models.frontier2 import level_caps
-        from ..ops.fused_level import default_tile_rows, max_slot_cap
         from ..ops.layout import hist_plane_bytes
-        kF = self.fused_bundle_cols or self.fused_f_oh
-        kB = (self.fused_bundle_col_bins if self.fused_bundle_cols
-              else self.fused_Bp)
+        kF, kB, caps = self._fused_plane()
         fb_padded = kF * kB
         fb = (self.fused_packed.fb if self.fused_packed is not None
               else fb_padded)
         nch = self.fused_nch
-        caps = level_caps(self.max_leaves, int(self.config.max_depth),
-                          int(self.config.tpu_extra_levels),
-                          slot_cap=max_slot_cap(fb_padded, nch))
         sp_max = max([8] + [max(8, c) for c in caps])
-        tile = min(self.fused_Rp,
-                   default_tile_rows(sp_max, fb_padded, nch,
-                                     wide_bins=kB > 256))
+        tile = min(self.fused_Rp, self._level_build(sp_max)["tile_rows"])
         per_level = hist_plane_bytes(fb, nch, sp_max, self.fused_Rp,
                                      tile, self.quant_bits)
         n_levels = len(caps) + 1   # + the root pass
@@ -3151,16 +3142,33 @@ class GBDT:
     def _fast_tree_depth_bound(self) -> int:
         """Static routing-step bound for trees grown by the fused engine:
         depth cannot exceed the number of scheduled level passes."""
+        return len(self._fused_plane()[2]) + 1
+
+    def _fused_plane(self) -> Tuple[int, int, Tuple[int, ...]]:
+        """(columns, bins a column, level caps) of the fused kernels'
+        plane: the EFB bundle columns where the job is bundled, else the
+        padded features; the caps are the grower's level schedule
+        (frontier2.level_caps under ops/fused_level.max_slot_cap)."""
         from ..models.frontier2 import level_caps
         from ..ops.fused_level import max_slot_cap
-        if self.fused_bundle_cols:
-            fb = self.fused_bundle_cols * self.fused_bundle_col_bins
-        else:
-            fb = self.fused_f_oh * self.fused_Bp
+        kF = self.fused_bundle_cols or self.fused_f_oh
+        kB = (self.fused_bundle_col_bins if self.fused_bundle_cols
+              else self.fused_Bp)
         caps = level_caps(self.max_leaves, int(self.config.max_depth),
                           int(self.config.tpu_extra_levels),
-                          slot_cap=max_slot_cap(fb, self.fused_nch))
-        return len(caps) + 1
+                          slot_cap=max_slot_cap(kF * kB, self.fused_nch))
+        return kF, kB, caps
+
+    def _level_build(self, Sp: int) -> dict:
+        """ops/fused_level.level_build of this job's ``level_pass`` at
+        ``Sp`` slots."""
+        from ..models.frontier2 import route_form
+        from ..ops.fused_level import level_build
+        kF, kB, _ = self._fused_plane()
+        bins_form = route_form(self.has_cat, self.fused_bundle_cols,
+                               kB)[0] == "bins"
+        return level_build(bins_form, Sp, kF * kB, self.fused_nch,
+                           max(kF, 8), wide_bins=kB > 256)
 
     def _valid_route_reason(self, vi: int) -> Optional[str]:
         """Why validation set ``vi`` keeps the gather walk, or None when
@@ -3221,23 +3229,34 @@ class GBDT:
         return route
 
     def _route_form(self) -> None:
-        """Say the routing form of a step that grows trees on the fused
-        engine (models/frontier2.route_form decides; this only tells):
-        counter ``route.form_<form>`` and a ``route_form`` event, once
-        per run and (form, reason). Called where each such step is
-        built."""
+        """Say the kernels' forms of a step that grows trees on the fused
+        engine, once per run and distinct payload; called where each
+        such step is built. The ROUTING form (models/frontier2.route_form
+        decides; this only tells): counter ``route.form_<form>`` and a
+        ``route_form`` event. How ``level_pass`` BUILDS its one-hot, which
+        follows from it (ops/fused_level.level_build decides): counter
+        ``level.build_<form>`` and a ``level_build`` event with the slab
+        size and the row tile per distinct slot count of the level
+        schedule (the ``reason`` of a ``scratch`` build is the table
+        form's)."""
         from ..models.frontier2 import route_form
-        said = route_form(
-            self.has_cat, self.fused_bundle_cols,
-            self.fused_bundle_col_bins if self.fused_bundle_cols
-            else self.fused_Bp)
+        _, kB, caps = self._fused_plane()
+        said = route_form(self.has_cat, self.fused_bundle_cols, kB)
         tel = self.telemetry
-        if tel.enabled and said not in self._route_form_said:
-            self._route_form_said.add(said)
-            form, reason = said
-            tel.inc("route.form_%s" % form)
-            tel.event("route_form", iteration=self.iter, form=form,
-                      **({"reason": reason} if reason else {}))
+        if not tel.enabled or said in self._route_form_said:
+            return
+        self._route_form_said.add(said)
+        form, reason = said
+        why = {"reason": reason} if reason else {}
+        tel.inc("route.form_%s" % form)
+        tel.event("route_form", iteration=self.iter, form=form, **why)
+        builds = {sp: self._level_build(sp)
+                  for sp in sorted({8} | {max(8, c) for c in caps})}
+        build = dict(builds[8])
+        build["tile_rows"] = {str(sp): min(self.fused_Rp, b["tile_rows"])
+                              for sp, b in builds.items()}
+        tel.inc("level.build_%s" % build["form"])
+        tel.event("level_build", iteration=self.iter, **build, **why)
 
     def _wants_route_log(self) -> bool:
         """Some validation set is routed by the kernels: the steps that

@@ -5,17 +5,37 @@ hottest loops (ref: src/io/dense_bin.hpp
 ConstructHistogram, src/treelearner/serial_tree_learner.cpp:355-453,
 ocl/histogram256.cl) with a single streaming kernel per tree level.
 
-Design (timings: TPU v5e, PERF.md section 6, PR 28's step 0):
+Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
 
 - Layout is TRANSPOSED: rows ride the 128-wide lane dimension,
   features/bins/slots ride sublanes. The bin one-hot build then uses only
   native sublane broadcasts (no per-feature lane broadcast / int8 sublane
   extraction, which cost 2-3x in a row-major kernel).
-- The one-hot ``oh[f*B+b, r] = (bins[f, r] == b)`` is built ONCE per row
-  tile of ``level_pass`` and feeds the histogram dot,
-  ``hist += oh @ ghs^T -> [FB, nch*S]``: the MXU streams the FB one-hot
-  rows through one latched [128, 128] tile of ``ghs`` per 128 data rows
-  and N-tile (70 ms per N-tile of 128 columns at 28M rows x FB 1,792).
+- The one-hot ``oh[f*B+b, r] = (bins[f, r] == b)`` feeds the histogram
+  dot, ``hist += oh @ ghs^T -> [FB, nch*S]``: the MXU streams the FB
+  one-hot rows through one latched [128, 128] tile of ``ghs`` per 128
+  data rows and N-tile (65 ms per N-tile of 128 columns at 28M rows x FB
+  1,792 is the chip's bound). It is built (``_onehot_slab``) per row tile
+  of ``level_pass``, in one of two ways that follow from the routing form
+  below (``level_build``):
+    * in SLABS, the bins form: ``ghs`` is known before any one-hot
+      element is, and each FB-row block of the one-hot has one reader,
+      its own rows of the accumulator. So SLAB_ROWS = 512 one-hot rows (8
+      features of 64 bins) are built and multiplied at once into
+      ``hist[slab rows]``, slab after slab in one basic block: there is
+      no [FB, C] scratch, the row tile does not shrink with FB (2,048
+      rows at every width a cell runs) and the VPU build of one slab
+      runs under the MXU's pass over its neighbours. 28M x 28 x 64 bins:
+      74.0 ms at 8 slots, 210.1 at 64 (the whole-scratch build: 96.3 /
+      247.6 at 1,024-row tiles); 6.81M x 137 x 64 at 16 slots: 81.8 ms
+      against a 78.2 ms bound (183.6 with the scratch, which left
+      128-row tiles: 53,216 grid steps, each rewriting the 2.8 MB
+      accumulator). A ``lax.fori_loop`` over the slabs is 4-10 % slower
+      (the loop's back-edge is a barrier to that overlap) and column
+      slabs (all FB rows x 512 of the tile's rows) 1-6 %;
+    * WHOLE, into an [FB, C] VMEM scratch (``_write_onehot``), the table
+      form: ``D = W @ oh`` must be complete before ``ghs`` exists, so the
+      one-hot is read twice and the tile is what the scratch leaves.
 - ROUTING (which rows of slot k's leaf go left) has two forms, chosen
   once per grower from what is static about the job
   (models/frontier2.route_form):
@@ -92,35 +112,70 @@ def _next_pow2(x: int) -> int:
 VMEM_BUDGET = 15 * 1024 * 1024  # scoped-vmem stack limit is 16 MB; leave
 # headroom for W/ghs/D values and the pipeline's operand double buffers
 
+SLAB_ROWS = 512   # one-hot rows of one slab of the bins form's build
+
 
 def default_tile_rows(Sp: int, FB: int, nch: int,
-                      wide_bins: bool = False) -> int:
-    """Row-tile width: the [FB, C] bf16 one-hot scratch (2 B/elem), the
-    [FB, C] repeated-bins intermediate, the [FB, C] iota plane (both
-    2 B/elem bf16 for B <= 256, else 4 B/elem f32 — see _write_onehot)
-    and the [FB, nch*Sp] f32 accumulator must fit the scoped-VMEM stack
-    together. Round 2's formula ignored the build intermediate entirely
-    and a 255-bin config exceeded the 16 MB stack limit — caught on-chip
-    in round 3. The iota term is charged CONSERVATIVELY (advisor r4):
-    Mosaic may fold the broadcasted_iota into the subtract, but that
-    cannot be verified off-chip and an overflow is a hard compile/run
-    failure. On a v5e (PR 21) every level of the Higgs layout (FB=1792,
-    nch=5, Sp 8..128 -> tiles 1024..512) compiled and ran at these
-    tiles under the default 16 MB scoped-VMEM limit, with no
-    vmem_limit_bytes; whether larger tiles also fit is for
-    scripts/ablate_kernel.py's tile sweep.
+                      wide_bins: bool = False, bins_rows: int = 0) -> int:
+    """Row-tile width of ``level_pass``: a power of two from 128 to 2,048
+    (``_init_fused`` aligns the rows to 2,048 a shard), from the PADDED
+    layout's shapes and the form alone, so an adaptive-bins job takes its
+    padded twin's tile.
 
-    Shallow levels (small Sp -> small accumulator) get LARGER tiles:
-    their per-pass cost is floor-bound (oh-build + per-tile overheads,
-    ROADMAP A2 — the Sp<=8 passes cost half the tree), so halving the
-    tile count halves the fixed per-tile cost where the MXU is padded
-    anyway."""
-    acc = FB * nch * Sp * 4
-    avail = max(VMEM_BUDGET - acc, 2 * 1024 * 1024)
-    per_elem = 4 if wide_bins else 2       # big + iota_b dtype width
-    c = avail // ((2 + 2 * per_elem) * FB)
+    BINS form (``bins_rows`` = the bin tile's Fp > 0; PR 31): there is no
+    [FB, C] scratch. What the kernel keeps on the scoped-VMEM stack is
+    charged per row of the tile: one slab of the one-hot with its two
+    build intermediates (SLAB_ROWS x 6 B), the [nch*Sp, C] ``ghs``
+    (2 B), the [Sp, C] int32 routing planes (16 B a slot, as
+    route_tile_rows charges them) and the converted bin tile (4 B + the
+    routing dot's bf16 copy). That is 2,048 rows up to Fp ~700 at 16
+    slots or 128 slots at Fp 28. The charge is CONSERVATIVE: compiled
+    for a described v5e the kernel needed 1.3-1.8 MB at 8-16 slots, 4.2
+    at 64 and 5.2 at 128 with 2,048-row tiles (the compiler fuses the
+    build's intermediates), and the [FB, nch*Sp] accumulator is the
+    pipeline's output window, not part of that stack (Epsilon's 20.5 MB
+    accumulator compiles under the 16 MB limit). On a v5e (PR 31's step
+    0) a pass over 6.81M x 137 x 64 bins at 16 slots took 105.1 / 92.3 /
+    86.4 / 83.8 / 81.8 ms at 128 / 256 / 512 / 1,024 / 2,048 rows, over
+    28M x 28 x 64 at 8 slots 88.7 / 80.8 / 74.0 at 512 / 1,024 / 2,048.
+
+    TABLE form: the [FB, C] bf16 one-hot scratch (2 B/elem), the
+    [FB, C] repeated-bins intermediate, the [FB, C] iota plane (both
+    2 B/elem bf16 for B <= 256, else 4 B/elem f32, see _onehot_slab)
+    and the [FB, nch*Sp] f32 accumulator are charged together. Round 2's
+    formula ignored the build intermediate entirely and a 255-bin config
+    exceeded the 16 MB stack limit on the chip. The iota term is charged
+    CONSERVATIVELY: Mosaic may fold the broadcasted_iota into the
+    subtract, but an overflow is a hard compile failure. On a v5e
+    (PR 21) every level of the Higgs layout (FB=1792, nch=5, Sp 8..128
+    -> tiles 1024..512) compiled and ran at these tiles under the
+    default 16 MB scoped-VMEM limit. Shallow levels (small Sp -> small
+    accumulator) get larger tiles."""
+    if bins_rows:
+        per_row = SLAB_ROWS * 6 + Sp * (2 * nch + 16) + bins_rows * 6
+        c = VMEM_BUDGET // per_row
+    else:
+        acc = FB * nch * Sp * 4
+        avail = max(VMEM_BUDGET - acc, 2 * 1024 * 1024)
+        per_elem = 4 if wide_bins else 2       # big + iota_b dtype width
+        c = avail // ((2 + 2 * per_elem) * FB)
     c = 1 << max(7, (int(c)).bit_length() - 1)      # floor to pow2, >= 128
     return int(min(2048, c))
+
+
+def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
+                wide_bins: bool = False) -> dict:
+    """How ``level_pass`` builds its one-hot at these shapes, and its row
+    tile. THE place both are chosen: the kernel asks here, and so does
+    the driver for its ``level_build`` event. ``slab`` (the bins form):
+    SLAB_ROWS one-hot rows at a time, each multiplied at once into its
+    own rows of the accumulator; ``scratch`` (the table form, whose
+    routing dot reads the whole one-hot first): all [FB, C] of it."""
+    if bins_form:
+        return {"form": "slab", "slab_rows": SLAB_ROWS,
+                "tile_rows": default_tile_rows(Sp, FB, nch, bins_rows=Fp)}
+    return {"form": "scratch",
+            "tile_rows": default_tile_rows(Sp, FB, nch, wide_bins=wide_bins)}
 
 
 def _fit_tile(C: int, R: int) -> int:
@@ -130,53 +185,56 @@ def _fit_tile(C: int, R: int) -> int:
     return C
 
 
+def _onehot_slab(rows, w: int, quant: bool):
+    """[k, C] bin values of k features of one width ``w`` -> their
+    [k*w, C] block of the one-hot, ``oh[f*w+b, r] = (rows[f, r] == b)``:
+    THE one spelling of the one-hot, for a slab of it (_level_kernel's
+    bins form) and for the whole of it (_write_onehot). Built
+    ARITHMETICALLY, relu(1 - |bins - b|), in bf16: integers <= 256 are
+    exact in bf16, so the result is bit-identical to a compare while the
+    repeated-bins intermediate stays 2 B/elem (Mosaic on this target
+    compiles only i32 compares, which forced a 4 B/elem intermediate in
+    the round-2/3 build). Bin counts > 256 (wide EFB bundle columns) use
+    an f32 intermediate instead. ``quant`` (int8 histograms): a plain
+    i32 compare cast to int8; the intermediate cost returns, but the
+    one-hot and the MXU dots halve to 1 B/elem on the native s8 path."""
+    k, C = rows.shape
+    span = k * w
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (span, C), 0) % w
+    if quant:
+        big = jnp.repeat(rows.astype(jnp.int32), w, axis=0)
+        return (big == iota_b).astype(jnp.int8)
+    dt = jnp.bfloat16 if w <= 256 else jnp.float32
+    big = jnp.repeat(rows.astype(dt), w, axis=0)
+    return jnp.maximum(1.0 - jnp.abs(big - iota_b.astype(dt)), 0.0) \
+        .astype(jnp.bfloat16)
+
+
 def _write_onehot(bins_ref, oh_ref, F_oh: int, B: int,
                   packed: PackedLayout = None, fm_ref=None) -> None:
-    """oh[f*B+b, r] = 1.0 iff bins[f, r] == b, written to the VMEM
-    scratch. Built ARITHMETICALLY — relu(1 - |bins - b|) — in bf16:
-    integers <= 256 are exact in bf16, so the result is bit-identical to
-    a compare while the repeated-bins intermediate stays 2 B/elem
-    (Mosaic on this target compiles only i32 compares, which forced a
-    4 B/elem intermediate in the round-2/3 build). Bin counts > 256
-    (wide EFB bundle columns) use an f32 intermediate instead.
+    """The WHOLE one-hot of a row tile, written to the [FB, C] VMEM
+    scratch: what the table form needs, because ``D = W @ oh`` must be
+    complete before the histogram dot's right-hand side exists.
 
-    Variants (tentpole cuts; the default path above is byte-unchanged):
-    - int8 scratch (quantized histograms): a plain i32 compare cast to
-      int8 — the intermediate cost returns, but the scratch and both
-      MXU dots halve to 1 B/elem on the native s8 path;
     - ``packed`` (adaptive per-feature bins): the bin matrix rows are
-      pre-permuted into width classes, so each class region builds with
-      the same bulk repeat+compare at ITS width instead of the global
-      pow2 B — class padding regions are zeroed;
+      pre-permuted into width classes, so each class region builds at
+      ITS width instead of the global pow2 B; class padding regions are
+      zeroed;
     - ``fm_ref`` ([FB, 128], col 0 live): gain-screened features'
       slabs are zeroed after the build so they contribute nothing to
-      either dot (the dynamic-mask form of skipping the slab; the
-      static slab-skip is the on-chip ablation's follow-up).
+      either dot.
     """
     quant = oh_ref.dtype == jnp.int8
     C = bins_ref.shape[1]
-
-    def build(seg_ref_rows, w, span):
-        """[rows] x width w -> one-hot block [rows*w, C]."""
-        if quant:
-            big = jnp.repeat(seg_ref_rows.astype(jnp.int32), w, axis=0)
-            iota_b = jax.lax.broadcasted_iota(jnp.int32, (span, C), 0) % w
-            return (big == iota_b).astype(jnp.int8)
-        dt = jnp.bfloat16 if w <= 256 else jnp.float32
-        big = jnp.repeat(seg_ref_rows.astype(dt), w, axis=0)
-        iota_b = (jax.lax.broadcasted_iota(jnp.int32, (span, C), 0) % w) \
-            .astype(dt)
-        return jnp.maximum(1.0 - jnp.abs(big - iota_b), 0.0) \
-            .astype(jnp.bfloat16)
-
     if packed is None:
-        oh_ref[:] = build(bins_ref[:F_oh], B, F_oh * B)
+        oh_ref[:] = _onehot_slab(bins_ref[:F_oh], B, quant)
     else:
         for ci, (w, cnt) in enumerate(packed.classes):
             r0 = int(packed.row_offsets[ci])
             o0 = int(packed.class_flat_offsets[ci])
             span = cnt * w
-            oh_ref[o0:o0 + span] = build(bins_ref[r0:r0 + cnt], w, span)
+            oh_ref[o0:o0 + span] = _onehot_slab(bins_ref[r0:r0 + cnt], w,
+                                                quant)
             pad = _round_up(span, 128) - span
             if pad:
                 oh_ref[o0 + span:o0 + span + pad] = jnp.zeros(
@@ -505,37 +563,112 @@ def _route_rows(leafb, left_i, tbl_ref):
     return leafb + delta, P_i
 
 
+def _small_child_channels(leafb, left_i, tbl_ref, gh_ref, nch: int,
+                          quant: bool):
+    """(new leaf [1, C], ghs [nch*Sp, C]): the row->leaf update, and the
+    histogram dot's right-hand side: every gh channel masked to the rows
+    of slot k's SMALLER child, all channels packed into one operand."""
+    Sp, C = left_i.shape
+    # ---- slot membership + row->leaf update: right-child rows move to
+    # their new leaf id
+    new_leaf, P_i = _route_rows(leafb, left_i, tbl_ref)
+    small_left_i = (tbl_ref[:, TBL_SMALL_LEFT:TBL_SMALL_LEFT + 1]
+                    > 0).astype(jnp.int32)                     # [Sp, 1] 0/1
+    same_i = 1 - jnp.bitwise_xor(left_i, small_left_i)         # left==small
+    in_small = P_i * same_i                                    # [Sp, C] 0/1
+    if not quant:
+        in_small = in_small.astype(jnp.bfloat16)
+
+    # ---- mask*g instead of a select (i1 selects also hit the relayout
+    # bug noted in _level_kernel); requires FINITE grad/hess: a NaN/Inf
+    # row would leak 0*NaN into other slots' bins, but non-finite
+    # gradients wreck training under any formulation. Quantized mode:
+    # int8 channels (integer sums are EXACT and associative,
+    # ops/quantize.py, rescaled outside). The mask product runs in i32
+    # and narrows afterwards: Mosaic on v5e refuses an i8 x i8 vector
+    # multiply ("failed to legalize operation 'arith.muli' ...
+    # vector<8x128x4xi8>").
+    chans = []
+    for ch in range(nch):
+        g = gh_ref[ch:ch + 1, :]                               # [1, C]
+        if quant:
+            chans.append((in_small * jnp.broadcast_to(
+                g.astype(jnp.int32), (Sp, C))).astype(jnp.int8))
+        else:
+            chans.append(in_small * jnp.broadcast_to(g, (Sp, C)))
+    return new_leaf, jnp.concatenate(chans, axis=0)            # [nch*Sp, C]
+
+
+def _slab_cuts(F_oh: int, B: int, packed: PackedLayout = None):
+    """The bins form's slabs, static: (first row of the bin matrix,
+    features, their width, first row of the accumulator) of each. A slab
+    is SLAB_ROWS one-hot rows (8 features of 64 bins) or what is left of
+    its width class; class padding rows of the ``packed`` layout belong
+    to no slab and stay zero."""
+    classes = [(B, F_oh, 0, 0)] if packed is None else [
+        (w, cnt, int(packed.row_offsets[ci]),
+         int(packed.class_flat_offsets[ci]))
+        for ci, (w, cnt) in enumerate(packed.classes)]
+    cuts = []
+    for w, cnt, r0, o0 in classes:
+        k = max(1, SLAB_ROWS // w)
+        cuts += [(r0 + f, min(k, cnt - f), w, o0 + f * w)
+                 for f in range(0, cnt, k)]
+    return cuts
+
+
 def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
                   quant: bool = False, packed: PackedLayout = None,
                   has_fm: bool = False, has_w: bool = True):
+    """``level_pass``'s body. Table form (``has_w``): the whole one-hot
+    goes to the [FB, C] scratch ``oh_ref`` first, because routing reads
+    all of it (``D = W @ oh``) before the histogram dot's right-hand side
+    exists. Bins form: routing reads the bin values, so ``ghs`` is known
+    before any one-hot element is, each slab of the one-hot has exactly
+    one reader (its own rows of the accumulator) and there is no scratch:
+    slabs are built and multiplied one after the other in ONE basic
+    block, which lets the scheduler put the VPU build of a slab under
+    the MXU's pass over its neighbours."""
     refs = list(refs)
     bins_ref, leaf_ref, gh_ref = refs[:3]
-    hist_ref, newleaf_ref, oh_ref = refs[-3:]
-    rest = refs[3:-3]
-    w_ref = rest.pop(0) if has_w else None
-    tbl_ref = rest.pop(0)
-    fm_ref = rest.pop(0) if has_fm else None
-    t = pl.program_id(0)
+    w_ref = refs[3] if has_w else None
+    tbl_ref = refs[3 + has_w]
+    fm_ref = refs[4 + has_w] if has_fm else None
+    hist_ref, newleaf_ref = refs[4 + has_w + has_fm:][:2]
 
-    @pl.when(t == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
-    C = bins_ref.shape[1]
-
-    _write_onehot(bins_ref, oh_ref, F_oh, B, packed=packed, fm_ref=fm_ref)
-
     leafb = leaf_ref[:]                                        # [1, C] i32
+    acc_dt = jnp.int32 if quant else jnp.float32
+    # the histogram dot: all channels packed into one wide-N operand
+    hist_dot = lambda oh, ghs: jax.lax.dot_general(
+        oh, ghs, (((1,), (1,)), ((), ())), preferred_element_type=acc_dt)
 
-    # ---- routing: left_i[k, r] = 1 iff row r goes left under slot k's
-    # split. Bins form: from the bin values (no W operand, nothing read
-    # from the one-hot). Table form: D = W @ one_hot; quantized mode
-    # routes on the same int8 one-hot through the MXU's native s8 x s8
-    # -> s32 path (W is 0/1-valued either way).
-    oh = oh_ref[:]
     if not has_w:
         left_i = _left_from_bins(bins_ref, tbl_ref)            # [Sp, C] 0/1
-    elif quant:
+        newleaf_ref[:], ghs = _small_child_channels(
+            leafb, left_i, tbl_ref, gh_ref, nch, quant)
+        # the bin tile converted ONCE to 4-byte rows (8 to a register):
+        # a slab's rows then start on whole sublanes, which int8's 32 to
+        # a register would not
+        binsv = bins_ref[:].astype(jnp.int32 if quant else jnp.float32)
+        for r0, k, w, o0 in _slab_cuts(F_oh, B, packed):
+            oh = _onehot_slab(binsv[r0:r0 + k], w, quant)
+            if fm_ref is not None:
+                oh = oh * fm_ref[o0:o0 + k * w, 0:1]
+            hist_ref[o0:o0 + k * w] += hist_dot(oh, ghs)
+        return
+
+    oh_ref = refs[-1]
+    _write_onehot(bins_ref, oh_ref, F_oh, B, packed=packed, fm_ref=fm_ref)
+    # ---- routing: left_i[k, r] = 1 iff row r goes left under slot k's
+    # split, D = W @ one_hot; quantized mode routes on the same int8
+    # one-hot through the MXU's native s8 x s8 -> s32 path (W is
+    # 0/1-valued either way).
+    oh = oh_ref[:]
+    if quant:
         D = jax.lax.dot_general(w_ref[:], oh, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.int32)
         left_i = (D > 0).astype(jnp.int32)                     # [Sp, C] 0/1
@@ -547,38 +680,9 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
         # ... 8x1024xi1" when an [Sp,1] bool meets an [Sp,C] bool), and
         # int select lowers to the same VPU ops anyway.
         left_i = (D > 0.5).astype(jnp.int32)                   # [Sp, C] 0/1
-
-    # ---- slot membership + row->leaf update: right-child rows move to
-    # their new leaf id
-    newleaf_ref[:], P_i = _route_rows(leafb, left_i, tbl_ref)
-    small_left_i = (tbl_ref[:, TBL_SMALL_LEFT:TBL_SMALL_LEFT + 1]
-                    > 0).astype(jnp.int32)                     # [Sp, 1] 0/1
-    same_i = 1 - jnp.bitwise_xor(left_i, small_left_i)         # left==small
-    in_small = P_i * same_i                                    # [Sp, C] 0/1
-    if not quant:
-        in_small = in_small.astype(jnp.bfloat16)
-
-    # ---- histogram: one wide-N dot, all channels packed. mask*g instead of
-    # a select (i1 selects also hit the relayout bug); requires FINITE
-    # grad/hess — a NaN/Inf row would leak 0*NaN into other slots' bins,
-    # but non-finite gradients wreck training under any formulation.
-    # Quantized mode: int8 channels, int32 accumulator — integer sums are
-    # EXACT and associative (ops/quantize.py), rescaled outside. The
-    # mask product runs in i32 and narrows afterwards: Mosaic on v5e
-    # refuses an i8 x i8 vector multiply ("failed to legalize operation
-    # 'arith.muli' ... vector<8x128x4xi8>").
-    chans = []
-    for ch in range(nch):
-        g = gh_ref[ch:ch + 1, :]                               # [1, C]
-        if quant:
-            chans.append((in_small * jnp.broadcast_to(
-                g.astype(jnp.int32), (Sp, C))).astype(jnp.int8))
-        else:
-            chans.append(in_small * jnp.broadcast_to(g, (Sp, C)))
-    ghs = jnp.concatenate(chans, axis=0)                       # [nch*Sp, C]
-    hist_ref[:] += jax.lax.dot_general(
-        oh, ghs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32 if quant else jnp.float32)
+    newleaf_ref[:], ghs = _small_child_channels(leafb, left_i, tbl_ref,
+                                                gh_ref, nch, quant)
+    hist_ref[:] += hist_dot(oh, ghs)
 
 
 def _kernel_fb(f_oh: int, num_bins: int, packed: PackedLayout) -> int:
@@ -607,7 +711,9 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
       gh_T: [8, R] bfloat16 channel block from pack_gh(), or the int8
         block from pack_gh_quant() when ``quant_bits`` is set.
       W: [Sp, FB] bfloat16 route table (build_route_table, packed via
-        pack_route_table under ``packed``).
+        pack_route_table under ``packed``), or None: the bins form, whose
+        splits ride ``tbl`` (route_table_columns) and whose one-hot is
+        built in slabs with no scratch (level_build).
       tbl: [Sp, 128] int32; col 0 leaf_of_slot (-1 = inactive slot),
         col 1 right_delta (new_leaf_id - leaf_id), col 2 small_is_left
         (any value > 0 means left). grad/hess/weight must be FINITE: the
@@ -621,10 +727,11 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
         rescales via hist_planes(quant_bits=..., scales=...).
       packed: adaptive per-feature bin layout (ops/layout.py). The row
         TILE is still derived from the PADDED layout's f_oh*num_bins so
-        the per-element accumulation order — and hence the f32 sums —
+        the per-element accumulation order, and hence the f32 sums,
         stay bit-identical to the padded kernel's (the adaptive-bin A/B
-        contract); the win is the smaller scratch/accumulator, and the
-        on-chip ablation (scripts/ablate_hist.py) measures larger tiles.
+        contract); the win is the smaller accumulator (and scratch, in
+        the table form); the bins form builds each width class in slabs
+        of its own.
 
     Returns:
       hist: [FB, nch*Sp] float32 (int32 under quant_bits) smaller-child
@@ -636,8 +743,9 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     FB = _kernel_fb(f_oh, B, packed)
     FB_tiles = f_oh * B       # padded formula: keeps tiling A/B-stable
     Sp = tbl.shape[0]
-    C = _fit_tile(tile_rows or default_tile_rows(Sp, FB_tiles, nch,
-                                                 wide_bins=B > 256), R)
+    C = _fit_tile(tile_rows or level_build(W is None, Sp, FB_tiles, nch, Fp,
+                                           wide_bins=B > 256)["tile_rows"],
+                  R)
     assert R % C == 0, f"rows {R} not padded to tile {C}"
     T = R // C
     quant = quant_bits > 0
@@ -674,7 +782,7 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
             jax.ShapeDtypeStruct((FB, nch * Sp), acc_dt),
             jax.ShapeDtypeStruct((1, R), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((FB, C), oh_dt)],
+        scratch_shapes=[] if W is None else [pltpu.VMEM((FB, C), oh_dt)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

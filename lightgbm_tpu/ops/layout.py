@@ -203,12 +203,15 @@ def packed_feature_layout(num_bin_per_feat, max_bin: int,
 
 def hist_plane_bytes(fb: int, nch: int, sp: int, rows_padded: int,
                      tile_rows: int, quant_bits: int) -> int:
-    """Bytes the histogram plane touches per level pass: the [FB, C]
-    one-hot scratch (built once per row tile, re-read by both MXU dots),
-    the [FB, nch*Sp] accumulator, and the [8, R] gh channel stream.
+    """Bytes the histogram plane touches per level pass: the one-hot
+    (every [FB, C] of it is built once per row tile and read by the MXU:
+    through the VMEM scratch in the table form, where both dots re-read
+    it, slab by slab with no scratch in the bins form,
+    ops/fused_level.level_build; the bytes built are the same), the
+    [FB, nch*Sp] accumulator, and the [8, R] gh channel stream.
     Quantization (``tpu_quantized_grad``) halves the one-hot and gh
     element widths (int8 channels vs bf16); adaptive bins shrink ``fb``.
-    The bins/leaf/W streams are layout-independent and excluded — this
+    The bins/leaf/W streams are layout-independent and excluded: this
     figure isolates exactly what the three histogram-plane cuts move."""
     oh_elem = 1 if quant_bits else 2
     gh_elem = 1 if quant_bits else 2

@@ -320,3 +320,155 @@ def test_bins_form_routes_and_histograms_like_the_table_form(Sp, case):
         assert moved < 100        # only missing rows that default right
     elif case != "root":
         assert moved > 100
+
+
+# ---- the one-hot in slabs (PR 31): the bins form builds SLAB_ROWS rows of
+# the one-hot at a time and has no [FB, C] scratch; the table form builds
+# all of it through the same _onehot_slab into its scratch
+def _slab_case(Sp, F, variant, R=1024, seed=0):
+    """One level's operands at 63 bins a feature, in both forms: (kw of
+    level_pass, operands of the table form, operands of the bins form,
+    the float64 sums of the g channel [F_oh*B, Sp] on the padded layout)."""
+    from lightgbm_tpu.ops.fused_level import (expand_feature_mask,
+                                              pack_gh_quant,
+                                              pack_route_table,
+                                              route_table_columns)
+    from lightgbm_tpu.ops.layout import packed_feature_layout
+    rng = np.random.RandomState(seed + Sp + F)
+    max_bin = 63
+    F_oh, B = feature_layout(F, max_bin)
+    num_bin = np.full(F, max_bin, np.int32)
+    if variant == "packed":
+        num_bin[1::2] = 9
+    mt = rng.randint(0, 3, F).astype(np.int32)
+    bins = np.stack([rng.randint(0, nb, R) for nb in num_bin]) \
+        .astype(np.int8)                                       # [F, R]
+    packed, order = None, np.arange(F)
+    if variant == "packed":
+        packed = packed_feature_layout(num_bin, max_bin, f_oh=F_oh)
+        order = np.asarray(packed.feat_order)
+    bins_T = np.zeros((max(F_oh, 8), R), np.int8)
+    bins_T[:F] = bins[order]
+    leaf = rng.randint(0, Sp, R).astype(np.int32)
+    feat = rng.randint(0, F, Sp).astype(np.int32)
+    feat[Sp - Sp // 4:] = -1
+    thr = np.array([rng.randint(0, num_bin[max(f, 0)]) for f in feat],
+                   np.int32)
+    dl = rng.randint(0, 2, Sp).astype(bool)
+    small_left = rng.randint(0, 2, Sp)
+    lof = np.where(feat >= 0, np.arange(Sp), -2).astype(np.int32)
+    tbl = np.zeros((Sp, 128), np.int32)
+    tbl[:, 0], tbl[:, 1], tbl[:, 2] = lof, np.where(feat >= 0, Sp, 0), \
+        small_left
+    tbl = jnp.asarray(tbl)
+    pad_f = lambda a: jnp.asarray(np.pad(a, (0, F_oh - F)))
+    meta = (pad_f(num_bin), pad_f(mt), jnp.zeros((F_oh,), jnp.int32))
+    split = (jnp.asarray(feat), jnp.asarray(thr), jnp.asarray(dl))
+    W = build_route_table(*split, *meta, Sp, F_oh, B)
+    if packed is not None:
+        W = pack_route_table(W, packed)
+    tbl_b = route_table_columns(tbl, *split, *meta, packed)
+    grad = jnp.asarray(rng.randn(R).astype(np.float32))
+    hess = jnp.asarray(rng.rand(R).astype(np.float32) + 0.1)
+    ones = jnp.ones((R,), jnp.float32)
+    kw = dict(num_slots=Sp, num_bins=B, f_oh=F_oh, interpret=True,
+              packed=packed)
+    if variant == "quant8":
+        gh_T, _ = pack_gh_quant(grad, hess, ones, 8, jnp.uint32(5))
+        kw.update(quant_bits=8, nch=3)
+    else:
+        gh_T = pack_gh(grad, hess, ones, NCH_PRECISE)
+    fmask = None
+    keep = np.ones(F_oh, bool)
+    if variant == "fmask":
+        keep[rng.rand(F_oh) < 0.4] = False
+        keep[0] = True
+        keep[feat[feat >= 0]] = True   # a screened feature is never split on
+        fb = expand_feature_mask(jnp.asarray(keep), F_oh, B)
+        fmask = jnp.broadcast_to(fb[:, None], (F_oh * B, 128)) \
+            .astype(jnp.bfloat16)
+    # float64 sums of what the kernels multiply: g_hi + g_lo per row
+    g64 = (np.asarray(gh_T[0].astype(jnp.float32), np.float64)
+           + np.asarray(gh_T[1].astype(jnp.float32), np.float64))
+    want = np.zeros((F_oh * B, Sp))
+    for k in np.nonzero(feat >= 0)[0]:
+        f = feat[k]
+        nbf = num_bin[f]
+        left = _np_route_left(bins[f], thr[k], dl[k], nbf, mt[f], 0)
+        rows = np.nonzero((leaf == lof[k]) & (left == bool(small_left[k])))[0]
+        for j in np.nonzero(keep[:F])[0]:
+            np.add.at(want[j * B:(j + 1) * B, k], bins[j, rows], g64[rows])
+        if F_oh > F and keep[F]:       # padding features hold bin 0
+            want[F * B::B, k] = g64[rows].sum()
+    ops = (jnp.asarray(bins_T), jnp.asarray(leaf)[None, :], gh_T)
+    return kw, ops + (W, tbl, fmask), ops + (None, tbl_b, fmask), want
+
+
+@pytest.mark.parametrize("variant", ["plain", "fmask", "quant8", "packed"])
+@pytest.mark.parametrize("F", [28, 137], ids=["28feat", "137feat_odd_tail"])
+@pytest.mark.parametrize("Sp", [8, 64])
+def test_slab_build_equals_the_whole_scratch_build(Sp, F, variant):
+    """At an equal tile the bins form's slab-wise one-hot (137 features: 17
+    slabs of 8 and a tail of 2) gives the histogram of the whole-scratch
+    build bit for bit, and the same leaves."""
+    kw, table_ops, bins_ops, _ = _slab_case(Sp, F, variant)
+    hist_t, leaf_t = level_pass(*table_ops, tile_rows=512, **kw)
+    hist_b, leaf_b = level_pass(*bins_ops, tile_rows=512, **kw)
+    assert np.array_equal(np.asarray(leaf_b), np.asarray(leaf_t))
+    assert np.array_equal(np.asarray(hist_b), np.asarray(hist_t))
+    assert np.abs(np.asarray(hist_b)).sum() > 0
+
+
+@pytest.mark.parametrize("F", [28, 137])
+def test_slab_build_at_a_larger_tile_is_as_close_to_float64(F):
+    """Where the tile differs (512 against the parent's 128) the float32
+    partial sums group differently and the last bits may move: both stay
+    within 1e-6 of the float64 sum of the same bf16 channel values,
+    relative to the largest sum (on a v5e at 6.81M rows: 1.0e-6 the
+    whole-scratch build at 128 rows, 0.4-0.7e-6 the slabs at 1,024-2,048;
+    PERF.md section 6, PR 31)."""
+    Sp = 16
+    kw, table_ops, bins_ops, want = _slab_case(Sp, F, "plain", R=2048)
+    errs = {}
+    for name, ops, tile in (("scratch_128", table_ops, 128),
+                            ("slab_512", bins_ops, 512)):
+        hist, _ = level_pass(*ops, tile_rows=tile, **kw)
+        g, _, _ = hist_planes(hist, NCH_PRECISE, Sp, kw["f_oh"],
+                              kw["num_bins"])
+        got = np.asarray(g, np.float64).reshape(Sp, -1).T
+        errs[name] = np.abs(got - want).max() / np.abs(want).max()
+    assert errs["scratch_128"] <= 1e-6, errs
+    assert errs["slab_512"] <= 1e-6, errs
+
+
+def test_packed_equals_padded_at_the_slab_builds_own_tile():
+    """The adaptive layout's twin takes the padded layout's tile (2,048
+    rows here, where the whole-scratch build took 128): the decoded planes
+    are the padded kernel's bit for bit."""
+    from lightgbm_tpu.ops.fused_level import default_tile_rows
+    Sp, F = 16, 137
+    kw_p, _, packed_ops, _ = _slab_case(Sp, F, "packed", R=4096, seed=3)
+    packed = kw_p["packed"]
+    F_oh, B = kw_p["f_oh"], kw_p["num_bins"]
+    Fp = packed_ops[0].shape[0]
+    assert default_tile_rows(Sp, F_oh * B, NCH_PRECISE, bins_rows=Fp) == 2048
+    assert default_tile_rows(Sp, F_oh * B, NCH_PRECISE) == 128
+    # the padded twin: the same rows in logical order, the splits' feature
+    # rows mapped back
+    order = np.asarray(packed.feat_order)
+    bins_T = np.zeros_like(np.asarray(packed_ops[0]))
+    bins_T[order] = np.asarray(packed_ops[0])[:len(order)]
+    tbl_b = np.asarray(packed_ops[4]).copy()
+    rows = tbl_b[:, 6]
+    tbl_b[:, 6] = np.where(rows >= 0, order[np.maximum(rows, 0)], -1)
+    padded_ops = (jnp.asarray(bins_T),) + packed_ops[1:4] \
+        + (jnp.asarray(tbl_b), None)
+    hist_k, leaf_k = level_pass(*packed_ops, **kw_p)
+    hist_p, leaf_p = level_pass(*padded_ops, **dict(kw_p, packed=None))
+    assert np.array_equal(np.asarray(leaf_k), np.asarray(leaf_p))
+    # (the padding feature, all bin 0, has no row in the packed layout)
+    for a, b in zip(hist_planes(hist_k, NCH_PRECISE, Sp, F_oh, B,
+                                packed=packed),
+                    hist_planes(hist_p, NCH_PRECISE, Sp, F_oh, B)):
+        assert np.array_equal(np.asarray(a)[:, :F], np.asarray(b)[:, :F])
+    assert np.abs(np.asarray(hist_p)).sum() > 0

@@ -7,8 +7,9 @@ splits, EFB bundle columns, bins over 255). Here: (a) the choice itself;
 (b) the grower in both forms over the same numerical data: the same tree,
 the same leaves, the same replay;
 (c) whole jobs say their form once, with the reason (`route_form` event,
-`route.form_*` counters); the bare `Booster.update` job takes the bins
-form like `lgb.train`'s.
+`route.form_*` counters), and how `level_pass` builds its one-hot under it
+(`level_build` event, `level.build_*` counters; PR 31); the bare
+`Booster.update` job takes the bins form like `lgb.train`'s.
 """
 import json
 
@@ -167,4 +168,19 @@ def test_a_job_says_its_form_once_with_the_reason(tmp_path, job, form, reason):
     assert counters["route.form_%s" % form] == 1
     assert counters.get("route.form_%s" % other, 0) == 0
     assert counters["events.route_form"] == 1
+    # how level_pass builds its one-hot follows from the form (PR 31): in
+    # slabs with no [FB, C] scratch where routing reads the bin values, the
+    # whole scratch where the routing dot reads all of it first
+    built = [e for e in map(json.loads, open(out))
+             if e.get("event") == "level_build"]
+    build = {"bins": "slab", "table": "scratch"}[form]
+    assert [(e["form"], e.get("reason")) for e in built] == [(build, reason)]
+    assert built[0].get("slab_rows") == (512 if build == "slab" else None)
+    tiles = built[0]["tile_rows"]
+    assert "8" in tiles and all(128 <= t <= 2048 and t & (t - 1) == 0
+                                for t in tiles.values())
+    other = {"slab": "scratch", "scratch": "slab"}[build]
+    assert counters["level.build_%s" % build] == 1
+    assert counters.get("level.build_%s" % other, 0) == 0
+    assert counters["events.level_build"] == 1
     assert bst.num_trees() == 2

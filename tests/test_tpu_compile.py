@@ -17,9 +17,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from lightgbm_tpu.ops.fused_level import (NCH_PRECISE, feature_layout,
-                                          level_pass, max_slot_cap,
-                                          route_pass, route_tile_rows)
+from lightgbm_tpu.ops.fused_level import (NCH_PRECISE, default_tile_rows,
+                                          feature_layout, level_pass,
+                                          max_slot_cap, route_pass,
+                                          route_tile_rows)
 
 ROWS = 65_536       # the grid's length only; tiles are per shape
 
@@ -78,14 +79,83 @@ def test_level_and_route_kernels_compile(one_chip, name, features, max_bin,
               o["bins"], o["leaf"], W, o["tbl"])
 
 
-def test_bins_form_route_kernel_has_no_fb_sized_scratch(one_chip):
-    """Epsilon's width (2,000 features, FB 128,000): the table form needs
-    31 MB of scoped VMEM for its one-hot and is refused; the bins form
-    holds the [Fp, C] bin tile alone and compiles (ROADMAP B-I.3)."""
+def _pallas_call(fn, *args):
+    """The pallas_call equation of a traced kernel wrapper."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = find(sub)
+                if found is not None:
+                    return found
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+# (name, deep) -> the row tile of the bins form (slab build, PR 31) and of
+# the table form (whole [FB, C] scratch: the tiles of PR 21-29)
+TILES = {("higgs63", False): (2048, 1024), ("higgs63", True): (2048, 1024),
+         ("msltr63", False): (2048, 256), ("msltr63", True): (2048, 128),
+         ("higgs255", False): (2048, 256), ("higgs255", True): (2048, 256)}
+
+
+@pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
+@pytest.mark.parametrize("name,features,max_bin", WIDTHS,
+                         ids=[w[0] for w in WIDTHS])
+def test_bins_form_level_kernel_has_no_fb_sized_scratch(one_chip, name,
+                                                        features, max_bin,
+                                                        deep):
+    """The bins form builds its one-hot in slabs: no scratch operand at
+    all, and the row tile no longer shrinks with FB (the compile test
+    above holds that these tiles fit the default 16 MB of scoped VMEM).
+    The table form keeps its [FB, C] scratch at the tile it had."""
+    f_oh, bp = feature_layout(features, max_bin)
+    fb = f_oh * bp
+    sp = min(128, max_slot_cap(fb, NCH_PRECISE)) if deep else 8
+    o = _operands(one_chip, features, max_bin, sp)
+    want_bins, want_table = TILES[name, deep]
+    assert default_tile_rows(sp, fb, NCH_PRECISE, bins_rows=o["fp"]) \
+        == want_bins
+    assert default_tile_rows(sp, fb, NCH_PRECISE) == want_table
+    fn = functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"])
+    args = (o["bins"], o["leaf"], o["gh"])
+    bins_call = _pallas_call(fn, *args, None, o["tbl"])
+    assert bins_call.params["grid_mapping"].num_scratch_operands == 0
+    assert bins_call.params["grid_mapping"].block_mappings[0] \
+        .block_shape[1].block_size == want_bins
+    table_call = _pallas_call(fn, *args, o["W"], o["tbl"])
+    assert table_call.params["grid_mapping"].num_scratch_operands == 1
+    assert table_call.params["jaxpr"].invars[-1].aval.shape \
+        == (fb, want_table)
+
+
+def test_bins_form_kernels_at_epsilon_width(one_chip):
+    """Epsilon's width (2,000 features, FB 128,000). ``route_pass``: the
+    table form needs 31 MB of scoped VMEM for its one-hot and is refused;
+    the bins form holds the [Fp, C] bin tile alone and compiles (ROADMAP
+    B-I.3). ``level_pass`` in the table form is refused too. Its bins
+    form has no scratch left to refuse (test below, slow: 250 unrolled
+    slabs)."""
     o = _operands(one_chip, 2000, 63, 8)
     assert route_tile_rows(8, o["fp"]) == 1024
+    assert default_tile_rows(8, o["fb"], NCH_PRECISE,
+                             bins_rows=o["fp"]) == 1024
     _compiles(functools.partial(route_pass, **o["kw"]),
               o["bins"], o["leaf"], None, o["tbl"])
     with pytest.raises(Exception, match="vmem"):
         _compiles(functools.partial(route_pass, **o["kw"]),
                   o["bins"], o["leaf"], o["W"], o["tbl"])
+    with pytest.raises(Exception, match="vmem"):
+        _compiles(functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"]),
+                  o["bins"], o["leaf"], o["gh"], o["W"], o["tbl"])
+
+
+@pytest.mark.slow
+def test_bins_form_level_kernel_compiles_at_epsilon_width(one_chip):
+    """What the compiler says once the scratch is gone: the bins-form
+    ``level_pass`` at FB 128,000 compiles at 1,024-row tiles (107 s for
+    250 unrolled slabs; the [128,000, 40] float32 accumulator is the
+    pipeline's output window, not scoped stack). Compiled, never run."""
+    o = _operands(one_chip, 2000, 63, 8)
+    _compiles(functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"]),
+              o["bins"], o["leaf"], o["gh"], None, o["tbl"])
