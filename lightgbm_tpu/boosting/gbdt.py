@@ -2832,6 +2832,9 @@ class GBDT:
         ht.leaf_count = np.asarray(tree.leaf_count)[:nl].astype(np.int64)
         ht.leaf_depth = np.asarray(tree.leaf_depth)[:nl].astype(np.int32)
         self._last_cat = (cat_flag, cat_mask) if self.has_cat else None
+        # what the drained trees split on (docs/Observability.md)
+        self.telemetry.inc("split.nodes", ni)
+        self.telemetry.inc("split.cat_nodes", int(np.count_nonzero(cat_flag)))
         return ht, sf_inner
 
     # ------------------------------------------------------------------
@@ -3257,6 +3260,13 @@ class GBDT:
                               for sp, b in builds.items()}
         tel.inc("level.build_%s" % build["form"])
         tel.event("level_build", iteration=self.iter, **build, **why)
+        if self.has_cat:
+            # the columns the categorical search runs over, once per job
+            ds = self.train_data
+            cols = [int(ds.real_feature_index(j))
+                    for j in np.flatnonzero(ds.is_categorical)]
+            tel.event("cat_layout", iteration=self.iter, columns=cols,
+                      bins=[int(ds.mappers[c].num_bin) for c in cols])
 
     def _wants_route_log(self) -> bool:
         """Some validation set is routed by the kernels: the steps that

@@ -342,11 +342,22 @@ def dump_model_json(booster, start_iteration: int = 0,
             }
         d = int(tree.decision_type[node])
         cat = bool(d & 1)
+        if cat:
+            # the category values that go left, "a||b||c" (ref: tree.cpp
+            # NodeToJSON): the node's bitset, 32 categories a word
+            ci = int(tree.threshold[node])
+            words = tree.cat_threshold[tree.cat_boundaries[ci]:
+                                       tree.cat_boundaries[ci + 1]]
+            threshold = "||".join(
+                str(32 * w + b) for w, word in enumerate(words)
+                for b in range(32) if (int(word) >> b) & 1)
+        else:
+            threshold = float(tree.threshold[node])
         return {
             "split_index": int(node),
             "split_feature": int(tree.split_feature[node]),
             "split_gain": float(tree.split_gain[node]),
-            "threshold": float(tree.threshold[node]),
+            "threshold": threshold,
             "decision_type": "==" if cat else "<=",
             "default_left": bool(d & 2),
             "missing_type": ["None", "Zero", "NaN"][(d >> 2) & 3],

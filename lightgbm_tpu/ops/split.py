@@ -651,9 +651,13 @@ def best_split_cm(grad: jax.Array, hess: jax.Array, cnt: jax.Array,
         bound_lo_plane=bound_lo_plane, bound_hi_plane=bound_hi_plane)
     if not has_cat:
         return num
-    cat = best_categorical_split_cm(
-        grad, hess, cnt, num_bin_per_feat, feature_mask & ic, params,
-        parent_output, cegb_delta=cegb_delta)
+    # the categorical search has a scope of its own inside the caller's
+    # (lgbm.grow > root, > level > split): its sort and its two prefix
+    # scans are what a categorical job adds to the split search
+    with jax.named_scope("cat"):
+        cat = best_categorical_split_cm(
+            grad, hess, cnt, num_bin_per_feat, feature_mask & ic, params,
+            parent_output, cegb_delta=cegb_delta)
     if use_bounds:
         # categorical features carry no monotone direction, but the leaf's
         # feasible output interval still applies (winner-level clamp;
@@ -682,8 +686,9 @@ def per_feature_gains_cm(grad, hess, cnt, num_bin_per_feat, missing_type,
         feature_mask & ~ic, monotone, params, parent_output,
         per_feature_gains=True)
     if has_cat:
-        gc = best_categorical_split_cm(
-            grad, hess, cnt, num_bin_per_feat, feature_mask & ic, params,
-            parent_output, per_feature_gains=True)
+        with jax.named_scope("cat"):
+            gc = best_categorical_split_cm(
+                grad, hess, cnt, num_bin_per_feat, feature_mask & ic,
+                params, parent_output, per_feature_gains=True)
         g = jnp.maximum(g, gc)
     return g

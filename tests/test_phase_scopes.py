@@ -40,10 +40,11 @@ STRUCTURAL = {"while", "cond", "closed_call", "shard_map", "body"}
 
 
 def _lowered_step(monkeypatch, with_eval: bool, learner: str,
-                  ranking: bool = False) -> str:
+                  ranking: bool = False, categorical: bool = False) -> str:
     """Debug text of the megastep `lgb.train` built for a small binary
     (or, ``ranking``, lambdarank + NDCG) job, lowered again from the
-    shapes it was called with."""
+    shapes it was called with. ``categorical``: columns 2 and 3 hold
+    category codes and are given as such."""
     seen = {}
     make = GBDT._make_megastep
 
@@ -76,7 +77,16 @@ def _lowered_step(monkeypatch, with_eval: bool, learner: str,
         y = np.floor(3 * X[:, 0] + X[:, 1]).astype(np.float32)
         yv = np.floor(3 * Xv[:, 0] + Xv[:, 1]).astype(np.float32)
         group, group_v = RANK_GROUPS, RANK_GROUPS_VALID
-    ds = lgb.Dataset(X, label=y, group=group)
+    cats = "auto"
+    if categorical:
+        for c, n in ((2, 40), (3, 9)):
+            X[:, c] = np.floor(n * X[:, c] ** 2)
+            Xv[:, c] = np.floor(n * Xv[:, c] ** 2)
+        y = ((X[:, 2] % 3 == 0) ^ (X[:, 0] > 0.5)).astype(np.float32)
+        yv = ((Xv[:, 2] % 3 == 0) ^ (Xv[:, 0] > 0.5)).astype(np.float32)
+        params.update(min_data_per_group=20, cat_smooth=5.0)
+        cats = [2, 3]
+    ds = lgb.Dataset(X, label=y, group=group, categorical_feature=cats)
     # with callbacks the scan evaluates the metric itself and carries the
     # early-stop latch; without them it only keeps the validation scores
     lgb.train(params, ds, num_boost_round=2,
@@ -189,3 +199,38 @@ def test_every_row_length_operation_of_the_lambdarank_step_is_scoped(
     assert any(n.endswith("lgbm.gradients/rank_sort/sort") for n in names)
     assert any("lgbm.eval/ndcg/" in n and n.endswith("top_k") for n in names)
     assert not any("lgbm.eval/ndcg@" in n for n in names)
+
+
+def test_every_row_and_histogram_length_operation_of_a_categorical_step_is_scoped(
+        monkeypatch):
+    """A job with categorical columns routes by ``W @ one_hot`` and searches
+    category sets: the search (a sort of every leaf's categories and two
+    prefix scans over the bins) has a scope of its own, ``cat``, inside the
+    root's and inside ``level/split``, which ``phase_of`` folds into its
+    stage; nothing as long as the rows or as large as a histogram plane (8
+    features of 64 bins, flat or not) is outside a scope."""
+    text, avals = _lowered_step(monkeypatch, True, "serial",
+                                categorical=True)
+    bins_T = avals[0]
+    lengths = {ROWS, bins_T.shape[1], VALID_ROWS, 8 * 64}
+    ops, calls = _scoped_ops(text)
+    names, unscoped = set(), []
+    for func, name, dims in ops:
+        for full in _full_names(func, name, calls):
+            names.add(full)
+            if phase_of(full) == UNSCOPED \
+                    and (dims & lengths or {8, 64} <= dims) \
+                    and full.split("/")[-1] not in STRUCTURAL:
+                unscoped.append(full)
+    assert not unscoped, f"row- or histogram-length operations outside " \
+        f"any lgbm. scope: {sorted(set(unscoped))[:10]}"
+    assert {phase_of(n).split("/")[0] for n in names} - {UNSCOPED} \
+        == TOP_LEVEL | EVAL_ONLY
+    under_cat = [n for n in names if "/cat/" in n]
+    assert {phase_of(n) for n in under_cat} == {"grow/root",
+                                                "grow/level/split"}
+    assert any(n.endswith("/sort") for n in under_cat)
+    assert any("/cat/" in n and "/while/" in n for n in under_cat)
+    # the numerical scan of the same call stays outside the scope
+    assert any(phase_of(n) == "grow/level/split" and "/cat/" not in n
+               for n in names)

@@ -59,8 +59,10 @@ def _compiles(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-# name, features, max_bin: the benchmark's two widths, and Higgs at 255 bins
-WIDTHS = [("higgs63", 28, 63), ("msltr63", 137, 63), ("higgs255", 28, 255)]
+# name, features, max_bin: the benchmark's widths (expo255: the categorical
+# cell, which runs the table form), and Higgs at 255 bins
+WIDTHS = [("higgs63", 28, 63), ("msltr63", 137, 63), ("higgs255", 28, 255),
+          ("expo255", 8, 255)]
 
 
 @pytest.mark.parametrize("form", ["bins", "table"])
@@ -96,7 +98,8 @@ def _pallas_call(fn, *args):
 # the table form (whole [FB, C] scratch: the tiles of PR 21-29)
 TILES = {("higgs63", False): (2048, 1024), ("higgs63", True): (2048, 1024),
          ("msltr63", False): (2048, 256), ("msltr63", True): (2048, 128),
-         ("higgs255", False): (2048, 256), ("higgs255", True): (2048, 256)}
+         ("higgs255", False): (2048, 256), ("higgs255", True): (2048, 256),
+         ("expo255", False): (2048, 1024), ("expo255", True): (2048, 1024)}
 
 
 @pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
