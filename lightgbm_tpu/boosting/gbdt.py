@@ -3171,7 +3171,8 @@ class GBDT:
         bins_form = route_form(self.has_cat, self.fused_bundle_cols,
                                kB)[0] == "bins"
         return level_build(bins_form, Sp, kF * kB, self.fused_nch,
-                           max(kF, 8), wide_bins=kB > 256)
+                           max(kF, 8), wide_bins=kB > 256,
+                           has_cat=self.has_cat)
 
     def _valid_route_reason(self, vi: int) -> Optional[str]:
         """Why validation set ``vi`` keeps the gather walk, or None when
@@ -3236,7 +3237,10 @@ class GBDT:
         engine, once per run and distinct payload; called where each
         such step is built. The ROUTING form (models/frontier2.route_form
         decides; this only tells): counter ``route.form_<form>`` and a
-        ``route_form`` event. How ``level_pass`` BUILDS its one-hot, which
+        ``route_form`` event; a job with a categorical column in the bins
+        form, whose kernels test a slot's bin SET, adds ``membership:
+        true`` and the counter ``route.cat_membership``. How
+        ``level_pass`` BUILDS its one-hot, which
         follows from it (ops/fused_level.level_build decides): counter
         ``level.build_<form>`` and a ``level_build`` event with the slab
         size and the row tile per distinct slot count of the level
@@ -3252,7 +3256,11 @@ class GBDT:
         form, reason = said
         why = {"reason": reason} if reason else {}
         tel.inc("route.form_%s" % form)
-        tel.event("route_form", iteration=self.iter, form=form, **why)
+        membership = self.has_cat and form == "bins"
+        if membership:
+            tel.inc("route.cat_membership")
+        tel.event("route_form", iteration=self.iter, form=form, **why,
+                  **({"membership": True} if membership else {}))
         builds = {sp: self._level_build(sp)
                   for sp in sorted({8} | {max(8, c) for c in caps})}
         build = dict(builds[8])
@@ -3336,7 +3344,8 @@ class GBDT:
                 num_bins=(self.fused_bundle_col_bins
                           if self.fused_bundle_cols else self.fused_Bp),
                 f_oh=self.fused_bundle_cols or self.fused_f_oh,
-                interpret=interp, packed=self.fused_packed)
+                interpret=interp, packed=self.fused_packed,
+                has_cat=has_cat)
 
         @scope
         def lookup(leaf_T, leaf_value):

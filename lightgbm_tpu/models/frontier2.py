@@ -77,19 +77,20 @@ def route_form(has_cat: bool, bundle_cols: int,
     job. THE place where the form is chosen: the grower asks here, and so
     does the driver for its ``route_form`` event.
 
-    ``bins``: a numerical split routes by the bin VALUE of its feature
+    ``bins``: a split routes by the bin VALUE of its feature
     (ops/fused_level._left_from_bins): the slot table carries threshold,
-    missing bin, default_left and the feature's row, no [Sp, FB] table is
-    built, logged or multiplied. ``table``: ``W @ one_hot``
-    (build_route_table*), kept where a split is not one comparison of one
-    stored value:
+    missing bin, default_left and the feature's row, and with ``has_cat``
+    (the job has a categorical column) each slot's categorical flag and
+    its left-going bin SET as 256 bits, which the kernels test the stored
+    bin's membership in; no [Sp, FB] table is built, logged or
+    multiplied. ``table``: ``W @ one_hot`` (build_route_table*), kept
+    where a split does not read the split feature's bin from one stored
+    value, with or without categorical columns:
 
-    - ``categorical``: "left" is membership in a bin set;
     - ``bundled``: the stored value is an EFB bundle bin that decodes
       to the split feature's bin by its window (and may pass 256);
-    - ``wide_bins``: bin values over 255 are not exact in bfloat16."""
-    if has_cat:
-        return "table", "categorical"
+    - ``wide_bins``: bin values over 255 are not exact in bfloat16 and
+      do not fit the slot table's 256-bit set."""
     if bundle_cols > 0:
         return "table", "bundled"
     if num_bins > 256:
@@ -297,7 +298,7 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                               num_slots=Sp0,
                               num_bins=k_B, f_oh=k_foh, nch=nch,
                               interpret=interpret, quant_bits=quant_bits,
-                              packed=packed)
+                              packed=packed, has_cat=has_cat)
         # feature mode: rows are replicated, the local histogram IS the
         # global one (a psum would multiply by the shard count); voting:
         # the root is always a full exchange like the XLA growers
@@ -466,27 +467,25 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
             tbl = jnp.zeros((Sp, 128), jnp.int32).at[:, :3].set(
                 jnp.stack([lof, delta_s, small_left_s.astype(jnp.int32)],
                           axis=1))
+            # a categorical slot's left-going bin set, in either form
+            sets = dict(cat_flag=cf_s, cat_mask=cm_s) if has_cat else {}
             if bins_form:
-                # (route_form) the splits ride the slot table; no [Sp, FB]
-                # table is built
+                # (route_form) the splits ride the slot table, a
+                # categorical one as its bin set; no [Sp, FB] table is built
                 W = None
                 tbl = route_table_columns(
                     tbl, feat_s, thr_s, dl_s, meta.num_bin,
-                    meta.missing_type, meta.default_bin, packed)
+                    meta.missing_type, meta.default_bin, packed, **sets)
             elif use_bundles:
                 W = build_route_table_bundled(
                     feat_s, thr_s, dl_s, meta.num_bin, meta.missing_type,
                     meta.default_bin, bundle_cfg.default_bin,
                     bundle_cfg.col_of_feat, bundle_cfg.offset_of_feat,
-                    bundle_cols, bundle_col_bins,
-                    cat_flag=cf_s if has_cat else None,
-                    cat_mask=cm_s if has_cat else None)
+                    bundle_cols, bundle_col_bins, **sets)
             else:
                 W = build_route_table(feat_s, thr_s, dl_s, meta.num_bin,
                                       meta.missing_type, meta.default_bin,
-                                      Sp, f_oh, B,
-                                      cat_flag=cf_s if has_cat else None,
-                                      cat_mask=cm_s if has_cat else None)
+                                      Sp, f_oh, B, **sets)
                 if packed is not None:
                     # route tables are built on the logical padded layout and
                     # re-indexed onto the packed flat axis (exact 0/1 gather)
@@ -505,14 +504,15 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
             if route_only:
                 leaf_T2 = route_pass(bins_T, leaf_T, W, tbl, num_slots=Sp,
                                      num_bins=k_B, f_oh=k_foh,
-                                     interpret=interpret, packed=packed)
+                                     interpret=interpret, packed=packed,
+                                     has_cat=has_cat)
                 pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
                 pool_valid2 = pool_valid
             else:
                 hist, leaf_T2 = level_pass(
                     bins_T, leaf_T, gh_T, W, tbl, fmask2d, num_slots=Sp,
                     num_bins=k_B, f_oh=k_foh, nch=nch, interpret=interpret,
-                    quant_bits=quant_bits, packed=packed)
+                    quant_bits=quant_bits, packed=packed, has_cat=has_cat)
                 if psum_axis is not None and not vote_live and not feat_par:
                     hist = record_psum(hist, psum_axis)
 
@@ -818,16 +818,17 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
 
 def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
                      num_bins: int, f_oh: int, interpret: bool = False,
-                     packed=None) -> jax.Array:
+                     packed=None, has_cat: bool = False) -> jax.Array:
     """Leaf of every row of ``bins_T`` in the tree whose route log
     (``grow_tree_fused(route_log=True)``) is ``log``: start the
     ``num_rows`` real rows at leaf 0 (padding columns at -1) and run one
     ``route_pass`` per logged level that has an active slot. ``bins_T``
     is any [Fp, Rp] matrix in the layout the tables were written over
-    (the grower's ``num_bins`` / ``f_oh`` / ``packed`` kernel layout).
-    The decisions are the training rows' own in either form (``W @ one_hot
-    > 0.5``, or the bin value against the slot's threshold where the log
-    holds no ``W``: exact arithmetic both), so over the training matrix
+    (the grower's ``num_bins`` / ``f_oh`` / ``packed`` / ``has_cat``
+    kernel layout). The decisions are the training rows' own in either
+    form (``W @ one_hot > 0.5``, or the bin value against the slot's
+    threshold or bin set where the log holds no ``W``: exact arithmetic
+    both), so over the training matrix
     this returns the grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
     log_W, log_tbl = log
     Rp = bins_T.shape[1]
@@ -841,7 +842,7 @@ def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
             lambda lt: route_pass(bins_T, lt, W, tbl,
                                   num_slots=tbl.shape[0], num_bins=num_bins,
                                   f_oh=f_oh, interpret=interpret,
-                                  packed=packed),
+                                  packed=packed, has_cat=has_cat),
             lambda lt: lt, leaf_T), None
 
     leaf_T, _ = jax.lax.scan(level, leaf_T, (log_W, log_tbl))

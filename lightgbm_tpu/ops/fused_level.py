@@ -39,13 +39,21 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
 - ROUTING (which rows of slot k's leaf go left) has two forms, chosen
   once per grower from what is static about the job
   (models/frontier2.route_form):
-    * BINS form, every dense numerical job: a split compares ONE stored
-      value with ONE threshold, so slot k's feature row is picked out of
+    * BINS form, every dense job of at most 256 bins a column: a split
+      reads ONE stored value, so slot k's feature row is picked out of
       the [Fp, C] bin tile with a K = Fp dot (``sel[Sp, Fp] @ bins``,
       exact: one non-zero term, values <= 255) and compared with the
       threshold and missing bin the slot table carries
-      (``route_table_columns``, ``_left_from_bins``). ``route_pass`` in
-      this form builds no one-hot and has no FB-sized scratch: 5.3 ms
+      (``route_table_columns``, ``_left_from_bins``). A categorical
+      split is MEMBERSHIP of that value in a bin set: the slot table
+      carries the set as 256 bits (eight int32 words) beside the slot's
+      categorical flag, and the kernel takes bit ``v & 31`` of word
+      ``v >> 5`` (eight compare-and-selects, one per-lane shift; VPU
+      work on [Sp, C] int32 planes before ``ghs`` exists, so nothing of
+      it hides under the MXU). That code is traced only when the job has
+      a categorical column (the grower's static ``has_cat``): every other
+      job's kernels lower as they did before it existed. ``route_pass``
+      in this form builds no one-hot and has no FB-sized scratch: 5.3 ms
       over 28M rows x 28 features at 64 slots, 2.4 ms over 6.8M x 137.
     * TABLE form: ``D = W @ oh -> [S, C]`` with W [S, FB] encoding the
       level's left-going bins per slot (``build_route_table*``). It
@@ -54,8 +62,9 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
       through each: 58-70 ms of every ``level_pass`` and, with the
       one-hot build it needs (28-60 ms), all 103 / 131 ms of a
       ``route_pass`` at the two widths above. Kept where "left" is not
-      one comparison of one stored value (categorical bin sets, EFB
-      bundle columns whose bins decode by window, bins over 255).
+      read from one stored value of at most 255 (EFB bundle columns
+      whose bins decode by window, bins over 255), with or without
+      categorical columns (``build_route_table*``'s ``cat_mask``).
       ``level_pass`` / ``route_pass`` take the form from their ``W``
       argument (None = bins form).
 - All gh channels are packed into ONE dot (N = nch*S): MXU efficiency
@@ -96,9 +105,13 @@ NCH_FAST = 3      # g, h, w
 
 # columns of the per-level slot table ``tbl`` [Sp, 128] int32. 0-2 are read
 # by both routing forms; 3-6 carry the split itself in the bins form
-# (route_table_columns) and stay zero in the table form.
+# (route_table_columns) and stay zero in the table form; 7-15 carry a
+# categorical split's left-going bin SET in the bins form of a job with a
+# categorical column: the slot's categorical flag, then bins 0-255 as the
+# bits of eight int32 words (bin b is bit b & 31 of word b >> 5).
 TBL_LEAF, TBL_RIGHT_DELTA, TBL_SMALL_LEFT = 0, 1, 2
 TBL_THRESHOLD, TBL_MISSING_BIN, TBL_DEFAULT_LEFT, TBL_FEATURE_ROW = 3, 4, 5, 6
+TBL_CAT_FLAG, TBL_CAT_WORD0, CAT_WORDS = 7, 8, 8
 
 
 def _round_up(x: int, m: int) -> int:
@@ -114,9 +127,15 @@ VMEM_BUDGET = 15 * 1024 * 1024  # scoped-vmem stack limit is 16 MB; leave
 
 SLAB_ROWS = 512   # one-hot rows of one slab of the bins form's build
 
+# what the bins form's set-membership test (_left_from_bins, jobs with a
+# categorical column) keeps per row and slot: the integer bin value, the
+# chosen word of the set and the shifted bit, int32 each
+CAT_PLANE_BYTES = 12
+
 
 def default_tile_rows(Sp: int, FB: int, nch: int,
-                      wide_bins: bool = False, bins_rows: int = 0) -> int:
+                      wide_bins: bool = False, bins_rows: int = 0,
+                      has_cat: bool = False) -> int:
     """Row-tile width of ``level_pass``: a power of two from 128 to 2,048
     (``_init_fused`` aligns the rows to 2,048 a shard), from the PADDED
     layout's shapes and the form alone, so an adaptive-bins job takes its
@@ -128,7 +147,9 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     build intermediates (SLAB_ROWS x 6 B), the [nch*Sp, C] ``ghs``
     (2 B), the [Sp, C] int32 routing planes (16 B a slot, as
     route_tile_rows charges them) and the converted bin tile (4 B + the
-    routing dot's bf16 copy). That is 2,048 rows up to Fp ~700 at 16
+    routing dot's bf16 copy); a job with a categorical column
+    (``has_cat``) is charged the membership test's [Sp, C] int32 planes
+    too (CAT_PLANE_BYTES a slot). That is 2,048 rows up to Fp ~700 at 16
     slots or 128 slots at Fp 28. The charge is CONSERVATIVE: compiled
     for a described v5e the kernel needed 1.3-1.8 MB at 8-16 slots, 4.2
     at 64 and 5.2 at 128 with 2,048-row tiles (the compiler fuses the
@@ -152,7 +173,8 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     default 16 MB scoped-VMEM limit. Shallow levels (small Sp -> small
     accumulator) get larger tiles."""
     if bins_rows:
-        per_row = SLAB_ROWS * 6 + Sp * (2 * nch + 16) + bins_rows * 6
+        per_row = (SLAB_ROWS * 6 + bins_rows * 6
+                   + Sp * (2 * nch + 16 + CAT_PLANE_BYTES * has_cat))
         c = VMEM_BUDGET // per_row
     else:
         acc = FB * nch * Sp * 4
@@ -164,7 +186,7 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
 
 
 def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
-                wide_bins: bool = False) -> dict:
+                wide_bins: bool = False, has_cat: bool = False) -> dict:
     """How ``level_pass`` builds its one-hot at these shapes, and its row
     tile. THE place both are chosen: the kernel asks here, and so does
     the driver for its ``level_build`` event. ``slab`` (the bins form):
@@ -173,7 +195,8 @@ def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
     routing dot reads the whole one-hot first): all [FB, C] of it."""
     if bins_form:
         return {"form": "slab", "slab_rows": SLAB_ROWS,
-                "tile_rows": default_tile_rows(Sp, FB, nch, bins_rows=Fp)}
+                "tile_rows": default_tile_rows(Sp, FB, nch, bins_rows=Fp,
+                                               has_cat=has_cat)}
     return {"form": "scratch",
             "tile_rows": default_tile_rows(Sp, FB, nch, wide_bins=wide_bins)}
 
@@ -401,16 +424,26 @@ def route_table_columns(tbl: jax.Array, feature: jax.Array,
                         threshold: jax.Array, default_left: jax.Array,
                         num_bin: jax.Array, missing_type: jax.Array,
                         default_bin: jax.Array,
-                        packed: PackedLayout = None) -> jax.Array:
-    """The BINS form of a level's numerical splits: ``tbl`` with columns
-    3-6 filled per slot (threshold bin; the bin that rides default_left,
-    -1 for none — build_route_table's ``is_missing``; default_left; the
-    split feature's ROW of the kernel's bin matrix, its position in
+                        packed: PackedLayout = None,
+                        cat_flag: jax.Array = None,
+                        cat_mask: jax.Array = None) -> jax.Array:
+    """The BINS form of a level's splits: ``tbl`` with columns 3-6 filled
+    per slot (threshold bin; the bin that rides default_left, -1 for
+    none — build_route_table's ``is_missing``; default_left; the split
+    feature's ROW of the kernel's bin matrix, its position in
     ``packed.feat_order`` under the adaptive layout). An inactive slot
     (feature -1) gets threshold -1, no missing bin and row -1: it reads
     "not left" for every row, as its all-zero W row does. The kernels
     decide ``left = where(bin == missing, default_left, bin <= threshold)``
     from these (_left_from_bins): the same 0/1 plane as ``W @ one_hot``.
+
+    With ``cat_flag`` [Sp] and ``cat_mask`` [Sp, B <= 256] (a job with a
+    categorical column) columns 7-15 are filled too: the slot's
+    categorical flag and its left-going bin SET, bin b as bit ``b & 31``
+    of word ``b >> 5``. A categorical slot is decided by membership of
+    the stored bin in that set alone (unseen, rare, negative and NaN
+    categories sit in bins outside it and go right), a numerical slot of
+    the same level as above; an inactive slot's flag and words are zero.
     Args as build_route_table ([Sp] per slot, [F] per feature)."""
     on = feature >= 0
     f = jnp.maximum(feature, 0)
@@ -421,8 +454,21 @@ def route_table_columns(tbl: jax.Array, feature: jax.Array,
     cols = jnp.stack([threshold, miss, default_left.astype(jnp.int32), row],
                      axis=1)
     cols = jnp.where(on[:, None], cols, jnp.array([-1, -1, 0, -1]))
-    return tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(
+    tbl = tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(
         cols.astype(jnp.int32))
+    if cat_flag is None:
+        return tbl
+    Sp, B = cat_mask.shape
+    assert B <= 32 * CAT_WORDS, f"bin set of {B} bins"
+    is_cat = cat_flag & on
+    bits = jnp.pad(cat_mask & is_cat[:, None],
+                   ((0, 0), (0, 32 * CAT_WORDS - B))) \
+        .reshape(Sp, CAT_WORDS, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=2,
+                    dtype=jnp.uint32)
+    return tbl.at[:, TBL_CAT_FLAG].set(is_cat.astype(jnp.int32)) \
+        .at[:, TBL_CAT_WORD0:TBL_CAT_WORD0 + CAT_WORDS].set(
+            jax.lax.bitcast_convert_type(words, jnp.int32))
 
 
 def root_route_tables(num_bins: int, kern_fb: int, first_width: int,
@@ -524,14 +570,21 @@ def bundle_plane_views(plane: jax.Array, flat_idx: jax.Array,
     return out[..., 0] if squeeze else out
 
 
-def _left_from_bins(bins_ref, tbl_ref):
+def _left_from_bins(bins_ref, tbl_ref, has_cat: bool = False):
     """left_i [Sp, C] int32 0/1 from the bin VALUES (bins form): slot
     k's feature row is picked with a K = Fp dot, ``v[k, r] = sum_f
     sel[k, f] * bins[f, r]`` (one non-zero term, bin values <= 255 are
     exact in bf16, so v is exact in f32), then compared with the slot's
     threshold and missing bin from ``tbl``. An inactive slot (feature
     row -1: sel row all zero, threshold -1) reads 0. Mask algebra stays
-    in i32 (the i1 relayout bug noted in _level_kernel)."""
+    in i32 (the i1 relayout bug noted in _level_kernel).
+
+    ``has_cat`` (static: the job has a categorical column): a slot whose
+    categorical flag is set is decided by MEMBERSHIP of v in the slot's
+    bin set instead, bit ``v & 31`` of word ``v >> 5`` of the eight words
+    the slot table carries (route_table_columns): the word by eight
+    compare-and-selects, the bit by a per-lane shift. Traced only then:
+    the kernels of a job without a categorical column lower as before."""
     Fp = bins_ref.shape[0]
     Sp = tbl_ref.shape[0]
     sel = (jax.lax.broadcasted_iota(jnp.int32, (Sp, Fp), 1)
@@ -546,7 +599,18 @@ def _left_from_bins(bins_ref, tbl_ref):
     dl = tbl_ref[:, TBL_DEFAULT_LEFT:TBL_DEFAULT_LEFT + 1]     # [Sp, 1]
     le = (v <= thr).astype(jnp.int32)
     is_miss = (v == miss).astype(jnp.int32)
-    return le + is_miss * (dl - le)        # where(is_miss, dl, v <= thr)
+    left = le + is_miss * (dl - le)        # where(is_miss, dl, v <= thr)
+    if not has_cat:
+        return left
+    vi = v.astype(jnp.int32)                                   # [Sp, C]
+    which = jax.lax.shift_right_logical(vi, 5)
+    word = jnp.zeros_like(vi)
+    for j in range(CAT_WORDS):
+        col = TBL_CAT_WORD0 + j
+        word = jnp.where(which == j, tbl_ref[:, col:col + 1], word)
+    member = jax.lax.shift_right_logical(word, vi & 31) & 1
+    is_cat = tbl_ref[:, TBL_CAT_FLAG:TBL_CAT_FLAG + 1]         # [Sp, 1]
+    return left + is_cat * (member - left)  # where(is_cat, member, left)
 
 
 def _route_rows(leafb, left_i, tbl_ref):
@@ -619,7 +683,8 @@ def _slab_cuts(F_oh: int, B: int, packed: PackedLayout = None):
 
 def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
                   quant: bool = False, packed: PackedLayout = None,
-                  has_fm: bool = False, has_w: bool = True):
+                  has_fm: bool = False, has_w: bool = True,
+                  has_cat: bool = False):
     """``level_pass``'s body. Table form (``has_w``): the whole one-hot
     goes to the [FB, C] scratch ``oh_ref`` first, because routing reads
     all of it (``D = W @ oh``) before the histogram dot's right-hand side
@@ -647,7 +712,7 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
         oh, ghs, (((1,), (1,)), ((), ())), preferred_element_type=acc_dt)
 
     if not has_w:
-        left_i = _left_from_bins(bins_ref, tbl_ref)            # [Sp, C] 0/1
+        left_i = _left_from_bins(bins_ref, tbl_ref, has_cat)   # [Sp, C] 0/1
         newleaf_ref[:], ghs = _small_child_channels(
             leafb, left_i, tbl_ref, gh_ref, nch, quant)
         # the bin tile converted ONCE to 4-byte rows (8 to a register):
@@ -692,13 +757,13 @@ def _kernel_fb(f_oh: int, num_bins: int, packed: PackedLayout) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "f_oh", "nch", "tile_rows",
-                     "interpret", "quant_bits", "packed"))
+                     "interpret", "quant_bits", "packed", "has_cat"))
 def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
                W: jax.Array, tbl: jax.Array, fmask: jax.Array = None,
                *, num_slots: int, num_bins: int, f_oh: int,
                nch: int = NCH_PRECISE, tile_rows: int = 0,
                interpret: bool = False, quant_bits: int = 0,
-               packed: PackedLayout = None):
+               packed: PackedLayout = None, has_cat: bool = False):
     """One fused route+histogram pass over all rows.
 
     Args:
@@ -732,6 +797,10 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
         contract); the win is the smaller accumulator (and scratch, in
         the table form); the bins form builds each width class in slabs
         of its own.
+      has_cat: the job has a categorical column (the grower's static):
+        in the bins form ``tbl`` then carries each slot's categorical
+        flag and bin set (route_table_columns) and the kernel tests
+        membership; the table form reads ``W`` and ignores it.
 
     Returns:
       hist: [FB, nch*Sp] float32 (int32 under quant_bits) smaller-child
@@ -744,7 +813,8 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     FB_tiles = f_oh * B       # padded formula: keeps tiling A/B-stable
     Sp = tbl.shape[0]
     C = _fit_tile(tile_rows or level_build(W is None, Sp, FB_tiles, nch, Fp,
-                                           wide_bins=B > 256)["tile_rows"],
+                                           wide_bins=B > 256,
+                                           has_cat=has_cat)["tile_rows"],
                   R)
     assert R % C == 0, f"rows {R} not padded to tile {C}"
     T = R // C
@@ -755,7 +825,8 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     kernel = functools.partial(_level_kernel, B=B, F_oh=f_oh, Sp=Sp,
                                nch=nch, quant=quant, packed=packed,
                                has_fm=fmask is not None,
-                               has_w=W is not None)
+                               has_w=W is not None,
+                               has_cat=has_cat and W is None)
     in_specs = [
         pl.BlockSpec((Fp, C), lambda t: (0, t)),
         pl.BlockSpec((1, C), lambda t: (0, t)),
@@ -806,24 +877,27 @@ def _route_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, newleaf_ref,
     newleaf_ref[:], _ = _route_rows(leaf_ref[:], left_i, tbl_ref)
 
 
-def _route_bins_kernel(bins_ref, leaf_ref, tbl_ref, newleaf_ref):
+def _route_bins_kernel(bins_ref, leaf_ref, tbl_ref, newleaf_ref, *,
+                       has_cat: bool = False):
     """_route_kernel in the BINS form: no one-hot, no [FB, C] scratch.
     Per tile: [Fp, C] int8 -> bf16, one K = Fp dot, a handful of [Sp, C]
-    VPU ops."""
-    left_i = _left_from_bins(bins_ref, tbl_ref)
+    VPU ops (the set-membership test among them under ``has_cat``)."""
+    left_i = _left_from_bins(bins_ref, tbl_ref, has_cat)
     newleaf_ref[:], _ = _route_rows(leaf_ref[:], left_i, tbl_ref)
 
 
-def route_tile_rows(Sp: int, Fp: int) -> int:
+def route_tile_rows(Sp: int, Fp: int, has_cat: bool = False) -> int:
     """Row-tile width of the bins-form route kernel. Nothing in it is
     FB-sized: what the compiler puts on the scoped-VMEM stack is the bf16
     copy of the [Fp, C] bin tile (2 B an element: 31.4 MB refused at Fp
     2,000 x 8,192 rows, 16.3 MB at 512 x 16,384, compiled for a described
     v5e), beside the int8 tile's double buffer; the [Sp, C] planes are
-    charged 16 B a row and slot. A power of two from 512 to 8,192: a grid
+    charged 16 B a row and slot, and CAT_PLANE_BYTES more for the
+    membership test of a job with a categorical column (``has_cat``). A
+    power of two from 512 to 8,192: a grid
     step costs ~0.35 us, and on a v5e (PR 28) the 28M-row Higgs pass took
     7.8 / 6.0 / 5.3 ms at 2,048 / 4,096 / 8,192 rows and 64 slots."""
-    c = VMEM_BUDGET // (4 * Fp + 16 * Sp)
+    c = VMEM_BUDGET // (4 * Fp + (16 + CAT_PLANE_BYTES * has_cat) * Sp)
     c = 1 << (int(c).bit_length() - 1)
     return int(max(512, min(8192, c)))
 
@@ -831,14 +905,16 @@ def route_tile_rows(Sp: int, Fp: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "f_oh", "tile_rows",
-                     "interpret", "packed"))
+                     "interpret", "packed", "has_cat"))
 def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
                tbl: jax.Array, *, num_slots: int, num_bins: int,
                f_oh: int, tile_rows: int = 0,
                interpret: bool = False,
-               packed: PackedLayout = None) -> jax.Array:
-    """Row->leaf update only (same W/tbl contract as level_pass; ``W``
-    None = bins form, whose tile is sized from Fp and Sp, not from FB)."""
+               packed: PackedLayout = None,
+               has_cat: bool = False) -> jax.Array:
+    """Row->leaf update only (same W/tbl/has_cat contract as level_pass;
+    ``W`` None = bins form, whose tile is sized from Fp and Sp, not from
+    FB)."""
     Fp, R = bins_T.shape
     B = num_bins
     Sp = tbl.shape[0]
@@ -846,10 +922,10 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
     tbl_spec = pl.BlockSpec((Sp, 128), lambda t: (0, 0))
     params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     if W is None:
-        C = _fit_tile(tile_rows or route_tile_rows(Sp, Fp), R)
+        C = _fit_tile(tile_rows or route_tile_rows(Sp, Fp, has_cat), R)
         assert R % C == 0, f"rows {R} not padded to tile {C}"
         return pl.pallas_call(
-            _route_bins_kernel,
+            functools.partial(_route_bins_kernel, has_cat=has_cat),
             grid=(R // C,),
             in_specs=[row_spec(Fp, C), row_spec(1, C), tbl_spec],
             out_specs=row_spec(1, C),

@@ -9,11 +9,23 @@ difference of the two ``level_pass`` timings is: the routing dot's cost
 inside a pass; the table-form ``route_pass`` minus that: the one-hot
 build.
 
+The categorical case (PR 34; CAT_COLUMNS > 0): the first CAT_COLUMNS
+columns are split by random bin SETS of 1-32 members, the others by
+thresholds. The table form is the one a categorical job ran until PR 34
+(``build_route_table(cat_flag, cat_mask)``); the bins form carries each
+slot's set as 256 bits of the slot table and tests membership in the
+kernel's routing prologue (``has_cat``). What the test costs: the same
+bins-form launch with every slot read as numerical (``has_cat`` off, so
+the membership code is not traced), printed as ``prologue_ms`` and its
+share of the launch.
+
 Run: ROWS=28000000 FEATURES=28 SLOTS=8,64 python scripts/ablate_route_form.py
      ROWS=6810888 FEATURES=137 SLOTS=8,16 VALID_ROWS=753611 ...
+     ROWS=28000000 FEATURES=8 MAX_BIN=255 CAT_COLUMNS=6 SLOTS=8,16,32,64 \
+         TILES=0 VALID_ROWS=1000000 ...
 (INTERPRET=1 rehearses the script on the CPU at a tiny ROWS.)
 One JSON line per timing on stdout, all of them in
-chiprun_out/ablate_route_form/<FEATURES>.jsonl.
+chiprun_out/ablate_route_form/<FEATURES>[-cat].jsonl.
 """
 import json
 import os
@@ -50,6 +62,18 @@ def _splits(rng, Sp, F, max_bin, F_oh):
     return jnp.asarray(tbl), args
 
 
+def _bin_sets(rng, feature, cat_columns, max_bin, B):
+    """cat_flag [Sp] / cat_mask [Sp, B] of the slots whose split column
+    is one of the first ``cat_columns``: a random set of 1-32 of the
+    column's ``max_bin`` bins goes left."""
+    feature = np.asarray(feature)
+    flag = (feature >= 0) & (feature < cat_columns)
+    mask = np.zeros((len(feature), B), bool)
+    for k in np.flatnonzero(flag):
+        mask[k, rng.choice(max_bin, rng.randint(1, 33), replace=False)] = True
+    return dict(cat_flag=jnp.asarray(flag), cat_mask=jnp.asarray(mask))
+
+
 def _time(fn, reps):
     out = jax.block_until_ready(fn())
     t0 = time.perf_counter()
@@ -64,6 +88,7 @@ def main():
     Rv = int(os.environ.get("VALID_ROWS", 0))
     F = int(os.environ.get("FEATURES", 28))
     max_bin = int(os.environ.get("MAX_BIN", 63))
+    cat_columns = int(os.environ.get("CAT_COLUMNS", 0))
     slots = [int(s) for s in os.environ.get("SLOTS", "8,64").split(",")]
     tiles = [int(t) for t in
              os.environ.get("TILES", "0,1024,2048,4096,8192").split(",")]
@@ -72,8 +97,9 @@ def main():
     Fp = max(F_oh, 8)
     Rp = -(-R // 2048) * 2048
     rng = np.random.RandomState(0)
-    bins_np = np.zeros((Fp, Rp), np.int8)
-    bins_np[:F] = rng.randint(0, max_bin, size=(F, Rp), dtype=np.int8)
+    bins_dt = np.int8 if B <= 128 else np.int16
+    bins_np = np.zeros((Fp, Rp), bins_dt)
+    bins_np[:F] = rng.randint(0, max_bin, size=(F, Rp), dtype=bins_dt)
     bins_T = jnp.asarray(bins_np)
     del bins_np
     g = jnp.asarray(rng.randn(Rp).astype(np.float32))
@@ -82,7 +108,8 @@ def main():
     dev = jax.devices()[0]
     out_dir = os.path.join("chiprun_out", "ablate_route_form")
     os.makedirs(out_dir, exist_ok=True)
-    sink = open(os.path.join(out_dir, f"{F}.jsonl"), "w")
+    sink = open(os.path.join(
+        out_dir, f"{F}{'-cat' if cat_columns else ''}.jsonl"), "w")
 
     def say(**rec):
         rec.update(rows=R, features=F, fb=F_oh * B, device=dev.device_kind)
@@ -96,10 +123,25 @@ def main():
         leaf_T = jnp.asarray(
             np.where(np.arange(Rp) < R, rng.randint(0, Sp, Rp), -1)
             .astype(np.int32))[None, :]
-        W = fl.build_route_table(*sp_args, Sp, F_oh, B)
-        tbl_b = fl.route_table_columns(tbl, *sp_args)
+        sets = _bin_sets(rng, sp_args[0], cat_columns, max_bin, B) \
+            if cat_columns else {}
+        W = fl.build_route_table(*sp_args, Sp, F_oh, B, **sets)
+        tbl_b = fl.route_table_columns(tbl, *sp_args, **sets)
         kw = dict(num_slots=Sp, num_bins=B, f_oh=F_oh,
                   interpret=bool(int(os.environ.get("INTERPRET", "0"))))
+        # the bins form of a categorical job traces the membership test
+        kw_b = dict(kw, has_cat=True) if sets else kw
+        # the same launch with every slot read as numerical: what is left
+        # of it without the test
+        tbl_n = fl.route_table_columns(tbl, *sp_args)
+
+        def prologue(ms, fn):
+            if not sets:
+                return {}
+            ms_n, _ = _time(fn, reps)
+            return dict(membership=True, numerical_ms=ms_n,
+                        prologue_ms=ms - ms_n,
+                        prologue_share=(ms - ms_n) / ms)
 
         ms_t, leaf_t = _time(
             lambda: fl.route_pass(bins_T, leaf_T, W, tbl, **kw), reps)
@@ -108,17 +150,20 @@ def main():
         for tile in tiles:
             ms_b, leaf_b = _time(
                 lambda: fl.route_pass(bins_T, leaf_T, None, tbl_b,
-                                      tile_rows=tile, **kw), reps)
+                                      tile_rows=tile, **kw_b), reps)
             say(kernel="route_pass", form="bins", slots=Sp, ms=ms_b,
-                tile=tile or fl.route_tile_rows(Sp, Fp),
+                tile=tile or fl.route_tile_rows(Sp, Fp, bool(sets)),
                 default_tile=tile == 0,
-                same_leaves=bool(jnp.array_equal(leaf_t, leaf_b)))
+                same_leaves=bool(jnp.array_equal(leaf_t, leaf_b)),
+                **prologue(ms_b, lambda: fl.route_pass(
+                    bins_T, leaf_T, None, tbl_n, tile_rows=tile, **kw)))
         if Rv:
             Rvp = -(-Rv // 2048) * 2048
             bins_v, leaf_v = bins_T[:, :Rvp], leaf_T[:, :Rvp]
-            for form, w, t in (("table", W, tbl), ("bins", None, tbl_b)):
+            for form, w, t, k in (("table", W, tbl, kw),
+                                  ("bins", None, tbl_b, kw_b)):
                 ms_v, _ = _time(
-                    lambda: fl.route_pass(bins_v, leaf_v, w, t, **kw), reps)
+                    lambda: fl.route_pass(bins_v, leaf_v, w, t, **k), reps)
                 say(kernel="route_pass", form=form, slots=Sp, ms=ms_v,
                     valid_rows=Rv)
         ms_lt, (hist_t, nl_t) = _time(
@@ -126,13 +171,22 @@ def main():
         say(kernel="level_pass", form="table", slots=Sp, ms=ms_lt,
             tile=fl.default_tile_rows(Sp, F_oh * B, fl.NCH_PRECISE))
         ms_lb, (hist_b, nl_b) = _time(
-            lambda: fl.level_pass(bins_T, leaf_T, gh_T, None, tbl_b, **kw),
+            lambda: fl.level_pass(bins_T, leaf_T, gh_T, None, tbl_b, **kw_b),
             reps)
+        # the two forms run different row tiles since PR 31, which regroups
+        # the float32 partial sums: the difference is said as a share of
+        # the largest sum
         say(kernel="level_pass", form="bins", slots=Sp, ms=ms_lb,
+            tile=fl.level_build(True, Sp, F_oh * B, fl.NCH_PRECISE, Fp,
+                                has_cat=bool(sets))["tile_rows"],
             same_leaves=bool(jnp.array_equal(nl_t, nl_b)
                              and jnp.array_equal(nl_t, leaf_t)),
             same_hist=bool(jnp.array_equal(hist_t, hist_b)),
-            routing_dot_ms=ms_lt - ms_lb, build_ms=ms_t - (ms_lt - ms_lb))
+            hist_diff=float(jnp.max(jnp.abs(hist_t - hist_b))
+                            / jnp.max(jnp.abs(hist_t))),
+            routing_dot_ms=ms_lt - ms_lb, build_ms=ms_t - (ms_lt - ms_lb),
+            **prologue(ms_lb, lambda: fl.level_pass(
+                bins_T, leaf_T, gh_T, None, tbl_n, **kw)))
 
 
 if __name__ == "__main__":

@@ -214,8 +214,9 @@ def test_the_traced_auc_is_the_host_auc(jobs, table):
 
 def test_the_replay_with_categorical_levels_is_predict(jobs, table):
     """The megastep's validation scores come from replaying each tree's
-    route log (``W @ one_hot`` tables with categorical rows) over the
-    validation bins: they are ``Booster.predict`` on the same rows."""
+    route log (slot tables whose categorical slots carry their bin
+    sets) over the validation bins: they are ``Booster.predict`` on the
+    same rows."""
     _, _, Xv, _ = table
     bst, _, events = jobs["megastep"]
     assert [e["path"] for e in events
@@ -231,9 +232,10 @@ def test_the_job_stays_on_the_megastep_and_says_its_layout(jobs):
     assert "degrade" not in kinds and "megastep_evicted" not in kinds
     assert kinds.count("megastep") == ITERS // 2
     (form,) = [e for e in events if e.get("event") == "route_form"]
-    assert (form["form"], form["reason"]) == ("table", "categorical")
+    assert (form["form"], form.get("reason"), form["membership"]) \
+        == ("bins", None, True)
     (build,) = [e for e in events if e.get("event") == "level_build"]
-    assert build["form"] == "scratch"
+    assert (build["form"], build["slab_rows"]) == ("slab", 512)
     (layout,) = [e for e in events if e.get("event") == "cat_layout"]
     assert layout["columns"] == list(data_cat.CATEGORICAL)
     mappers = bst._gbdt.train_data.mappers
@@ -242,6 +244,8 @@ def test_the_job_stays_on_the_megastep_and_says_its_layout(jobs):
     g = bst._gbdt
     assert (g.fused_Bp, g.fused_f_oh) == (256, 8)
     counters = bst.telemetry()["counters"]
+    assert counters["route.cat_membership"] == counters["route.form_bins"] \
+        == 1 and "route.form_table" not in counters
     trees = reference_cat.flatten(bst.dump_model())
     nodes = sum(t["feature"].size for t in trees)
     assert counters["split.nodes"] == nodes

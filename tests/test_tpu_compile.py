@@ -43,7 +43,7 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-def _operands(chip, features, max_bin, sp):
+def _operands(chip, features, max_bin, sp, has_cat=False):
     f_oh, bp = feature_layout(features, max_bin)
     fp = max(f_oh, 8)
     shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=chip)
@@ -52,28 +52,32 @@ def _operands(chip, features, max_bin, sp):
         leaf=shape((1, ROWS), jnp.int32), gh=shape((8, ROWS), jnp.bfloat16),
         W=shape((sp, f_oh * bp), jnp.bfloat16),
         tbl=shape((sp, 128), jnp.int32),
-        kw=dict(num_slots=sp, num_bins=bp, f_oh=f_oh), fp=fp, fb=f_oh * bp)
+        kw=dict(num_slots=sp, num_bins=bp, f_oh=f_oh, has_cat=has_cat),
+        fp=fp, fb=f_oh * bp)
 
 
 def _compiles(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-# name, features, max_bin: the benchmark's widths (expo255: the categorical
-# cell, which runs the table form), and Higgs at 255 bins
-WIDTHS = [("higgs63", 28, 63), ("msltr63", 137, 63), ("higgs255", 28, 255),
-          ("expo255", 8, 255)]
+# name, features, max_bin, has_cat: the benchmark's widths, and Higgs at
+# 255 bins. expo255-cat is the categorical cell as it runs since PR 34:
+# int16 bins, FB 2,048, the bins form with the set-membership test traced
+# (``has_cat``; the table form ignores it); expo255 the same width without
+WIDTHS = [("higgs63", 28, 63, False), ("msltr63", 137, 63, False),
+          ("higgs255", 28, 255, False), ("expo255", 8, 255, False),
+          ("expo255-cat", 8, 255, True)]
 
 
 @pytest.mark.parametrize("form", ["bins", "table"])
 @pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
-@pytest.mark.parametrize("name,features,max_bin", WIDTHS,
+@pytest.mark.parametrize("name,features,max_bin,has_cat", WIDTHS,
                          ids=[w[0] for w in WIDTHS])
 def test_level_and_route_kernels_compile(one_chip, name, features, max_bin,
-                                         deep, form):
+                                         has_cat, deep, form):
     fb = feature_layout(features, max_bin)
     sp = min(128, max_slot_cap(fb[0] * fb[1], NCH_PRECISE)) if deep else 8
-    o = _operands(one_chip, features, max_bin, sp)
+    o = _operands(one_chip, features, max_bin, sp, has_cat)
     W = o["W"] if form == "table" else None
     _compiles(functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"]),
               o["bins"], o["leaf"], o["gh"], W, o["tbl"])
@@ -99,26 +103,30 @@ def _pallas_call(fn, *args):
 TILES = {("higgs63", False): (2048, 1024), ("higgs63", True): (2048, 1024),
          ("msltr63", False): (2048, 256), ("msltr63", True): (2048, 128),
          ("higgs255", False): (2048, 256), ("higgs255", True): (2048, 256),
-         ("expo255", False): (2048, 1024), ("expo255", True): (2048, 1024)}
+         ("expo255", False): (2048, 1024), ("expo255", True): (2048, 1024),
+         ("expo255-cat", False): (2048, 1024),
+         ("expo255-cat", True): (2048, 1024)}
 
 
 @pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
-@pytest.mark.parametrize("name,features,max_bin", WIDTHS,
+@pytest.mark.parametrize("name,features,max_bin,has_cat", WIDTHS,
                          ids=[w[0] for w in WIDTHS])
 def test_bins_form_level_kernel_has_no_fb_sized_scratch(one_chip, name,
                                                         features, max_bin,
-                                                        deep):
+                                                        has_cat, deep):
     """The bins form builds its one-hot in slabs: no scratch operand at
     all, and the row tile no longer shrinks with FB (the compile test
     above holds that these tiles fit the default 16 MB of scoped VMEM).
-    The table form keeps its [FB, C] scratch at the tile it had."""
+    The table form keeps its [FB, C] scratch at the tile it had. The
+    membership planes of a categorical job are charged to the tile
+    (CAT_PLANE_BYTES) and leave it 2,048 rows at the cell's 64 slots."""
     f_oh, bp = feature_layout(features, max_bin)
     fb = f_oh * bp
     sp = min(128, max_slot_cap(fb, NCH_PRECISE)) if deep else 8
-    o = _operands(one_chip, features, max_bin, sp)
+    o = _operands(one_chip, features, max_bin, sp, has_cat)
     want_bins, want_table = TILES[name, deep]
-    assert default_tile_rows(sp, fb, NCH_PRECISE, bins_rows=o["fp"]) \
-        == want_bins
+    assert default_tile_rows(sp, fb, NCH_PRECISE, bins_rows=o["fp"],
+                             has_cat=has_cat) == want_bins
     assert default_tile_rows(sp, fb, NCH_PRECISE) == want_table
     fn = functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"])
     args = (o["bins"], o["leaf"], o["gh"])

@@ -41,8 +41,10 @@ def _grow_and_replay(signal: str, bins_form: bool = False):
     """(tree, the grower's own row_leaf, the replayed leaves, the log) of
     one tree grown on ``signal``; every case shares one set of static
     arguments but ``has_cat`` (off for ``bins_form``: no column is
-    categorical then and the log holds no ``W``), so the grower compiles
-    twice in this file."""
+    categorical then and the kernels trace no membership test), so the
+    grower compiles twice in this file. Both take the bins form of the
+    routing since PR 34 (the log holds no ``W``: a categorical split
+    rides the slot table as its bin set)."""
     bins, rng = _bins()
     y = {"numeric": (bins[:, 0] > 12) + 0.5 * (bins[:, 1] > 20)
          + 0.3 * (bins[:, 4] > 7),
@@ -71,9 +73,9 @@ def _grow_and_replay(signal: str, bins_form: bool = False):
                         cat_smooth=1.0, min_data_per_group=5),
             4, B, F_oh)
     tree, row_leaf, log = grow_tree_fused(*args, **kw)
-    assert (log[0] is None) == bins_form
+    assert log[0] is None
     leaves = replay_route_log(args[0], log, R, num_bins=Bp, f_oh=F_oh,
-                              interpret=True)
+                              interpret=True, has_cat=not bins_form)
     return jax.device_get(tree), np.asarray(row_leaf), \
         np.asarray(leaves)[0], jax.device_get(log)
 
