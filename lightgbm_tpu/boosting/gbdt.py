@@ -210,8 +210,13 @@ class GBDT:
         # matrix when a drain-replay consumer is armed, else None.
         self._pending: List[Tuple] = []
         self._pending_iters = 0
-        self._fast_step_fn = None
+        # per pending sampled step (GOSS), the traced (top, other) row
+        # counts of its iterations; fetched with the trees at the drain
+        self._sample_counts: List = []
+        self._goss_layout_said = False
+        self._fast_step_fns = {}
         self._fast_ok_cache = None
+        self._sample_plan_cache = None
         self._stopped_early = False
         # multi-iteration megastep state (see _train_one_megastep): armed
         # only by driver loops that tolerate train_one_iter advancing
@@ -399,7 +404,6 @@ class GBDT:
         self.bag_streams = ref_random.BlockBaggingStreams(
             int(config.bagging_seed), n)
         self._bag_round_cache = None
-        self.bag_rng = np.random.RandomState(config.bagging_seed)  # GOSS
         self.feat_rng = ref_random.Random(int(config.feature_fraction_seed))
         self.balanced_bagging = False
         self.is_bagging = False
@@ -1986,8 +1990,9 @@ class GBDT:
         """Resolve tpu_engine/grow_policy into the learner flags (called by
         init and again by reset_config so reset_parameter can switch
         engines)."""
-        self._fast_step_fn = None     # engine/params changed: re-derive
+        self._fast_step_fns = {}      # engine/params changed: re-derive
         self._fast_ok_cache = None
+        self._sample_plan_cache = None
         self._megastep_fns = {}       # megastep closes over params too
         self._megastep_fm = {}
         self._fast_fm_pads = None
@@ -2328,7 +2333,7 @@ class GBDT:
         self._megastep_fns = {}       # valid-set count is baked into the
         # megastep signature; the per-iteration step keeps the route log
         # a new set may ask for
-        self._fast_step_fn = None
+        self._fast_step_fns = {}
         if self._eval_consumer is not None:
             # the traced eval plan enumerated the old valid-set list; a
             # new set mid-run invalidates it (cannot happen through
@@ -3039,9 +3044,23 @@ class GBDT:
     # asynchronously at enqueue time so drains mostly find the data ready.
     _FAST_SYNC_EVERY = 32
 
+    def _sample_plan(self):
+        """ops/goss.GossPlan of a job whose trees, from the plan's
+        ``first_iter`` on, grow on a sample of the rows that the step
+        itself draws and compacts (GOSS overrides); its ``evict_reason``
+        names what the job has that the compact matrix does not compose
+        with. None: the job does not sample."""
+        return None
+
+    def _step_sample(self, it: int):
+        """The plan when iteration ``it`` of a fast-path job is a sampled
+        one, else None."""
+        plan = self._sample_plan()
+        return plan if plan is not None and it >= plan.first_iter else None
+
     def _fast_path_ok(self) -> bool:
         """Per-tree host work forces the synchronous path: subclass drivers
-        (DART drop-sets, GOSS resampling, RF), leaf renewal, linear leaves,
+        (DART drop-sets, RF), leaf renewal, linear leaves,
         CEGB feature accounting, forced splits, and per-node mask key
         folding. Valid sets stay on the fast path since round 3: their
         score updates run in-jit from the device TreeArrays
@@ -3069,8 +3088,12 @@ class GBDT:
             # feature-parallel stays on the sync driver: its contract is
             # bit-equality with the serial model (replicated rows), and
             # the fast path's f32 leaf-value shrink would break it.
+            plan = self._sample_plan()
             self._fast_ok_cache = bool(
-                type(self) is GBDT
+                # (DART's drop sets and RF's fixed gradients are per-tree
+                # host work; GOSS draws its sample inside the step)
+                (type(self) is GBDT
+                 or (plan is not None and plan.evict_reason is None))
                 and bool(self.config.tpu_fast_path)
                 and self.use_fused
                 and self.parallel_mode in ("serial", "data", "voting")
@@ -3096,7 +3119,8 @@ class GBDT:
         if self.telemetry.enabled \
                 and self._tel_granularity() == "section":
             return "config:telemetry_granularity=section"
-        if type(self) is not GBDT:
+        plan = self._sample_plan()
+        if type(self) is not GBDT and plan is None:
             return f"boosting:{self.name}"
         if not bool(self.config.tpu_fast_path):
             return "config:tpu_fast_path=false"
@@ -3107,6 +3131,8 @@ class GBDT:
                 # the shard_map growers through grow_tree_fused only)
                 return "engine:multiproc_xla_growers"
             return f"engine:{self.config.tpu_engine}"
+        if plan is not None and plan.evict_reason is not None:
+            return plan.evict_reason
         if getattr(self, "mp", None) is not None \
                 and not bool(getattr(self.config, "tpu_mp_megastep", True)):
             return "config:tpu_mp_megastep=false"
@@ -3413,15 +3439,25 @@ class GBDT:
             self.valid_scores[vi] = self._valid_upd_fns[vi](
                 self.valid_scores[vi], operands[vi], trees, logs)
 
-    def _make_fused_tree_loop(self):
+    def _make_fused_tree_loop(self, sample=None):
         """Traced per-iteration tree-growing core: gh pack -> fused
         growth -> score delta for each of the k class trees, returning
         the updated scores, the stacked [k, ...] TreeArrays, the gain
-        EMA and the k trees' route logs (None unless a validation set is
-        routed by the kernels: _valid_route). The ONE body the
+        EMA, the k trees' route logs (None unless a validation set is
+        routed by the kernels: _valid_route) and the sample's traced
+        counts (None in a step that does not sample). The ONE body the
         per-iteration fast step and the megastep scan share, so the
         megastep stays bit-identical to the fast path by
-        construction."""
+        construction.
+
+        ``sample`` (ops/goss.GossPlan; GOSS from its first sampled
+        iteration on): the body draws the iteration's sample, grows each
+        tree on the COMPACT matrix of the in-bag rows and updates the
+        scores of all rows by replaying the tree's route log over the
+        full matrix (``_sampled_growth``). Such a step has one operand
+        more, the iteration ``it`` (the draw's counter), and one result
+        more, ``counts``: the traced (top, other) rows of the sample. It
+        is built apart from the plain step, which lowers as it did."""
         from ..models.frontier2 import grow_tree_fused, tree_score_delta
         from ..ops.fused_level import pack_gh, table_lookup
         k = self.num_tree_per_iteration
@@ -3449,8 +3485,10 @@ class GBDT:
         screening = self.use_screening
         mask_oh = self._mask_onehot()
         packed = self.fused_packed
-        route_log = self._wants_route_log()
+        route_log = self._wants_route_log() or sample is not None
         self._route_form()
+        if sample is not None:
+            draw_sample, compact = self._sampled_growth(sample)
         if quant:
             from ..ops.fused_level import pack_gh_quant
         if screening:
@@ -3496,7 +3534,7 @@ class GBDT:
                 check_vma=False)
 
         def grow_k_trees(bins_T, scores, grad, hess, bag_weight, fm_pads,
-                         ema=None, explore=None, seed=None):
+                         ema=None, explore=None, seed=None, it=None):
             smask = None
             if screening:
                 # EMA-FS screening (arxiv 2606.26337): one in-trace
@@ -3505,13 +3543,18 @@ class GBDT:
                 # rounds keep the mask fully open
                 smask = _screening_mask_fn(ema, explore, F_real, keep_k)
             trees, logs = [], []
+            grow_rows, grow_bins, counts = n, bins_T, None
+            if sample is not None:
+                mult, bag_weight, counts = draw_sample(grad, hess, it)
+                grow_rows = sample.bag_rows
             for tid in range(k):
                 fm_t = fm_pads[tid] & smask if screening \
                     else fm_pads[tid]
                 scales = None
                 with jax.named_scope("lgbm.gh_pack"):
-                    g_p = jnp.pad(grad[tid] * bag_weight, (0, pad))
-                    h_p = jnp.pad(hess[tid] * bag_weight, (0, pad))
+                    w_g = bag_weight if sample is None else mult
+                    g_p = jnp.pad(grad[tid] * w_g, (0, pad))
+                    h_p = jnp.pad(hess[tid] * w_g, (0, pad))
                     w_p = jnp.pad(bag_weight, (0, pad))
                     if quant:
                         gh_T, scales = pack_gh_quant(
@@ -3519,6 +3562,8 @@ class GBDT:
                             seed + jnp.uint32(tid))
                     else:
                         gh_T = pack_gh(g_p, h_p, w_p, self.fused_nch)
+                if sample is not None:
+                    grow_bins, gh_T = compact(bins_T, gh_T, bag_weight)
                 if par:
                     args = (bins_T, gh_T, fm_t) \
                         + ((scales,) if quant else ())
@@ -3528,9 +3573,10 @@ class GBDT:
                         tree, delta, *log = grow_one_sharded(*args)
                 else:
                     tree, row_leaf, *log = grow_tree_fused(
-                        bins_T, gh_T, self.fused_meta, fm_t,
+                        grow_bins, gh_T, self.fused_meta, fm_t,
                         self.params, self.max_leaves, self.fused_Bp,
-                        self.fused_f_oh, num_rows=n, nch=self.fused_nch,
+                        self.fused_f_oh, num_rows=grow_rows,
+                        nch=self.fused_nch,
                         max_depth=max_depth, extra_levels=extra,
                         has_cat=self.has_cat,
                         use_mono_bounds=self.use_mono_bounds,
@@ -3543,6 +3589,10 @@ class GBDT:
                         mask_onehot=mask_oh, gh_scales=scales,
                         route_log=route_log)
                 with jax.named_scope("lgbm.score_update"):
+                    if sample is not None:
+                        # the tree was grown on the sample: the leaves
+                        # of ALL rows, out-of-bag too, from its route log
+                        row_leaf = self._replay_train_rows(bins_T, log[0])
                     if par:
                         # a dried-up class (no split found) contributes
                         # NOTHING: the sync path appends a zero constant
@@ -3566,14 +3616,68 @@ class GBDT:
                 gvec = _tree_gain_vec(stacked.split_feature,
                                       stacked.split_gain, F_oh)
                 ema = alpha * ema + (1.0 - alpha) * gvec
-            return scores, stacked, ema, tuple(logs) or None
+            return scores, stacked, ema, tuple(logs) or None, counts
         return grow_k_trees
 
-    def _make_fast_step(self):
+    def _replay_train_rows(self, bins_T, log) -> jax.Array:
+        """[Rp] leaf of every training row in the tree whose route log is
+        ``log`` (a tree grown on a sample of the rows)."""
+        from ..models.frontier2 import replay_route_log
+        return replay_route_log(
+            bins_T, log, self.num_data,
+            num_bins=(self.fused_bundle_col_bins
+                      if self.fused_bundle_cols else self.fused_Bp),
+            f_oh=self.fused_bundle_cols or self.fused_f_oh,
+            interpret=self.fused_interpret, packed=self.fused_packed,
+            has_cat=self.has_cat)[0]
+
+    def _sampled_growth(self, sample):
+        """The two traced stages a sampled step adds (ops/goss.py), under
+        ``lgbm.sample``: ``draw(grad, hess, it)`` -> (multiplier on
+        gradient and hessian [n], in-bag 0/1 [n], (top, other) counts);
+        ``compact(bins_T, gh_T, inbag)`` -> the in-bag columns of both
+        at the front of [.., capacity]. Says the layout once per job."""
+        from ..ops import goss
+        # (compact_rows' precondition: the draw's count is exact)
+        assert 0 <= sample.capacity - sample.bag_rows < goss.ROW_ALIGN
+        tel = self.telemetry
+        if tel.enabled and not self._goss_layout_said:
+            self._goss_layout_said = True
+            tel.gauge("goss.capacity", float(sample.capacity))
+            tel.event("goss_layout", iteration=self.iter, n=sample.n,
+                      top_k=sample.top_k, other_k=sample.other_k,
+                      capacity=sample.capacity,
+                      first_sampled_iteration=sample.first_iter,
+                      compaction="pallas_window",
+                      tile_rows=goss.COMPACT_TILE)
+        interp = self.fused_interpret
+
+        @jax.named_scope("lgbm.sample")
+        def draw(grad, hess, it):
+            with jax.named_scope("select"):
+                abs_gh = jnp.sum(jnp.abs(grad * hess), axis=0)
+            top, other = goss.goss_sample(abs_gh, it, sample.seed,
+                                          sample.top_k, sample.other_k)
+            with jax.named_scope("draw"):
+                mult, inbag = goss.sample_weights(top, other,
+                                                  sample.multiply)
+                counts = jnp.stack([jnp.sum(top.astype(jnp.int32)),
+                                    jnp.sum(other.astype(jnp.int32))])
+            return mult, inbag, counts
+
+        @jax.named_scope("lgbm.sample")
+        def compact(bins_T, gh_T, inbag):
+            with jax.named_scope("compact"):
+                return goss.compact_rows(bins_T, gh_T, inbag > 0,
+                                         capacity=sample.capacity,
+                                         interpret=interp)
+        return draw, compact
+
+    def _make_fast_step(self, sample=None):
         obj = self.objective
         in_jit_grads = (obj is not None
                         and obj.supports_traced_gradients())
-        grow_k = self._make_fused_tree_loop()
+        grow_k = self._make_fused_tree_loop(sample)
 
         # bins_T/gradient operands are ARGUMENTS, not closures: a
         # closed-over device array of O(rows) size would be embedded in
@@ -3586,29 +3690,31 @@ class GBDT:
         # round-tripping a fresh allocation through HBM each iteration.
         ext = bool(self.use_screening or self.quant_bits)
         if not ext:
+            # (``it`` / ``counts``: a sampled step's; None in the plain
+            # step, where they are no operand and no result)
             def step(bins_T, scores, grad_in, hess_in, bag_weight,
-                     fm_pads):
+                     fm_pads, it=None):
                 if in_jit_grads:
                     with jax.named_scope("lgbm.gradients"):
                         grad, hess = obj.gradients_from(scores, grad_in)
                 else:
                     grad, hess = grad_in, hess_in
-                scores, stacked, _, logs = grow_k(
-                    bins_T, scores, grad, hess, bag_weight, fm_pads)
-                return scores, stacked, logs
+                scores, stacked, _, logs, counts = grow_k(
+                    bins_T, scores, grad, hess, bag_weight, fm_pads, it=it)
+                return scores, stacked, logs, counts
             return jax.jit(step, donate_argnums=_donate(1))
 
         def step_ext(bins_T, scores, grad_in, hess_in, bag_weight,
-                     fm_pads, ema, explore, seed):
+                     fm_pads, ema, explore, seed, it=None):
             if in_jit_grads:
                 with jax.named_scope("lgbm.gradients"):
                     grad, hess = obj.gradients_from(scores, grad_in)
             else:
                 grad, hess = grad_in, hess_in
-            scores, stacked, ema, logs = grow_k(
+            scores, stacked, ema, logs, counts = grow_k(
                 bins_T, scores, grad, hess, bag_weight, fm_pads, ema,
-                explore, seed)
-            return scores, stacked, logs, ema
+                explore, seed, it)
+            return scores, stacked, logs, ema, counts
         return jax.jit(step_ext, donate_argnums=_donate(1))
 
     def _train_one_iter_fast(self) -> bool:
@@ -3647,15 +3753,26 @@ class GBDT:
                     if self.objective is not None
                     and self.objective.supports_traced_gradients()
                     else None)
+        # (one step per kind of iteration: GOSS's sampled iterations
+        # grow on another row count than its first, unsampled ones)
+        sample = self._step_sample(self.iter)
         if operands is not None:     # gradients traced into the step
             grad_in, hess_in = operands, None
             self._bagging(self.iter, None, None)
         else:
             grad_in, hess_in = self._get_gradients()
-            grad_in, hess_in = self._bagging(self.iter, grad_in, hess_in)
-        fresh_step = self._fast_step_fn is None
+            if sample is not None:
+                # the step draws the sample itself, from these gradients
+                # as the objective gave them
+                self._bagging(self.iter, None, None)
+            else:
+                grad_in, hess_in = self._bagging(self.iter, grad_in,
+                                                 hess_in)
+        step_fn = self._fast_step_fns.get(sample)
+        fresh_step = step_fn is None
         if fresh_step:
-            self._fast_step_fn = self._make_fast_step()
+            step_fn = self._fast_step_fns[sample] = \
+                self._make_fast_step(sample)
         F_oh = self.fused_f_oh
         if float(self.config.feature_fraction) >= 1.0:
             if getattr(self, "_fast_fm_pads", None) is None:
@@ -3666,6 +3783,7 @@ class GBDT:
             fm_pads = jnp.stack([
                 jnp.zeros((F_oh,), bool).at[:self.train_data.num_features]
                 .set(self._feature_mask()) for _ in range(k)])
+        its = () if sample is None else (np.int32(self.iter),)
         self.telemetry.inc("train.dispatches")
         self._place_carries()
         ext = bool(self.use_screening or self.quant_bits)
@@ -3684,15 +3802,17 @@ class GBDT:
                         if self.quant_bits else None)
                 call_args = (self.fused_bins_T, self.scores, grad_in,
                              hess_in, self.bag_weight, fm_pads, ema,
-                             explore, seed)
-                self.scores, trees, logs, ema2 = \
-                    self._fast_step_fn(*call_args)
+                             explore, seed) + its
+                self.scores, trees, logs, ema2, counts = \
+                    step_fn(*call_args)
                 if self.use_screening:
                     self._gain_ema_dev = ema2
             else:
                 call_args = (self.fused_bins_T, self.scores, grad_in,
-                             hess_in, self.bag_weight, fm_pads)
-                self.scores, trees, logs = self._fast_step_fn(*call_args)
+                             hess_in, self.bag_weight, fm_pads) + its
+                self.scores, trees, logs, counts = step_fn(*call_args)
+        if counts is not None:
+            self._sample_counts.append(counts)
         if rec is not None:
             self._coll_per_iter = rec.profile
         if fresh_step and self.telemetry.enabled:
@@ -3702,12 +3822,13 @@ class GBDT:
             # the cost-ledger note defers fn.lower() to the next drain
             op_bytes = sum(int(getattr(a, "nbytes", 0))
                            for a in call_args if a is not None)
-            sig = f"fast_step[k={k},ext={ext}]"
+            sig = f"fast_step[k={k},ext={ext}" \
+                + (",sampled]" if sample is not None else "]")
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
                 iteration=self.iter)
             if self._cost is not None:
-                self._cost.note(self._fast_step_fn, call_args, sig,
+                self._cost.note(step_fn, call_args, sig,
                                 kind="fast_step", scale=1,
                                 operand_bytes=op_bytes,
                                 iteration=self.iter)
@@ -3755,11 +3876,12 @@ class GBDT:
                              or self._es_carry is None)
                     else (self._es_carry[2], self._es_carry[3]))
         # (Fetch is the wait for the device, not host work)
+        counts, self._sample_counts = self._sample_counts, []
         with timer.section("GBDT::Drain::Fetch"):
-            trees_host, metrics_host, es_host = jax.device_get(
+            trees_host, metrics_host, es_host, counts_host = jax.device_get(
                 ([t for t, _, _, _ in pend],
                  [m for _, _, _, m in pend if m is not None],
-                 es_state))
+                 es_state, counts))
         # flatten megastep entries ([B, k, ...] stacked trees covering B
         # iterations) and per-iteration entries ([k, ...], batch == 1)
         # into one per-iteration sequence of host TreeArrays fields,
@@ -3786,6 +3908,8 @@ class GBDT:
                 mi += 1
                 flat_metrics.extend(rows[b] for b in range(batch))
         base_iter = self.iter - len(flat)
+        streamed = self._count_streamed_rows(base_iter, len(flat),
+                                             counts_host)
         # scan-native early stop: the device latch decides the
         # bookkeeping below — iterations past the latch were frozen
         # in-jit (their score deltas masked to zero), so they must be
@@ -3960,7 +4084,7 @@ class GBDT:
                          wall_start=self._batch_w0, engine="fused",
                          mode=self.parallel_mode,
                          fused_iterations=self._batch_fused,
-                         stopped=self._stopped_early)
+                         stopped=self._stopped_early, **streamed)
             if gain_acc:
                 gains = np.concatenate(gain_acc)
                 if gains.size:
@@ -4017,6 +4141,36 @@ class GBDT:
         if flat:
             self._profile_ctl_step()
             self._slo_step()
+
+    def _count_streamed_rows(self, base_iter: int, n_iters: int,
+                             counts_host) -> Dict[str, int]:
+        """Counters of a drained batch of the fused fast path:
+        ``level.rows_streamed`` (rows every ``level_pass`` launch of a
+        tree streams, summed over the batch's trees) beside
+        ``level.trees``, and for the sampled iterations (GOSS) the
+        sample's rows as the step counted them: ``goss.top_rows``,
+        ``goss.other_rows``, ``goss.bag_rows``, ``goss.iterations``.
+        Returns the batch's ``rows_streamed`` and ``trees`` for its
+        ``megastep`` event."""
+        tel = self.telemetry
+        if not tel.enabled or not n_iters:
+            return {}
+        k = self.num_tree_per_iteration
+        sample = self._step_sample(base_iter + n_iters - 1)
+        n_sampled = 0 if sample is None \
+            else n_iters - max(0, sample.first_iter - base_iter)
+        streamed = k * ((n_iters - n_sampled) * self.fused_Rp
+                        + n_sampled * (sample.capacity if sample else 0))
+        tel.inc("level.trees", k * n_iters)
+        tel.inc("level.rows_streamed", streamed)
+        if counts_host:
+            got = np.concatenate([np.asarray(c).reshape(-1, 2)
+                                  for c in counts_host]).sum(axis=0)
+            tel.inc("goss.iterations", n_sampled)
+            tel.inc("goss.top_rows", int(got[0]))
+            tel.inc("goss.other_rows", int(got[1]))
+            tel.inc("goss.bag_rows", int(got[0] + got[1]))
+        return {"rows_streamed": streamed, "trees": k * n_iters}
 
     def _replay_drained_eval(self, flat_metrics, base_iter: int,
                              n_flat: int, stop_i: Optional[int],
@@ -4280,6 +4434,11 @@ class GBDT:
             next_fire = ((self.iter // cfg.bagging_freq) + 1) \
                 * cfg.bagging_freq
             chunk = min(chunk, next_fire - self.iter)
+        plan = self._sample_plan()
+        if plan is not None and self.iter < plan.first_iter:
+            # GOSS: the unsampled and the sampled iterations are two
+            # steps (they grow on different row counts)
+            chunk = min(chunk, plan.first_iter - self.iter)
         return chunk
 
     def _place_carries(self) -> None:
@@ -4337,10 +4496,16 @@ class GBDT:
         self._publish_rank_layout()
         self._bagging(self.iter, None, None)   # chunk-aligned: a round
         # can fire only at the chunk's first iteration
-        fn = self._megastep_fns.get(chunk)
+        # (a chunk never crosses GOSS's first sampled iteration:
+        # _megastep_chunk; a sampled chunk is a step of its own)
+        sample = self._step_sample(self.iter)
+        fn_key = chunk if sample is None else (chunk, sample)
+        fn = self._megastep_fns.get(fn_key)
         fresh_fn = fn is None
         if fresh_fn:
-            fn = self._megastep_fns[chunk] = self._make_megastep(chunk)
+            fn = self._megastep_fns[fn_key] = (
+                self._make_megastep(chunk) if sample is None
+                else self._make_megastep(chunk, sample))
         F_oh = self.fused_f_oh
         F = self.train_data.num_features
         if float(self.config.feature_fraction) >= 1.0:
@@ -4379,35 +4544,38 @@ class GBDT:
                          self._valid_operands(),
                          tuple(self.valid_scores),
                          operands, self.bag_weight, fm_pads)
+            # host arange: jnp.arange(start > 0) is an eager add,
+            # i.e. one more executable compiled in the second chunk
+            iters_B = np.arange(self.iter, self.iter + chunk,
+                                dtype=np.int32)
             if plan is None:
+                # (a sampled step takes the iterations: its draws' counter)
+                its = () if sample is None else (iters_B,)
                 if ext:
                     ema0, explore_B, seeds_B = self._megastep_aux(chunk)
-                    call_args = base_args + (ema0, explore_B, seeds_B)
-                    scores, vscores, trees_B, ema2 = fn(*call_args)
+                    call_args = base_args + (ema0, explore_B, seeds_B) + its
+                    scores, vscores, trees_B, ema2, counts_B = \
+                        fn(*call_args)
                     if self.use_screening:
                         self._gain_ema_dev = ema2
                 else:
-                    call_args = base_args
-                    scores, vscores, trees_B = fn(*call_args)
+                    call_args = base_args + its
+                    scores, vscores, trees_B, counts_B = fn(*call_args)
             else:
-                # host arange: jnp.arange(start > 0) is an eager add,
-                # i.e. one more executable compiled in the second chunk
-                iters_B = np.arange(self.iter, self.iter + chunk,
-                                    dtype=np.int32)
                 if ext:
                     ema0, explore_B, seeds_B = self._megastep_aux(chunk)
                     call_args = base_args + (iters_B, self._plan_ops,
                                              self._es_carry, ema0,
                                              explore_B, seeds_B)
                     (scores, vscores, self._es_carry, trees_B,
-                     metrics_B, ema2) = fn(*call_args)
+                     metrics_B, ema2, counts_B) = fn(*call_args)
                     if self.use_screening:
                         self._gain_ema_dev = ema2
                 else:
                     call_args = base_args + (iters_B, self._plan_ops,
                                              self._es_carry)
                     (scores, vscores, self._es_carry, trees_B,
-                     metrics_B) = fn(*call_args)
+                     metrics_B, counts_B) = fn(*call_args)
         if coll_rec is not None:
             # the scan traces its body ONCE regardless of chunk length,
             # so the recorded totals are the per-iteration schedule
@@ -4422,7 +4590,8 @@ class GBDT:
                 int(getattr(a, "nbytes", 0)) for a in
                 [self.fused_bins_T, self.scores, self.bag_weight,
                  fm_pads, *base_args[2], *self.valid_scores])
-            sig = f"megastep[chunk={chunk},k={k},eval={plan is not None}]"
+            sig = f"megastep[chunk={chunk},k={k},eval={plan is not None}" \
+                + (",sampled]" if sample is not None else "]")
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
                 iteration=self.iter)
@@ -4436,6 +4605,8 @@ class GBDT:
                                 iteration=self.iter)
         self.scores = scores
         self.valid_scores = list(vscores)
+        if counts_B is not None:
+            self._sample_counts.append(counts_B)
         for leaf in jax.tree_util.tree_leaves(trees_B):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
@@ -4459,9 +4630,9 @@ class GBDT:
                 jnp.zeros((), bool),
                 jnp.full((), -1, jnp.int32))
 
-    def _make_megastep(self, chunk: int):
+    def _make_megastep(self, chunk: int, sample=None):
         obj = self.objective
-        grow_k = self._make_fused_tree_loop()
+        grow_k = self._make_fused_tree_loop(sample)
         valid_appliers = [self._make_valid_apply(vi)
                           for vi in range(len(self.valid_scores))]
 
@@ -4469,58 +4640,61 @@ class GBDT:
 
         def one_iteration(bins_T, scores, vbins, vscores, grad_ops,
                           bag_weight, fm_pads, ema=None, explore=None,
-                          seed=None):
+                          seed=None, it=None):
             """The SAME traced bodies as the per-iteration fast path —
             _make_fused_tree_loop for growth/score updates and
             _make_valid_apply per valid set — scanned, so the megastep
             is bit-identical to the pipelined path by construction."""
             with jax.named_scope("lgbm.gradients"):
                 grad, hess = obj.gradients_from(scores, grad_ops)
-            scores, stacked, ema, logs = grow_k(
+            scores, stacked, ema, logs, counts = grow_k(
                 bins_T, scores, grad, hess, bag_weight, fm_pads, ema,
-                explore, seed)
+                explore, seed, it)
             vscores = tuple(
                 apply_v(vscore, vb, stacked, logs)
                 for apply_v, vscore, vb in zip(valid_appliers, vscores,
                                                vbins))
-            return scores, vscores, stacked, ema
+            # (``counts``: a sampled step's; None, and so no result of
+            # the scan, in the plain step)
+            return scores, vscores, (stacked, counts), ema
 
         plan = self._traced_plan if self._eval_consumer is not None \
             else None
         if plan is None:
             if not ext:
                 def step(bins_T, scores, vbins, vscores, grad_ops,
-                         bag_weight, fm_pads_B):
-                    def body(carry, fm_pads):
+                         bag_weight, fm_pads_B, iters_B=None):
+                    def body(carry, xs):
                         scores, vscores = carry
-                        scores, vscores, stacked, _ = one_iteration(
+                        fm_pads, it = xs
+                        scores, vscores, grown, _ = one_iteration(
                             bins_T, scores, vbins, vscores, grad_ops,
-                            bag_weight, fm_pads)
-                        return (scores, vscores), stacked
-                    (scores, vscores), trees_B = jax.lax.scan(
-                        body, (scores, vscores), fm_pads_B)
-                    return scores, vscores, trees_B
+                            bag_weight, fm_pads, it=it)
+                        return (scores, vscores), grown
+                    (scores, vscores), (trees_B, counts_B) = jax.lax.scan(
+                        body, (scores, vscores), (fm_pads_B, iters_B))
+                    return scores, vscores, trees_B, counts_B
                 # donate the score carry and every valid-score buffer:
                 # the scan rewrites them in place across the whole chunk
                 return jax.jit(step, donate_argnums=_donate(1, 3))
 
             def step_ext(bins_T, scores, vbins, vscores, grad_ops,
                          bag_weight, fm_pads_B, ema0, explore_B,
-                         seeds_B):
+                         seeds_B, iters_B=None):
                 # the gain EMA rides the scan CARRY (screening feedback
                 # within the chunk); exploration flags and dither seeds
                 # ride as xs alongside the feature masks
                 def body(carry, xs):
                     scores, vscores, ema = carry
-                    fm_pads, explore, seed = xs
-                    scores, vscores, stacked, ema = one_iteration(
+                    fm_pads, explore, seed, it = xs
+                    scores, vscores, grown, ema = one_iteration(
                         bins_T, scores, vbins, vscores, grad_ops,
-                        bag_weight, fm_pads, ema, explore, seed)
-                    return (scores, vscores, ema), stacked
-                (scores, vscores, ema), trees_B = jax.lax.scan(
+                        bag_weight, fm_pads, ema, explore, seed, it)
+                    return (scores, vscores, ema), grown
+                (scores, vscores, ema), (trees_B, counts_B) = jax.lax.scan(
                     body, (scores, vscores, ema0),
-                    (fm_pads_B, explore_B, seeds_B))
-                return scores, vscores, trees_B, ema
+                    (fm_pads_B, explore_B, seeds_B, iters_B))
+                return scores, vscores, trees_B, ema, counts_B
             return jax.jit(step_ext, donate_argnums=_donate(1, 3))
 
         # ---- on-device eval variant: the scan additionally computes
@@ -4571,9 +4745,9 @@ class GBDT:
                     scores, vscores, es = carry
                     fm_pads, it = xs
                     active = ~es[2]
-                    new_scores, new_vscores, stacked, _ = one_iteration(
+                    new_scores, new_vscores, grown, _ = one_iteration(
                         bins_T, scores, vbins, vscores, grad_ops,
-                        bag_weight, fm_pads)
+                        bag_weight, fm_pads, it=it)
                     # freeze past the stop latch: the tree still comes
                     # out of the scan (static shapes) but contributes
                     # nothing
@@ -4584,11 +4758,11 @@ class GBDT:
                                                          vscores))
                     mvals = plan.eval_in_scan(scores, vscores, metric_ops)
                     es = es_update(es, mvals, it, active)
-                    return (scores, vscores, es), (stacked, mvals)
-                (scores, vscores, es), (trees_B, metrics_B) = \
+                    return (scores, vscores, es), (grown, mvals)
+                (scores, vscores, es), ((trees_B, counts_B), metrics_B) = \
                     jax.lax.scan(body, (scores, vscores, es0),
                                  (fm_pads_B, iters_B))
-                return scores, vscores, es, trees_B, metrics_B
+                return scores, vscores, es, trees_B, metrics_B, counts_B
             return jax.jit(step, donate_argnums=_donate(1, 3, 9))
 
         def step_ext(bins_T, scores, vbins, vscores, grad_ops, bag_weight,
@@ -4598,10 +4772,10 @@ class GBDT:
                 scores, vscores, es, ema = carry
                 fm_pads, it, explore, seed = xs
                 active = ~es[2]
-                (new_scores, new_vscores, stacked,
+                (new_scores, new_vscores, grown,
                  new_ema) = one_iteration(
                     bins_T, scores, vbins, vscores, grad_ops,
-                    bag_weight, fm_pads, ema, explore, seed)
+                    bag_weight, fm_pads, ema, explore, seed, it)
                 with jax.named_scope("lgbm.freeze"):
                     scores = jnp.where(active, new_scores, scores)
                     vscores = tuple(jnp.where(active, nv, v)
@@ -4612,11 +4786,11 @@ class GBDT:
                         ema = jnp.where(active, new_ema, ema)
                 mvals = plan.eval_in_scan(scores, vscores, metric_ops)
                 es = es_update(es, mvals, it, active)
-                return (scores, vscores, es, ema), (stacked, mvals)
-            (scores, vscores, es, ema), (trees_B, metrics_B) = \
+                return (scores, vscores, es, ema), (grown, mvals)
+            (scores, vscores, es, ema), ((trees_B, counts_B), metrics_B) = \
                 jax.lax.scan(body, (scores, vscores, es0, ema0),
                              (fm_pads_B, iters_B, explore_B, seeds_B))
-            return scores, vscores, es, trees_B, metrics_B, ema
+            return scores, vscores, es, trees_B, metrics_B, ema, counts_B
         return jax.jit(step_ext, donate_argnums=_donate(1, 3, 9))
 
     # ------------------------------------------------------------------
@@ -5340,7 +5514,6 @@ class DART(GBDT):
     """DART dropout boosting (ref: src/boosting/dart.hpp:23)."""
 
     name = "dart"
-
     def init(self, config, train_data, objective, training_metrics=()):
         super().init(config, train_data, objective, training_metrics)
         self.drop_rng = ref_random.Random(int(config.drop_seed))
@@ -5479,7 +5652,27 @@ class DART(GBDT):
 
 
 class GOSS(GBDT):
-    """Gradient-based One-Side Sampling (ref: src/boosting/goss.hpp:25)."""
+    """Gradient-based One-Side Sampling (ref: src/boosting/goss.hpp:25;
+    Ke et al., NeurIPS 2017, Algorithm 2).
+
+    From iteration int(1 / learning_rate) on, every iteration keeps
+    exactly ``top_k`` rows of the largest |g * h| (summed over the
+    classes; ties by the lower row index), draws exactly ``other_k`` of
+    the others from a counter-based stream keyed by ``bagging_seed`` and
+    the iteration, multiplies their gradient and hessian by (n - top_k) /
+    other_k and gives every other row weight 0 (ops/goss.py: one traced
+    sampler for every driver). goss.hpp samples per thread block with a
+    sequential acceptance probability, so its counts vary; these do not.
+
+    On the fused fast path (one process) the sample is drawn INSIDE the
+    step and each tree is grown on the compact matrix of the in-bag rows;
+    all rows' scores are updated from the tree's route log
+    (``_make_fused_tree_loop``). The synchronous driver calls the same
+    sampler jitted alone and takes the sample as a weight vector, on
+    every engine. Multi-process:
+    sampling is rank-LOCAL over this rank's rows, like the reference's
+    per-machine GOSS; thresholds and draws differ per rank by design and
+    touch rank-local rows only, so the SPMD control flow stays identical."""
 
     name = "goss"
 
@@ -5493,94 +5686,111 @@ class GOSS(GBDT):
             log.fatal("Cannot use bagging in GOSS")
         log.info("Using GOSS")
         self.is_bagging = False
+        self._goss_jit = None
+
+    def _goss_plan(self, n: int, evict_reason: Optional[str] = None):
+        from ..ops.goss import goss_plan
+        cfg = self.config
+        return goss_plan(n, float(cfg.top_rate), float(cfg.other_rate),
+                         float(cfg.learning_rate), int(cfg.bagging_seed),
+                         evict_reason)
+
+    def _sample_plan(self):
+        if self._sample_plan_cache is None:
+            # what the compact matrix does not compose with in this job
+            # (the job then takes the synchronous driver and the sample
+            # as a weight vector)
+            what = None
+            if getattr(self, "mp", None) is not None:
+                what = "multiproc"
+            elif self.parallel_mode != "serial":
+                what = f"tree_learner={self.parallel_mode}"
+            elif getattr(self, "fused_bundle_cols", 0):
+                what = "efb"
+            elif getattr(self, "quant_bits", 0):
+                what = "tpu_quantized_grad"
+            elif getattr(self, "fused_packed", None) is not None:
+                what = "tpu_adaptive_bins"
+            elif getattr(self, "fused_Bp", 0) > 256:
+                what = "wide_bins"
+            self._sample_plan_cache = self._goss_plan(
+                self.num_data, what and f"boosting:goss+{what}")
+        return self._sample_plan_cache
 
     def _capture_boosting_extra(self):
-        # GOSS resamples every iteration from scores (recomputed on
-        # resume) + this MT19937 stream — only the stream needs saving
-        kind, keys, pos, has_gauss, cached = self.bag_rng.get_state()
-        payload = {"goss_mt": {"pos": int(pos),
-                               "has_gauss": int(has_gauss),
-                               "cached": float(cached)}}
-        return payload, {"goss_mt_keys": np.asarray(keys, np.uint32)}
+        # the sampler's stream is a function of (bagging_seed, iteration)
+        # and the scores, which a resume recomputes: nothing to carry but
+        # the stream's identity, checked at restore
+        return {"goss_stream": {"kind": "counter_hash_v1",
+                                "seed": int(self.config.bagging_seed)}}, {}
 
     def _restore_boosting_extra(self, payload, arrays):
-        mt = payload.get("goss_mt")
-        if mt:
-            self.bag_rng.set_state(
-                ("MT19937", np.asarray(arrays["goss_mt_keys"], np.uint32),
-                 int(mt["pos"]), int(mt["has_gauss"]),
-                 float(mt["cached"])))
+        st = payload.get("goss_stream")
+        if st is None or st.get("kind") != "counter_hash_v1":
+            log.warning("checkpoint holds no GOSS counter stream (written "
+                        "before the traced sampler): the resumed run "
+                        "samples from bagging_seed and the iteration")
+        elif int(st["seed"]) != int(self.config.bagging_seed):
+            log.warning("bagging_seed %d differs from the checkpoint's %d: "
+                        "the resumed run's samples differ",
+                        int(self.config.bagging_seed), int(st["seed"]))
+
+    def _sampler(self, n: int):
+        """The traced sampler (ops/goss.py) jitted alone, for the
+        synchronous driver: (grad [k, n], hess [k, n], it) -> (multiplier
+        [n], in-bag 0/1 [n])."""
+        if self._goss_jit is None or self._goss_jit[0] != n:
+            from ..ops import goss
+            plan = self._goss_plan(n)
+
+            @jax.jit
+            def sample(grad, hess, it, seed):
+                top, other = goss.goss_sample(
+                    jnp.sum(jnp.abs(grad * hess), axis=0), it, seed,
+                    plan.top_k, plan.other_k)
+                return goss.sample_weights(top, other, plan.multiply)
+            self._goss_jit = (n, sample, plan)
+        return self._goss_jit[1:]
 
     def _bagging(self, it, grad, hess):
-        """(ref: goss.hpp:103-159 BaggingHelper/Bagging). Multi-process:
-        sampling is rank-LOCAL over this rank's rows, exactly like the
-        reference's per-machine GOSS (each machine's BaggingHelper runs
-        on its own bag_data_cnt_); thresholds and draws differ per rank
-        by design — they only touch rank-local rows, so the SPMD control
-        flow stays identical."""
-        cfg = self.config
+        """(ref: goss.hpp:103-159 BaggingHelper/Bagging.) ``grad`` None:
+        a step that draws its own sample (the fast paths)."""
         mp = getattr(self, "mp", None)
-        n = self.num_data
-        # no subsampling in the first 1/learning_rate iterations
-        if it < int(1.0 / cfg.learning_rate):
+        if it < self._sample_plan().first_iter:
+            # no subsampling in the first 1/learning_rate iterations
             self.bag_weight = self._bag_ones()
-            self.bag_cnt = mp.total_real if mp is not None else n
+            self.bag_cnt = mp.total_real if mp is not None else self.num_data
             return grad, hess
-        # sum over classes of |g*h| (ref: goss.hpp:108-113 accumulates
-        # fabs(g*h) per tree-per-iteration model)
-        if mp is not None:
-            n = mp.local_real
-            if n == 0:
-                # a rank can legitimately hold zero rows (query-aligned
-                # shards); it contributes nothing but must keep the SPMD
-                # control flow
-                self._bag_weight_local = np.zeros(mp.block, np.float32)
-                self.bag_weight = mp.shard_local(self._bag_weight_local)
-                from jax.experimental import multihost_utils
-                cnts = np.asarray(multihost_utils.process_allgather(
-                    np.asarray([0], np.int64)))
-                self.bag_cnt = int(cnts.sum())
-                mult_dev = mp.shard_local(
-                    np.ones(mp.block, np.float32))[None, :]
-                return grad * mult_dev, hess * mult_dev
-            g_np = np.asarray(jnp.sum(jnp.abs(
-                mp.local_block(grad, axis=1)
-                * mp.local_block(hess, axis=1)), axis=0))
-            g_np = g_np[:n]
-        else:
-            g_np = np.asarray(jnp.sum(jnp.abs(grad * hess), axis=0))
-        top_k = max(1, int(n * cfg.top_rate))
-        other_k = max(1, int(n * cfg.other_rate))
-        threshold = np.partition(g_np, n - top_k)[n - top_k]
-        multiply = (n - top_k) / other_k
-        is_top = g_np >= threshold
-        rest = ~is_top
-        rest_idx = np.nonzero(rest)[0]
-        n_rest = len(rest_idx)
-        if n_rest > 0:
-            take = min(other_k, n_rest)
-            sampled = self.bag_rng.choice(rest_idx, size=take, replace=False)
-        else:
-            sampled = np.zeros(0, np.int64)
-        mask = is_top.copy()
-        mask[sampled] = True
-        mult = np.ones(n, np.float32)
-        mult[sampled] = multiply
-        if mp is not None:
-            pad = mp.block - n
-            maskp = np.pad(mask.astype(np.float32), (0, pad))
-            multp = np.pad(mult, (0, pad), constant_values=1.0)
-            self._bag_weight_local = maskp
-            self.bag_weight = mp.shard_local(maskp)
-            from jax.experimental import multihost_utils
-            cnts = np.asarray(multihost_utils.process_allgather(
-                np.asarray([mask.sum()], np.int64)))
-            self.bag_cnt = int(cnts.sum())
-            mult_dev = mp.shard_local(multp)[None, :]
-        else:
-            self.bag_cnt = int(mask.sum())
-            self.bag_weight = jnp.asarray(mask.astype(np.float32))
-            mult_dev = jnp.asarray(mult)[None, :]
+        if grad is None:
+            self.bag_cnt = self._sample_plan().bag_rows
+            return grad, hess
+        seed = int(self.config.bagging_seed)
+        if mp is None:
+            sample, plan = self._sampler(self.num_data)
+            mult, self.bag_weight = sample(grad, hess, np.int32(it),
+                                           np.uint32(seed))
+            self.bag_cnt = plan.bag_rows
+            return grad * mult[None, :], hess * mult[None, :]
+        n = mp.local_real
+        maskp = np.zeros(mp.block, np.float32)
+        multp = np.ones(mp.block, np.float32)
+        if n > 0:
+            # (a rank can hold zero rows, query-aligned shards; it
+            # contributes nothing but keeps the SPMD control flow)
+            sample, plan = self._sampler(n)
+            mult, inbag = sample(
+                jnp.asarray(mp.local_block(grad, axis=1))[:, :n],
+                jnp.asarray(mp.local_block(hess, axis=1))[:, :n],
+                np.int32(it), np.uint32(seed + mp.process_index))
+            maskp[:n] = np.asarray(inbag)
+            multp[:n] = np.where(maskp[:n] > 0, np.asarray(mult), 1.0)
+        self._bag_weight_local = maskp
+        self.bag_weight = mp.shard_local(maskp)
+        from jax.experimental import multihost_utils
+        cnts = np.asarray(multihost_utils.process_allgather(
+            np.asarray([maskp.sum()], np.int64)))
+        self.bag_cnt = int(cnts.sum())
+        mult_dev = mp.shard_local(multp)[None, :]
         return grad * mult_dev, hess * mult_dev
 
 
@@ -5591,7 +5801,6 @@ class RF(GBDT):
     stored prediction is the average over trees."""
 
     name = "rf"
-
     def init(self, config, train_data, objective, training_metrics=()):
         if not (config.bagging_freq > 0 and 0.0 < config.bagging_fraction
                 < 1.0):

@@ -40,16 +40,19 @@ STRUCTURAL = {"while", "cond", "closed_call", "shard_map", "body"}
 
 
 def _lowered_step(monkeypatch, with_eval: bool, learner: str,
-                  ranking: bool = False, categorical: bool = False) -> str:
+                  ranking: bool = False, categorical: bool = False,
+                  goss: bool = False) -> str:
     """Debug text of the megastep `lgb.train` built for a small binary
     (or, ``ranking``, lambdarank + NDCG) job, lowered again from the
     shapes it was called with. ``categorical``: columns 2 and 3 hold
-    category codes and are given as such."""
+    category codes and are given as such. ``goss``: ``boosting=goss``
+    sampling from iteration 2 on, four iterations: the LAST step built,
+    the sampled one, is the one lowered."""
     seen = {}
     make = GBDT._make_megastep
 
-    def recording(self, chunk):
-        fn = make(self, chunk)
+    def recording(self, chunk, *sample):
+        fn = make(self, chunk, *sample)
 
         def call(*args):
             seen["fn"] = fn
@@ -86,10 +89,12 @@ def _lowered_step(monkeypatch, with_eval: bool, learner: str,
         yv = ((Xv[:, 2] % 3 == 0) ^ (Xv[:, 0] > 0.5)).astype(np.float32)
         params.update(min_data_per_group=20, cat_smooth=5.0)
         cats = [2, 3]
+    if goss:
+        params.update(boosting="goss", learning_rate=0.5)
     ds = lgb.Dataset(X, label=y, group=group, categorical_feature=cats)
     # with callbacks the scan evaluates the metric itself and carries the
     # early-stop latch; without them it only keeps the validation scores
-    lgb.train(params, ds, num_boost_round=2,
+    lgb.train(params, ds, num_boost_round=4 if goss else 2,
               valid_sets=[lgb.Dataset(Xv, label=yv, group=group_v,
                                       reference=ds)],
               callbacks=[lgb.record_evaluation({})] if with_eval else None)
@@ -233,4 +238,38 @@ def test_every_row_and_histogram_length_operation_of_a_categorical_step_is_scope
     assert any("/cat/" in n and "/while/" in n for n in under_cat)
     # the numerical scan of the same call stays outside the scope
     assert any(phase_of(n) == "grow/level/split" and "/cat/" not in n
+               for n in names)
+
+
+def test_every_row_length_operation_of_a_sampled_goss_step_is_scoped(
+        monkeypatch):
+    """The sampled step of a ``boosting=goss`` job: the counting passes,
+    the keys and the second select, the mask's prefix sum and the
+    compaction kernel are under ``lgbm.sample`` > ``select`` / ``draw`` /
+    ``compact``; the replay of the route log over all rows is under
+    ``lgbm.score_update``; nothing as long as the rows or the compact
+    matrix is outside a scope."""
+    from lightgbm_tpu.ops.goss import goss_plan
+    text, avals = _lowered_step(monkeypatch, True, "serial", goss=True)
+    bins_T = avals[0]
+    plan = goss_plan(ROWS, 0.2, 0.1, 0.5, 3)
+    lengths = {ROWS, bins_T.shape[1], VALID_ROWS, plan.capacity,
+               plan.bag_rows}
+    ops, calls = _scoped_ops(text)
+    names, unscoped = set(), []
+    for func, name, dims in ops:
+        for full in _full_names(func, name, calls):
+            names.add(full)
+            if phase_of(full) == UNSCOPED and dims & lengths \
+                    and full.split("/")[-1] not in STRUCTURAL:
+                unscoped.append(full)
+    assert not unscoped, f"row-length operations outside any lgbm. " \
+        f"scope: {sorted(set(unscoped))[:10]}"
+    assert {phase_of(n).split("/")[0] for n in names} - {UNSCOPED} \
+        == TOP_LEVEL | EVAL_ONLY | {"sample"}
+    for stage in ("select", "draw", "compact"):
+        assert any(f"lgbm.sample/{stage}/" in n for n in names), stage
+    assert any("lgbm.sample/compact/" in n and "compact_rows" in n
+               for n in names)
+    assert any("lgbm.score_update/" in n and "route_pass" in n
                for n in names)

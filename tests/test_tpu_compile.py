@@ -170,3 +170,53 @@ def test_bins_form_level_kernel_compiles_at_epsilon_width(one_chip):
     o = _operands(one_chip, 2000, 63, 8)
     _compiles(functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"]),
               o["bins"], o["leaf"], o["gh"], None, o["tbl"])
+
+
+# ---- GOSS on the fast path (PR 35): the sampled step's own shapes
+GOSS_ROWS, GOSS_CAPACITY = 28_000_256, 8_400_896   # the cell's Rp and K
+
+
+@pytest.mark.parametrize("tile", [256, 512, 1024])
+def test_the_row_compaction_compiles_at_the_cell_s_rows(one_chip, tile):
+    """``ops/goss.compact_rows`` at 28M rows -> 8.4M columns, Higgs width:
+    the scalar-prefetched block table (one int32 a tile: 437 KB at 256-row
+    tiles), the [40, 2C] float32 window and the [2C, C] permutation fit."""
+    from lightgbm_tpu.ops import goss
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    _compiles(functools.partial(goss.compact_rows, capacity=GOSS_CAPACITY,
+                                tile_rows=tile),
+              shape((32, GOSS_ROWS), jnp.int8),
+              shape((8, GOSS_ROWS), jnp.bfloat16),
+              shape((28_000_000,), jnp.bool_))
+
+
+def test_the_compaction_compiles_for_int16_bins(one_chip):
+    from lightgbm_tpu.ops import goss
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    _compiles(functools.partial(goss.compact_rows, capacity=GOSS_CAPACITY),
+              shape((8, GOSS_ROWS), jnp.int16),
+              shape((8, GOSS_ROWS), jnp.bfloat16),
+              shape((28_000_000,), jnp.bool_))
+
+
+def test_the_sampler_compiles_at_the_cell_s_rows(one_chip):
+    from lightgbm_tpu.ops import goss
+    _compiles(lambda a: goss.goss_sample(a, 12, 3, 5_600_000, 2_800_000),
+              jax.ShapeDtypeStruct((28_000_000,), jnp.float32,
+                                   sharding=one_chip))
+
+
+@pytest.mark.parametrize("deep", [False, True], ids=["8slots", "cap"])
+def test_level_pass_compiles_at_the_sample_s_capacity(one_chip, deep):
+    """``level_pass`` over K = 8,400,896 columns (4,102 tiles of 2,048), the
+    row count a sampled tree is grown on, beside the 28M-row one."""
+    fb = feature_layout(28, 63)
+    sp = min(128, max_slot_cap(fb[0] * fb[1], NCH_PRECISE)) if deep else 8
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    assert GOSS_CAPACITY % 2048 == 0
+    _compiles(functools.partial(level_pass, nch=NCH_PRECISE, num_slots=sp,
+                                num_bins=fb[1], f_oh=fb[0]),
+              shape((32, GOSS_CAPACITY), jnp.int8),
+              shape((1, GOSS_CAPACITY), jnp.int32),
+              shape((8, GOSS_CAPACITY), jnp.bfloat16), None,
+              shape((sp, 128), jnp.int32))
