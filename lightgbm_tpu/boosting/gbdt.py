@@ -3198,7 +3198,7 @@ class GBDT:
                                kB)[0] == "bins"
         return level_build(bins_form, Sp, kF * kB, self.fused_nch,
                            max(kF, 8), wide_bins=kB > 256,
-                           has_cat=self.has_cat)
+                           has_cat=self.has_cat, quant=self.quant_bits > 0)
 
     def _valid_route_reason(self, vi: int) -> Optional[str]:
         """Why validation set ``vi`` keeps the gather walk, or None when
@@ -3269,9 +3269,15 @@ class GBDT:
         ``level_pass`` BUILDS its one-hot, which
         follows from it (ops/fused_level.level_build decides): counter
         ``level.build_<form>`` and a ``level_build`` event with the slab
-        size and the row tile per distinct slot count of the level
-        schedule (the ``reason`` of a ``scratch`` build is the table
-        form's)."""
+        size and, per distinct slot count of the level schedule, the row
+        tile and which operand of the histogram dot the MXU streams
+        (``dot``); the counters ``level.dot_stream_channels`` /
+        ``level.dot_stream_onehot`` count the histogram passes of one
+        full tree in each order: the root and every level of the static
+        schedule up to the one that spends the leaf budget, which only
+        routes (the ``tpu_extra_levels`` passes behind it run only for
+        a skewed tree and are not counted). The ``reason`` of a
+        ``scratch`` build is the table form's."""
         from ..models.frontier2 import route_form
         _, kB, caps = self._fused_plane()
         said = route_form(self.has_cat, self.fused_bundle_cols, kB)
@@ -3292,7 +3298,11 @@ class GBDT:
         build = dict(builds[8])
         build["tile_rows"] = {str(sp): min(self.fused_Rp, b["tile_rows"])
                               for sp, b in builds.items()}
+        build["dot"] = {str(sp): b["dot"] for sp, b in builds.items()}
         tel.inc("level.build_%s" % build["form"])
+        full_tree = caps[:len(caps) - int(self.config.tpu_extra_levels)]
+        for sp in [8] + [max(8, c) for c in full_tree[:-1]]:
+            tel.inc("level.dot_stream_%s" % builds[sp]["dot"])
         tel.event("level_build", iteration=self.iter, **build, **why)
         if self.has_cat:
             # the columns the categorical search runs over, once per job

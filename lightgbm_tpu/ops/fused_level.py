@@ -12,12 +12,32 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
   native sublane broadcasts (no per-feature lane broadcast / int8 sublane
   extraction, which cost 2-3x in a row-major kernel).
 - The one-hot ``oh[f*B+b, r] = (bins[f, r] == b)`` feeds the histogram
-  dot, ``hist += oh @ ghs^T -> [FB, nch*S]``: the MXU streams the FB
-  one-hot rows through one latched [128, 128] tile of ``ghs`` per 128
-  data rows and N-tile (65 ms per N-tile of 128 columns at 28M rows x FB
-  1,792 is the chip's bound). It is built (``_onehot_slab``) per row tile
-  of ``level_pass``, in one of two ways that follow from the routing form
-  below (``level_build``):
+  dot against the masked channels ``ghs [nch*S, C]``, whose order
+  ``level_build`` chooses (timings: PR 36's step 0, PERF.md section 6):
+    * ``channels``, the bf16 passes of the bins form whose nch*S columns
+      are not a whole number of 128 (every pass of at most 64 slots):
+      ``hist^T += ghs @ oh^T -> [nch*S, FB]``. The MXU LATCHES the one-hot's [128, 128]
+      tiles and streams the nch*S channel rows through each, into a
+      TRANSPOSED accumulator whose lanes are the (feature, bin) pairs
+      (``level_pass`` hands back [FB, nch*S] all the same). The streamed
+      axis pads to 8 sublanes, so a pass pays for the columns it has: a
+      latched tile costs about 105-115 cycles while it streams at most
+      ~100 rows and the rows it streams above that. In units of the
+      other order's N-tile (65.2 ms over 28M rows x FB 1,792): 0.82 /
+      0.90 / 1.29 / 2.62 at 8 / 16 / 32 / 64 slots of five channels.
+    * ``onehot``, the table form and the int8 paths (neither timed the
+      other way) and a pass of 128 slots (640 columns pad nothing: 206.3
+      ms against 211.8): ``hist += oh @ ghs^T -> [FB, nch*S]``. The MXU streams
+      the FB one-hot rows through one latched [128, 128] tile of ``ghs``
+      per 128 data rows and N-tile, so the channel axis pads to whole
+      N-tiles of 128 columns: ceil(nch*S / 128) units, 1 / 1 / 2 / 3 at
+      the same slot counts (160 columns pay for 256, 320 for 384). Until
+      PR 36 every pass ran this order: 74.1 / 75.2 / 142.2 / 210.3 ms
+      where ``channels`` takes 62.6 / 67.5 / 93.2 / 180.0, bit for bit
+      the same histogram (both sum a tile's products in the same 128-deep
+      chunks).
+  The one-hot is built (``_onehot_slab``) per row tile of ``level_pass``,
+  in one of two ways that follow from the routing form below:
     * in SLABS, the bins form: ``ghs`` is known before any one-hot
       element is, and each FB-row block of the one-hot has one reader,
       its own rows of the accumulator. So SLAB_ROWS = 512 one-hot rows (8
@@ -25,12 +45,13 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
       ``hist[slab rows]``, slab after slab in one basic block: there is
       no [FB, C] scratch, the row tile does not shrink with FB (2,048
       rows at every width a cell runs) and the VPU build of one slab
-      runs under the MXU's pass over its neighbours. 28M x 28 x 64 bins:
-      74.0 ms at 8 slots, 210.1 at 64 (the whole-scratch build: 96.3 /
-      247.6 at 1,024-row tiles); 6.81M x 137 x 64 at 16 slots: 81.8 ms
-      against a 78.2 ms bound (183.6 with the scratch, which left
-      128-row tiles: 53,216 grid steps, each rewriting the 2.8 MB
-      accumulator). A ``lax.fori_loop`` over the slabs is 4-10 % slower
+      runs under the MXU's pass over its neighbours. In the ``onehot``
+      order, 28M x 28 x 64 bins: 74.0 ms at 8 slots, 210.1 at 64 (the
+      whole-scratch build: 96.3 / 247.6 at 1,024-row tiles); 6.81M x 137
+      x 64 at 16 slots: 81.8 ms (71.9 in the ``channels`` order) against
+      183.6 with the scratch, which left 128-row tiles: 53,216 grid
+      steps, each rewriting the 2.8 MB accumulator. A ``lax.fori_loop``
+      over the slabs is 4-10 % slower
       (the loop's back-edge is a barrier to that overlap) and column
       slabs (all FB rows x 512 of the tile's rows) 1-6 %;
     * WHOLE, into an [FB, C] VMEM scratch (``_write_onehot``), the table
@@ -67,17 +88,16 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
       categorical columns (``build_route_table*``'s ``cat_mask``).
       ``level_pass`` / ``route_pass`` take the form from their ``W``
       argument (None = bins form).
-- All gh channels are packed into ONE dot (N = nch*S): MXU efficiency
-  rises with N.
+- All gh channels are packed into ONE dot operand of nch*S rows.
 - Channels (``nch=5``, default): g_hi, g_lo, h_hi, h_lo, w — grad/hess are
   split into two bfloat16 halves (hi + exact residual) so the accumulated
   histogram carries ~fp32 input precision, matching the reference GPU
   precision contract (ref: docs/GPU-Performance.rst:130-160) instead of
   raw-bf16 rounding. ``nch=3`` (g, h, w single-bf16) is the fast
   mode.
-- The grid is sequential on a TPU core, so the [FB, nch*S] output block
-  accumulates across row tiles race-free; the updated row->leaf vector is
-  emitted per-tile alongside.
+- The grid is sequential on a TPU core, so the [FB, nch*S] (or
+  transposed) output block accumulates across row tiles race-free; the
+  updated row->leaf vector is emitted per-tile alongside.
 - The ROOT pass needs no special kernel: slot 0 holds leaf 0, sends every
   row "left" (``root_route_tables``) and is its own smaller child, so it
   collects the full-data histogram.
@@ -133,6 +153,14 @@ SLAB_ROWS = 512   # one-hot rows of one slab of the bins form's build
 CAT_PLANE_BYTES = 12
 
 
+def slab_row_bytes(Sp: int, nch: int, bins_rows: int,
+                   has_cat: bool = False) -> int:
+    """Scoped-VMEM bytes the bins form's ``level_pass`` is charged per row
+    of its tile (default_tile_rows says what for)."""
+    return (SLAB_ROWS * 6 + bins_rows * 6
+            + Sp * (2 * nch + 16 + CAT_PLANE_BYTES * has_cat))
+
+
 def default_tile_rows(Sp: int, FB: int, nch: int,
                       wide_bins: bool = False, bins_rows: int = 0,
                       has_cat: bool = False) -> int:
@@ -150,10 +178,13 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     routing dot's bf16 copy); a job with a categorical column
     (``has_cat``) is charged the membership test's [Sp, C] int32 planes
     too (CAT_PLANE_BYTES a slot). That is 2,048 rows up to Fp ~700 at 16
-    slots or 128 slots at Fp 28. The charge is CONSERVATIVE: compiled
-    for a described v5e the kernel needed 1.3-1.8 MB at 8-16 slots, 4.2
-    at 64 and 5.2 at 128 with 2,048-row tiles (the compiler fuses the
-    build's intermediates), and the [FB, nch*Sp] accumulator is the
+    slots or 128 slots at Fp 28. The charge is CONSERVATIVE, and the
+    same in both orders of the histogram dot (the same operands, the
+    same bytes): compiled for a described v5e at Higgs's width and
+    2,048-row tiles the kernel needs 1.13 / 1.61 / 2.39 / 4.31 MB at 8 /
+    16 / 32 / 64 slots with the channels streamed (1.22 / 1.48 / 3.37 /
+    5.05 with the one-hot streamed; the compiler fuses the build's
+    intermediates), and the [FB, nch*Sp] accumulator is the
     pipeline's output window, not part of that stack (Epsilon's 20.5 MB
     accumulator compiles under the 16 MB limit). On a v5e (PR 31's step
     0) a pass over 6.81M x 137 x 64 bins at 16 slots took 105.1 / 92.3 /
@@ -173,9 +204,7 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     default 16 MB scoped-VMEM limit. Shallow levels (small Sp -> small
     accumulator) get larger tiles."""
     if bins_rows:
-        per_row = (SLAB_ROWS * 6 + bins_rows * 6
-                   + Sp * (2 * nch + 16 + CAT_PLANE_BYTES * has_cat))
-        c = VMEM_BUDGET // per_row
+        c = VMEM_BUDGET // slab_row_bytes(Sp, nch, bins_rows, has_cat)
     else:
         acc = FB * nch * Sp * 4
         avail = max(VMEM_BUDGET - acc, 2 * 1024 * 1024)
@@ -186,18 +215,31 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
 
 
 def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
-                wide_bins: bool = False, has_cat: bool = False) -> dict:
-    """How ``level_pass`` builds its one-hot at these shapes, and its row
-    tile. THE place both are chosen: the kernel asks here, and so does
-    the driver for its ``level_build`` event. ``slab`` (the bins form):
-    SLAB_ROWS one-hot rows at a time, each multiplied at once into its
-    own rows of the accumulator; ``scratch`` (the table form, whose
-    routing dot reads the whole one-hot first): all [FB, C] of it."""
+                wide_bins: bool = False, has_cat: bool = False,
+                quant: bool = False) -> dict:
+    """How ``level_pass`` builds its one-hot at these shapes, its row
+    tile, and which operand of the histogram dot the MXU streams. THE
+    place all three are chosen, from static shapes alone: the kernel
+    asks here, and so does the driver for its ``level_build`` event.
+    ``slab`` (the bins form): SLAB_ROWS one-hot rows at a time, each
+    multiplied at once into its own part of the accumulator; ``scratch``
+    (the table form, whose routing dot reads the whole one-hot first):
+    all [FB, C] of it. ``dot``: ``onehot`` streams the one-hot's rows
+    through latched tiles of ``ghs``; ``channels`` latches the one-hot's
+    tiles and streams the nch*Sp channel rows: the bf16 slab build's
+    wherever the channel columns would pad an N-tile of 128, which is
+    every slot count a cell runs (faster at each, and the same bits:
+    module docstring). A whole number of N-tiles pads nothing (128 slots
+    x 5 channels = 640 columns: 206.3 ms streamed the old way, 211.8 the
+    new), the table form's dot reads the whole scratch and the int8 paths
+    have another MXU tile (neither timed): all three keep ``onehot``."""
     if bins_form:
+        channels = not quant and (nch * Sp) % 128 != 0
         return {"form": "slab", "slab_rows": SLAB_ROWS,
                 "tile_rows": default_tile_rows(Sp, FB, nch, bins_rows=Fp,
-                                               has_cat=has_cat)}
-    return {"form": "scratch",
+                                               has_cat=has_cat),
+                "dot": "channels" if channels else "onehot"}
+    return {"form": "scratch", "dot": "onehot",
             "tile_rows": default_tile_rows(Sp, FB, nch, wide_bins=wide_bins)}
 
 
@@ -684,7 +726,7 @@ def _slab_cuts(F_oh: int, B: int, packed: PackedLayout = None):
 def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
                   quant: bool = False, packed: PackedLayout = None,
                   has_fm: bool = False, has_w: bool = True,
-                  has_cat: bool = False):
+                  has_cat: bool = False, dot: str = "onehot"):
     """``level_pass``'s body. Table form (``has_w``): the whole one-hot
     goes to the [FB, C] scratch ``oh_ref`` first, because routing reads
     all of it (``D = W @ oh``) before the histogram dot's right-hand side
@@ -693,7 +735,10 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
     one reader (its own rows of the accumulator) and there is no scratch:
     slabs are built and multiplied one after the other in ONE basic
     block, which lets the scheduler put the VPU build of a slab under
-    the MXU's pass over its neighbours."""
+    the MXU's pass over its neighbours. ``dot`` (level_build's, bins
+    form only): ``channels`` multiplies the other way round,
+    ``ghs @ slab^T``, into a TRANSPOSED accumulator [nch*Sp, FB] whose
+    lanes o0..o0 + k*w are the slab's."""
     refs = list(refs)
     bins_ref, leaf_ref, gh_ref = refs[:3]
     w_ref = refs[3] if has_w else None
@@ -707,9 +752,10 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
 
     leafb = leaf_ref[:]                                        # [1, C] i32
     acc_dt = jnp.int32 if quant else jnp.float32
-    # the histogram dot: all channels packed into one wide-N operand
-    hist_dot = lambda oh, ghs: jax.lax.dot_general(
-        oh, ghs, (((1,), (1,)), ((), ())), preferred_element_type=acc_dt)
+    # the histogram dot over a tile's rows, a @ b^T: all channels packed
+    # into one operand; the MXU streams a's rows through latched tiles of b
+    hist_dot = lambda a, b: jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=acc_dt)
 
     if not has_w:
         left_i = _left_from_bins(bins_ref, tbl_ref, has_cat)   # [Sp, C] 0/1
@@ -723,7 +769,10 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
             oh = _onehot_slab(binsv[r0:r0 + k], w, quant)
             if fm_ref is not None:
                 oh = oh * fm_ref[o0:o0 + k * w, 0:1]
-            hist_ref[o0:o0 + k * w] += hist_dot(oh, ghs)
+            if dot == "channels":
+                hist_ref[:, o0:o0 + k * w] += hist_dot(ghs, oh)
+            else:
+                hist_ref[o0:o0 + k * w] += hist_dot(oh, ghs)
         return
 
     oh_ref = refs[-1]
@@ -812,13 +861,15 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     FB = _kernel_fb(f_oh, B, packed)
     FB_tiles = f_oh * B       # padded formula: keeps tiling A/B-stable
     Sp = tbl.shape[0]
-    C = _fit_tile(tile_rows or level_build(W is None, Sp, FB_tiles, nch, Fp,
-                                           wide_bins=B > 256,
-                                           has_cat=has_cat)["tile_rows"],
-                  R)
+    quant = quant_bits > 0
+    build = level_build(W is None, Sp, FB_tiles, nch, Fp, wide_bins=B > 256,
+                        has_cat=has_cat, quant=quant)
+    C = _fit_tile(tile_rows or build["tile_rows"], R)
     assert R % C == 0, f"rows {R} not padded to tile {C}"
     T = R // C
-    quant = quant_bits > 0
+    # the accumulator of a pass that streams the channels is transposed
+    channels = build["dot"] == "channels"
+    acc_shape = (nch * Sp, FB) if channels else (FB, nch * Sp)
     oh_dt = jnp.int8 if quant else jnp.bfloat16
     acc_dt = jnp.int32 if quant else jnp.float32
 
@@ -826,7 +877,8 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
                                nch=nch, quant=quant, packed=packed,
                                has_fm=fmask is not None,
                                has_w=W is not None,
-                               has_cat=has_cat and W is None)
+                               has_cat=has_cat and W is None,
+                               dot=build["dot"])
     in_specs = [
         pl.BlockSpec((Fp, C), lambda t: (0, t)),
         pl.BlockSpec((1, C), lambda t: (0, t)),
@@ -846,11 +898,11 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
         grid=(T,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((FB, nch * Sp), lambda t: (0, 0)),
+            pl.BlockSpec(acc_shape, lambda t: (0, 0)),
             pl.BlockSpec((1, C), lambda t: (0, t)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((FB, nch * Sp), acc_dt),
+            jax.ShapeDtypeStruct(acc_shape, acc_dt),
             jax.ShapeDtypeStruct((1, R), jnp.int32),
         ],
         scratch_shapes=[] if W is None else [pltpu.VMEM((FB, C), oh_dt)],
@@ -858,7 +910,7 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
-    return hist, new_leaf
+    return (hist.T if channels else hist), new_leaf
 
 
 def _route_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, newleaf_ref,

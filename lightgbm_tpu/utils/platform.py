@@ -14,6 +14,7 @@ Two decisions, each made in exactly one place:
 from __future__ import annotations
 
 import os
+import re
 
 from . import log
 
@@ -77,3 +78,13 @@ def compilation_cache_dir(requested: str = "") -> str:
     path = requested or _DEFAULT_CACHE_DIR
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def scoped_vmem_bytes(compiled) -> int | None:
+    """Most scoped VMEM a Pallas kernel of a compiled TPU executable asks
+    for: its stack (values and spills), beside the pipeline's operand
+    windows. Read from the ``tpu_custom_call``'s backend config in the
+    compiled text; None where the text names none (no kernel, no TPU)."""
+    found = re.findall(r'used_scoped_memory_configs":\[\{"memory_space":"1",'
+                       r'"offset":"0","size":"(\d+)"', compiled.as_text())
+    return max(map(int, found)) if found else None

@@ -32,7 +32,8 @@ Run: ROWS=28000000 FEATURES=28 SLOTS=8,64 TILES=512,1024,2048 \
      ROWS=6810888 FEATURES=137 SLOTS=8,16 TILES=128,256,512,1024,2048 ...
 VARIANTS=fb512.fori1,col256 keeps some; INTERPRET=1 rehearses on the CPU
 at a tiny ROWS; COMPILE_ONLY=1 compiles every variant for a described v5e
-(no chip, no timing: what the compiler refuses, and how long it takes).
+(no chip, no timing: what the compiler refuses, how long it takes and
+the scoped VMEM the kernel asks for).
 One JSON line per timing on stdout, all of them in
 chiprun_out/ablate_slab_build/<FEATURES>.jsonl.
 """
@@ -189,9 +190,10 @@ def _parent_at(parent, Sp, F_oh, B, C, vmem_limit, interpret):
                  F_oh * B, C, vmem_limit, interpret)
 
 
-def _float64_sum(bins_np, leaf_np, g_np, tbl_b, Sp, F, B):
+def _float64_sum(bins_np, leaf_np, g_np, tbl_b, Sp, F, B, sets=None):
     """[F*B, Sp] float64 sums of the g channel (hi + lo, the values the
-    kernels multiply) over each slot's smaller child."""
+    kernels multiply) over each slot's smaller child; ``sets``: the
+    categorical slots' flag and bin sets (ablate_route_form._bin_sets)."""
     t = np.asarray(tbl_b)
     on = leaf_np >= 0
     k = np.where(on, leaf_np, 0)
@@ -200,6 +202,9 @@ def _float64_sum(bins_np, leaf_np, g_np, tbl_b, Sp, F, B):
                 np.arange(leaf_np.size)].astype(np.int32)
     left = np.where(v == t[k, fl.TBL_MISSING_BIN],
                     t[k, fl.TBL_DEFAULT_LEFT] > 0, v <= t[k, fl.TBL_THRESHOLD])
+    if sets:
+        flag = np.asarray(sets["cat_flag"])
+        left = np.where(flag[k], np.asarray(sets["cat_mask"])[k, v], left)
     left &= t[k, fl.TBL_FEATURE_ROW] >= 0
     keep = np.nonzero(active & (left == (t[k, fl.TBL_SMALL_LEFT] > 0)))[0]
     key0 = k[keep] * B
@@ -223,8 +228,11 @@ def _time(fn, reps):
 
 
 def _compile_only(fn, shapes):
+    """Compile for a described v5e: what the compiler refuses, how long
+    it takes, and the scoped VMEM the kernel it accepts asks for."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.utils.platform import scoped_vmem_bytes
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
@@ -232,10 +240,11 @@ def _compile_only(fn, shapes):
             for a in shapes]
     t0 = time.perf_counter()
     try:
-        jax.jit(fn).lower(*args).compile()
-        return dict(compiled=True, compile_s=time.perf_counter() - t0)
+        compiled = jax.jit(fn).lower(*args).compile()
     except Exception as e:  # what the chip's compiler would refuse
         return dict(compiled=False, error=str(e).splitlines()[-1][:300])
+    return dict(compiled=True, compile_s=time.perf_counter() - t0,
+                scoped_vmem_bytes=scoped_vmem_bytes(compiled))
 
 
 def main():

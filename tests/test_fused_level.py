@@ -472,3 +472,168 @@ def test_packed_equals_padded_at_the_slab_builds_own_tile():
                     hist_planes(hist_p, NCH_PRECISE, Sp, F_oh, B)):
         assert np.array_equal(np.asarray(a)[:, :F], np.asarray(b)[:, :F])
     assert np.abs(np.asarray(hist_p)).sum() > 0
+
+
+# ---- which operand of the histogram dot the MXU streams (PR 36): the bins
+# form's slab dot in both orders, and level_build's rule for the order
+def _pass_in_order(monkeypatch, order, *ops, **kw):
+    """``level_pass`` with level_build's ``dot`` forced to ``order``
+    (the jitted wrapper caches on its static arguments, which do not
+    include the rule: the plain function is called instead)."""
+    from lightgbm_tpu.ops import fused_level as fl
+    rule = fl.level_build
+    with monkeypatch.context() as patch:
+        patch.setattr(fl, "level_build",
+                      lambda *a, **k: dict(rule(*a, **k), dot=order))
+        out = fl.level_pass.__wrapped__(*ops, **kw)
+    return [np.asarray(o) for o in out]
+
+
+def _order_case(Sp, case):
+    """One bins-form level at Higgs's width (28 features x 64 bins, FB
+    1,792: three slabs of 512 and a tail of 256), 1,500 rows padded to
+    2,048: (level_pass kw, operands, the leaves and the [FB, nch*Sp]
+    float64 sums numpy expects from the slot table's own columns)."""
+    from lightgbm_tpu.ops.fused_level import (TBL_CAT_FLAG, TBL_CAT_WORD0,
+                                              expand_feature_mask,
+                                              route_table_columns)
+    rng = np.random.RandomState(Sp + len(case))
+    F, max_bin, R, Rp = 28, 63, 1500, 2048
+    F_oh, B = feature_layout(F, max_bin)
+    bins = rng.randint(0, max_bin, (F, R))
+    leaf = rng.randint(0, Sp, R).astype(np.int32)
+    feat = rng.randint(0, F, Sp).astype(np.int32)
+    if case == "inactive_beside_padding":
+        feat[1::2] = -1                   # every other slot is off
+        leaf[rng.rand(R) < 0.2] = Sp + 3  # a leaf no slot holds
+    lof = np.where(feat >= 0, np.arange(Sp), -2).astype(np.int32)
+    tbl = np.zeros((Sp, 128), np.int32)
+    tbl[:, 0], tbl[:, 1] = lof, np.where(feat >= 0, Sp, 0)
+    tbl[:, 2] = rng.randint(0, 2, Sp)
+    split = (jnp.asarray(feat),
+             jnp.asarray(rng.randint(0, max_bin - 1, Sp).astype(np.int32)),
+             jnp.asarray(rng.randint(0, 2, Sp).astype(bool)))
+    meta = (jnp.full((F_oh,), max_bin, jnp.int32),
+            jnp.asarray(rng.randint(0, 3, F_oh).astype(np.int32)),
+            jnp.zeros((F_oh,), jnp.int32))
+    sets = {}
+    if case == "has_cat":                 # the first ten columns: bin sets
+        flag = (feat >= 0) & (feat < 10)
+        mask = np.zeros((Sp, B), bool)
+        for k in np.flatnonzero(flag):
+            mask[k, rng.choice(max_bin, rng.randint(1, 33), False)] = True
+        sets = dict(cat_flag=jnp.asarray(flag), cat_mask=jnp.asarray(mask))
+    t = np.asarray(route_table_columns(jnp.asarray(tbl), *split, *meta,
+                                       **sets))
+    keep = np.ones(F_oh, bool)
+    fmask = None
+    if case == "fmask":
+        keep[rng.rand(F_oh) < 0.4] = False
+        keep[0] = True
+        fb = expand_feature_mask(jnp.asarray(keep), F_oh, B)
+        fmask = jnp.broadcast_to(fb[:, None], (F_oh * B, 128)) \
+            .astype(jnp.bfloat16)
+    pad = lambda a, fill: np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, Rp - R)],
+                                 constant_values=fill)
+    gh_T = pack_gh(jnp.asarray(pad(rng.randn(R).astype(np.float32), 0)),
+                   jnp.asarray(pad(rng.rand(R).astype(np.float32) + .1, 0)),
+                   jnp.asarray(pad(np.ones(R, np.float32), 0)), NCH_PRECISE)
+    # numpy, from the table's columns 0-15 alone
+    want_leaf = leaf.copy()
+    want = np.zeros((F_oh * B, NCH_PRECISE * Sp))
+    gh64 = np.asarray(gh_T.astype(jnp.float32), np.float64)[:, :R]
+    for k in np.flatnonzero(t[:, 6] >= 0):
+        v = bins[t[k, 6]]
+        left = np.where(v == t[k, 4], t[k, 5] > 0, v <= t[k, 3])
+        if t[k, TBL_CAT_FLAG]:
+            words = t[k, TBL_CAT_WORD0:TBL_CAT_WORD0 + 8].view(np.uint32)
+            left = (words[v >> 5] >> (v & 31).astype(np.uint32)) & 1 > 0
+        on = leaf == t[k, 0]
+        want_leaf[on & ~left] += t[k, 1]
+        rows = np.flatnonzero(on & (left == (t[k, 2] > 0)))
+        for f in np.flatnonzero(keep[:F]):
+            for c in range(NCH_PRECISE):
+                np.add.at(want[f * B:(f + 1) * B, c * Sp + k],
+                          bins[f, rows], gh64[c, rows])
+    bins_T = np.zeros((max(F_oh, 8), Rp), np.int8)
+    bins_T[:F, :R] = bins
+    kw = dict(num_slots=Sp, num_bins=B, f_oh=F_oh, tile_rows=512,
+              interpret=True, has_cat=bool(sets))
+    ops = (jnp.asarray(bins_T), jnp.asarray(pad(leaf, -1))[None, :], gh_T,
+           None, jnp.asarray(t), fmask)
+    return kw, ops, pad(want_leaf, -1), want
+
+
+@pytest.mark.parametrize("case", ["numerical", "has_cat", "fmask",
+                                  "inactive_beside_padding"])
+@pytest.mark.parametrize("Sp", [8, 32, 64])
+def test_both_operand_orders_of_the_histogram_dot(monkeypatch, Sp, case):
+    """The slab dot with the one-hot streamed (``onehot``: the order until
+    PR 36) and with the channels streamed into a transposed accumulator
+    (``channels``): the same leaves, the same [FB, nch*Sp] histogram bit
+    for bit in interpret mode, numpy's sums, and the wired kernel is one
+    of the two."""
+    kw, ops, want_leaf, want = _order_case(Sp, case)
+    hist_o, leaf_o = _pass_in_order(monkeypatch, "onehot", *ops, **kw)
+    hist_c, leaf_c = _pass_in_order(monkeypatch, "channels", *ops, **kw)
+    assert np.array_equal(leaf_o[0], want_leaf)
+    assert np.array_equal(leaf_c, leaf_o)
+    assert hist_c.shape == hist_o.shape == want.shape
+    assert np.array_equal(hist_c, hist_o)
+    np.testing.assert_allclose(hist_o, want, rtol=1e-5, atol=1e-5)
+    assert np.abs(want).sum() > 0
+    hist_w, leaf_w = level_pass(*ops, **kw)
+    assert np.array_equal(np.asarray(hist_w), hist_o)
+    assert np.array_equal(np.asarray(leaf_w), leaf_o)
+
+
+# a tree's histogram passes at the five cells' shapes (the root at 8 slots,
+# then every level of the schedule but the one that spends the leaf budget)
+# -> how many stream the channels
+CELL_SHAPES = [
+    ("higgs63", dict(features=28, max_bin=63), 9, 9),
+    ("higgs63-goss", dict(features=28, max_bin=63), 9, 9),
+    ("higgs63-dp4", dict(features=28, max_bin=63), 9, 9),
+    ("expo255-cat", dict(features=8, max_bin=255, has_cat=True), 9, 9),
+    ("msltr63", dict(features=137, max_bin=63), 19, 19),
+    ("higgs63-table-form", dict(features=28, max_bin=63, bins_form=False),
+     9, 0),
+    ("higgs63-int8", dict(features=28, max_bin=63, quant=True), 9, 0),
+    ("higgs63-3-channels", dict(features=28, max_bin=63, nch=NCH_FAST), 9,
+     9),
+]
+
+
+@pytest.mark.parametrize("name,shape,passes,channels", CELL_SHAPES,
+                         ids=[c[0] for c in CELL_SHAPES])
+def test_level_build_streams_the_channels_in_the_bf16_slab_build(
+        name, shape, passes, channels):
+    """``level_build``'s rule, from static shapes alone: a pass of the bf16
+    slab build streams the channels wherever its columns would pad an
+    N-tile (all nine of a Higgs tree, all nineteen at the ranking cell's
+    width: on the chip the order won at 8, 16, 32 and 64 slots alike);
+    the table form and the int8 paths, which were not timed, keep the
+    one-hot streamed. The build and the tile do not depend on the
+    order."""
+    from lightgbm_tpu.models.frontier2 import level_caps
+    from lightgbm_tpu.ops.fused_level import level_build, max_slot_cap
+    shape = dict(shape)
+    f_oh, bp = feature_layout(shape.pop("features"), shape.pop("max_bin"))
+    nch = shape.pop("nch", NCH_PRECISE)
+    bins_form = shape.pop("bins_form", True)
+    caps = level_caps(255, -1, 0, slot_cap=max_slot_cap(f_oh * bp,
+                                                        NCH_PRECISE))
+    sched = [8] + [max(8, c) for c in caps[:-1]]
+    builds = [level_build(bins_form, sp, f_oh * bp, nch, max(f_oh, 8),
+                          **shape) for sp in sched]
+    dots = [b["dot"] for b in builds]
+    assert len(sched) == passes
+    assert dots.count("channels") == channels
+    assert dots.count("onehot") == passes - channels
+    assert {b["form"] for b in builds} \
+        == {"slab" if bins_form else "scratch"}
+    if bins_form:
+        assert {b["tile_rows"] for b in builds} == {2048}
+    # a whole number of N-tiles pads nothing: 128 slots x 5 channels = 640
+    # columns keep the one-hot streamed (2.6 % faster on the chip)
+    assert level_build(True, 128, 1024, NCH_PRECISE, 16)["dot"] == "onehot"

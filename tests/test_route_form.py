@@ -332,11 +332,47 @@ def test_a_job_says_its_form_once_with_the_reason(tmp_path, job, form, reason):
     tiles = built[0]["tile_rows"]
     assert "8" in tiles and all(128 <= t <= 2048 and t & (t - 1) == 0
                                 for t in tiles.values())
+    # which operand of the histogram dot streams (PR 36): the channels in
+    # the slab build, the one-hot where the whole scratch is read
+    order = {"slab": "channels", "scratch": "onehot"}[build]
+    assert built[0]["dot"] == {sp: order for sp in tiles}
+    assert counters["level.dot_stream_%s" % order] >= 2
+    assert len([k for k in counters if k.startswith("level.dot_")]) == 1
     other = {"slab": "scratch", "scratch": "slab"}[build]
     assert counters["level.build_%s" % build] == 1
     assert counters.get("level.build_%s" % other, 0) == 0
     assert counters["events.level_build"] == 1
     assert bst.num_trees() == 2
+
+
+@pytest.mark.parametrize("features,passes,slots", [(28, 9, (8, 16, 32, 64)),
+                                                   (137, 19, (8, 16))],
+                         ids=["higgs_shaped", "ranking_shaped"])
+def test_a_job_says_how_many_passes_stream_the_channels(tmp_path, features,
+                                                        passes, slots):
+    """255 leaves at 63 bins: a full tree's histogram passes (the root,
+    then every level up to the one that spends the leaf budget) are nine
+    at Higgs's 28 features and nineteen at the ranking cell's 137, where
+    the slot cap is 16; all of them stream the channels. Said where the
+    step is built, from shapes alone: no step is compiled here."""
+    rng = np.random.RandomState(0)
+    X = rng.randn(2000, features).astype(np.float32)
+    out = tmp_path / "t.jsonl"
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                       "max_bin": 63, "min_data_in_leaf": 1, "verbose": -1,
+                       "tpu_engine": "fused", "tpu_megastep": True,
+                       "telemetry_out": str(out)},
+                      lgb.Dataset(X, label=(X[:, 0] > 0).astype(np.float32)))
+    bst._gbdt._route_form()
+    bst._gbdt._route_form()             # said once
+    counters = bst.telemetry()["counters"]
+    assert counters["level.dot_stream_channels"] == passes
+    assert "level.dot_stream_onehot" not in counters
+    (build,) = [e for e in map(json.loads, open(out))
+                if e.get("event") == "level_build"]
+    assert build["form"] == "slab"
+    assert build["dot"] == {str(sp): "channels" for sp in slots}
+    assert build["tile_rows"] == {str(sp): 2048 for sp in slots}
 
 
 def test_a_categorical_job_on_the_mesh_is_the_one_device_job(tmp_path):

@@ -220,3 +220,50 @@ def test_level_pass_compiles_at_the_sample_s_capacity(one_chip, deep):
               shape((1, GOSS_CAPACITY), jnp.int32),
               shape((8, GOSS_CAPACITY), jnp.bfloat16), None,
               shape((sp, 128), jnp.int32))
+
+
+# ---- the histogram dot with the channels streamed (PR 36): the bf16 slab
+# build latches the one-hot's tiles and accumulates into a transposed
+# [nch*Sp, FB] window
+# name, features, max_bin, has_cat, slots, rows: Higgs's and the
+# categorical cell's deep passes, the GOSS cell's compact width and the
+# ranking cell's passes at its slot cap
+CHANNEL_PASSES = [("higgs63", 28, 63, False, 32, ROWS),
+                  ("higgs63", 28, 63, False, 64, ROWS),
+                  ("expo255-cat", 8, 255, True, 64, ROWS),
+                  ("higgs63-goss", 28, 63, False, 64, GOSS_CAPACITY),
+                  ("msltr63", 137, 63, False, 16, ROWS)]
+
+
+@pytest.mark.parametrize("name,features,max_bin,has_cat,sp,rows",
+                         CHANNEL_PASSES,
+                         ids=["%s-%d" % (c[0], c[4]) for c in CHANNEL_PASSES])
+def test_the_channel_streaming_pass_compiles_within_its_charge(
+        one_chip, name, features, max_bin, has_cat, sp, rows):
+    """What ``level_build`` wires in the bf16 slab build compiles for the
+    described v5e at 2,048-row tiles, its accumulator is the transposed
+    window, and the scoped VMEM the compiler takes is under what
+    ``default_tile_rows`` charges the tile (the same operands, the same
+    bytes as the other order: the charge did not change). Compiled: 2.39 MB
+    at Higgs's 32 slots (the other order 3.37), 4.31 at 64 (5.05), 5.67 in
+    the categorical cell at 64 (5.77), 4.25 at the ranking cell's width and
+    16 slots (4.90), against charges of 8.3-11.4 MB."""
+    from lightgbm_tpu.ops.fused_level import level_build, slab_row_bytes
+    from lightgbm_tpu.utils.platform import scoped_vmem_bytes
+    o = _operands(one_chip, features, max_bin, sp, has_cat)
+    build = level_build(True, sp, o["fb"], NCH_PRECISE, o["fp"],
+                        has_cat=has_cat)
+    assert build == {"form": "slab", "slab_rows": 512, "tile_rows": 2048,
+                     "dot": "channels"}
+    shape = lambda like: jax.ShapeDtypeStruct(
+        (like.shape[0], rows), like.dtype, sharding=one_chip)
+    fn = functools.partial(level_pass, nch=NCH_PRECISE, **o["kw"])
+    args = (shape(o["bins"]), shape(o["leaf"]), shape(o["gh"]), None,
+            o["tbl"])
+    call = _pallas_call(fn, *args)
+    assert call.outvars[0].aval.shape == (NCH_PRECISE * sp, o["fb"])
+    assert jax.eval_shape(fn, *args)[0].shape == (o["fb"], NCH_PRECISE * sp)
+    charge = 2048 * slab_row_bytes(sp, NCH_PRECISE, o["fp"], has_cat)
+    used = scoped_vmem_bytes(_compiles(fn, *args))
+    print(f"{name} {sp} slots: compiled {used} B, charged {charge} B")
+    assert used < charge < 16 * 1024 * 1024
