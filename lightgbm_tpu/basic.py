@@ -20,6 +20,7 @@ from .io import model_io
 from .metric import create_metric, default_metric_for_objective
 from .models.tree import HostTree
 from .objective import create_objective, create_objective_from_string
+from .obs.registry import Span
 from .utils import log
 from .utils.log import LightGBMError
 
@@ -196,6 +197,17 @@ class Dataset:
         """(ref: basic.py Dataset.construct / _lazy_init)"""
         if self._inner is not None:
             return self
+        # the ``bin`` span and its children close into a list of their
+        # own: no Booster has a sink yet (TpuDataset.setup_spans)
+        spans: List[Dict[str, Any]] = []
+        with Span(None, "bin", hold=spans) as span:
+            self._construct()
+            span.set(rows=self._inner.num_data,
+                     features=self._inner.num_total_features)
+        self._inner.setup_spans = spans
+        return self
+
+    def _construct(self) -> None:
         cfg = Config(self.params)
         if isinstance(self.data, Sequence) or (
                 isinstance(self.data, list) and self.data
@@ -205,7 +217,7 @@ class Dataset:
         pending_cache = None
         if isinstance(self.data, (str, os.PathLike)):
             if self._construct_from_file(cfg):
-                return self
+                return
             pending_cache = self._pending_cache_write
             self._pending_cache_write = None
         is_sparse = _is_scipy_sparse(self.data)
@@ -233,24 +245,22 @@ class Dataset:
             self._inner = TpuDataset.from_data(
                 data, cfg, categorical_feature=cats,
                 feature_names=feature_names, reference=ref_inner)
-        if not is_sparse and bool(cfg.linear_tree):
-            # linear leaves fit ridge models on RAW feature values
-            # (ref: dataset raw-data retention for linear_tree)
-            self._inner.raw_data = np.asarray(data, np.float32)
-        if self.label is not None:
-            self._inner.metadata.set_label(np.asarray(self.label))
-        if self.weight is not None:
-            self._inner.metadata.set_weight(np.asarray(self.weight))
-        if self.group is not None:
-            self._inner.metadata.set_group(np.asarray(self.group))
-        if self.init_score is not None:
-            self._inner.metadata.set_init_score(np.asarray(self.init_score))
-        if self.free_raw_data:
-            # keep raw features for prediction-time use only if small
-            pass
-        if pending_cache is not None:
-            self._write_sidecar_cache(*pending_cache)
-        return self
+        with Span(None, "bin/finalize", rows=self._inner.num_data):
+            if not is_sparse and bool(cfg.linear_tree):
+                # linear leaves fit ridge models on RAW feature values
+                # (ref: dataset raw-data retention for linear_tree)
+                self._inner.raw_data = np.asarray(data, np.float32)
+            if self.label is not None:
+                self._inner.metadata.set_label(np.asarray(self.label))
+            if self.weight is not None:
+                self._inner.metadata.set_weight(np.asarray(self.weight))
+            if self.group is not None:
+                self._inner.metadata.set_group(np.asarray(self.group))
+            if self.init_score is not None:
+                self._inner.metadata.set_init_score(
+                    np.asarray(self.init_score))
+            if pending_cache is not None:
+                self._write_sidecar_cache(*pending_cache)
 
     # ------------------------------------------------------------------
     def _apply_explicit_metadata(self) -> None:
@@ -677,35 +687,39 @@ class Booster:
     def _init_train(self, train_set: Dataset) -> None:
         if not isinstance(train_set, Dataset):
             raise TypeError("Training data should be Dataset instance")
-        merged = dict(train_set.params)
-        merged.update(self.params)
-        self.config = Config(merged)
-        train_set.params = merged
-        train_set.construct()
-        self.train_set = train_set
-        inner = train_set._inner
-        # a binary-cache-loaded dataset restores the binning-defining
-        # params it was built with (construction may have happened just
-        # now, AFTER the config snapshot above): fold them in unless
-        # the user explicitly set a conflicting value, so the resolved
-        # config (and the serialized parameters echo) matches the
-        # original build's
-        restored = {k: v for k, v in (getattr(inner, "dataset_params",
-                                              None) or {}).items()
-                    if not self.config.was_set(k)}
-        if restored:
-            self.config.update(restored)
-            train_set.params.update(restored)
-        self.objective = create_objective(self.config)
-        if self.objective is not None:
-            if inner.metadata.label is None:
-                raise ValueError("Label should not be None")
-            self.objective.init(inner.metadata, inner.num_data)
-        self.num_class = max(1, int(self.config.num_class))
-        self._gbdt = create_boosting(self.config)
-        train_metrics = []
-        if self.config.is_provide_training_metric:
-            train_metrics = self._make_metrics(inner)
+        # (config, objective and its per-row state, the driver object:
+        # what runs before the driver's registry exists closes into the
+        # span around it, engine.train's)
+        with Span(None, "init/config_objective"):
+            merged = dict(train_set.params)
+            merged.update(self.params)
+            self.config = Config(merged)
+            train_set.params = merged
+            train_set.construct()
+            self.train_set = train_set
+            inner = train_set._inner
+            # a binary-cache-loaded dataset restores the binning-defining
+            # params it was built with (construction may have happened just
+            # now, AFTER the config snapshot above): fold them in unless
+            # the user explicitly set a conflicting value, so the resolved
+            # config (and the serialized parameters echo) matches the
+            # original build's
+            restored = {k: v for k, v in (getattr(inner, "dataset_params",
+                                                  None) or {}).items()
+                        if not self.config.was_set(k)}
+            if restored:
+                self.config.update(restored)
+                train_set.params.update(restored)
+            self.objective = create_objective(self.config)
+            if self.objective is not None:
+                if inner.metadata.label is None:
+                    raise ValueError("Label should not be None")
+                self.objective.init(inner.metadata, inner.num_data)
+            self.num_class = max(1, int(self.config.num_class))
+            self._gbdt = create_boosting(self.config)
+            train_metrics = []
+            if self.config.is_provide_training_metric:
+                train_metrics = self._make_metrics(inner)
         self._gbdt.init(self.config, inner, self.objective, train_metrics)
         self.num_tree_per_iteration = self._gbdt.num_tree_per_iteration
         self.average_output = getattr(self._gbdt, "average_output", False)
@@ -716,7 +730,9 @@ class Booster:
         if inner.monotone_constraints is not None:
             self.monotone_constraints = inner.monotone_constraints
         if bool(getattr(self.config, "drift_profile", True)):
-            self._capture_profile(train_set, inner)
+            with self._gbdt.telemetry.timed("init/profile",
+                                            rows=inner.num_data):
+                self._capture_profile(train_set, inner)
 
     def _capture_profile(self, train_set: Dataset, inner) -> None:
         """Capture the DataProfile + provenance record at train init
@@ -778,7 +794,8 @@ class Booster:
         if data.reference is not self.train_set:
             data.reference = self.train_set
         data.construct()
-        metrics = self._make_metrics(data._inner)
+        with self._gbdt.telemetry.timed("valid/metrics", valid_set=name):
+            metrics = self._make_metrics(data._inner)
         self._gbdt.add_valid_data(data._inner, name, metrics)
         self.valid_sets.append(data)
         self.name_valid_sets.append(name)
@@ -831,21 +848,24 @@ class Booster:
         if self._gbdt is not None:
             if self.data_profile is not None \
                     and "score" not in self.data_profile:
-                # final train-margin distribution: the scores are being
-                # fetched to host here anyway — no extra dispatch
-                try:
-                    from .obs.drift import (add_score_distribution,
-                                            profile_digest)
-                    scores = getattr(self._gbdt, "scores", None)
-                    if scores is not None:
-                        add_score_distribution(self.data_profile,
-                                               np.asarray(scores))
-                        if self.provenance is not None:
-                            self.provenance["profile_digest"] = \
-                                profile_digest(self.data_profile)
-                except Exception as exc:
-                    log.warning("score-profile capture failed: %s", exc)
+                # final train-margin distribution: ONE fetch of the
+                # training scores to the host, then numpy
+                with self._gbdt.telemetry.timed("finish/score_profile"):
+                    self._capture_score_profile()
             self._gbdt.finalize_telemetry()
+
+    def _capture_score_profile(self) -> None:
+        try:
+            from .obs.drift import add_score_distribution, profile_digest
+            scores = getattr(self._gbdt, "scores", None)
+            if scores is not None:
+                add_score_distribution(self.data_profile,
+                                       np.asarray(scores))
+                if self.provenance is not None:
+                    self.provenance["profile_digest"] = \
+                        profile_digest(self.data_profile)
+        except Exception as exc:
+            log.warning("score-profile capture failed: %s", exc)
 
     def _dump_crash(self, exc: BaseException) -> None:
         """Crash flight recorder hook (engine.train calls this when an

@@ -73,6 +73,8 @@ class _NullSecHandle:
 # shared no-op handle: zero per-section allocation when telemetry and
 # the TIMETAG timer are both off
 _NULL_SEC = _NullSecHandle()
+# in place of a ``first_call`` span where a step's signature is not new
+_NO_SPAN = contextlib.nullcontext()
 
 
 def feature_meta_from_dataset(ds: TpuDataset) -> FeatureMeta:
@@ -327,104 +329,109 @@ class GBDT:
         self.on_tpu = platform.on_tpu()
         self._setup_telemetry(config)
         self._setup_resilience(config)
-        self.training_metrics = list(training_metrics)
-        self.num_data = train_data.num_data
-        self.num_tree_per_iteration = (objective.num_model_per_iteration
-                                       if objective is not None else
-                                       max(1, int(config.num_class)))
-        self.shrinkage_rate = float(config.learning_rate)
-        self.max_leaves = max(2, int(config.num_leaves))
-        # static padded bin count shared by all jit instances
-        self.max_bins = int(train_data.max_num_bin)
-        self.params = split_params_from_config(config)
-        self.meta = feature_meta_from_dataset(train_data)
-        self.has_cat = bool(np.any(train_data.is_categorical))
-        self.use_mono_bounds = bool(np.any(np.asarray(self.meta.monotone)
-                                           != 0))
-        self._setup_cegb(config)
-        self._setup_forced_splits(config, train_data)
-        self._setup_bundles(config, train_data)
-        # NOTE: computed before _setup_engine, which reads them
-        ic = config.interaction_constraints
-        bynode = float(config.feature_fraction_bynode)
-        self.use_node_masks = bool(ic) or (0.0 < bynode < 1.0)
-        self.node_masks = None
-        if self.use_node_masks:
-            from ..models.learner import make_node_mask_cfg
-            # constraints are in REAL feature indices; map to inner
-            inner_ic = []
-            for g in (ic or []):
-                gi = [train_data.inner_feature_index(int(f)) for f in g]
-                inner_ic.append([f for f in gi if f >= 0])
-            self.node_masks = make_node_mask_cfg(
-                train_data.num_features, inner_ic, bynode,
-                int(config.feature_fraction_seed) + 12345)
-        # lazy: the parallel XLA path holds a SHARDED copy (bins_par) and
-        # only rollback/stop-subtract/DART replay need this replicated one
-        self._bins_dev = None
-        self._setup_parallel(config)
+        tel = self.telemetry
+        # the Dataset was binned before this registry existed
+        tel.publish_spans(getattr(train_data, "setup_spans", ()))
+        with tel.timed("init/meta"):
+            self.training_metrics = list(training_metrics)
+            self.num_data = train_data.num_data
+            self.num_tree_per_iteration = (objective.num_model_per_iteration
+                                           if objective is not None else
+                                           max(1, int(config.num_class)))
+            self.shrinkage_rate = float(config.learning_rate)
+            self.max_leaves = max(2, int(config.num_leaves))
+            # static padded bin count shared by all jit instances
+            self.max_bins = int(train_data.max_num_bin)
+            self.params = split_params_from_config(config)
+            self.meta = feature_meta_from_dataset(train_data)
+            self.has_cat = bool(np.any(train_data.is_categorical))
+            self.use_mono_bounds = bool(np.any(np.asarray(self.meta.monotone)
+                                               != 0))
+            self._setup_cegb(config)
+            self._setup_forced_splits(config, train_data)
+            self._setup_bundles(config, train_data)
+            # NOTE: computed before _setup_engine, which reads them
+            ic = config.interaction_constraints
+            bynode = float(config.feature_fraction_bynode)
+            self.use_node_masks = bool(ic) or (0.0 < bynode < 1.0)
+            self.node_masks = None
+            if self.use_node_masks:
+                from ..models.learner import make_node_mask_cfg
+                # constraints are in REAL feature indices; map to inner
+                inner_ic = []
+                for g in (ic or []):
+                    gi = [train_data.inner_feature_index(int(f)) for f in g]
+                    inner_ic.append([f for f in gi if f >= 0])
+                self.node_masks = make_node_mask_cfg(
+                    train_data.num_features, inner_ic, bynode,
+                    int(config.feature_fraction_seed) + 12345)
+            # lazy: the parallel XLA path holds a SHARDED copy (bins_par) and
+            # only rollback/stop-subtract/DART replay need this replicated one
+            self._bins_dev = None
+            self._setup_parallel(config)
         self._setup_engine(config)
 
-        md = self._mp_metadata if self.mp is not None else train_data.metadata
-        k, n = self.num_tree_per_iteration, self.num_data
-        self.has_init_score = md.init_score is not None
-        from jax.sharding import PartitionSpec as P
-        if self.has_init_score:
-            init = np.asarray(md.init_score, np.float64)
-            if init.size == n * k:
-                scores = init.reshape(k, n, order="C")
+        with tel.timed("init/state"):
+            md = self._mp_metadata if self.mp is not None else train_data.metadata
+            k, n = self.num_tree_per_iteration, self.num_data
+            self.has_init_score = md.init_score is not None
+            from jax.sharding import PartitionSpec as P
+            if self.has_init_score:
+                init = np.asarray(md.init_score, np.float64)
+                if init.size == n * k:
+                    scores = init.reshape(k, n, order="C")
+                else:
+                    scores = np.tile(init.reshape(1, n), (k, 1))
+                self.scores = (self.mp.shard_full(scores.astype(np.float32),
+                                                  P(None, self.axis_name))
+                               if self.mp is not None
+                               else jnp.asarray(scores, jnp.float32))
+            elif self.mp is not None:
+                self.scores = self.mp.zeros_sharded((k, n),
+                                                    P(None, self.axis_name))
             else:
-                scores = np.tile(init.reshape(1, n), (k, 1))
-            self.scores = (self.mp.shard_full(scores.astype(np.float32),
-                                              P(None, self.axis_name))
-                           if self.mp is not None
-                           else jnp.asarray(scores, jnp.float32))
-        elif self.mp is not None:
-            self.scores = self.mp.zeros_sharded((k, n),
-                                                P(None, self.axis_name))
-        else:
-            self.scores = jnp.zeros((k, n), jnp.float32)
+                self.scores = jnp.zeros((k, n), jnp.float32)
 
-        self.valid_data: List[TpuDataset] = []
-        self.valid_bins: List = []
-        self._valid_routes: Dict[int, Tuple] = {}   # see _valid_route
-        self._valid_route_said: set = set()
-        self._route_form_said: set = set()          # see _route_form
-        self.valid_scores: List = []
-        self.valid_metrics: List[List] = []
-        self.valid_names: List[str] = []
+            self.valid_data: List[TpuDataset] = []
+            self.valid_bins: List = []
+            self._valid_routes: Dict[int, Tuple] = {}   # see _valid_route
+            self._valid_route_said: set = set()
+            self._route_form_said: set = set()          # see _route_form
+            self.valid_scores: List = []
+            self.valid_metrics: List[List] = []
+            self.valid_names: List[str] = []
 
-        self.class_need_train = [
-            objective.class_need_train(i) if objective is not None else True
-            for i in range(self.num_tree_per_iteration)]
+            self.class_need_train = [
+                objective.class_need_train(i) if objective is not None else True
+                for i in range(self.num_tree_per_iteration)]
 
-        # bagging state (ref: gbdt.cpp:686-758 ResetBaggingConfig)
-        # reference-parity streams (ref: utils/random.h LCG; gbdt.cpp:804
-        # per-block bagging generators; col_sampler.hpp:26 by-tree stream)
-        self.bag_streams = ref_random.BlockBaggingStreams(
-            int(config.bagging_seed), n)
-        self._bag_round_cache = None
-        self.feat_rng = ref_random.Random(int(config.feature_fraction_seed))
-        self.balanced_bagging = False
-        self.is_bagging = False
-        if config.bagging_freq > 0:
-            if config.bagging_fraction < 1.0:
-                self.is_bagging = True
-            elif (self.objective is not None
-                  and self.objective.name == "binary"
-                  and (config.pos_bagging_fraction < 1.0
-                       or config.neg_bagging_fraction < 1.0)):
-                self.is_bagging = True
-                self.balanced_bagging = True
-        self.bag_weight = self._bag_ones()  # 1=in bag (mp: 0 on pad rows)
-        self.bag_cnt = n
+            # bagging state (ref: gbdt.cpp:686-758 ResetBaggingConfig)
+            # reference-parity streams (ref: utils/random.h LCG; gbdt.cpp:804
+            # per-block bagging generators; col_sampler.hpp:26 by-tree stream)
+            self.bag_streams = ref_random.BlockBaggingStreams(
+                int(config.bagging_seed), n)
+            self._bag_round_cache = None
+            self.feat_rng = ref_random.Random(int(config.feature_fraction_seed))
+            self.balanced_bagging = False
+            self.is_bagging = False
+            if config.bagging_freq > 0:
+                if config.bagging_fraction < 1.0:
+                    self.is_bagging = True
+                elif (self.objective is not None
+                      and self.objective.name == "binary"
+                      and (config.pos_bagging_fraction < 1.0
+                           or config.neg_bagging_fraction < 1.0)):
+                    self.is_bagging = True
+                    self.balanced_bagging = True
+            self.bag_weight = self._bag_ones()  # 1=in bag (mp: 0 on pad rows)
+            self.bag_cnt = n
 
-        self.best_score: Dict[Tuple[int, str], float] = {}
-        self.best_iter: Dict[Tuple[int, str], int] = {}
-        self.early_stopping_round = int(config.early_stopping_round)
-        self.es_first_metric_only = bool(config.first_metric_only)
-        self._rank_layout_said = False
-        self._publish_rank_layout()
+            self.best_score: Dict[Tuple[int, str], float] = {}
+            self.best_iter: Dict[Tuple[int, str], int] = {}
+            self.early_stopping_round = int(config.early_stopping_round)
+            self.es_first_metric_only = bool(config.first_metric_only)
+            self._rank_layout_said = False
+            self._publish_rank_layout()
 
     def _publish_rank_layout(self) -> None:
         """A ranking objective's query layout, once per run: the exact
@@ -457,8 +464,9 @@ class GBDT:
             self._bins_dev = self._dataset_bins_to_device(self.train_data)
         return self._bins_dev
 
-    def _dataset_bins_to_device(self, ds):
-        """Host->device transfer of a dataset's bin matrix.  Streamed /
+    def _dataset_bins_to_device(self, ds, span: str = "init/upload"):
+        """Host->device transfer of a dataset's bin matrix, under the
+        span ``span`` (closed when the copy has landed).  Streamed /
         mmap-cached datasets (ingest/) go through the double-buffered
         chunk prefetcher — the next chunk's host read (page faults on a
         cache mmap) overlaps the in-flight copy, at most two chunks
@@ -466,20 +474,24 @@ class GBDT:
         instead of faulting the whole artifact into RAM for one giant
         ``jnp.asarray``.  The result is elementwise-identical either
         way (prefetch is a transfer schedule, not a data transform)."""
-        if getattr(ds, "streamed", False) \
-                and bool(getattr(self.config, "ingest_prefetch", True)):
-            from ..ingest.prefetch import stream_to_device
-            tel = self.telemetry
-            out = stream_to_device(
-                ds.bins, int(self.config.ingest_chunk_rows), tel=tel)
-            if tel.enabled and getattr(self, "_mem_watermarks", False):
-                # the prefetch assembly is where a streamed dataset's
-                # HBM residency materializes — watermark it like the
-                # drain boundary
-                from ..obs.jaxmon import memory_watermarks
-                memory_watermarks(tel, where="prefetch")
-            return out
-        return jnp.asarray(ds.bins)
+        tel = self.telemetry
+        with tel.timed(span, rows=int(ds.bins.shape[0]),
+                       bytes=int(ds.bins.nbytes)) as sp:
+            if getattr(ds, "streamed", False) \
+                    and bool(getattr(self.config, "ingest_prefetch", True)):
+                from ..ingest.prefetch import stream_to_device
+                out = stream_to_device(
+                    ds.bins, int(self.config.ingest_chunk_rows), tel=tel)
+                if tel.enabled and getattr(self, "_mem_watermarks", False):
+                    # the prefetch assembly is where a streamed dataset's
+                    # HBM residency materializes — watermark it like the
+                    # drain boundary
+                    from ..obs.jaxmon import memory_watermarks
+                    memory_watermarks(tel, where="prefetch")
+            else:
+                out = jnp.asarray(ds.bins)
+            sp.sync(out)
+        return out
 
     def _publish_ingest(self, ds) -> None:
         """Fold a dataset's ingest counters (chunked parse/bin stats,
@@ -943,34 +955,54 @@ class GBDT:
             self._ctl_no_open = False
 
     def _finalize_telemetry_body(self) -> None:
-        self._profiler_stop()
+        tel = self.telemetry
+        if self._prof_active:
+            with tel.timed("finish/profiler_stop"):
+                self._profiler_stop()
         if self._ckpt is not None:
             # join the in-flight write: a checkpoint enqueued at the
             # last drain must commit before the process can exit
-            try:
-                self._ckpt.wait()
-            except Exception as e:
-                log.warning("checkpoint writer drain failed: %s", e)
-        tel = self.telemetry
+            with tel.timed("finish/checkpoint_wait"):
+                try:
+                    self._ckpt.wait()
+                except Exception as e:
+                    log.warning("checkpoint writer drain failed: %s", e)
         if not tel.enabled:
             self._close_ctl_window("closed_at_finalize")
             return
-        self.drain_pending()
+        with tel.timed("finish/drain"):
+            self.drain_pending()
         if self._slo is not None:
             # one forced final evaluation so even a sub-tick-period run
             # gets a non-vacuous slo.ticks count, then disarm the
             # training-liveness watchdog (clean finalize is not a stall)
             # and the ticker thread
-            self._slo.note_training_heartbeat(self.iter)
-            self._slo.step(force=True)
-            self._slo.note_training_done()
-            self._slo.stop()
+            with tel.timed("finish/slo"):
+                self._slo.note_training_heartbeat(self.iter)
+                self._slo.step(force=True)
+                self._slo.note_training_done()
+                self._slo.stop()
         # the tail drain may have closed an elapsed window at its
         # boundary; anything still open ends here, after the last
         # iterations it covered are drained
         self._close_ctl_window("closed_at_finalize")
         if self._cost is not None:
-            self._cost.flush()   # analyses queued since the last drain
+            with tel.timed("finish/cost_flush"):
+                self._cost.flush()   # analyses queued since the last drain
+        with tel.timed("finish/summary"):
+            snap, rank_sections = self._summary_event()
+        with tel.timed("finish/report"):
+            self._write_run_report(snap, rank_sections)
+        with tel.timed("finish/trace_export"):
+            self._export_trace()
+        with tel.timed("finish/flush"):
+            tel.flush()
+
+    def _summary_event(self):
+        """The ``summary`` event (per-rank counters gathered at rank 0
+        under multi-process); returns the snapshot it was made from and
+        the per-rank report sections that rode the same allgather."""
+        tel = self.telemetry
         snap = tel.snapshot()
         rank_sections = None
         if getattr(self, "mp", None) is not None:
@@ -997,9 +1029,7 @@ class GBDT:
             tel.event("summary", iteration=self.iter,
                       counters=snap["counters"],
                       timings=snap["timings"])
-        self._write_run_report(snap, rank_sections)
-        self._export_trace()
-        tel.flush()
+        return snap, rank_sections
 
     # -------------------------------------------------------- run report
     def _evicted_snapshot(self):
@@ -2182,7 +2212,12 @@ class GBDT:
         route+histogram level kernel (ops/fused_level.py). With EFB the
         matrix holds bundle COLUMNS (kernel layout) while split search
         stays on the logical feature layout."""
-        from ..ops.fused_level import NCH_FAST, NCH_PRECISE, feature_layout
+        tel = self.telemetry
+        # (the first import of the kernels' module brings Pallas in: a
+        # second on the host, once a process)
+        with tel.timed("init/kernels_import"):
+            from ..ops.fused_level import (NCH_FAST, NCH_PRECISE,
+                                           feature_layout)
         F = train_data.num_features
         F_oh, Bp = feature_layout(F, self.max_bins)
         R = self.num_data
@@ -2207,82 +2242,90 @@ class GBDT:
         blk = 2048 * (self.n_shards
                       if self.parallel_mode in ("data", "voting") else 1)
         Rp = ((R + blk - 1) // blk) * blk
-        if getattr(self, "use_bundles", False):
-            n_cols = int(self.bundle_bins_dev.shape[1])
-            C_oh, Bc_p = feature_layout(n_cols, self.bundle_col_bins)
-            Fp = max(C_oh, 8)
-            dtype = jnp.int8 if Bc_p <= 128 else jnp.int16
-            if self.mp is not None:
+        if not getattr(self, "use_bundles", False) and self.mp is None:
+            rows_dev = self.bins_dev    # (its upload is a span of its own)
+        # the transposed kernel layout; closed when the device has it
+        with tel.timed("init/pack", rows=R) as sp:
+            if getattr(self, "use_bundles", False):
+                n_cols = int(self.bundle_bins_dev.shape[1])
+                C_oh, Bc_p = feature_layout(n_cols, self.bundle_col_bins)
+                Fp = max(C_oh, 8)
+                dtype = jnp.int8 if Bc_p <= 128 else jnp.int16
+                if self.mp is not None:
+                    self.fused_bins_T = self._mp_fused_bins_T(
+                        np.asarray(self.bundle_bins_host), Fp, Rp, Bc_p)
+                else:
+                    self.fused_bins_T = _fused_layout_T(self.bundle_bins_dev,
+                                                        Fp, Rp, dtype)
+                self.fused_bundle_cols = C_oh
+                self.fused_bundle_col_bins = Bc_p
+                # decode tables padded to the logical f_oh (padding features:
+                # invalid everywhere, residual suppressed by bundle_plane_views)
+                from ..models.learner import BundleCfg
+                bc = self.bundle_cfg
+                # logical plane layout is [f_oh, Bp] (pow2-padded bins like the
+                # unbundled fused pool); kernel flat stride is the padded Bc_p
+                fi = jnp.zeros((F_oh, Bp), jnp.int32)
+                va = jnp.zeros((F_oh, Bp), bool)
+                db = jnp.zeros((F_oh,), jnp.int32)
+                cof = jnp.full((F_oh,), -1, jnp.int32)
+                off = jnp.zeros((F_oh,), jnp.int32)
+                col = bc.col_of_feat
+                offs = bc.offset_of_feat
+                b_i = jnp.arange(Bp, dtype=jnp.int32)[None, :]
+                fi = fi.at[:F].set(jnp.minimum(
+                    col[:, None] * Bc_p + offs[:, None] + b_i,
+                    C_oh * Bc_p - 1))
+                va = va.at[:F, :bc.valid.shape[1]].set(bc.valid)
+                db = db.at[:F].set(bc.default_bin)
+                cof = cof.at[:F].set(col)
+                off = off.at[:F].set(offs)
+                self.fused_bundle_cfg = BundleCfg(
+                    flat_idx=fi, valid=va, default_bin=db, col_of_feat=cof,
+                    offset_of_feat=off)
+            elif self.mp is not None:
+                Fp = max(F_oh, 8)
+                dtype = jnp.int8 if Bp <= 128 else jnp.int16
+                rows_np = np.asarray(self.train_data.bins)
+                if feat_order is not None:
+                    rows_np = rows_np[:, feat_order]
                 self.fused_bins_T = self._mp_fused_bins_T(
-                    np.asarray(self.bundle_bins_host), Fp, Rp, Bc_p)
+                    rows_np, Fp, Rp, Bp)
+                self.fused_bundle_cols = 0
+                self.fused_bundle_col_bins = 0
+                self.fused_bundle_cfg = None
             else:
-                self.fused_bins_T = _fused_layout_T(self.bundle_bins_dev,
-                                                    Fp, Rp, dtype)
-            self.fused_bundle_cols = C_oh
-            self.fused_bundle_col_bins = Bc_p
-            # decode tables padded to the logical f_oh (padding features:
-            # invalid everywhere, residual suppressed by bundle_plane_views)
-            from ..models.learner import BundleCfg
-            bc = self.bundle_cfg
-            # logical plane layout is [f_oh, Bp] (pow2-padded bins like the
-            # unbundled fused pool); kernel flat stride is the padded Bc_p
-            fi = jnp.zeros((F_oh, Bp), jnp.int32)
-            va = jnp.zeros((F_oh, Bp), bool)
-            db = jnp.zeros((F_oh,), jnp.int32)
-            cof = jnp.full((F_oh,), -1, jnp.int32)
-            off = jnp.zeros((F_oh,), jnp.int32)
-            col = bc.col_of_feat
-            offs = bc.offset_of_feat
-            b_i = jnp.arange(Bp, dtype=jnp.int32)[None, :]
-            fi = fi.at[:F].set(jnp.minimum(
-                col[:, None] * Bc_p + offs[:, None] + b_i,
-                C_oh * Bc_p - 1))
-            va = va.at[:F, :bc.valid.shape[1]].set(bc.valid)
-            db = db.at[:F].set(bc.default_bin)
-            cof = cof.at[:F].set(col)
-            off = off.at[:F].set(offs)
-            self.fused_bundle_cfg = BundleCfg(
-                flat_idx=fi, valid=va, default_bin=db, col_of_feat=cof,
-                offset_of_feat=off)
-        elif self.mp is not None:
-            Fp = max(F_oh, 8)
-            dtype = jnp.int8 if Bp <= 128 else jnp.int16
-            rows_np = np.asarray(self.train_data.bins)
-            if feat_order is not None:
-                rows_np = rows_np[:, feat_order]
-            self.fused_bins_T = self._mp_fused_bins_T(
-                rows_np, Fp, Rp, Bp)
-            self.fused_bundle_cols = 0
-            self.fused_bundle_col_bins = 0
-            self.fused_bundle_cfg = None
-        else:
-            Fp = max(F_oh, 8)
-            # int8 covers bins <= 127; larger max_bin needs int16 (a uint8
-            # bin index >= 128 would wrap negative in int8 and corrupt the
-            # one-hot)
-            dtype = jnp.int8 if Bp <= 128 else jnp.int16
-            # transpose + pad ON DEVICE from the already-uploaded bin
-            # matrix instead of a second 300+ MB host transpose + upload.
-            # Three full copies are live on device 0 while this runs
-            # (the [R, F] source, the zeros target, the .at[].set
-            # result); data-parallel runs reshard only afterwards.
-            self.fused_bins_T = _fused_layout_T(self.bins_dev, Fp, Rp,
-                                                dtype, feat_order)
-            self.fused_bundle_cols = 0
-            self.fused_bundle_col_bins = 0
-            self.fused_bundle_cfg = None
-        if self.parallel_mode in ("data", "voting") and self.mp is None:
-            # place the transposed matrix row-sharded once, not per call
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            self.fused_bins_T = jax.device_put(
-                self.fused_bins_T,
-                NamedSharding(self.mesh, P(None, self.axis_name)))
-        elif self.parallel_mode == "feature":
-            # feature-parallel replicates rows (zero histogram traffic;
-            # per-level record merge instead) — replicate the matrix
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            self.fused_bins_T = jax.device_put(
-                self.fused_bins_T, NamedSharding(self.mesh, P()))
+                Fp = max(F_oh, 8)
+                # int8 covers bins <= 127; larger max_bin needs int16 (a uint8
+                # bin index >= 128 would wrap negative in int8 and corrupt the
+                # one-hot)
+                dtype = jnp.int8 if Bp <= 128 else jnp.int16
+                # transpose + pad ON DEVICE from the already-uploaded bin
+                # matrix instead of a second 300+ MB host transpose + upload.
+                # Three full copies are live on device 0 while this runs
+                # (the [R, F] source, the zeros target, the .at[].set
+                # result); data-parallel runs reshard only afterwards.
+                self.fused_bins_T = _fused_layout_T(rows_dev, Fp, Rp,
+                                                    dtype, feat_order)
+                self.fused_bundle_cols = 0
+                self.fused_bundle_col_bins = 0
+                self.fused_bundle_cfg = None
+            sp.sync(self.fused_bins_T)
+            sp.set(bytes=int(self.fused_bins_T.nbytes))
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        # row-sharded modes place the transposed matrix sharded once, not
+        # per call; feature-parallel replicates rows (zero histogram
+        # traffic; per-level record merge instead): the matrix too
+        spec = (P(None, self.axis_name)
+                if self.parallel_mode in ("data", "voting")
+                and self.mp is None
+                else P() if self.parallel_mode == "feature" else None)
+        if spec is not None:
+            with tel.timed("init/reshard", shards=self.n_shards,
+                           bytes=int(self.fused_bins_T.nbytes)) as sp:
+                self.fused_bins_T = jax.device_put(
+                    self.fused_bins_T, NamedSharding(self.mesh, spec))
+                sp.sync(self.fused_bins_T)
         # the replicated [R, F] copy served only as the transpose source;
         # release it so HBM holds one binned matrix (the property rebuilds
         # it on the rare rollback/stop-subtract/DART replay paths)
@@ -2343,7 +2386,9 @@ class GBDT:
             self.arm_megastep(self._megastep_armed, eval_consumer=None)
         self.valid_data.append(valid_data)
         self._publish_ingest(valid_data)
-        self.valid_bins.append(self._dataset_bins_to_device(valid_data))
+        self.telemetry.publish_spans(getattr(valid_data, "setup_spans", ()))
+        self.valid_bins.append(
+            self._dataset_bins_to_device(valid_data, span="valid/upload"))
         k = self.num_tree_per_iteration
         n = valid_data.num_data
         md = valid_data.metadata
@@ -2362,11 +2407,15 @@ class GBDT:
         # set, is part of the set's upload
         self._valid_route(len(self.valid_data) - 1)
         # replay existing model onto the new valid set (continued training)
-        for i, dt in enumerate(self.device_trees):
-            tree_id = i % self.num_tree_per_iteration
-            self.valid_scores[-1] = self._add_tree_to_score(
-                self.valid_scores[-1], self.valid_bins[-1], dt, tree_id,
-                bundle=self._valid_bundle(len(self.valid_data) - 1))
+        if self.device_trees:
+            with self.telemetry.timed("valid/replay",
+                                      trees=len(self.device_trees)):
+                for i, dt in enumerate(self.device_trees):
+                    tree_id = i % self.num_tree_per_iteration
+                    self.valid_scores[-1] = self._add_tree_to_score(
+                        self.valid_scores[-1], self.valid_bins[-1], dt,
+                        tree_id,
+                        bundle=self._valid_bundle(len(self.valid_data) - 1))
 
     # ------------------------------------------------------------------
     def _boost_from_average(self, class_id: int, update_scorer: bool) -> float:
@@ -3242,11 +3291,13 @@ class GBDT:
             mat = None
             if reason is None:
                 Rv = int(self.valid_bins[vi].shape[0])
-                mat = _fused_layout_T(
-                    self.valid_bins[vi], self.fused_bins_T.shape[0],
-                    -(-Rv // 2048) * 2048, self.fused_bins_T.dtype,
-                    self.fused_packed.feat_order
-                    if self.fused_packed is not None else None)
+                with self.telemetry.timed("valid/pack", rows=Rv) as sp:
+                    mat = _fused_layout_T(
+                        self.valid_bins[vi], self.fused_bins_T.shape[0],
+                        -(-Rv // 2048) * 2048, self.fused_bins_T.dtype,
+                        self.fused_packed.feat_order
+                        if self.fused_packed is not None else None)
+                    sp.sync(mat)
             route = self._valid_routes[vi] = (mat, reason)
         tel = self.telemetry
         if tel.enabled and vi not in self._valid_route_said:
@@ -3797,8 +3848,13 @@ class GBDT:
         self.telemetry.inc("train.dispatches")
         self._place_carries()
         ext = bool(self.use_screening or self.quant_bits)
+        sig = f"fast_step[k={k},ext={ext}" \
+            + (",sampled]" if sample is not None else "]")
         t_call0 = time.perf_counter() if fresh_step else 0.0
-        with self._maybe_record_collectives(fresh_step) as rec, \
+        with (self.telemetry.timed("first_call", adopt=True, signature=sig,
+                                   iter=self.iter)
+              if fresh_step else _NO_SPAN), \
+                self._maybe_record_collectives(fresh_step) as rec, \
                 jax.profiler.StepTraceAnnotation("fast_step",
                                                  step_num=self.iter):
             # the kind-named anchor span the roofline plane
@@ -3832,8 +3888,6 @@ class GBDT:
             # the cost-ledger note defers fn.lower() to the next drain
             op_bytes = sum(int(getattr(a, "nbytes", 0))
                            for a in call_args if a is not None)
-            sig = f"fast_step[k={k},ext={ext}" \
-                + (",sampled]" if sample is not None else "]")
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
                 iteration=self.iter)
@@ -4501,53 +4555,67 @@ class GBDT:
 
     def _megastep_body(self, chunk: int) -> None:
         k = self.num_tree_per_iteration
-        init0 = [self._boost_from_average(tid, True) for tid in range(k)]
-        operands = self.objective.gradient_operands()
-        self._publish_rank_layout()
-        self._bagging(self.iter, None, None)   # chunk-aligned: a round
-        # can fire only at the chunk's first iteration
         # (a chunk never crosses GOSS's first sampled iteration:
         # _megastep_chunk; a sampled chunk is a step of its own)
         sample = self._step_sample(self.iter)
         fn_key = chunk if sample is None else (chunk, sample)
-        fn = self._megastep_fns.get(fn_key)
-        fresh_fn = fn is None
-        if fresh_fn:
-            fn = self._megastep_fns[fn_key] = (
-                self._make_megastep(chunk) if sample is None
-                else self._make_megastep(chunk, sample))
-        F_oh = self.fused_f_oh
-        F = self.train_data.num_features
-        if float(self.config.feature_fraction) >= 1.0:
-            fm_pads = self._megastep_fm.get(chunk)
-            if fm_pads is None:
-                fm_pads = self._megastep_fm[chunk] = \
-                    jnp.ones((chunk, k, F_oh), bool) \
-                    .at[:, :, F:].set(False)
-        else:
-            # host LCG draws in exactly the per-iteration order
-            # (iteration-major, then tree) so column sampling stays
-            # reference-parity across the fused chunk
-            masks = np.zeros((chunk, k, F_oh), bool)
-            for b in range(chunk):
-                for tid in range(k):
-                    masks[b, tid, :F] = np.asarray(self._feature_mask())
-            fm_pads = jnp.asarray(masks)
-        self.telemetry.inc("train.dispatches")
+        fresh_fn = fn_key not in self._megastep_fns
         plan = self._traced_plan if self._eval_consumer is not None \
             else None
-        if plan is not None:
-            if self._plan_ops is None:
-                self._plan_ops = plan.operands()
-            if self._es_carry is None:
-                self._es_carry = self._init_es_carry(plan.n_slots)
-        self._place_carries()
+        tel = self.telemetry
+        # a NEW step signature: the Python before its first call is the
+        # span ``first_call/build``, the call itself ``first_call``
+        with tel.timed("first_call/build") if fresh_fn else _NO_SPAN:
+            init0 = [self._boost_from_average(tid, True)
+                     for tid in range(k)]
+            operands = self.objective.gradient_operands()
+            self._publish_rank_layout()
+            self._bagging(self.iter, None, None)   # chunk-aligned: a
+            # round can fire only at the chunk's first iteration
+            if fresh_fn:
+                self._megastep_fns[fn_key] = (
+                    self._make_megastep(chunk) if sample is None
+                    else self._make_megastep(chunk, sample))
+            fn = self._megastep_fns[fn_key]
+            F_oh = self.fused_f_oh
+            F = self.train_data.num_features
+            if float(self.config.feature_fraction) >= 1.0:
+                fm_pads = self._megastep_fm.get(chunk)
+                if fm_pads is None:
+                    fm_pads = self._megastep_fm[chunk] = \
+                        jnp.ones((chunk, k, F_oh), bool) \
+                        .at[:, :, F:].set(False)
+            else:
+                # host LCG draws in exactly the per-iteration order
+                # (iteration-major, then tree) so column sampling stays
+                # reference-parity across the fused chunk
+                masks = np.zeros((chunk, k, F_oh), bool)
+                for b in range(chunk):
+                    for tid in range(k):
+                        masks[b, tid, :F] = np.asarray(
+                            self._feature_mask())
+                fm_pads = jnp.asarray(masks)
+            tel.inc("train.dispatches")
+            if plan is not None:
+                if self._plan_ops is None:
+                    self._plan_ops = plan.operands()
+                if self._es_carry is None:
+                    self._es_carry = self._init_es_carry(plan.n_slots)
+            self._place_carries()
         metrics_B = None
+        sig = f"megastep[chunk={chunk},k={k},eval={plan is not None}" \
+            + (",sampled]" if sample is not None else "]")
+        # (``first_call`` has the bounds of ``compile_executable``'s
+        # ``compile_ms``; jax's trace / lower / compile time spans inside
+        # it become its children: obs/jaxmon.py)
+        t_call0 = time.perf_counter() if fresh_fn else 0.0
         # profiler users see the fused chunk as one annotated step
         # (profile_dir / jax.profiler traces); free when no trace is on
-        t_call0 = time.perf_counter() if fresh_fn else 0.0
-        with jax.profiler.StepTraceAnnotation("megastep",
-                                              step_num=self.iter), \
+        with (tel.timed("first_call", adopt=True, signature=sig,
+                        iter=self.iter)
+              if fresh_fn else _NO_SPAN), \
+                jax.profiler.StepTraceAnnotation("megastep",
+                                                 step_num=self.iter), \
                 self._maybe_record_collectives(fresh_fn) as coll_rec:
             ext = bool(self.use_screening or self.quant_bits)
             base_args = (self.fused_bins_T, self.scores,
@@ -4600,8 +4668,6 @@ class GBDT:
                 int(getattr(a, "nbytes", 0)) for a in
                 [self.fused_bins_T, self.scores, self.bag_weight,
                  fm_pads, *base_args[2], *self.valid_scores])
-            sig = f"megastep[chunk={chunk},k={k},eval={plan is not None}" \
-                + (",sampled]" if sample is not None else "]")
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
                 iteration=self.iter)

@@ -26,6 +26,7 @@ import numpy as np
 
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN, BinMapper)
 from .config import Config
+from .obs.registry import Span
 from .utils import log
 
 # host binning of dense rows (TpuDataset.bin_rows): rows per transpose
@@ -212,6 +213,11 @@ class TpuDataset:
         # mappers (validation builds): a cache of such a dataset must
         # never be reused as standalone training data
         self.reference_binned: bool = False
+        # the closed spans of this set's construction (``bin`` and its
+        # children, obs/registry.Span): a Dataset is binned before any
+        # Booster has a sink, so the Booster that trains on it (or takes
+        # it as a validation set) publishes them, with their true start
+        self.setup_spans: List[Dict[str, Any]] = []
 
     # ------------------------------------------------------------------
     @classmethod
@@ -259,11 +265,13 @@ class TpuDataset:
             return self
 
         cat_set = set(int(c) for c in categorical_feature)
-        sample_idx = _sample_rows(n, config.bin_construct_sample_cnt,
-                                  config.data_random_seed)
-        sample = np.asarray(data[sample_idx], dtype=np.float64)
-        self.build_mappers_from_sample(sample, config, cat_set,
-                                       forced_bounds)
+        with Span(None, "bin/sample", rows=n, features=f):
+            sample_idx = _sample_rows(n, config.bin_construct_sample_cnt,
+                                      config.data_random_seed)
+            sample = np.asarray(data[sample_idx], dtype=np.float64)
+        with Span(None, "bin/mappers", rows=len(sample_idx), features=f):
+            self.build_mappers_from_sample(sample, config, cat_set,
+                                           forced_bounds)
         self._push_data(data)
         if config.monotone_constraints:
             mc = np.asarray(config.monotone_constraints, dtype=np.int32)
@@ -527,7 +535,9 @@ class TpuDataset:
         return out
 
     def _push_data(self, data: np.ndarray) -> None:
-        self.bins = self.bin_rows(data)
+        with Span(None, "bin/rows", rows=int(data.shape[0]),
+                  features=int(data.shape[1])):
+            self.bins = self.bin_rows(data)
 
     # ------------------------------------------------------------------
     def add_features_from(self, other: "TpuDataset") -> None:

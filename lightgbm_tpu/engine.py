@@ -6,6 +6,7 @@ cv :399, CVBooster :285, _make_n_folds :323).
 from __future__ import annotations
 
 import collections
+import contextlib
 import copy
 from typing import Any, Dict, List, Optional, Union
 
@@ -14,6 +15,7 @@ import numpy as np
 from . import callback as callback_mod
 from .basic import Booster, Dataset
 from .config import Config
+from .obs.registry import Span
 from .utils import log
 
 __all__ = ["train", "cv", "CVBooster"]
@@ -43,6 +45,17 @@ def train(params: Dict[str, Any], train_set: Dataset,
     to an uninterrupted run with the same params/seed; pass the same
     dataset, valid sets and callbacks the interrupted run used
     (docs/Reliability.md). The ``resume`` params key is equivalent."""
+    # the whole call is the span ``train``; until the Booster exists its
+    # children close into a list, which the Booster's registry then takes
+    with Span(None, "train", hold=[]) as span:
+        return _train(span, params, train_set, num_boost_round, valid_sets,
+                      valid_names, fobj, feval, init_model, feature_name,
+                      categorical_feature, callbacks, resume_from)
+
+
+def _train(span: Span, params, train_set, num_boost_round, valid_sets,
+           valid_names, fobj, feval, init_model, feature_name,
+           categorical_feature, callbacks, resume_from) -> Booster:
     params = dict(params) if params else {}
     # pop BOTH keys unconditionally: a resume path left in params would
     # echo into the serialized model's parameters block and break the
@@ -105,22 +118,27 @@ def train(params: Dict[str, Any], train_set: Dataset,
         if any(vs is train_set for vs in vs_list):
             params.setdefault("is_provide_training_metric", True)
 
-    booster = Booster(params=params, train_set=train_set)
+    with Span(None, "train/booster_init"):
+        booster = Booster(params=params, train_set=train_set)
+    tel = booster._gbdt.telemetry
+    span.bind(tel)
     if valid_sets is not None:
         if not isinstance(valid_sets, list):
             valid_sets = [valid_sets]
-        for i, vs in enumerate(valid_sets):
-            if vs is train_set:
-                name = "training"
-            elif valid_names is not None and i < len(valid_names):
-                name = valid_names[i]
-            else:
-                name = f"valid_{i}"
-            if vs is not train_set:
-                if predictor is not None and vs.init_score is None:
-                    raw = predictor.predict(vs.data, raw_score=True)
-                    vs.set_init_score(np.asarray(raw).reshape(-1, order="F"))
-                booster.add_valid(vs, name)
+        with tel.timed("train/valid_sets", sets=len(valid_sets)):
+            for i, vs in enumerate(valid_sets):
+                if vs is train_set:
+                    name = "training"
+                elif valid_names is not None and i < len(valid_names):
+                    name = valid_names[i]
+                else:
+                    name = f"valid_{i}"
+                if vs is not train_set:
+                    if predictor is not None and vs.init_score is None:
+                        raw = predictor.predict(vs.data, raw_score=True)
+                        vs.set_init_score(
+                            np.asarray(raw).reshape(-1, order="F"))
+                    booster.add_valid(vs, name)
     train_in_valid = valid_sets is not None and any(
         vs is train_set for vs in valid_sets)
 
@@ -154,32 +172,34 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # with a structured megastep_evicted event naming the blocker.
     gbdt = booster._gbdt
     consumer = None
-    want_replay = bool(callbacks) or snapshot_freq > 0
-    if want_replay and feval is None and fobj is None:
-        blocker = callback_mod.drain_replay_blocker(
-            callbacks_before + callbacks_after)
-        if blocker is None:
-            ok, blocker = gbdt.megastep_eval_precheck(
-                include_training=train_in_valid,
-                es_spec=callback_mod.find_es_spec(callbacks_after))
-            if ok:
-                consumer = callback_mod.DrainEvalReplay(
-                    booster=booster, params=params,
-                    callbacks_before=callbacks_before,
-                    callbacks_after=callbacks_after,
-                    end_iteration=num_boost_round,
-                    snapshot_freq=snapshot_freq,
-                    snapshot_base=snapshot_base,
-                    include_training=train_in_valid)
-                gbdt.arm_megastep(True, eval_consumer=consumer)
-        if consumer is None:
-            gbdt._report_eviction(blocker, stage="engine")
-    elif want_replay or feval is not None or fobj is not None:
-        gbdt._report_eviction("feval" if feval is not None else "fobj",
-                              stage="engine")
-    if consumer is None and not callbacks and feval is None \
-            and fobj is None and snapshot_freq <= 0:
-        gbdt.arm_megastep(True)
+    # (the drain-replay consumer and the traced metric plan)
+    with tel.timed("train/callbacks_plan"):
+        want_replay = bool(callbacks) or snapshot_freq > 0
+        if want_replay and feval is None and fobj is None:
+            blocker = callback_mod.drain_replay_blocker(
+                callbacks_before + callbacks_after)
+            if blocker is None:
+                ok, blocker = gbdt.megastep_eval_precheck(
+                    include_training=train_in_valid,
+                    es_spec=callback_mod.find_es_spec(callbacks_after))
+                if ok:
+                    consumer = callback_mod.DrainEvalReplay(
+                        booster=booster, params=params,
+                        callbacks_before=callbacks_before,
+                        callbacks_after=callbacks_after,
+                        end_iteration=num_boost_round,
+                        snapshot_freq=snapshot_freq,
+                        snapshot_base=snapshot_base,
+                        include_training=train_in_valid)
+                    gbdt.arm_megastep(True, eval_consumer=consumer)
+            if consumer is None:
+                gbdt._report_eviction(blocker, stage="engine")
+        elif want_replay or feval is not None or fobj is not None:
+            gbdt._report_eviction("feval" if feval is not None else "fobj",
+                                  stage="engine")
+        if consumer is None and not callbacks and feval is None \
+                and fobj is None and snapshot_freq <= 0:
+            gbdt.arm_megastep(True)
     evaluation_result_list: List = []
     start_iteration = 0
     if resume_from:
@@ -219,6 +239,9 @@ def train(params: Dict[str, Any], train_set: Dataset,
                     "eval_list": [list(t) for t in ev]}
         gbdt.set_checkpoint_extra(_engine_ckpt_extra)
     i = -1
+    # the span ``finish``: from the return of the last update to the
+    # return of this call
+    finish = contextlib.ExitStack()
     try:
       for i in range(start_iteration, num_boost_round):
         try:
@@ -286,38 +309,42 @@ def train(params: Dict[str, Any], train_set: Dataset,
             # the flight recorder's primary "where was it stuck" case
             booster._dump_crash(exc)
             raise
+      finish.enter_context(tel.timed("finish"))
     finally:
         # a kept booster must return to the one-iteration-per-update
         # contract once this loop stops consuming multi-iteration steps
         # (disarming with a consumer bound drains + replays the tail
         # first, so no queued metric rows are dropped)
-        booster._gbdt.arm_megastep(False)
+        with tel.timed("finish/drain"):
+            booster._gbdt.arm_megastep(False)
         booster._gbdt.set_checkpoint_extra(None)
 
-    if consumer is not None:
-        # the tail drain above may have replayed the final iterations —
-        # pick up a late early-stop verdict or the last eval list
-        if consumer.stop is not None and booster.best_iteration <= 0:
-            booster.best_iteration = consumer.stop[0] + 1
-            evaluation_result_list = consumer.stop[1]
-        elif consumer.last_eval and not evaluation_result_list:
-            evaluation_result_list = list(consumer.last_eval)
+    with finish:
+        if consumer is not None:
+            # the tail drain above may have replayed the final iterations —
+            # pick up a late early-stop verdict or the last eval list
+            if consumer.stop is not None and booster.best_iteration <= 0:
+                booster.best_iteration = consumer.stop[0] + 1
+                evaluation_result_list = consumer.stop[1]
+            elif consumer.last_eval and not evaluation_result_list:
+                evaluation_result_list = list(consumer.last_eval)
 
-    booster.best_score = collections.defaultdict(collections.OrderedDict)
-    for name, metric, value, _ in (evaluation_result_list or []):
-        booster.best_score[name][metric] = value
-    # observability epilogue: stop an open profiler trace, write the
-    # telemetry summary + flush the JSONL sink, then let callbacks with a
-    # finalize hook (record_telemetry) drain the completed records
-    booster._finalize_telemetry()
-    for cb in callbacks_before + callbacks_after:
-        fin = getattr(cb, "finalize", None)
-        if fin is not None:
-            fin(callback_mod.CallbackEnv(
-                model=booster, params=params, iteration=i,
-                begin_iteration=0, end_iteration=num_boost_round,
-                evaluation_result_list=evaluation_result_list))
-    return booster
+        booster.best_score = collections.defaultdict(collections.OrderedDict)
+        for name, metric, value, _ in (evaluation_result_list or []):
+            booster.best_score[name][metric] = value
+        # observability epilogue: stop an open profiler trace, write the
+        # telemetry summary + flush the JSONL sink, then let callbacks with a
+        # finalize hook (record_telemetry) drain the completed records
+        booster._finalize_telemetry()
+        with tel.timed("finish/callbacks"):
+            for cb in callbacks_before + callbacks_after:
+                fin = getattr(cb, "finalize", None)
+                if fin is not None:
+                    fin(callback_mod.CallbackEnv(
+                        model=booster, params=params, iteration=i,
+                        begin_iteration=0, end_iteration=num_boost_round,
+                        evaluation_result_list=evaluation_result_list))
+        return booster
 
 
 class CVBooster:

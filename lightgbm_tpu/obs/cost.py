@@ -165,8 +165,12 @@ class CostLedger:
         with self._lock:
             pending, self._pending = self._pending, []
         for ent in pending:
-            ca = analyze_jit(ent["fn"], ent["args"], ent["kwargs"],
-                             self.mode)
+            # (a retrace and a lowering of the whole step: seconds for a
+            # megastep, so it is a span of its own on the stream)
+            with self.tel.timed("cost/analyze", signature=ent["signature"],
+                                mode=self.mode):
+                ca = analyze_jit(ent["fn"], ent["args"], ent["kwargs"],
+                                 self.mode)
             if ca is None:
                 self.tel.inc("cost.analysis_failed")
                 continue

@@ -1,14 +1,20 @@
 """jax.monitoring bridge + per-device memory accounting.
 
-JAX reports compile phases through ``jax.monitoring`` duration events
+JAX reports compile phases through ``jax.monitoring``
 (``/jax/core/compile/jaxpr_trace_duration``,
-``.../jaxpr_to_mlir_module_duration``, ``.../backend_compile_duration``).
-A single process-wide listener is installed on first attach and fans the
-events out to every live, enabled :class:`Telemetry` — so per-booster
-registries see the compiles their iterations trigger (a recompile
-mid-training is exactly the kind of cliff one-off timing scripts keep
-missing).  Whatever identity kwargs the monitoring API
-passes (``fun_name`` on newer jax) ride along on the compile record.
+``.../jaxpr_to_mlir_module_duration``, ``.../backend_compile_duration``):
+each as a duration and as a time span with its true start and end.
+Process-wide listeners are installed on first attach. The durations fan
+out to every live, enabled :class:`Telemetry` as the ``compile.*``
+counters and timings, so per-booster registries see the compiles their
+iterations trigger (a recompile mid-training is exactly the kind of
+cliff one-off timing scripts keep missing). The time spans go two ways:
+to the ``compile`` track of ``trace_out``, with jax's own bounds, and,
+while a step's ``first_call`` span is open on the calling thread
+(registry.Span, ``adopt=True``), into that span as its children
+``first_call/trace`` / ``lower`` / ``load`` with jax's ``fun_name``; the
+two ``/jax/compilation_cache/*`` durations jax records on a cache hit
+land on the ``first_call/load`` they precede.
 
 Memory accounting covers EVERY local device, not just device 0: a
 multi-chip host where one device's allocator is near its limit while
@@ -25,6 +31,14 @@ import weakref
 from typing import Dict, Optional
 
 _COMPILE_PREFIX = "/jax/core/compile"
+# the children of a step's first call, by jax's phase
+_PHASE_SPAN = {"jaxpr_trace_duration": "first_call/trace",
+               "jaxpr_to_mlir_module_duration": "first_call/lower",
+               "backend_compile_duration": "first_call/load"}
+# what jax records on a persistent-cache hit, inside the backend phase
+_CACHE_ATTR = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "compile_time_saved_s"}
 
 _lock = threading.Lock()
 _installed = False
@@ -47,6 +61,7 @@ def attach(tel) -> None:
         try:
             from jax import monitoring
             monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_time_span_listener(_on_time_span)
         except Exception:  # monitoring API unavailable: degrade silently
             pass
         _installed = True
@@ -57,32 +72,72 @@ def detach(tel) -> None:
         _active.discard(tel)
 
 
-# parameter names of compile_event / span that a monitoring kwarg must
-# never shadow — a colliding key would raise TypeError INSIDE jax's
-# compile path and kill the jit that triggered the listener
+# parameter names of Telemetry.span that a monitoring kwarg must never
+# shadow — a colliding key would raise TypeError INSIDE jax's compile
+# path and kill the jit that triggered the listener
 _RESERVED_ATTRS = frozenset(
     {"phase", "seconds", "name", "track", "iteration", "wall_start",
-     "event", "duration"})
+     "event", "duration", "start", "end", "t0", "dur_s", "parent", "job"})
+
+
+def _identity(kwargs: dict) -> dict:
+    """Only plain scalar identity attrs survive — the record must stay
+    JSON- and trace-serializable whatever jax adds to the callback."""
+    return {k: v for k, v in kwargs.items()
+            if isinstance(v, (str, int, float, bool))
+            and k not in _RESERVED_ATTRS}
+
+
+def _adopting_span():
+    """The innermost open span of this thread that adopts jax's time
+    spans (a step's ``first_call``), or None."""
+    from .registry import open_spans
+    for span in reversed(open_spans()):
+        if span.adopts:
+            return span
+    return None
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event in _CACHE_ATTR:
+        span = _adopting_span()
+        if span is not None:
+            span.cache[_CACHE_ATTR[event]] = round(float(duration), 6)
+        return
     if not event.startswith(_COMPILE_PREFIX):
         return
     # short phase name: "backend_compile_duration" etc.
     phase = event.rsplit("/", 1)[-1]
-    # only plain scalar identity attrs survive — the record must stay
-    # JSON- and trace-serializable whatever jax adds to the callback
-    attrs = {k: v for k, v in kwargs.items()
-             if isinstance(v, (str, int, float, bool))
-             and k not in _RESERVED_ATTRS}
     for tel in list(_active):
         if tel.enabled:
             try:
-                tel.compile_event(phase, float(duration), **attrs)
+                tel.compile_event(phase, float(duration))
             except Exception:
                 # a telemetry bug must never propagate out of the
                 # monitoring listener into the XLA compile it observes
                 pass
+
+
+def _on_time_span(event: str, start: float, end: float, **kwargs) -> None:
+    if not event.startswith(_COMPILE_PREFIX):
+        return
+    phase = event.rsplit("/", 1)[-1]
+    try:
+        attrs = _identity(kwargs)
+        for tel in list(_active):
+            if tel.enabled:
+                tel.compile_span(phase, float(start), float(end), **attrs)
+        span = _adopting_span()
+        name = _PHASE_SPAN.get(phase)
+        if span is None or name is None:
+            return
+        if phase == "backend_compile_duration":
+            # key, cache read, deserialise and load; or the compile
+            hit, span.cache = span.cache, {}
+            attrs = dict(attrs, cache="hit" if hit else "miss", **hit)
+        span.adopt(name, start, end, **attrs)
+    except Exception:
+        pass    # as above: never into the compile it observes
 
 
 _STAT_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",

@@ -14,7 +14,12 @@ One :class:`Telemetry` instance per booster (GBDT driver).  It holds
   ``record_telemetry`` callback to drain;
 - **spans** — wall-clock (start, duration) pairs collected only when the
   trace exporter is on (``trace_out=<path>``), drained by obs.trace into
-  a Perfetto/Chrome-trace timeline (one track per rank).
+  a Perfetto/Chrome-trace timeline (one track per rank);
+- **set-up spans** — :class:`Span` (``Telemetry.timed``): the ONE timed
+  span of everything outside the boosting loop (binning, upload and
+  pack, a step's first call, the end of ``engine.train``), written as one
+  ``setup_span`` event per closed span with its start on the clock of
+  every event's ``ts`` (docs/Observability.md §3b).
 
 Disabled-path contract: every recording method returns after a single
 ``self.enabled`` attribute check — no allocation, no locking, no
@@ -34,6 +39,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from ..utils.timer import global_timer as timer
+
 _EVENT_RING = 512       # bounded in-memory event history
 _RECORD_RING = 65536    # per-iteration records awaiting a drain
 _SPAN_RING = 16384      # trace spans awaiting export (a few per iteration)
@@ -41,6 +50,166 @@ _FINDING_RING = 1024    # health/guard findings kept for the whole run
 _DIST_RING = 8192       # recent samples per value distribution
 _FINDING_EVENTS = frozenset(
     {"anomaly", "rank_divergence", "straggler", "alert"})
+_HELD_SPANS = 256       # set-up spans closed before the registry is on
+
+# the spans open on this thread, outermost first: what gives a span its
+# parent and the jax.monitoring listener its ``first_call``
+_open = threading.local()
+
+
+def open_spans() -> List["Span"]:
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+class Span:
+    """One timed span of the set-up or the end of a job: the ONE
+    primitive (``Telemetry.timed`` makes it). In one place it is a
+    ``jax.profiler.TraceAnnotation`` (a ``profile_dir`` trace that covers
+    the set-up shows it on the host plane, on the device operations'
+    clock), an entry of the crash recorder's section stack, a TIMETAG
+    section, a ``trace_out`` span on the ``setup`` track and ONE
+    ``setup_span`` JSONL event when it closes: ``name``, ``t0`` (its
+    start, ``time.time()``: the clock of every event's ``ts``),
+    ``dur_s``, ``parent`` (the span open around it on this thread),
+    ``job`` (the registry's ``run_id``) and its attributes.
+
+    ``sync(arrays)`` blocks on them before the span closes, so the
+    device work a span started is charged to it and not to whichever
+    later call waits; it blocks only while the registry (or the TIMETAG
+    timer) is on, so a job with telemetry off syncs nothing more.
+
+    A span needs no live registry: with ``tel=None`` it closes into
+    ``hold`` (its own list or the one of the span around it): the
+    Dataset's, which is binned before any Booster has a sink, and
+    ``engine.train``'s until its Booster exists (``bind``). A registry
+    that is not enabled yet keeps the closed span and writes it when it
+    is (``record_telemetry`` enables at the first iteration).
+
+    ``adopt=True`` (a step's ``first_call``) takes children known only by
+    their bounds, jax's own trace / lower / compile time spans
+    (obs/jaxmon.py), and writes them when it closes; an inner jit's trace
+    lies inside the step's and is folded into it (``adopt``), so a reader
+    that sums the spans whose parent is ``first_call`` counts nothing
+    twice."""
+
+    __slots__ = ("tel", "name", "attrs", "hold", "parent", "t0", "_p0",
+                 "_sync", "_ann", "_adopted", "cache")
+
+    def __init__(self, tel, name: str, hold: Optional[list] = None,
+                 adopt: bool = False, **attrs: Any):
+        self.tel = tel
+        self.name = name
+        self.attrs = attrs
+        self.hold = hold
+        self.parent: Optional[str] = None
+        self._sync = None
+        # the outermost children it adopted: (name, t0, t1, attrs)
+        self._adopted: Optional[list] = [] if adopt else None
+        self.cache: Dict[str, float] = {}   # jaxmon: a hit's durations
+
+    # ------------------------------------------------------------ handle
+    def sync(self, arrays) -> None:
+        """Block on ``arrays`` before the span closes (while the
+        registry or the TIMETAG timer is on)."""
+        if self._live():
+            self._sync = arrays
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only inside the span (``bytes``)."""
+        self.attrs.update(attrs)
+
+    def bind(self, tel) -> None:
+        """Give a span that opened without a registry the one its job
+        now has, and hand over what closed into its ``hold`` so far."""
+        held, self.hold = self.hold, None
+        self.tel = tel
+        tel.publish_spans(held or ())
+
+    def adopt(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
+        """A child known by its bounds, reported when it ENDS (jax's
+        phases): the children reported before it that started inside it
+        are its own, and fold into it as ``inner_jits`` (how many, at any
+        depth) and ``inner``: its direct ones by ``fun_name`` as
+        ``[fun_name, count, seconds]``, the eight largest, and where a
+        name stands for ONE span that held others, that span's own
+        ``inner`` as a fourth item. So a step's thousands of inner jits
+        cost its stream three lines and not thousands, and the lines
+        still say which jit inside which carries the time."""
+        roots = self._adopted
+        count, by_fun = 0, {}
+        while roots and roots[-1][1] >= t0:
+            _, c0, c1, cattrs = roots.pop()
+            count += 1 + cattrs.get("inner_jits", 0)
+            ent = by_fun.setdefault(str(cattrs.get("fun_name", "?")),
+                                    [0, 0.0, None])
+            ent[0] += 1
+            ent[1] += c1 - c0
+            ent[2] = cattrs.get("inner") if ent[0] == 1 else None
+        if count:
+            top = sorted(by_fun.items(), key=lambda kv: -kv[1][1])[:8]
+            attrs.update(inner_jits=count, inner=[
+                [fun, n, round(sec, 6)] + ([deeper] if deeper else [])
+                for fun, (n, sec, deeper) in top])
+        roots.append((name, float(t0), float(t1), attrs))
+
+    @property
+    def adopts(self) -> bool:
+        return self._adopted is not None
+
+    def _live(self) -> bool:
+        tel = self.tel
+        return (tel is not None and tel.enabled) or timer.enabled
+
+    # ----------------------------------------------------------- context
+    def __enter__(self) -> "Span":
+        stack = open_spans()
+        if stack:
+            above = stack[-1]
+            self.parent = above.name
+            if self.tel is None and self.hold is None:
+                self.tel, self.hold = above.tel, above.hold
+        stack.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self.tel is not None:
+            self.tel.push_section(self.name)
+        self.t0 = time.time()
+        self._p0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is None and self._sync is not None:
+            import jax
+            jax.block_until_ready(self._sync)
+        self._sync = None
+        dur = time.perf_counter() - self._p0
+        stack = open_spans()
+        if self in stack:           # and whatever an exception left above
+            del stack[stack.index(self):]
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            # like GBDT._sec: the section stays on the crash recorder's
+            # stack, and a span that did not finish is not written
+            return False
+        tel = self.tel
+        if tel is not None:
+            tel.pop_section()
+        timer.add(self.name, dur)
+        records = [{"name": self.name, "t0": self.t0, "dur_s": dur,
+                    "parent": self.parent, **self.attrs}]
+        # (what was adopted and not folded into another is this span's)
+        records += [{"name": name, "t0": t0, "dur_s": t1 - t0,
+                     "parent": self.name, **attrs}
+                    for name, t0, t1, attrs in self._adopted or ()]
+        for rec in records:
+            if tel is not None:
+                tel.write_span(rec)
+            elif self.hold is not None:
+                self.hold.append(rec)
+        return False
 
 
 class Telemetry:
@@ -73,6 +242,9 @@ class Telemetry:
         self._dist_totals: Dict[str, List[float]] = {}
         self._records = collections.deque(maxlen=_RECORD_RING)
         self._spans = collections.deque(maxlen=_SPAN_RING)
+        # set-up spans that closed while the registry was off
+        # (write_span); enable() writes them
+        self._held_spans: List[Dict[str, Any]] = []
         self._trace_on = False
         # trace timebase: wall-clock epoch + monotonic offsets, so span
         # timestamps stay comparable ACROSS ranks (shared epoch) yet a
@@ -129,7 +301,9 @@ class Telemetry:
             if trace is not None:
                 self._trace_on = bool(trace)
             self.enabled = True
+            held, self._held_spans = self._held_spans, []
         jaxmon.attach(self)
+        self.publish_spans(held)
         return attached
 
     @property
@@ -246,6 +420,10 @@ class Telemetry:
         if iteration is not None:
             rec["iter"] = int(iteration)
         rec.update(attrs)
+        self._record(rec)
+
+    def _record(self, rec: Dict[str, Any]) -> None:
+        name = rec["event"]
         with self._lock:
             self._events.append(rec)
             if name in _FINDING_EVENTS:
@@ -311,6 +489,40 @@ class Telemetry:
                 self._counters["trace.spans_dropped"] = \
                     self._counters.get("trace.spans_dropped", 0) + 1
             self._spans.append(rec)
+
+    # ----------------------------------------------------- set-up spans
+    def timed(self, name: str, adopt: bool = False, **attrs: Any) -> Span:
+        """``with tel.timed("init/upload", bytes=n) as s: ...;
+        s.sync(x)``: the one span of the set-up and of the end of a job
+        (:class:`Span`)."""
+        return Span(self, name, adopt=adopt, **attrs)
+
+    def write_span(self, span: Dict[str, Any]) -> None:
+        """One closed span (``name``, ``t0``, ``dur_s``, ``parent`` and
+        its attributes) as a ``setup_span`` event of this job, and under
+        ``trace_out`` as a span of the ``setup`` track; kept for
+        ``enable()`` while the registry is off."""
+        if not self.enabled:
+            if len(self._held_spans) < _HELD_SPANS:
+                self._held_spans.append(span)
+            return
+        span = dict(span)
+        rec: Dict[str, Any] = {
+            "ts": time.time(), "rank": self.rank, "event": "setup_span",
+            "name": span.pop("name"), "t0": span.pop("t0"),
+            "dur_s": round(span.pop("dur_s"), 6),
+            "parent": span.pop("parent", None), "job": self.run_id}
+        rec.update(span)
+        self._record(rec)
+        self.span(rec["name"], rec["t0"], rec["dur_s"], track="setup",
+                  **span)
+
+    def publish_spans(self, spans) -> None:
+        """Spans that closed before this registry could take them (a
+        Dataset's ``setup_spans``, ``engine.train``'s before its Booster
+        existed), with their true ``t0``."""
+        for span in spans:
+            self.write_span(span)
 
     def drain_spans(self) -> List[Dict[str, Any]]:
         """Collected trace spans since the last drain (the trace
@@ -408,13 +620,11 @@ class Telemetry:
                       seconds or 0.0, track="collectives",
                       count=int(count), bytes=int(nbytes))
 
-    def compile_event(self, phase: str, seconds: float,
-                      **attrs: Any) -> None:
+    def compile_event(self, phase: str, seconds: float) -> None:
         """XLA compile phase (fed by obs.jaxmon); attributed to the open
-        iteration when one is active.  ``attrs`` carry whatever identity
-        jax.monitoring passed along (e.g. ``fun_name`` on newer jax) —
-        kept on the counters so the exporter can expose recompile
-        rates, not per-phase JSONL spam."""
+        iteration when one is active: the counters the exporter's
+        recompile rate reads, not per-phase JSONL spam (a step's first
+        call writes its phases as ``setup_span`` events, obs/jaxmon.py)."""
         if not self.enabled:
             return
         with self._lock:
@@ -426,12 +636,14 @@ class Telemetry:
             if self._cur_iter is not None:
                 self._cur_compile["count"] += 1
                 self._cur_compile["secs"] += seconds
-        if self._trace_on:
-            # the monitoring callback fires at phase END; back-date the
-            # span so it occupies its real window on the compile track
-            now = self.wall_now()
-            self.span("compile:" + phase, now - seconds, seconds,
-                      track="compile", **attrs)
+
+    def compile_span(self, phase: str, start: float, end: float,
+                     **attrs: Any) -> None:
+        """The same phase with the bounds jax measured
+        (``record_event_time_span``), for the ``compile`` track of
+        ``trace_out``."""
+        self.span("compile:" + phase, start, end - start, track="compile",
+                  **attrs)
 
     def compile_executable(self, signature: str, compile_ms: float,
                            operand_bytes: int, **attrs: Any) -> None:

@@ -100,7 +100,7 @@ def test_trace_out_writes_loadable_timeline(tmp_path):
             assert s["pid"] == it["pid"] and s["tid"] == it["tid"]
             assert s["ts"] >= it["ts"] - slack
             assert s["ts"] + s["dur"] <= it["ts"] + it["dur"] + slack
-    # iteration 0 compiles: the compile track carries back-dated spans
+    # iteration 0 compiles: the compile track carries the phases jax timed
     compiles = [e for e in xs if str(e["name"]).startswith("compile:")]
     assert compiles, "no compile spans on the compile track"
     assert {e["cat"] for e in compiles} == {"compile"}
