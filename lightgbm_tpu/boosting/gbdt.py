@@ -4403,6 +4403,19 @@ class GBDT:
         plan, err = build_plan(self, include_training)
         if plan is None:
             return False, err
+        from ..metric import AUCMetric
+        sets = list(zip(self.valid_names, self.valid_metrics))
+        if include_training and self.training_metrics:
+            sets.insert(0, ("training", self.training_metrics))
+        for ds_name, metrics in sets:
+            for m in metrics:
+                if isinstance(m, AUCMetric):
+                    # which form of the traced AUC runs: the sort carries
+                    # 2 operands, 3 when weighted (no metric reads it)
+                    self.telemetry.event(
+                        "auc_form", iteration=self.iter, dataset=ds_name,
+                        rows=int(m.num_data),
+                        weighted=m.weight is not None)
         self._traced_plan = plan
         self._plan_ops = None
         self._es_spec = es_spec

@@ -451,28 +451,52 @@ def _weighted_auc(label: np.ndarray, score: np.ndarray,
     return float(s_area / (total_pos * total_neg))
 
 
+# the traced AUC's scan block, the GOSS prefix sums' (PR 35);
+# scripts/ablate_auc.py times others beside it
+AUC_SCAN_BLOCK = 2048
+
+
 def _weighted_auc_jnp(label, score, weight):
-    """jnp mirror of _weighted_auc — same tie-grouped trapezoid, f32
-    accumulation, one scalar leaves the device."""
+    """jnp mirror of _weighted_auc with no scatter and no gather. One sort
+    by score, descending, carries each row's positive weight (and its
+    weight). Each row's tie group is read from blocked prefix scans of the
+    positives: before the group, a running max of the exclusive sum at
+    group starts; at its end, a reverse running min of the inclusive sum
+    at group ends (both sums never decrease). A negative row then scores
+    the positives before its group plus half of its group's, which only
+    group sums decide, so any order inside a tie group does. f32, one
+    scalar leaves the device; without weights the positives are counted
+    in int32."""
     import jax
     import jax.numpy as jnp
-    n = score.shape[0]
-    pos = (label > 0).astype(jnp.float32)
-    w = weight if weight is not None else jnp.ones_like(pos)
-    order = jnp.argsort(-score, stable=True)
-    sp = pos[order] * w[order]
-    sw = w[order]
-    ss = score[order]
-    new_group = jnp.concatenate([jnp.ones((1,), bool), ss[1:] != ss[:-1]])
-    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-    g_pos = jax.ops.segment_sum(sp, gid, num_segments=n)
-    g_all = jax.ops.segment_sum(sw, gid, num_segments=n)
-    g_neg = g_all - g_pos
-    cum_pos_before = jnp.concatenate(
-        [jnp.zeros((1,), g_pos.dtype), jnp.cumsum(g_pos)[:-1]])
-    s_area = jnp.sum(g_neg * (cum_pos_before + 0.5 * g_pos))
-    total_pos = jnp.sum(sp)
-    total_neg = jnp.sum(sw) - total_pos
+    from ..ops.scan import blocked_scan
+    pos = label > 0
+    if weight is None:
+        key, p = jax.lax.sort((-score, pos.astype(jnp.int32)),
+                              num_keys=1, is_stable=False)
+    else:
+        key, p, w = jax.lax.sort(
+            (-score, jnp.where(pos, weight, 0.0), weight),
+            num_keys=1, is_stable=False)
+    cum = blocked_scan(p, "sum", AUC_SCAN_BLOCK)
+    change = key[1:] != key[:-1]          # -0.0 == 0.0: one group
+    first = jnp.concatenate([jnp.ones((1,), bool), change])
+    last = jnp.concatenate([change, jnp.ones((1,), bool)])
+    before = jnp.concatenate([jnp.zeros((1,), cum.dtype), cum[:-1]])
+    start = blocked_scan(jnp.where(first, before, 0), "max",
+                         AUC_SCAN_BLOCK)
+    end = blocked_scan(jnp.where(last, cum, jnp.iinfo(jnp.int32).max
+                                 if weight is None else jnp.inf),
+                       "min", AUC_SCAN_BLOCK, reverse=True)
+    mid = 0.5 * (start + end).astype(jnp.float32)
+    if weight is None:
+        s_area = jnp.sum(jnp.where(p == 0, mid, 0.0))
+        total_pos = cum[-1].astype(jnp.float32)
+        total_neg = (score.shape[0] - cum[-1]).astype(jnp.float32)
+    else:
+        s_area = jnp.sum((w - p) * mid)
+        total_pos = jnp.sum(p)
+        total_neg = jnp.sum(w - p)
     # one-class degenerate case matches the host path's 1.0
     return jnp.where((total_pos <= 0) | (total_neg <= 0), 1.0,
                      s_area / (total_pos * total_neg))

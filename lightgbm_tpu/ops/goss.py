@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .scan import blocked_scan
+
 ROW_ALIGN = 2048          # the fused grower's widest row tile
 COMPACT_TILE = 512        # rows of one compaction tile (and output block)
 
@@ -85,19 +87,6 @@ def goss_plan(n: int, top_rate: float, other_rate: float,
                     int(1.0 / learning_rate), evict_reason)
 
 
-def blocked_cumsum(x: jax.Array, block: int = 2048) -> jax.Array:
-    """Inclusive prefix sum of a long int32 vector: within blocks, then
-    the blocks' totals (two short scans instead of one of the whole
-    length)."""
-    n = x.shape[0]
-    pad = -n % block
-    xb = jnp.pad(x, (0, pad)).reshape(-1, block)
-    inner = jnp.cumsum(xb, axis=1)
-    totals = inner[:, -1]
-    offs = jnp.cumsum(totals) - totals
-    return (inner + offs[:, None]).reshape(-1)[:n]
-
-
 def kth_largest(v: jax.Array, k) -> jax.Array:
     """The k-th largest of the non-negative int32 vector ``v`` (entries
     below zero never count): the largest t with count(v >= t) >= k, built
@@ -122,7 +111,7 @@ def select_top(v: jax.Array, k) -> jax.Array:
     ties = jax.lax.cond(
         jnp.sum(eq.astype(jnp.int32)) == need,
         lambda: eq,
-        lambda: eq & (blocked_cumsum(eq.astype(jnp.int32)) <= need))
+        lambda: eq & (blocked_scan(eq.astype(jnp.int32)) <= need))
     return gt | ties
 
 
@@ -211,7 +200,7 @@ def compact_tables(inbag: jax.Array, Rp: int, capacity: int,
     and zero the blocks behind it: as many as a count within ROW_ALIGN of
     ``capacity`` leaves (``compact_rows``' precondition)."""
     m = jnp.pad(inbag.astype(jnp.int32), (0, Rp - inbag.shape[0]))
-    csum = blocked_cumsum(m)
+    csum = blocked_scan(m)
     dest = jnp.where(m > 0, csum - 1, -1)[None, :]
     ends = csum.reshape(-1, C)[:, -1]
     before = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])

@@ -28,12 +28,14 @@ a hash-consistent set by the launcher.
 """
 from __future__ import annotations
 
+import copy
 import json
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..models.tree import HostTree
+from ..obs.drift import add_score_distribution, profile_digest
 from ..obs.health import model_state_hash
 from ..utils import log
 
@@ -172,8 +174,10 @@ def capture(gbdt) -> Tuple[Dict[str, Any], Dict[str, np.ndarray]]:
         # record ride every checkpoint manifest, so a booster resurrected
         # from a checkpoint (rollover source) carries its training
         # distribution and lineage exactly like a model-file booster
-        "data_profile": getattr(gbdt, "data_profile", None),
-        "provenance": getattr(gbdt, "provenance", None),
+        # (copies: the writer serialises on its own thread while the
+        # finalize epilogue adds "score" to the live dicts)
+        "data_profile": copy.deepcopy(getattr(gbdt, "data_profile", None)),
+        "provenance": copy.deepcopy(getattr(gbdt, "provenance", None)),
     }
     return payload, arrays
 
@@ -423,6 +427,12 @@ def booster_from_checkpoint(path: str, rank: int = 0):
     b.objective = create_objective_from_string(obj)
     b.data_profile = payload.get("data_profile")
     b.provenance = payload.get("provenance")
+    if b.data_profile and "score" not in b.data_profile:
+        # the finalize epilogue (Booster._capture_score_profile), from the
+        # scores this checkpoint holds
+        add_score_distribution(b.data_profile, arrays["scores"])
+        if b.provenance is not None:
+            b.provenance["profile_digest"] = profile_digest(b.data_profile)
     b.best_iteration = -1
     b._model_version += 1
     log.info("rollover source: checkpoint %s (iteration %s, %d trees, "

@@ -6,7 +6,7 @@ of a sampled iteration timed alone, at the Higgs cell's shape.
               the same vector beside it (the thing it avoids)
   draw        the counter-hash keys + the second select
   cumsum      the prefix sum of the in-bag mask, flat (``jnp.cumsum``) and in
-              blocks (ops/goss.blocked_cumsum)
+              blocks (ops/scan.blocked_scan)
   compact     option A, the Pallas window compaction (ops/goss.compact_rows),
               at tiles of 256 / 512 / 1,024 rows, checked against a gather of
               a slice; option B, XLA only: ``nonzero(size=K)`` for the index
@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.ops import fused_level as fl
 from lightgbm_tpu.ops import goss
+from lightgbm_tpu.ops.scan import blocked_scan
 
 from ablate_route_form import _splits, _time
 
@@ -97,7 +98,7 @@ def main():
         m = inbag.astype(jnp.int32)
         ms, a = _time(lambda: jax.jit(jnp.cumsum)(m), reps)
         say(stage="cumsum.flat", ms=ms)
-        ms, b = _time(lambda: jax.jit(goss.blocked_cumsum)(m), reps)
+        ms, b = _time(lambda: jax.jit(blocked_scan)(m), reps)
         say(stage="cumsum.blocked", ms=ms, equal=bool(jnp.all(a == b)))
     mult, w = goss.sample_weights(top, other, plan.multiply)
     pad = Rp - R

@@ -140,6 +140,16 @@ def test_profile_roundtrip_checkpoint(tmp_path):
     assert b.provenance["run_id"] == a.provenance["run_id"]
 
 
+def test_checkpoint_payload_holds_a_copy_of_the_profile(bst):
+    # the writer thread serialises the payload while the finalize epilogue
+    # may still add "score" to the live profile
+    from lightgbm_tpu.resilience.state import capture
+    payload, _ = capture(bst._gbdt)
+    assert payload["data_profile"] == bst.data_profile
+    assert payload["data_profile"] is not bst._gbdt.data_profile
+    assert payload["provenance"] is not bst._gbdt.provenance
+
+
 def test_resume_chains_parent_checkpoint(tmp_path):
     X, y = _data(seed=4)
     ck = str(tmp_path / "ck")

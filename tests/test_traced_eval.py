@@ -93,6 +93,70 @@ def test_auc_tie_handling_parity():
     _host_vs_traced("auc", label, score)
 
 
+def _auc_case(n, ties, weighted, labels="mixed", seed=0):
+    rng = np.random.default_rng([n, seed])
+    score = {"distinct": lambda: rng.permutation(n) * 0.5 - n / 4,
+             "heavy": lambda: rng.integers(0, 5, n),
+             "equal": lambda: np.full(n, 0.25),
+             # -0.0 and 0.0 are one group, as != says
+             "signed_zero": lambda: rng.choice([-0.0, 0.0, 1.0, -1.0], n),
+             }[ties]().astype(np.float32)
+    if labels == "mixed":
+        label = (rng.random(n) < 0.4).astype(np.float32)
+        label[:2] = [0.0, 1.0]
+    elif labels in ("top", "bottom"):
+        # the positives all at one end of the score order
+        ranked = np.argsort(-score, kind="stable")
+        label = np.zeros(n, np.float32)
+        half = ranked[:n // 2] if labels == "top" else ranked[n // 2:]
+        label[half] = 1.0
+    else:
+        label = np.full(n, float(labels == "ones"), np.float32)
+    weight = (rng.uniform(0.1, 2.0, n).astype(np.float32)
+              if weighted else None)
+    return label, score, weight
+
+
+_AUC_CASES = (
+    [(n, t, w, "mixed") for n in (2, 2047, 2049, 100003)
+     for t in ("distinct", "heavy", "equal", "signed_zero")
+     for w in (False, True)]
+    + [(2049, "distinct", w, one) for w in (False, True)
+       for one in ("zeros", "ones", "top", "bottom")])
+
+
+@pytest.mark.parametrize("n,ties,weighted,labels", _AUC_CASES)
+def test_auc_jnp_matches_host(n, ties, weighted, labels):
+    """The traced AUC (one sort, blocked scans) against the float64 host
+    AUC: lengths not a multiple of the scan block, ties of every kind,
+    one class (1.0), positives all at one end (1.0 / 0.0)."""
+    import jax
+    from lightgbm_tpu.metric import _weighted_auc, _weighted_auc_jnp
+    label, score, weight = _auc_case(n, ties, weighted, labels)
+    host = _weighted_auc(label, score.astype(np.float64), weight)
+    traced = float(jax.jit(_weighted_auc_jnp)(label, score, weight))
+    assert abs(traced - host) <= 1e-6, (traced, host)
+    if labels in ("zeros", "ones", "top"):
+        assert host == 1.0
+    if labels == "bottom" and ties == "distinct":
+        assert host == 0.0
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_auc_jnp_lowers_without_scatter_or_gather(weighted):
+    """The traced AUC is a sort, scans and one reduction: a scatter or a
+    gather brought back into it (they cost 5-12 ms each at 1.33M rows on
+    the chip, PERF.md section 6) fails here."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.metric import _weighted_auc_jnp
+    x = jax.ShapeDtypeStruct((100003,), jnp.float32)
+    text = jax.jit(_weighted_auc_jnp).lower(
+        x, x, x if weighted else None).as_text()
+    assert "sort" in text
+    assert "scatter" not in text and "gather" not in text
+
+
 def test_multiclass_metrics_parity():
     from lightgbm_tpu.objective import create_objective
     nc = 4
@@ -275,6 +339,10 @@ def test_megastep_stays_on_with_builtin_callbacks(tmp_path):
     assert all(not r["stopped"] for r in eb)
     assert eb[0]["slots"] == ["valid_0/binary_logloss", "valid_0/auc",
                               "valid_1/binary_logloss", "valid_1/auc"]
+    # one auc_form event per AUC of the plan: which form ran
+    forms = [r for r in recs if r["event"] == "auc_form"]
+    assert [(r["dataset"], r["rows"], r["weighted"]) for r in forms] == \
+        [("valid_0", 1200, False), ("valid_1", 1200, False)]
     assert len(eb[0]["last"]) == 4
     # host-recomputed parity for the final iteration's logged values
     host = dict(
