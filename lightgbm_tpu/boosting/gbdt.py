@@ -395,6 +395,7 @@ class GBDT:
             self.valid_data: List[TpuDataset] = []
             self.valid_bins: List = []
             self._valid_routes: Dict[int, Tuple] = {}   # see _valid_route
+            self._valid_exact_dev: Dict[int, Tuple] = {}  # _valid_exact
             self._valid_route_said: set = set()
             self._route_form_said: set = set()          # see _route_form
             self.valid_scores: List = []
@@ -1348,7 +1349,8 @@ class GBDT:
                 return
         if getattr(self, "n_forced", 0) > 0:
             return  # forced splits route through the leaf-wise grower
-        from ..ops.efb import BundleLayout, encode_bundles, find_bundles
+        from ..ops.efb import (BundleLayout, dropped_values, encode_bundles,
+                               find_bundles)
         bins_np = np.asarray(train_data.bins)
         mfb = getattr(train_data, "most_freq_bins", None)
         if mfb is None:
@@ -1400,17 +1402,20 @@ class GBDT:
         layout = BundleLayout(bundles, nb_all)
         enc = encode_bundles(bins_np, mfb, layout)
         self._install_bundle_layout(train_data, layout, enc,
-                                    np.asarray(mfb, np.int32))
+                                    np.asarray(mfb, np.int32),
+                                    dropped_values(masks, bundles))
         log.info("EFB: %d features bundled into %d columns",
                  train_data.num_features, layout.num_columns)
         self.telemetry.event("efb", features=train_data.num_features,
                              columns=layout.num_columns)
 
     def _install_bundle_layout(self, train_data, layout, enc_np,
-                               mfb_np) -> None:
+                               mfb_np, conflict_rows: int = 0) -> None:
         """BundleCfg + device bundle matrix from a BundleLayout (shared by
         the dense default-on EFB path and sparse-built prebundled
-        datasets)."""
+        datasets); ``conflict_rows``: the values the encode dropped (a row
+        non-default in k members of one bundle keeps the first: k - 1),
+        said with the layout (``_route_form``)."""
         nb = [int(x) for x in train_data.num_bin_per_feat]
         Bc = max(layout.col_num_bin)
         B = self.max_bins
@@ -1438,6 +1443,9 @@ class GBDT:
         self.bundle_bins_dev = jnp.asarray(enc_small)
         self.bundle_col_bins = int(Bc)
         self.use_bundles = True
+        self._efb_layout = {"features": F, "columns": layout.num_columns,
+                            "max_bins": int(Bc),
+                            "conflict_rows": int(conflict_rows)}
 
     # ------------------------------------------------------------------
     def _setup_forced_splits(self, config: Config, train_data) -> None:
@@ -3075,8 +3083,35 @@ class GBDT:
         return self.bins_dev
 
     def _valid_bundle(self, vi: int):
-        return (self._replay_bundle
-                if self.valid_data[vi].prebundled is not None else None)
+        """Replay-decode args of validation set ``vi``'s bins (None for
+        logical bins); a set stored in bundle columns adds its
+        conflicting rows (_valid_exact)."""
+        if self.valid_data[vi].prebundled is None:
+            return None
+        return self._replay_bundle + self._valid_exact(vi)
+
+    def _valid_exact(self, vi: int):
+        """(rows [M], logical bins [M, F]) on the device of the rows of
+        validation set ``vi`` that its bundle columns cannot hold
+        (TpuDataset.from_sparse's ``exact_rows``), whose leaves are
+        walked over these bins; None for a set stored in logical bins.
+        M is a power of two of at least 8, the rows past the set's own
+        are padding (row index past every set's end), so the step's
+        shapes do not move with how many rows conflict."""
+        exact = getattr(self.valid_data[vi], "exact_rows", None)
+        if exact is None:
+            return None
+        got = self._valid_exact_dev.get(vi)
+        if got is None:
+            rows, bins = exact
+            M = max(8, 1 << (max(len(rows), 1) - 1).bit_length())
+            rows_p = np.full(M, np.iinfo(np.int32).max, np.int32)
+            rows_p[:len(rows)] = rows
+            bins_p = np.zeros((M, bins.shape[1]), bins.dtype)
+            bins_p[:len(rows)] = bins
+            got = self._valid_exact_dev[vi] = (jnp.asarray(rows_p),
+                                               jnp.asarray(bins_p))
+        return got
 
     # ------------------------------------------------------------------
     # Async pipelined fast path.
@@ -3247,7 +3282,8 @@ class GBDT:
                                kB)[0] == "bins"
         return level_build(bins_form, Sp, kF * kB, self.fused_nch,
                            max(kF, 8), wide_bins=kB > 256,
-                           has_cat=self.has_cat, quant=self.quant_bits > 0)
+                           has_cat=self.has_cat, quant=self.quant_bits > 0,
+                           bundled=bins_form and self.fused_bundle_cols > 0)
 
     def _valid_route_reason(self, vi: int) -> Optional[str]:
         """Why validation set ``vi`` keeps the gather walk, or None when
@@ -3284,7 +3320,10 @@ class GBDT:
         trees (rollback, a continued model, recovery). Says which path
         the set took once per run: the ``valid.route_*_sets`` counters
         and a ``valid_route`` event (not a ``degrade``: nothing that was
-        asked for is lost, both paths give the same bits)."""
+        asked for is lost, both paths give the same bits), with
+        ``exact_rows`` where the set is stored in the training bundles:
+        the rows whose values conflict there, whose leaves are walked
+        over their logical bins (_valid_exact)."""
         route = self._valid_routes.get(vi)
         if route is None:
             reason = self._valid_route_reason(vi)
@@ -3304,9 +3343,12 @@ class GBDT:
             self._valid_route_said.add(vi)
             path = "gather" if route[1] else "kernel"
             tel.inc("valid.route_%s_sets" % path)
+            exact = getattr(self.valid_data[vi], "exact_rows", None)
             tel.event("valid_route", iteration=self.iter,
                       valid_set=self.valid_names[vi], path=path,
-                      **({"reason": route[1]} if route[1] else {}))
+                      **({"reason": route[1]} if route[1] else {}),
+                      **({"exact_rows": len(exact[0])} if exact is not None
+                         else {}))
         return route
 
     def _route_form(self) -> None:
@@ -3328,7 +3370,12 @@ class GBDT:
         schedule up to the one that spends the leaf budget, which only
         routes (the ``tpu_extra_levels`` passes behind it run only for
         a skewed tree and are not counted). The ``reason`` of a
-        ``scratch`` build is the table form's."""
+        ``scratch`` build is the table form's. A job stored as EFB bundle
+        columns says its layout beside them, once: an ``efb_layout``
+        event with the logical features, the bundle columns, the largest
+        column's bins, the values the encode dropped (``conflict_rows``)
+        and the form (``bins``: the kernels decode the bundle values by
+        window)."""
         from ..models.frontier2 import route_form
         _, kB, caps = self._fused_plane()
         said = route_form(self.has_cat, self.fused_bundle_cols, kB)
@@ -3344,6 +3391,9 @@ class GBDT:
             tel.inc("route.cat_membership")
         tel.event("route_form", iteration=self.iter, form=form, **why,
                   **({"membership": True} if membership else {}))
+        if self.fused_bundle_cols:
+            tel.event("efb_layout", iteration=self.iter,
+                      **self._efb_layout, form=form)
         builds = {sp: self._level_build(sp)
                   for sp in sorted({8} | {max(8, c) for c in caps})}
         build = dict(builds[8])
@@ -3372,11 +3422,15 @@ class GBDT:
     def _valid_operands(self) -> Tuple:
         """Per validation set, the matrix its traced score update reads:
         the passenger on the kernel path, the row-major bins on the
-        gather path."""
-        mats = [self._valid_route(vi)[0]
-                for vi in range(len(self.valid_bins))]
-        return tuple(vb if m is None else m
-                     for vb, m in zip(self.valid_bins, mats))
+        gather path; a set stored in bundle columns gives (matrix,
+        *_valid_exact)."""
+        ops = []
+        for vi, vb in enumerate(self.valid_bins):
+            m = self._valid_route(vi)[0]
+            exact = self._valid_exact(vi)
+            m = vb if m is None else m
+            ops.append(m if exact is None else (m,) + exact)
+        return tuple(ops)
 
     def _make_valid_apply(self, vi: int):
         """Traced valid-score update of validation set ``vi`` for one
@@ -3401,6 +3455,10 @@ class GBDT:
           a static ``_fast_tree_depth_bound()`` levels of row-length
           gathers. Serves the sets whose storage the tables do not
           describe (``_valid_route_reason``);
+        - a set stored in bundle columns (``vmat`` = (matrix, rows,
+          logical bins): _valid_operands) has the leaves of the rows its
+          bundles cannot hold walked over their logical bins, as the
+          gather path walks;
         - then one ``table_lookup`` of the shrunk leaf values and the
           add, the training scores' own formula (tree_score_delta). The
           product leaf_value * shrink is rounded before the lookup on
@@ -3416,7 +3474,8 @@ class GBDT:
         meta = self.meta
         has_cat = self.has_cat
         kernel = self._valid_route(vi)[1] is None
-        bundle = self._valid_bundle(vi)
+        bundle = (self._replay_bundle
+                  if self.valid_data[vi].prebundled is not None else None)
         n_valid = int(self.valid_bins[vi].shape[0])
         interp = self.fused_interpret
 
@@ -3432,7 +3491,7 @@ class GBDT:
                           if self.fused_bundle_cols else self.fused_Bp),
                 f_oh=self.fused_bundle_cols or self.fused_f_oh,
                 interpret=interp, packed=self.fused_packed,
-                has_cat=has_cat)
+                has_cat=has_cat, bundled=self.fused_bundle_cols > 0)
 
         @scope
         def lookup(leaf_T, leaf_value):
@@ -3453,19 +3512,28 @@ class GBDT:
 
         @scope
         def apply_trees(vscore, vmat, trees, logs=None):
+            exact = None
+            if isinstance(vmat, tuple):
+                vmat, *exact = vmat
+
+            def walk(bins, tid, bundle):
+                return route_rows_to_leaves(
+                    bins, trees.split_feature[tid],
+                    trees.threshold_bin[tid], trees.default_left[tid],
+                    trees.left_child[tid], trees.right_child[tid],
+                    meta.num_bin, meta.missing_type, meta.default_bin,
+                    max_steps=steps,
+                    cat_flag=trees.cat_flag[tid] if has_cat else None,
+                    cat_mask=trees.cat_mask[tid] if has_cat else None,
+                    bundle=bundle)
             for tid in range(k):
                 if kernel:
                     leaf_T = replay(vmat, logs[tid])
                 else:
-                    leaf_T = route_rows_to_leaves(
-                        vmat, trees.split_feature[tid],
-                        trees.threshold_bin[tid], trees.default_left[tid],
-                        trees.left_child[tid], trees.right_child[tid],
-                        meta.num_bin, meta.missing_type, meta.default_bin,
-                        max_steps=steps,
-                        cat_flag=trees.cat_flag[tid] if has_cat else None,
-                        cat_mask=trees.cat_mask[tid] if has_cat else None,
-                        bundle=bundle)[None, :]
+                    leaf_T = walk(vmat, tid, bundle)[None, :]
+                if exact is not None:
+                    leaf_T = leaf_T.at[0, exact[0]].set(
+                        walk(exact[1], tid, None), mode="drop")
                 new_row = vscore[tid] + lookup(
                     leaf_T, trees.leaf_value[tid] * shrink)
                 # dried class: zero contribution (matches the training
@@ -3690,7 +3758,7 @@ class GBDT:
                       if self.fused_bundle_cols else self.fused_Bp),
             f_oh=self.fused_bundle_cols or self.fused_f_oh,
             interpret=self.fused_interpret, packed=self.fused_packed,
-            has_cat=self.has_cat)[0]
+            has_cat=self.has_cat, bundled=self.fused_bundle_cols > 0)[0]
 
     def _sampled_growth(self, sample):
         """The two traced stages a sampled step adds (ops/goss.py), under
@@ -4542,6 +4610,9 @@ class GBDT:
         self._valid_routes = {
             vi: (m if m is None else jax.device_put(m, rep), reason)
             for vi, (m, reason) in self._valid_routes.items()}
+        self._valid_exact_dev = {
+            vi: jax.device_put(x, rep)
+            for vi, x in self._valid_exact_dev.items()}
         if self._es_carry is not None:
             self._es_carry = jax.device_put(self._es_carry, rep)
 
@@ -4680,7 +4751,8 @@ class GBDT:
             op_bytes = sum(
                 int(getattr(a, "nbytes", 0)) for a in
                 [self.fused_bins_T, self.scores, self.bag_weight,
-                 fm_pads, *base_args[2], *self.valid_scores])
+                 fm_pads, *jax.tree_util.tree_leaves(base_args[2]),
+                 *self.valid_scores])
             self.telemetry.compile_executable(
                 sig, (time.perf_counter() - t_call0) * 1000.0, op_bytes,
                 iteration=self.iter)
@@ -4720,6 +4792,56 @@ class GBDT:
                 jnp.full((), -1, jnp.int32))
 
     def _make_megastep(self, chunk: int, sample=None):
+        """The jitted megastep of ``chunk`` iterations (_megastep_step).
+        A job stored as bundle columns takes its dataset's tables (the
+        padded and logical feature metadata, the bundle decode) as an
+        operand rather than as constants of the program: the bundle
+        layout moves with every dataset, and as constants each one would
+        make a new program (_jit_over_tables)."""
+        if not self.fused_bundle_cols:
+            step, donate = self._megastep_step(chunk, sample)
+            return jax.jit(step, donate_argnums=_donate(*donate))
+        return self._jit_over_tables(
+            lambda: self._megastep_step(chunk, sample))
+
+    # the per-dataset tables a bundled job's step reads (_jit_over_tables)
+    _STEP_TABLES = ("fused_meta", "fused_bundle_cfg", "meta",
+                    "_replay_bundle")
+
+    def _jit_over_tables(self, build):
+        """jax.jit of the step ``build()`` returns (with the indices of
+        its donated arguments), called with ``_STEP_TABLES`` as a leading
+        operand: the step is traced with those attributes bound to the
+        operand's tracers, so the program holds the tables' shapes and
+        not their values, and a dataset of the same shapes is served by
+        the same compiled program. Returns a callable of the step's own
+        arguments (with a ``lower`` of them, for the cost ledger)."""
+        names = self._STEP_TABLES
+        donate = build()[1]
+
+        def tables():
+            return tuple(getattr(self, n) for n in names)
+
+        def step(tabs, *args):
+            saved = tables()
+            try:
+                for n, t in zip(names, tabs):
+                    setattr(self, n, t)
+                return build()[0](*args)
+            finally:
+                for n, t in zip(names, saved):
+                    setattr(self, n, t)
+        jitted = jax.jit(step,
+                         donate_argnums=_donate(*(i + 1 for i in donate)))
+
+        def call(*args):
+            return jitted(tables(), *args)
+        call.lower = lambda *args: jitted.lower(tables(), *args)
+        return call
+
+    def _megastep_step(self, chunk: int, sample=None):
+        """(step, indices of its donated arguments): the megastep's
+        traced body (_make_megastep jits it)."""
         obj = self.objective
         grow_k = self._make_fused_tree_loop(sample)
         valid_appliers = [self._make_valid_apply(vi)
@@ -4765,7 +4887,7 @@ class GBDT:
                     return scores, vscores, trees_B, counts_B
                 # donate the score carry and every valid-score buffer:
                 # the scan rewrites them in place across the whole chunk
-                return jax.jit(step, donate_argnums=_donate(1, 3))
+                return step, (1, 3)
 
             def step_ext(bins_T, scores, vbins, vscores, grad_ops,
                          bag_weight, fm_pads_B, ema0, explore_B,
@@ -4784,7 +4906,7 @@ class GBDT:
                     body, (scores, vscores, ema0),
                     (fm_pads_B, explore_B, seeds_B, iters_B))
                 return scores, vscores, trees_B, ema, counts_B
-            return jax.jit(step_ext, donate_argnums=_donate(1, 3))
+            return step_ext, (1, 3)
 
         # ---- on-device eval variant: the scan additionally computes
         # every configured metric per iteration (traced reductions over
@@ -4852,7 +4974,7 @@ class GBDT:
                     jax.lax.scan(body, (scores, vscores, es0),
                                  (fm_pads_B, iters_B))
                 return scores, vscores, es, trees_B, metrics_B, counts_B
-            return jax.jit(step, donate_argnums=_donate(1, 3, 9))
+            return step, (1, 3, 9)
 
         def step_ext(bins_T, scores, vbins, vscores, grad_ops, bag_weight,
                      fm_pads_B, iters_B, metric_ops, es0, ema0,
@@ -4880,7 +5002,7 @@ class GBDT:
                 jax.lax.scan(body, (scores, vscores, es0, ema0),
                              (fm_pads_B, iters_B, explore_B, seeds_B))
             return scores, vscores, es, trees_B, metrics_B, ema, counts_B
-        return jax.jit(step_ext, donate_argnums=_donate(1, 3, 9))
+        return step_ext, (1, 3, 9)
 
     # ------------------------------------------------------------------
     def train_one_iter(self, gradients=None, hessians=None) -> bool:

@@ -127,45 +127,113 @@ def _sample_rows(num_data: int, sample_cnt: int, seed: int) -> np.ndarray:
     return np.sort(rng.choice(num_data, size=sample_cnt, replace=False))
 
 
+class _ExactRows:
+    """Every row of a sparse set, for ``ops.efb.find_bundles``: a feature
+    the sample let into a bundle joins it only if no row of the whole set
+    is non-default in it and in a member already there (the sample alone
+    misses rare pairs: two rare one-hot columns that never meet in the
+    200,000 sampled rows can meet a few times in millions). Rows are read
+    from the CSC columns; a bundle's rows are kept as a boolean array,
+    made when first asked about."""
+
+    def __init__(self, csc, mappers, used_features, most_freq_bins, n):
+        self.csc, self.mappers, self.used = csc, mappers, used_features
+        self.mfb, self.n = most_freq_bins, n
+        self.members: List[List[int]] = []
+        self.taken: List[Optional[np.ndarray]] = []
+
+    def rows(self, k: int) -> np.ndarray:
+        """Rows non-default in used feature k."""
+        return _nondefault(self.csc, self.mappers[self.used[k]],
+                           self.used[k], int(self.mfb[k]), self.n)[0]
+
+    def fits(self, k: int, bi: int) -> bool:
+        if self.taken[bi] is None:
+            t = np.zeros(self.n, bool)
+            for m in self.members[bi]:
+                t[self.rows(m)] = True
+            self.taken[bi] = t
+        return not self.taken[bi][self.rows(k)].any()
+
+    def add(self, k: int, bi: int) -> None:
+        if bi == len(self.members):
+            self.members.append([])
+            self.taken.append(None)
+        self.members[bi].append(k)
+        if self.taken[bi] is not None:
+            self.taken[bi][self.rows(k)] = True
+
+
+def _nondefault(csc, mapper, j: int, mfb: int, n: int):
+    """(rows, bins) of the rows of CSC column j whose bin is not the
+    most-frequent one ``mfb``, ascending."""
+    lo, hi = csc.indptr[j], csc.indptr[j + 1]
+    rows_j = csc.indices[lo:hi]
+    # (a float32 column bins exactly against the mapper's float32 bounds)
+    bins_nz = mapper.value_to_bin(csc.data[lo:hi]).astype(np.int64)
+    zero_bin = int(mapper.value_to_bin(np.zeros(1))[0])
+    if zero_bin == mfb:
+        # implicit zeros are default: only the stored values can be not
+        nd = bins_nz != mfb
+        return rows_j[nd], bins_nz[nd]
+    # zeros bin away from the most-frequent bin (e.g. zero_as_missing):
+    # expand the column densely
+    dense_bins = np.full(n, zero_bin, np.int64)
+    dense_bins[rows_j] = bins_nz
+    rows = np.nonzero(dense_bins != mfb)[0]
+    return rows, dense_bins[rows]
+
+
 def _encode_sparse_bundles(csc, mappers, used_features, layout,
-                           most_freq_bins, n: int) -> np.ndarray:
-    """[R, C] bundle-column matrix straight from CSC columns — the dense
-    [R, F] logical matrix is never materialised. Bundle bin 0 = the row is
-    default (most-frequent bin) in every member; conflicts keep the first
-    member's encoding (ops/efb.py contract)."""
+                           most_freq_bins, n: int):
+    """([R, C] bundle-column matrix, lost rows) straight from CSC
+    columns — the dense [R, F] logical matrix is never materialised.
+    Bundle bin 0 = the row is default (most-frequent bin) in every
+    member; conflicts keep the first member's encoding (ops/efb.py
+    contract). ``lost rows``: ascending, the rows where a later member's
+    value was not stored that way."""
     C = layout.num_columns
     dtype = np.uint16 if max(layout.col_num_bin) > 255 else np.uint8
-    out = np.zeros((n, C), dtype)
+    # built a column at a time in [C, R] (contiguous writes), turned once
+    outT = np.zeros((C, n), dtype)
+    lost = []
     for ci, bundle in enumerate(layout.bundles):
-        col = np.zeros(n, np.int64)
+        col = outT[ci]
         taken = np.zeros(n, bool)
         for k in bundle:
             j = used_features[k]
-            m = mappers[j]
-            off = int(layout.offset_of_feat[k])
-            mfb = int(most_freq_bins[k])
-            lo, hi = csc.indptr[j], csc.indptr[j + 1]
-            rows_j = csc.indices[lo:hi]
-            bins_nz = m.value_to_bin(
-                np.asarray(csc.data[lo:hi], np.float64)).astype(np.int64)
-            zero_bin = int(m.value_to_bin(np.zeros(1))[0])
-            if zero_bin == mfb:
-                # implicit zeros are default: only non-default nonzeros
-                # need storing
-                nd = bins_nz != mfb
-                sel = rows_j[nd]
-                keep = ~taken[sel]
-                col[sel[keep]] = off + bins_nz[nd][keep]
-                taken[sel[keep]] = True
-            else:
-                # zeros bin away from the most-frequent bin (e.g.
-                # zero_as_missing): expand this member densely
-                dense_bins = np.full(n, zero_bin, np.int64)
-                dense_bins[rows_j] = bins_nz
-                sel = np.nonzero((dense_bins != mfb) & ~taken)[0]
-                col[sel] = off + dense_bins[sel]
-                taken[sel] = True
-        out[:, ci] = col.astype(dtype)
+            rows, bins = _nondefault(csc, mappers[j], j,
+                                     int(most_freq_bins[k]), n)
+            keep = ~taken[rows]
+            col[rows[keep]] = (int(layout.offset_of_feat[k])
+                               + bins[keep]).astype(dtype)
+            taken[rows[keep]] = True
+            lost.append(rows[~keep])
+    return (np.ascontiguousarray(outT.T),
+            np.unique(np.concatenate(lost)) if lost
+            else np.zeros(0, np.int64))
+
+
+def _logical_bins(csc, mappers, used_features, n: int, dtype,
+                  rows: np.ndarray = None) -> np.ndarray:
+    """[len(rows), F] logical bins of ``rows`` (ascending; all n rows
+    when None) straight from CSC columns."""
+    m_rows = n if rows is None else len(rows)
+    if rows is not None:
+        # row -> its place among ``rows`` (-1: not asked for)
+        place = np.full(n, -1, np.int64)
+        place[rows] = np.arange(m_rows)
+    out = np.zeros((m_rows, len(used_features)), dtype)
+    for k, j in enumerate(used_features):
+        m = mappers[j]
+        lo, hi = csc.indptr[j], csc.indptr[j + 1]
+        at, vals = csc.indices[lo:hi], csc.data[lo:hi]
+        if rows is not None:
+            at = place[at]
+            vals, at = vals[at >= 0], at[at >= 0]
+        out[:, k] = int(m.value_to_bin(np.zeros(1))[0])
+        out[at, k] = m.value_to_bin(np.asarray(vals, np.float64)) \
+            .astype(dtype)
     return out
 
 
@@ -196,8 +264,13 @@ class TpuDataset:
         self.missing_types: np.ndarray = np.zeros(0, np.int32)
         self.monotone_constraints: Optional[np.ndarray] = None
         # sparse-built datasets: ``bins`` holds EFB BUNDLE columns and
-        # this carries the ops.efb.BundleLayout decode (None = logical)
+        # this carries the ops.efb.BundleLayout decode (None = logical);
+        # a validation set stored in its training set's bundles keeps the
+        # rows whose values conflict there (a row non-default in two
+        # members of one bundle) beside them, in logical bins: (rows
+        # ascending, [rows, F] bins), none in a training set
         self.prebundled = None
+        self.exact_rows = None
         # streaming-ingest bookkeeping (ingest/): counters published into
         # the training telemetry registry at booster init, and the flag
         # that routes host->device transfer through the double-buffered
@@ -366,6 +439,21 @@ class TpuDataset:
         with bundles, matching the role of the reference's MultiValBin.
         The resulting dataset is 'prebundled': ``bins`` holds BUNDLE
         columns and ``prebundled`` carries the decode layout.
+
+        With ``reference`` (a validation set) the reference's mappers bin
+        the values, into the reference's bundle columns where it has them
+        (so the set is stored as the training matrix is, and the kernels'
+        route logs replay over it), else as logical bins. Stored in
+        bundles, a row non-default in two members of one bundle keeps the
+        first one's value there, and the set keeps such rows' logical
+        bins beside it (``exact_rows``): its leaves are read from those
+        (GBDT._valid_exact), so the set's scores stay the trees' walk over
+        its logical columns.
+
+        Set-up spans: ``bin/sparse/csc`` (the column pass),
+        ``bin/sparse/sample`` (the mappers from the sample),
+        ``bin/bundle/find`` and ``bin/bundle/encode``; the column pass and
+        the encode also sit under ``bin/rows``.
         """
         import scipy.sparse as sp
 
@@ -373,9 +461,14 @@ class TpuDataset:
         from .utils.timer import global_timer as timer
         with timer.section("DatasetLoader::ConstructSparse"):
             self = cls()
-            csc = sp.csc_matrix(data)
-            csc.sort_indices()
-            n, f = csc.shape
+            n, f = data.shape
+            # the row-length work of the sparse path (this column pass
+            # and the encode below) sits under ``bin/rows``, as the dense
+            # path's binning of the rows does
+            with Span(None, "bin/rows", rows=n, features=f), \
+                    Span(None, "bin/sparse/csc", rows=n, features=f):
+                csc = sp.csc_matrix(data)
+                csc.sort_indices()
             self.num_data = n
             self.num_total_features = f
             self.feature_names = (list(feature_names) if feature_names
@@ -383,68 +476,76 @@ class TpuDataset:
             self.metadata = Metadata(n)
 
             if reference is not None:
-                # validation data is only ROUTED (never histogrammed), so
-                # it stores EXACT logical bins: re-encoding through the
-                # train bundles would silently drop conflicting values the
-                # train sample never saw, skewing eval vs predict
                 self.mappers = reference.mappers
                 self.used_features = reference.used_features
                 self.reference_binned = True
                 self._finalize_feature_arrays()
+                layout = reference.prebundled
                 dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
-                out = np.zeros((n, len(self.used_features)), dtype)
-                for k, j in enumerate(self.used_features):
-                    m = self.mappers[j]
-                    lo, hi = csc.indptr[j], csc.indptr[j + 1]
-                    zero_bin = int(m.value_to_bin(np.zeros(1))[0])
-                    col = np.full(n, zero_bin, dtype)
-                    col[csc.indices[lo:hi]] = m.value_to_bin(
-                        np.asarray(csc.data[lo:hi], np.float64)) \
-                        .astype(dtype)
-                    out[:, k] = col
-                self.bins = out
+                if layout is not None:
+                    # in the training set's bundle columns, so that the
+                    # kernels' route logs replay over it; the rows a
+                    # conflict in a bundle leaves short keep their logical
+                    # bins beside them
+                    with Span(None, "bin/rows", rows=n, features=f), \
+                            Span(None, "bin/bundle/encode", rows=n,
+                                 columns=layout.num_columns):
+                        self.bins, lost = _encode_sparse_bundles(
+                            csc, self.mappers, self.used_features, layout,
+                            self.most_freq_bins, n)
+                        self.prebundled = layout
+                        self.exact_rows = (lost, _logical_bins(
+                            csc, self.mappers, self.used_features, n,
+                            dtype, lost))
+                    if lost.size:
+                        log.info("Sparse EFB: %d rows of the validation set "
+                                 "conflict in the training bundles; their "
+                                 "logical bins are kept beside them",
+                                 lost.size)
+                    return self
+                # routed only (never histogrammed): logical bins
+                with Span(None, "bin/rows", rows=n, features=f):
+                    self.bins = _logical_bins(csc, self.mappers,
+                                              self.used_features, n, dtype)
                 return self
 
             # ---- sample + per-feature mappers (zeros implicit, like the
             # dense path / ref dataset_loader.cpp:988); one pass also
             # collects the sample non-default masks for bundling
-            sample_idx = np.sort(_sample_rows(
-                n, config.bin_construct_sample_cnt, config.data_random_seed))
-            n_sample = len(sample_idx)
-            self.mappers = []
-            sample_masks = []
-
-            def _in_sample(rows_j):
-                # sorted-membership: O(nnz log n_sample) per column, no
-                # per-call re-sorts (np.isin sorts its second arg)
-                pos = np.searchsorted(sample_idx, rows_j)
-                pos_c = np.minimum(pos, n_sample - 1)
-                return (pos < n_sample) & (sample_idx[pos_c] == rows_j), \
-                    pos_c
-
-            for j in range(f):
-                lo, hi = csc.indptr[j], csc.indptr[j + 1]
-                rows_j = csc.indices[lo:hi]
-                vals_j = csc.data[lo:hi]
-                hit, pos = _in_sample(rows_j)
-                nz = np.asarray(vals_j[hit], np.float64)
-                nz = nz[(np.abs(nz) > 1e-35) | np.isnan(nz)]
-                m = BinMapper()
-                m.find_bin(nz, total_sample_cnt=n_sample,
-                           max_bin=config.max_bin,
-                           min_data_in_bin=config.min_data_in_bin,
-                           min_split_data=(config.min_data_in_leaf
-                                           if config.feature_pre_filter
-                                           else 0),
-                           pre_filter=config.feature_pre_filter,
-                           bin_type=BIN_NUMERICAL,
-                           use_missing=config.use_missing,
-                           zero_as_missing=config.zero_as_missing)
-                self.mappers.append(m)
-                if not m.is_trivial:
-                    mask = np.zeros(n_sample, bool)
-                    mask[pos[hit]] = True
-                    sample_masks.append(mask)
+            with Span(None, "bin/sparse/sample", rows=n, features=f):
+                sample_idx = np.sort(_sample_rows(
+                    n, config.bin_construct_sample_cnt,
+                    config.data_random_seed))
+                n_sample = len(sample_idx)
+                # row -> its place in the sample (-1: not sampled): one
+                # gather a stored value finds its sampled rows by
+                pos_of_row = np.full(n, -1, np.int32)
+                pos_of_row[sample_idx] = np.arange(n_sample)
+                self.mappers = []
+                sample_masks = []
+                for j in range(f):
+                    lo, hi = csc.indptr[j], csc.indptr[j + 1]
+                    pos = pos_of_row[csc.indices[lo:hi]]
+                    hit = pos >= 0
+                    nz = np.asarray(csc.data[lo:hi][hit], np.float64)
+                    nz = nz[(np.abs(nz) > 1e-35) | np.isnan(nz)]
+                    m = BinMapper()
+                    m.find_bin(nz, total_sample_cnt=n_sample,
+                               max_bin=config.max_bin,
+                               min_data_in_bin=config.min_data_in_bin,
+                               min_split_data=(config.min_data_in_leaf
+                                               if config.feature_pre_filter
+                                               else 0),
+                               pre_filter=config.feature_pre_filter,
+                               bin_type=BIN_NUMERICAL,
+                               use_missing=config.use_missing,
+                               zero_as_missing=config.zero_as_missing)
+                    self.mappers.append(m)
+                    if not m.is_trivial:
+                        mask = np.zeros(n_sample, bool)
+                        mask[pos[hit]] = True
+                        sample_masks.append(mask)
+                del pos_of_row
             self.used_features = [j for j in range(f)
                                   if not self.mappers[j].is_trivial]
             if not self.used_features:
@@ -452,21 +553,29 @@ class TpuDataset:
                             "satisfy the provided configuration.")
             self._finalize_feature_arrays()
 
-            # ---- conflict-bounded bundling on the SAMPLE rows (the
-            # reference also bundles from its sample,
-            # dataset_loader.cpp FindGroups call sites)
-            masks = sample_masks
+            # ---- bundling on the SAMPLE rows (the reference also
+            # bundles from its sample, dataset_loader.cpp FindGroups call
+            # sites), every placement then checked on all the rows: no
+            # conflict anywhere, so the bundle matrix is lossless
             nb = [int(x) for x in self.num_bin_per_feat]
-            bundles = find_bundles(
-                masks, n_sample,
-                max_conflict_rate=0.0,
-                max_bundle_bins=int(config.tpu_max_bundle_bins),
-                num_bin_per_feat=nb)
-            layout = BundleLayout(bundles, nb)
+            with Span(None, "bin/bundle/find", rows=n_sample,
+                      features=len(nb)):
+                bundles = find_bundles(
+                    sample_masks, n_sample,
+                    max_conflict_rate=0.0,
+                    max_bundle_bins=int(config.tpu_max_bundle_bins),
+                    num_bin_per_feat=nb,
+                    exact=_ExactRows(csc, self.mappers, self.used_features,
+                                     self.most_freq_bins, n))
+                layout = BundleLayout(bundles, nb)
             self.prebundled = layout
-            self.bins = _encode_sparse_bundles(
-                csc, self.mappers, self.used_features, layout,
-                self.most_freq_bins, n)
+            with Span(None, "bin/rows", rows=n, features=f), \
+                    Span(None, "bin/bundle/encode", rows=n,
+                         columns=layout.num_columns):
+                # (no conflicts: every placement was checked on all rows)
+                self.bins, _ = _encode_sparse_bundles(
+                    csc, self.mappers, self.used_features, layout,
+                    self.most_freq_bins, n)
             log.info("Sparse EFB: %d used features -> %d bundle columns "
                      "(max %d bins)", len(self.used_features),
                      layout.num_columns, max(layout.col_num_bin))

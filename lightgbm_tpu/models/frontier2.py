@@ -83,18 +83,20 @@ def route_form(has_cat: bool, bundle_cols: int,
     (the job has a categorical column) each slot's categorical flag and
     its left-going bin SET as 256 bits, which the kernels test the stored
     bin's membership in; no [Sp, FB] table is built, logged or
-    multiplied. ``table``: ``W @ one_hot`` (build_route_table*), kept
-    where a split does not read the split feature's bin from one stored
-    value, with or without categorical columns:
+    multiplied. A job stored as EFB bundle columns (``bundle_cols > 0``)
+    takes it too: the row is the split feature's bundle column and the
+    slot table carries the feature's window, by which the kernels decode
+    the stored bundle value to the feature's bin before the same tests.
+    ``table``: ``W @ one_hot`` (build_route_table*), kept where a stored
+    value (``num_bins`` a column of the kernels' matrix) can pass 255,
+    which is not exact in bfloat16 and does not fit the slot table's
+    256-bit set, with or without categorical columns; the reason names
+    the column:
 
-    - ``bundled``: the stored value is an EFB bundle bin that decodes
-      to the split feature's bin by its window (and may pass 256);
-    - ``wide_bins``: bin values over 255 are not exact in bfloat16 and
-      do not fit the slot table's 256-bit set."""
-    if bundle_cols > 0:
-        return "table", "bundled"
+    - ``bundled``: an EFB bundle column of over 256 bins;
+    - ``wide_bins``: a feature of over 256 bins."""
     if num_bins > 256:
-        return "table", "wide_bins"
+        return "table", "bundled" if bundle_cols > 0 else "wide_bins"
     return "bins", None
 
 
@@ -252,6 +254,9 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
                       slot_cap=max_slot_cap(k_foh * k_B, nch))
     kern_fb = packed.fb if packed is not None else k_foh * k_B
     bins_form = route_form(has_cat, bundle_cols, k_B)[0] == "bins"
+    # the kernels decode bundle values by window (the bins form of a
+    # bundled job); the table form's W is written over the bundle bins
+    window_decode = use_bundles and bins_form
 
     def _decode(hist, Sp_):
         """Kernel accumulator -> (g, h, c) f32 planes on the logical
@@ -293,12 +298,13 @@ def grow_tree_fused(bins_T: jax.Array, gh_T: jax.Array, meta: FeatureMeta,
         # feature's slab under the adaptive layout)
         W0, tbl0 = root_route_tables(
             k_B, kern_fb, packed.widths[0] if packed is not None else k_B,
-            bins_form, Sp0)
+            bins_form, Sp0, bundled=window_decode)
         hist0, _ = level_pass(bins_T, leaf_T, gh_T, W0, tbl0, fmask2d,
                               num_slots=Sp0,
                               num_bins=k_B, f_oh=k_foh, nch=nch,
                               interpret=interpret, quant_bits=quant_bits,
-                              packed=packed, has_cat=has_cat)
+                              packed=packed, has_cat=has_cat,
+                              bundled=window_decode)
         # feature mode: rows are replicated, the local histogram IS the
         # global one (a psum would multiply by the shard count); voting:
         # the root is always a full exchange like the XLA growers
@@ -405,6 +411,7 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
      leaf_lo, leaf_hi, leaf_groups, reg_lo, reg_hi, pool_valid,
      log) = state
     use_bundles = bundle_cols > 0
+    window_decode = use_bundles and bins_form
     inter = use_mono_bounds and mono_mode == "intermediate"
     voting = psum_axis is not None and parallel_mode == "voting"
     # a vote covering every column is statically a full exchange: take
@@ -471,11 +478,16 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
             sets = dict(cat_flag=cf_s, cat_mask=cm_s) if has_cat else {}
             if bins_form:
                 # (route_form) the splits ride the slot table, a
-                # categorical one as its bin set; no [Sp, FB] table is built
+                # categorical one as its bin set, a bundled feature's
+                # with its window; no [Sp, FB] table is built
                 W = None
                 tbl = route_table_columns(
                     tbl, feat_s, thr_s, dl_s, meta.num_bin,
-                    meta.missing_type, meta.default_bin, packed, **sets)
+                    meta.missing_type, meta.default_bin, packed,
+                    bundle=((bundle_cfg.col_of_feat,
+                             bundle_cfg.offset_of_feat,
+                             bundle_cfg.default_bin)
+                            if use_bundles else None), **sets)
             elif use_bundles:
                 W = build_route_table_bundled(
                     feat_s, thr_s, dl_s, meta.num_bin, meta.missing_type,
@@ -505,14 +517,16 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
                 leaf_T2 = route_pass(bins_T, leaf_T, W, tbl, num_slots=Sp,
                                      num_bins=k_B, f_oh=k_foh,
                                      interpret=interpret, packed=packed,
-                                     has_cat=has_cat)
+                                     has_cat=has_cat,
+                                     bundled=window_decode)
                 pool_g2, pool_h2, pool_c2 = pool_g, pool_h, pool_c
                 pool_valid2 = pool_valid
             else:
                 hist, leaf_T2 = level_pass(
                     bins_T, leaf_T, gh_T, W, tbl, fmask2d, num_slots=Sp,
                     num_bins=k_B, f_oh=k_foh, nch=nch, interpret=interpret,
-                    quant_bits=quant_bits, packed=packed, has_cat=has_cat)
+                    quant_bits=quant_bits, packed=packed, has_cat=has_cat,
+                    bundled=window_decode)
                 if psum_axis is not None and not vote_live and not feat_par:
                     hist = record_psum(hist, psum_axis)
 
@@ -818,18 +832,20 @@ def _one_level(state, bins_T, gh_T, meta, feature_mask, params, L, B, f_oh,
 
 def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
                      num_bins: int, f_oh: int, interpret: bool = False,
-                     packed=None, has_cat: bool = False) -> jax.Array:
+                     packed=None, has_cat: bool = False,
+                     bundled: bool = False) -> jax.Array:
     """Leaf of every row of ``bins_T`` in the tree whose route log
     (``grow_tree_fused(route_log=True)``) is ``log``: start the
     ``num_rows`` real rows at leaf 0 (padding columns at -1) and run one
     ``route_pass`` per logged level that has an active slot. ``bins_T``
     is any [Fp, Rp] matrix in the layout the tables were written over
     (the grower's ``num_bins`` / ``f_oh`` / ``packed`` / ``has_cat``
-    kernel layout). The decisions are the training rows' own in either
-    form (``W @ one_hot > 0.5``, or the bin value against the slot's
-    threshold or bin set where the log holds no ``W``: exact arithmetic
-    both), so over the training matrix
-    this returns the grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
+    kernel layout; ``bundled``: EFB bundle columns, decoded by the
+    windows the bins form's tables carry). The decisions are the training
+    rows' own in either form (``W @ one_hot > 0.5``, or the bin value
+    against the slot's threshold or bin set where the log holds no ``W``:
+    exact arithmetic both), so over the training matrix this returns the
+    grower's ``row_leaf``. Returns leaf_T [1, Rp] int32."""
     log_W, log_tbl = log
     Rp = bins_T.shape[1]
     leaf_T = jnp.where(jnp.arange(Rp)[None, :] < num_rows, 0, -1) \
@@ -842,7 +858,8 @@ def replay_route_log(bins_T: jax.Array, log, num_rows: int, *,
             lambda lt: route_pass(bins_T, lt, W, tbl,
                                   num_slots=tbl.shape[0], num_bins=num_bins,
                                   f_oh=f_oh, interpret=interpret,
-                                  packed=packed, has_cat=has_cat),
+                                  packed=packed, has_cat=has_cat,
+                                  bundled=bundled and W is None),
             lambda lt: lt, leaf_T), None
 
     leaf_T, _ = jax.lax.scan(level, leaf_T, (log_W, log_tbl))

@@ -76,16 +76,26 @@ Design (timings: TPU v5e, PERF.md section 6, step 0 of PR 28 and PR 31):
       job's kernels lower as they did before it existed. ``route_pass``
       in this form builds no one-hot and has no FB-sized scratch: 5.3 ms
       over 28M rows x 28 features at 64 slots, 2.4 ms over 6.8M x 137.
+      A job stored as EFB bundle columns (the grower's static
+      ``bundled``) reads a bundle value, which DECODES to the split
+      feature's bin by its window (ops/efb.py): the slot table carries
+      the feature's bundle column as its row, its window's first value
+      and width and its most-frequent bin, and the kernel turns the
+      picked value ``v`` into ``v - offset`` inside the window and the
+      most-frequent bin outside it (the rows that are default in the
+      feature, and the rows another member of the bundle wrote first)
+      before the same compares. Three more [Sp, C] VPU planes, traced
+      only for a bundled job, under the ``bundle_decode`` scope.
     * TABLE form: ``D = W @ oh -> [S, C]`` with W [S, FB] encoding the
       level's left-going bins per slot (``build_route_table*``). It
       contracts over K = FB to learn one bit per row and slot, latching
       FB/128 x C/128 one-hot tiles and streaming only S <= 128 rows
       through each: 58-70 ms of every ``level_pass`` and, with the
       one-hot build it needs (28-60 ms), all 103 / 131 ms of a
-      ``route_pass`` at the two widths above. Kept where "left" is not
-      read from one stored value of at most 255 (EFB bundle columns
-      whose bins decode by window, bins over 255), with or without
-      categorical columns (``build_route_table*``'s ``cat_mask``).
+      ``route_pass`` at the two widths above. Kept where a stored value
+      can pass 255 (bins over 255, EFB bundle columns of over 256
+      bins), with or without categorical columns
+      (``build_route_table*``'s ``cat_mask``).
       ``level_pass`` / ``route_pass`` take the form from their ``W``
       argument (None = bins form).
 - All gh channels are packed into ONE dot operand of nch*S rows.
@@ -128,10 +138,14 @@ NCH_FAST = 3      # g, h, w
 # (route_table_columns) and stay zero in the table form; 7-15 carry a
 # categorical split's left-going bin SET in the bins form of a job with a
 # categorical column: the slot's categorical flag, then bins 0-255 as the
-# bits of eight int32 words (bin b is bit b & 31 of word b >> 5).
+# bits of eight int32 words (bin b is bit b & 31 of word b >> 5); 16-18
+# carry the split feature's bundle window in the bins form of a job stored
+# as EFB bundle columns: its first value, its width (the feature's bins)
+# and the feature's most-frequent bin.
 TBL_LEAF, TBL_RIGHT_DELTA, TBL_SMALL_LEFT = 0, 1, 2
 TBL_THRESHOLD, TBL_MISSING_BIN, TBL_DEFAULT_LEFT, TBL_FEATURE_ROW = 3, 4, 5, 6
 TBL_CAT_FLAG, TBL_CAT_WORD0, CAT_WORDS = 7, 8, 8
+TBL_WINDOW_OFFSET, TBL_WINDOW_WIDTH, TBL_WINDOW_MFB = 16, 17, 18
 
 
 def _round_up(x: int, m: int) -> int:
@@ -151,19 +165,28 @@ SLAB_ROWS = 512   # one-hot rows of one slab of the bins form's build
 # categorical column) keeps per row and slot: the integer bin value, the
 # chosen word of the set and the shifted bit, int32 each
 CAT_PLANE_BYTES = 12
+# what the bins form's window decode (_left_from_bins, jobs stored as EFB
+# bundle columns) keeps per row and slot: the value less the window's
+# offset, the in-window 0/1 and the decoded bin, four bytes each
+DECODE_PLANE_BYTES = 12
+
+
+def _plane_bytes(has_cat: bool, bundled: bool) -> int:
+    """Bytes a row and slot of the bins form's routing planes take."""
+    return 16 + CAT_PLANE_BYTES * has_cat + DECODE_PLANE_BYTES * bundled
 
 
 def slab_row_bytes(Sp: int, nch: int, bins_rows: int,
-                   has_cat: bool = False) -> int:
+                   has_cat: bool = False, bundled: bool = False) -> int:
     """Scoped-VMEM bytes the bins form's ``level_pass`` is charged per row
     of its tile (default_tile_rows says what for)."""
     return (SLAB_ROWS * 6 + bins_rows * 6
-            + Sp * (2 * nch + 16 + CAT_PLANE_BYTES * has_cat))
+            + Sp * (2 * nch + _plane_bytes(has_cat, bundled)))
 
 
 def default_tile_rows(Sp: int, FB: int, nch: int,
                       wide_bins: bool = False, bins_rows: int = 0,
-                      has_cat: bool = False) -> int:
+                      has_cat: bool = False, bundled: bool = False) -> int:
     """Row-tile width of ``level_pass``: a power of two from 128 to 2,048
     (``_init_fused`` aligns the rows to 2,048 a shard), from the PADDED
     layout's shapes and the form alone, so an adaptive-bins job takes its
@@ -177,8 +200,9 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     route_tile_rows charges them) and the converted bin tile (4 B + the
     routing dot's bf16 copy); a job with a categorical column
     (``has_cat``) is charged the membership test's [Sp, C] int32 planes
-    too (CAT_PLANE_BYTES a slot). That is 2,048 rows up to Fp ~700 at 16
-    slots or 128 slots at Fp 28. The charge is CONSERVATIVE, and the
+    too (CAT_PLANE_BYTES a slot), a job stored as bundle columns
+    (``bundled``) the window decode's (DECODE_PLANE_BYTES). That is
+    2,048 rows up to Fp ~700 at 16 slots or 128 slots at Fp 28. The charge is CONSERVATIVE, and the
     same in both orders of the histogram dot (the same operands, the
     same bytes): compiled for a described v5e at Higgs's width and
     2,048-row tiles the kernel needs 1.13 / 1.61 / 2.39 / 4.31 MB at 8 /
@@ -204,7 +228,8 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
     default 16 MB scoped-VMEM limit. Shallow levels (small Sp -> small
     accumulator) get larger tiles."""
     if bins_rows:
-        c = VMEM_BUDGET // slab_row_bytes(Sp, nch, bins_rows, has_cat)
+        c = VMEM_BUDGET // slab_row_bytes(Sp, nch, bins_rows, has_cat,
+                                          bundled)
     else:
         acc = FB * nch * Sp * 4
         avail = max(VMEM_BUDGET - acc, 2 * 1024 * 1024)
@@ -216,7 +241,7 @@ def default_tile_rows(Sp: int, FB: int, nch: int,
 
 def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
                 wide_bins: bool = False, has_cat: bool = False,
-                quant: bool = False) -> dict:
+                quant: bool = False, bundled: bool = False) -> dict:
     """How ``level_pass`` builds its one-hot at these shapes, its row
     tile, and which operand of the histogram dot the MXU streams. THE
     place all three are chosen, from static shapes alone: the kernel
@@ -237,7 +262,8 @@ def level_build(bins_form: bool, Sp: int, FB: int, nch: int, Fp: int,
         channels = not quant and (nch * Sp) % 128 != 0
         return {"form": "slab", "slab_rows": SLAB_ROWS,
                 "tile_rows": default_tile_rows(Sp, FB, nch, bins_rows=Fp,
-                                               has_cat=has_cat),
+                                               has_cat=has_cat,
+                                               bundled=bundled),
                 "dot": "channels" if channels else "onehot"}
     return {"form": "scratch", "dot": "onehot",
             "tile_rows": default_tile_rows(Sp, FB, nch, wide_bins=wide_bins)}
@@ -324,14 +350,28 @@ def pack_gh(grad: jax.Array, hess: jax.Array, weight: jax.Array,
     nch=5: g_hi, g_lo, h_hi, h_lo, w  (hi/lo bf16 split => fp32-grade sums)
     nch=3: g, h, w
     Rows beyond nch are zero padding (the sublane block is 8 tall anyway).
+
+    The high half is the value rounded to bfloat16 (to nearest, ties to
+    even) by integer arithmetic on its bits, so exact in bfloat16, and
+    the low half the exact float32 rest rounded to bfloat16. Not
+    ``x - f32(bf16(x))``: XLA on the TPU may keep a bfloat16 intermediate
+    at float32 (excess precision), and then reads that rest as 0 and every
+    low half as 0 (measured on a v5e: the histogram's sums were
+    bfloat16's, a root split's gain 0.3-0.4 % off the float64 one; with
+    the bits rounded by hand, 5e-7). Finite inputs only, as the kernel
+    asks.
     """
     R = grad.shape[-1]
     z = jnp.zeros((R,), jnp.bfloat16)
     if nch == NCH_PRECISE:
-        g_hi = grad.astype(jnp.bfloat16)
-        g_lo = (grad - g_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        h_hi = hess.astype(jnp.bfloat16)
-        h_lo = (hess - h_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        def split(x):
+            u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+            u = (u + jnp.uint32(0x7FFF) + ((u >> 16) & jnp.uint32(1))) \
+                & jnp.uint32(0xFFFF0000)
+            hi = jax.lax.bitcast_convert_type(u, jnp.float32)
+            return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+        g_hi, g_lo = split(grad)
+        h_hi, h_lo = split(hess)
         rows = [g_hi, g_lo, h_hi, h_lo, weight.astype(jnp.bfloat16), z, z, z]
     else:
         rows = [grad.astype(jnp.bfloat16), hess.astype(jnp.bfloat16),
@@ -468,7 +508,8 @@ def route_table_columns(tbl: jax.Array, feature: jax.Array,
                         default_bin: jax.Array,
                         packed: PackedLayout = None,
                         cat_flag: jax.Array = None,
-                        cat_mask: jax.Array = None) -> jax.Array:
+                        cat_mask: jax.Array = None,
+                        bundle=None) -> jax.Array:
     """The BINS form of a level's splits: ``tbl`` with columns 3-6 filled
     per slot (threshold bin; the bin that rides default_left, -1 for
     none — build_route_table's ``is_missing``; default_left; the split
@@ -486,18 +527,32 @@ def route_table_columns(tbl: jax.Array, feature: jax.Array,
     the stored bin in that set alone (unseen, rare, negative and NaN
     categories sit in bins outside it and go right), a numerical slot of
     the same level as above; an inactive slot's flag and words are zero.
+
+    With ``bundle`` = (column, window offset, most-frequent bin), [F]
+    each (a job stored as EFB bundle columns, ops/efb.py), a slot's row
+    is its feature's bundle COLUMN and columns 16-18 carry the feature's
+    window (offset, width = its bins) and most-frequent bin, which the
+    kernels decode the column's value by before any compare; an inactive
+    slot's window is empty.
     Args as build_route_table ([Sp] per slot, [F] per feature)."""
     on = feature >= 0
     f = jnp.maximum(feature, 0)
     mt = missing_type[f]
     miss = jnp.where(mt == 1, default_bin[f],
                      jnp.where(mt == 2, num_bin[f] - 1, -1))
-    row = f if packed is None else jnp.asarray(packed.row_of_feat)[f]
+    if bundle is not None:
+        row = bundle[0][f]
+    else:
+        row = f if packed is None else jnp.asarray(packed.row_of_feat)[f]
     cols = jnp.stack([threshold, miss, default_left.astype(jnp.int32), row],
                      axis=1)
     cols = jnp.where(on[:, None], cols, jnp.array([-1, -1, 0, -1]))
     tbl = tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(
         cols.astype(jnp.int32))
+    if bundle is not None:
+        window = jnp.stack([bundle[1][f], num_bin[f], bundle[2][f]], axis=1)
+        tbl = tbl.at[:, TBL_WINDOW_OFFSET:TBL_WINDOW_MFB + 1].set(
+            jnp.where(on[:, None], window, 0).astype(jnp.int32))
     if cat_flag is None:
         return tbl
     Sp, B = cat_mask.shape
@@ -514,14 +569,15 @@ def route_table_columns(tbl: jax.Array, feature: jax.Array,
 
 
 def root_route_tables(num_bins: int, kern_fb: int, first_width: int,
-                      bins_form: bool, Sp: int = 8):
+                      bins_form: bool, Sp: int = 8, bundled: bool = False):
     """(W, tbl) of the ROOT pass: slot 0 holds leaf 0, is its own
     "smaller child" and sends every row left, so it collects the
     full-data histogram; the other slots are inactive. Table form: W[0]
     is 1 over the FIRST kernel column's ``first_width`` bins (each row's
     one-hot holds exactly one of them). Bins form (W None): whatever bin
     the first kernel row holds is <= num_bins - 1, and no bin is the
-    missing one (the root sends missing rows left too)."""
+    missing one (the root sends missing rows left too); ``bundled``: the
+    window of slot 0 is the whole column, so its decode is the value."""
     tbl = jnp.zeros((Sp, 128), jnp.int32) \
         .at[:, TBL_LEAF].set(jnp.where(jnp.arange(Sp) == 0, 0, -2)) \
         .at[0, TBL_SMALL_LEFT].set(1)
@@ -530,7 +586,10 @@ def root_route_tables(num_bins: int, kern_fb: int, first_width: int,
         return W, tbl
     cols = jnp.array([[num_bins - 1, -1, 0, 0]] + [[-1, -1, 0, -1]] * (Sp - 1),
                      jnp.int32)
-    return None, tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(cols)
+    tbl = tbl.at[:, TBL_THRESHOLD:TBL_FEATURE_ROW + 1].set(cols)
+    if bundled:
+        tbl = tbl.at[0, TBL_WINDOW_WIDTH].set(num_bins)
+    return None, tbl
 
 
 def build_route_table_bundled(feature: jax.Array, threshold: jax.Array,
@@ -612,7 +671,8 @@ def bundle_plane_views(plane: jax.Array, flat_idx: jax.Array,
     return out[..., 0] if squeeze else out
 
 
-def _left_from_bins(bins_ref, tbl_ref, has_cat: bool = False):
+def _left_from_bins(bins_ref, tbl_ref, has_cat: bool = False,
+                    bundled: bool = False):
     """left_i [Sp, C] int32 0/1 from the bin VALUES (bins form): slot
     k's feature row is picked with a K = Fp dot, ``v[k, r] = sum_f
     sel[k, f] * bins[f, r]`` (one non-zero term, bin values <= 255 are
@@ -626,7 +686,16 @@ def _left_from_bins(bins_ref, tbl_ref, has_cat: bool = False):
     bin set instead, bit ``v & 31`` of word ``v >> 5`` of the eight words
     the slot table carries (route_table_columns): the word by eight
     compare-and-selects, the bit by a per-lane shift. Traced only then:
-    the kernels of a job without a categorical column lower as before."""
+    the kernels of a job without a categorical column lower as before.
+
+    ``bundled`` (static: the job is stored as EFB bundle columns, the
+    feature row is the split feature's bundle column): v is decoded to
+    the feature's bin first, ``v - offset`` inside the slot's window
+    [offset, offset + width) and its most-frequent bin outside it
+    (ops/efb.py's encoding: the rows default in the feature, and those a
+    member before it in the bundle wrote), exact in f32, under the
+    ``bundle_decode`` scope; the compares, and the membership test,
+    read the decoded bin. Traced only then, like ``has_cat``."""
     Fp = bins_ref.shape[0]
     Sp = tbl_ref.shape[0]
     sel = (jax.lax.broadcasted_iota(jnp.int32, (Sp, Fp), 1)
@@ -635,6 +704,15 @@ def _left_from_bins(bins_ref, tbl_ref, has_cat: bool = False):
     v = jax.lax.dot_general(sel, bins_ref[:].astype(jnp.bfloat16),
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [Sp, C]
+    if bundled:
+        with jax.named_scope("bundle_decode"):
+            def col(c):
+                return tbl_ref[:, c:c + 1].astype(jnp.float32)   # [Sp, 1]
+            d = v - col(TBL_WINDOW_OFFSET)
+            inside = ((d >= 0.0).astype(jnp.int32)
+                      * (d < col(TBL_WINDOW_WIDTH)).astype(jnp.int32))
+            mfb = col(TBL_WINDOW_MFB)
+            v = mfb + inside.astype(jnp.float32) * (d - mfb)
     thr = tbl_ref[:, TBL_THRESHOLD:TBL_THRESHOLD + 1].astype(jnp.float32)
     miss = tbl_ref[:, TBL_MISSING_BIN:TBL_MISSING_BIN + 1] \
         .astype(jnp.float32)
@@ -726,7 +804,8 @@ def _slab_cuts(F_oh: int, B: int, packed: PackedLayout = None):
 def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
                   quant: bool = False, packed: PackedLayout = None,
                   has_fm: bool = False, has_w: bool = True,
-                  has_cat: bool = False, dot: str = "onehot"):
+                  has_cat: bool = False, dot: str = "onehot",
+                  bundled: bool = False):
     """``level_pass``'s body. Table form (``has_w``): the whole one-hot
     goes to the [FB, C] scratch ``oh_ref`` first, because routing reads
     all of it (``D = W @ oh``) before the histogram dot's right-hand side
@@ -758,7 +837,8 @@ def _level_kernel(*refs, B: int, F_oh: int, Sp: int, nch: int,
         a, b, (((1,), (1,)), ((), ())), preferred_element_type=acc_dt)
 
     if not has_w:
-        left_i = _left_from_bins(bins_ref, tbl_ref, has_cat)   # [Sp, C] 0/1
+        left_i = _left_from_bins(bins_ref, tbl_ref, has_cat,
+                                 bundled)                      # [Sp, C] 0/1
         newleaf_ref[:], ghs = _small_child_channels(
             leafb, left_i, tbl_ref, gh_ref, nch, quant)
         # the bin tile converted ONCE to 4-byte rows (8 to a register):
@@ -806,13 +886,15 @@ def _kernel_fb(f_oh: int, num_bins: int, packed: PackedLayout) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "f_oh", "nch", "tile_rows",
-                     "interpret", "quant_bits", "packed", "has_cat"))
+                     "interpret", "quant_bits", "packed", "has_cat",
+                     "bundled"))
 def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
                W: jax.Array, tbl: jax.Array, fmask: jax.Array = None,
                *, num_slots: int, num_bins: int, f_oh: int,
                nch: int = NCH_PRECISE, tile_rows: int = 0,
                interpret: bool = False, quant_bits: int = 0,
-               packed: PackedLayout = None, has_cat: bool = False):
+               packed: PackedLayout = None, has_cat: bool = False,
+               bundled: bool = False):
     """One fused route+histogram pass over all rows.
 
     Args:
@@ -850,6 +932,11 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
         in the bins form ``tbl`` then carries each slot's categorical
         flag and bin set (route_table_columns) and the kernel tests
         membership; the table form reads ``W`` and ignores it.
+      bundled: ``bins_T`` holds EFB bundle columns (the grower's
+        static): in the bins form ``tbl`` then carries each slot's
+        window (route_table_columns) and the kernel decodes the picked
+        value by it; the table form's ``W`` is written over the bundle
+        bins already and ignores it.
 
     Returns:
       hist: [FB, nch*Sp] float32 (int32 under quant_bits) smaller-child
@@ -862,8 +949,9 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
     FB_tiles = f_oh * B       # padded formula: keeps tiling A/B-stable
     Sp = tbl.shape[0]
     quant = quant_bits > 0
+    bundled = bundled and W is None
     build = level_build(W is None, Sp, FB_tiles, nch, Fp, wide_bins=B > 256,
-                        has_cat=has_cat, quant=quant)
+                        has_cat=has_cat, quant=quant, bundled=bundled)
     C = _fit_tile(tile_rows or build["tile_rows"], R)
     assert R % C == 0, f"rows {R} not padded to tile {C}"
     T = R // C
@@ -878,7 +966,7 @@ def level_pass(bins_T: jax.Array, leaf_T: jax.Array, gh_T: jax.Array,
                                has_fm=fmask is not None,
                                has_w=W is not None,
                                has_cat=has_cat and W is None,
-                               dot=build["dot"])
+                               dot=build["dot"], bundled=bundled)
     in_specs = [
         pl.BlockSpec((Fp, C), lambda t: (0, t)),
         pl.BlockSpec((1, C), lambda t: (0, t)),
@@ -930,26 +1018,29 @@ def _route_kernel(bins_ref, leaf_ref, w_ref, tbl_ref, newleaf_ref,
 
 
 def _route_bins_kernel(bins_ref, leaf_ref, tbl_ref, newleaf_ref, *,
-                       has_cat: bool = False):
+                       has_cat: bool = False, bundled: bool = False):
     """_route_kernel in the BINS form: no one-hot, no [FB, C] scratch.
     Per tile: [Fp, C] int8 -> bf16, one K = Fp dot, a handful of [Sp, C]
-    VPU ops (the set-membership test among them under ``has_cat``)."""
-    left_i = _left_from_bins(bins_ref, tbl_ref, has_cat)
+    VPU ops (the set-membership test among them under ``has_cat``, the
+    window decode under ``bundled``)."""
+    left_i = _left_from_bins(bins_ref, tbl_ref, has_cat, bundled)
     newleaf_ref[:], _ = _route_rows(leaf_ref[:], left_i, tbl_ref)
 
 
-def route_tile_rows(Sp: int, Fp: int, has_cat: bool = False) -> int:
+def route_tile_rows(Sp: int, Fp: int, has_cat: bool = False,
+                    bundled: bool = False) -> int:
     """Row-tile width of the bins-form route kernel. Nothing in it is
     FB-sized: what the compiler puts on the scoped-VMEM stack is the bf16
     copy of the [Fp, C] bin tile (2 B an element: 31.4 MB refused at Fp
     2,000 x 8,192 rows, 16.3 MB at 512 x 16,384, compiled for a described
     v5e), beside the int8 tile's double buffer; the [Sp, C] planes are
-    charged 16 B a row and slot, and CAT_PLANE_BYTES more for the
-    membership test of a job with a categorical column (``has_cat``). A
+    charged 16 B a row and slot, CAT_PLANE_BYTES more for the
+    membership test of a job with a categorical column (``has_cat``) and
+    DECODE_PLANE_BYTES more for the window decode of a bundled job. A
     power of two from 512 to 8,192: a grid
     step costs ~0.35 us, and on a v5e (PR 28) the 28M-row Higgs pass took
     7.8 / 6.0 / 5.3 ms at 2,048 / 4,096 / 8,192 rows and 64 slots."""
-    c = VMEM_BUDGET // (4 * Fp + (16 + CAT_PLANE_BYTES * has_cat) * Sp)
+    c = VMEM_BUDGET // (4 * Fp + _plane_bytes(has_cat, bundled) * Sp)
     c = 1 << (int(c).bit_length() - 1)
     return int(max(512, min(8192, c)))
 
@@ -957,16 +1048,16 @@ def route_tile_rows(Sp: int, Fp: int, has_cat: bool = False) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("num_slots", "num_bins", "f_oh", "tile_rows",
-                     "interpret", "packed", "has_cat"))
+                     "interpret", "packed", "has_cat", "bundled"))
 def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
                tbl: jax.Array, *, num_slots: int, num_bins: int,
                f_oh: int, tile_rows: int = 0,
                interpret: bool = False,
                packed: PackedLayout = None,
-               has_cat: bool = False) -> jax.Array:
-    """Row->leaf update only (same W/tbl/has_cat contract as level_pass;
-    ``W`` None = bins form, whose tile is sized from Fp and Sp, not from
-    FB)."""
+               has_cat: bool = False, bundled: bool = False) -> jax.Array:
+    """Row->leaf update only (same W/tbl/has_cat/bundled contract as
+    level_pass; ``W`` None = bins form, whose tile is sized from Fp and
+    Sp, not from FB)."""
     Fp, R = bins_T.shape
     B = num_bins
     Sp = tbl.shape[0]
@@ -974,10 +1065,12 @@ def route_pass(bins_T: jax.Array, leaf_T: jax.Array, W: jax.Array,
     tbl_spec = pl.BlockSpec((Sp, 128), lambda t: (0, 0))
     params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     if W is None:
-        C = _fit_tile(tile_rows or route_tile_rows(Sp, Fp, has_cat), R)
+        C = _fit_tile(tile_rows or route_tile_rows(Sp, Fp, has_cat,
+                                                   bundled), R)
         assert R % C == 0, f"rows {R} not padded to tile {C}"
         return pl.pallas_call(
-            functools.partial(_route_bins_kernel, has_cat=has_cat),
+            functools.partial(_route_bins_kernel, has_cat=has_cat,
+                              bundled=bundled),
             grid=(R // C,),
             in_specs=[row_spec(Fp, C), row_spec(1, C), tbl_spec],
             out_specs=row_spec(1, C),

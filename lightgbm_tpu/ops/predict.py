@@ -57,8 +57,22 @@ def route_rows_to_leaves(bins: jax.Array, split_feature: jax.Array,
     holds EFB BUNDLE columns (sparse-built datasets) — the logical bin is
     decoded per node: in-window values shift by the feature's offset,
     out-of-window rows are bundle-default and carry the feature's most
-    frequent bin (ops/efb.py encoding).
+    frequent bin (ops/efb.py encoding). Two more entries, (rows [M],
+    logical bins [M, F]), give rows whose logical bins the bundles do not
+    hold (a validation set's conflicting rows, TpuDataset.from_sparse):
+    their leaves are walked over those bins instead (a row index past the
+    end is padding).
     """
+    if bundle is not None and len(bundle) == 5:
+        leaves = route_rows_to_leaves(
+            bins, split_feature, threshold_bin, default_left, left_child,
+            right_child, num_bin, missing_type, default_bin, max_steps,
+            cat_flag, cat_mask, bundle=bundle[:3])
+        exact = route_rows_to_leaves(
+            bundle[4], split_feature, threshold_bin, default_left,
+            left_child, right_child, num_bin, missing_type, default_bin,
+            max_steps, cat_flag, cat_mask)
+        return leaves.at[bundle[3]].set(exact, mode="drop")
     R = bins.shape[0]
     node = jnp.zeros((R,), jnp.int32)
 

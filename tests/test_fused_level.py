@@ -637,3 +637,24 @@ def test_level_build_streams_the_channels_in_the_bf16_slab_build(
     # a whole number of N-tiles pads nothing: 128 slots x 5 channels = 640
     # columns keep the one-hot streamed (2.6 % faster on the chip)
     assert level_build(True, 128, 1024, NCH_PRECISE, 16)["dot"] == "onehot"
+
+
+def test_pack_gh_splits_into_exact_halves():
+    """The high half is the value rounded to bfloat16 on its bits (to
+    nearest, ties to even: the float32 value whose low 16 bits are 0,
+    whatever precision the compiler keeps an intermediate at), the low
+    half the rest: together 17 bits of mantissa."""
+    rng = np.random.RandomState(0)
+    g = (rng.randn(4096) * 10.0 ** rng.uniform(-6, 3, 4096)) \
+        .astype(np.float32)
+    h = np.abs(g) + np.float32(1e-3)
+    gh = pack_gh(jnp.asarray(g), jnp.asarray(h), jnp.ones(4096, jnp.float32),
+                 NCH_PRECISE)
+    out = np.asarray(gh.astype(jnp.float32))
+    for x, hi, lo in ((g, out[0], out[1]), (h, out[2], out[3])):
+        assert np.all(hi.view(np.uint32) & 0xFFFF == 0)
+        assert np.array_equal(
+            hi, np.asarray(jnp.asarray(x).astype(jnp.bfloat16)
+                           .astype(jnp.float32)))
+        np.testing.assert_allclose(hi + lo, x, rtol=2.0 ** -17, atol=0)
+    assert np.array_equal(out[4], np.ones(4096))

@@ -3,9 +3,11 @@
 `models/frontier2.route_form` picks, from what is static about a job,
 between routing by the bin values (`bins`: the slot table carries the
 splits, a categorical split as its 256-bit bin set since PR 34; no
-`[Sp, FB]` table exists) and `W @ one_hot` (`table`: EFB bundle columns,
-bins over 255). Here: (a) the choice itself, and the bins form's
-set-membership test against the table form's plane, kernel by kernel;
+`[Sp, FB]` table exists; EFB bundle columns of at most 256 bins too,
+decoded by window in the kernel) and `W @ one_hot` (`table`:
+bins over 255, bundle columns of over 256 bins). Here: (a) the choice
+itself, and the bins form's set-membership test against the table form's
+plane, kernel by kernel;
 (b) the grower in both forms over the same data with numerical and
 categorical splits: the same tree, the same leaves, the same replay;
 (c) whole jobs say their form once, with the reason (`route_form` event,
@@ -46,6 +48,8 @@ from test_valid_route import BINARY, _binary_data
     ((True, 3, 512), ("table", "bundled")),
     ((True, 0, 256), ("bins", None)),
     ((True, 0, 512), ("table", "wide_bins")),
+    ((False, 3, 256), ("bins", None)),
+    ((True, 3, 64), ("bins", None)),
 ], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else None)
 def test_route_form_is_chosen_from_what_is_static(static, want):
     assert route_form(*static) == want

@@ -267,3 +267,47 @@ def test_the_channel_streaming_pass_compiles_within_its_charge(
     used = scoped_vmem_bytes(_compiles(fn, *args))
     print(f"{name} {sp} slots: compiled {used} B, charged {charge} B")
     assert used < charge < 16 * 1024 * 1024
+
+
+# ---- the bundled job in the bins form (the one-hot cell): 13 kernel
+# columns of EFB bundles (14 where a draw bundles into one more), 256 bins
+# each (int16), the window decode traced
+@pytest.mark.parametrize("cols", [13, 14])
+@pytest.mark.parametrize("sp", [8, 32])
+def test_the_bundled_bins_form_compiles_within_its_charge(one_chip, sp,
+                                                          cols):
+    """``level_pass`` and ``route_pass`` with ``bundled``: the decode's
+    three [Sp, C] planes are charged to the tile (DECODE_PLANE_BYTES), the
+    level pass keeps its 2,048-row tile at the cell's 32-slot cap, and the
+    scoped VMEM the compiler takes is under the charge."""
+    from lightgbm_tpu.ops.fused_level import level_build, slab_row_bytes
+    from lightgbm_tpu.utils.platform import scoped_vmem_bytes
+    o = _operands(one_chip, cols, 255, sp)
+    assert o["fb"] == cols * 256 and max_slot_cap(o["fb"], NCH_PRECISE) == 32
+    build = level_build(True, sp, o["fb"], NCH_PRECISE, o["fp"],
+                        bundled=True)
+    assert build["tile_rows"] == 2048 and build["dot"] == "channels"
+    fn = functools.partial(level_pass, nch=NCH_PRECISE, bundled=True,
+                           **o["kw"])
+    used = scoped_vmem_bytes(_compiles(fn, o["bins"], o["leaf"], o["gh"],
+                                       None, o["tbl"]))
+    charge = 2048 * slab_row_bytes(sp, NCH_PRECISE, o["fp"], bundled=True)
+    print(f"bundled {cols} columns, {sp} slots: compiled {used} B, "
+          f"charged {charge} B")
+    assert used < charge < 16 * 1024 * 1024
+    assert route_tile_rows(sp, o["fp"], bundled=True) == 8192
+    _compiles(functools.partial(route_pass, bundled=True, **o["kw"]),
+              o["bins"], o["leaf"], None, o["tbl"])
+
+
+def test_the_gradient_split_survives_the_tpu_compiler(one_chip):
+    """``pack_gh``'s high half is rounded on the value's bits in the
+    program compiled for a described v5e: XLA may keep a bfloat16
+    intermediate at float32, which turned ``x - f32(bf16(x))`` into 0 on
+    the chip (every low half 0, the histogram bfloat16's); integer
+    operations it cannot drop."""
+    from lightgbm_tpu.ops.fused_level import pack_gh
+    x = jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)
+    text = _compiles(lambda g, h: pack_gh(g, h, jnp.ones_like(g),
+                                          NCH_PRECISE), x, x).as_text()
+    assert " and(" in text and "bitcast-convert" in text
