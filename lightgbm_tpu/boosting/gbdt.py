@@ -3777,8 +3777,11 @@ class GBDT:
                       top_k=sample.top_k, other_k=sample.other_k,
                       capacity=sample.capacity,
                       first_sampled_iteration=sample.first_iter,
-                      compaction="pallas_window",
-                      tile_rows=goss.COMPACT_TILE)
+                      compaction="staircase",
+                      tile_rows=goss.compact_tile(     # (8 channel rows)
+                          self.fused_bins_T.shape[0],
+                          self.fused_bins_T.dtype.itemsize, 8,
+                          self.fused_Rp))
         interp = self.fused_interpret
 
         @jax.named_scope("lgbm.sample")

@@ -19,23 +19,44 @@ driver calls them (boosting/gbdt.py):
 - COMPACT (``compact_rows``): the in-bag COLUMNS of the transposed bin
   matrix ``[Fp, Rp]`` and of the packed channels ``[nch8, Rp]`` moved, in
   row order, to the front of ``[Fp, Kp]`` / ``[nch8, Kp]``. A Pallas stream
-  compaction: per tile of C rows a ``[2C, C]`` permutation one-hot is built
-  from each row's destination (an XLA prefix sum of the mask) and one MXU
-  dot moves the tile's in-bag columns into a 2C-wide window of the output;
-  the window's first half is the output block the grid step names through
-  scalar prefetch, the second half the spill into the next block. Bin
-  values (<= 255) and bfloat16 channels are exact in bfloat16, every
+  compaction over tiles of C rows (``compact_tile``: 16,384 at the Higgs
+  cell's shapes), each moved 128 rows at a time. The destinations are
+  monotone, so a chunk's in-bag rows land in two consecutive 128-lane
+  blocks of the output, from the lane its first destination names (XLA
+  prefix sums of the mask give every row's destination and every chunk's
+  first): a [128, 128] 0/1 rotation built from the destinations modulo
+  128 and one MXU dot move the chunk's [Fp + G, 128] columns, and two
+  masked stores split them between the two blocks of a 2C-wide window at
+  a 128-aligned lane offset. The work per row does not grow with the
+  tile. The window's first half is the output block the grid step names
+  through scalar prefetch, the second half the spill into the next block.
+  Bin values (<= 255) and bfloat16 channels are exact in bfloat16, every
   output element has one non-zero term: the copy is exact.
 
-On a v5e (PR 35's step 0; 28,000,000 rows -> 8,400,896 columns of 32 int8
-bin rows + 8 bfloat16 channel rows): select 5.2 ms (a full ``jnp.sort`` of
-the vector: 88.8), draw 1.7 (the keys are computed inside the counting
-passes, which then read one byte a row), the mask's prefix sum 2.3 in
-blocks (6.5 flat), the compaction 42.5 / 35.8 / 48.0 ms at tiles of 256 /
-512 / 1,024 rows (0.35 us a grid step against 2C compares and 2C x 40
-multiply-adds a row), 6 % of what the HBM would need for the move; the
-same move in XLA (``nonzero`` 318 ms, a row gather of a row-major copy and a
-transpose 134, element gathers of the channels 78) is 15 x slower.
+On a v5e (``scripts/step0_goss.py``; 28,000,000 rows -> 8,400,896 columns
+of 32 int8 bin rows + 8 bfloat16 channel rows): select 5.2 ms (a full
+``jnp.sort`` of the vector: 88.8), draw 1.7 (the keys are computed inside
+the counting passes, which then read one byte a row), the mask's prefix
+sum 2.3 in blocks (6.5 flat). The compaction, the launch and its tables,
+in ms at tiles of 1,024 / 2,048 / 4,096 / 8,192 / 16,384 rows, int8 x 32
+bin rows, then int16 x 8:
+
+- this form: 15.77 / 11.06 / 8.51 / 7.10 / 6.51 and 13.84 / 9.58 / 7.32 /
+  6.10 / 5.55 (of it the tables 2.38; the HBM's bound is 2.1);
+- the same with the chunk loop unrolled 16 deep (8 deep) and read-add-write
+  stores, at 2,048-8,192: 12.21 / 10.42 / 9.78 (13.67 / 12.34 / 11.69);
+- a [256, 128] block per chunk into both output blocks at once, 8 deep:
+  18.16 / 14.98 / 14.50 / 13.75 and 16.47 / 13.20 / 13.51 / 12.80 at
+  1,024-8,192;
+- every column shifted left by its count of out-of-bag rows before it in
+  the tile, log2 C stages of a roll and a select, LSB first, no MXU:
+  37.47 / 23.02 / 19.14 / 18.77 and 25.70 / 17.08 / 15.71 / 15.79 (these
+  two forms with tables of 4.3);
+- a [2C, C] permutation one-hot per tile, the form before: 35.79 at 512
+  rows (42.5 / 48.0 at 256 / 1,024).
+
+The same move in XLA (``nonzero`` 318 ms, a row gather of a row-major copy
+and a transpose 134, element gathers of the channels 78) is 80 x slower.
 
 Departures from goss.hpp, which samples per thread block with a
 sequential acceptance probability (a varying count): here the counts are
@@ -55,7 +76,25 @@ from jax.experimental.pallas import tpu as pltpu
 from .scan import blocked_scan
 
 ROW_ALIGN = 2048          # the fused grower's widest row tile
-COMPACT_TILE = 512        # rows of one compaction tile (and output block)
+LANES = 128               # rows of one chunk of the compaction's move
+COMPACT_TILE_MAX = 16384  # rows of the compaction's widest tile
+COMPACT_VMEM = 12 * 1024 * 1024   # what its window and buffers may hold
+
+
+def compact_tile(Fp: int, itemsize: int, G: int, Rp: int,
+                 most: int = COMPACT_TILE_MAX) -> int:
+    """Row tile (and output block) of ``compact_rows``, from the shapes
+    alone: the widest power of two up to ``most`` whose window and
+    buffers fit COMPACT_VMEM (per row: the [Fp + G, 2C] float32 window,
+    the double-buffered bins, channels and int32 destinations, the
+    latter padded to 8 sublanes, and the two output blocks), halved
+    until it divides the ``Rp`` rows. No tile is under 256 rows."""
+    per_row = 8 * (Fp + G) + 4 * (Fp * itemsize + 2 * G) + 64
+    c = 1 << ((COMPACT_VMEM // per_row).bit_length() - 1)
+    c = max(256, min(most, c))
+    while Rp % c:
+        c //= 2
+    return c
 
 
 class GossPlan(NamedTuple):
@@ -160,8 +199,9 @@ def sample_weights(top: jax.Array, other: jax.Array,
 
 
 # ------------------------------------------------------------- compaction
-def _compact_kernel(blk_ref, dest_ref, bins_ref, gh_ref, obins_ref, ogh_ref,
-                    acc_ref, *, C: int, T: int, Fp: int):
+def _compact_kernel(blk_ref, dest_ref, first_ref, bins_ref, gh_ref, obins_ref,
+                    ogh_ref, acc_ref, *, C: int, T: int, Fp: int,
+                    steps: int):
     t = pl.program_id(0)
     b = blk_ref[t]
 
@@ -172,49 +212,71 @@ def _compact_kernel(blk_ref, dest_ref, bins_ref, gh_ref, obins_ref, ogh_ref,
     # the window moved on by one block: its spill half is the new block
     @pl.when((t > 0) & (b != blk_ref[jnp.maximum(t - 1, 0)]))
     def _():
-        acc_ref[:, :C] = acc_ref[:, C:]
-        acc_ref[:, C:] = jnp.zeros((acc_ref.shape[0], C), acc_ref.dtype)
+        acc_ref[:, :C] = acc_ref[:, C:2 * C]
+        acc_ref[:, C:] = jnp.zeros((acc_ref.shape[0], C + LANES),
+                                   acc_ref.dtype)
 
     @pl.when(t < T)      # (the steps past the rows only flush the window)
     def _():
-        local = dest_ref[:] - b * C                            # [1, C]
-        perm = (jax.lax.broadcasted_iota(jnp.int32, (2 * C, C), 0)
-                == jnp.broadcast_to(local, (2 * C, C))) \
-            .astype(jnp.bfloat16)                              # [2C, C]
-        x = jnp.concatenate([bins_ref[:].astype(jnp.bfloat16), gh_ref[:]],
-                            axis=0)                            # [Fp+G, C]
-        acc_ref[:] += jax.lax.dot_general(
-            x, perm, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    obins_ref[:] = acc_ref[:Fp, :C].astype(obins_ref.dtype)
-    ogh_ref[:] = acc_ref[Fp:, :C].astype(ogh_ref.dtype)
+        for j in range(C // LANES):    # (unrolled: the dots overlap)
+            # the chunk's in-bag rows land in the two 128-lane blocks of
+            # the window from ``off`` on, from lane ``v % 128`` of the
+            # first: a [128, 128] rotation moves them, a lane mask splits
+            cols = slice(j * LANES, (j + 1) * LANES)
+            v = first_ref[0, j]
+            off = pl.multiple_of(v & -LANES, LANES)
+            w = dest_ref[:, cols] - (b * C + off)                # [1, 128]
+            rot = jnp.where(w >= 0, w & (LANES - 1), -1)
+            perm = (rows == jnp.broadcast_to(rot, rows.shape)) \
+                .astype(jnp.bfloat16)                           # [128, 128]
+            x = jnp.concatenate([bins_ref[:, cols].astype(jnp.bfloat16),
+                                 gh_ref[:, cols]], axis=0)      # [Fp+G, 128]
+            y = jax.lax.dot_general(x, perm, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            # (masked stores, no read: a lane the mask takes is this
+            # chunk's column or a later chunk's, which that chunk writes)
+            low = jnp.broadcast_to(lanes >= (v & (LANES - 1)), y.shape)
+            pltpu.store(acc_ref.at[:, pl.ds(off, LANES)], y, mask=low)
+            pltpu.store(acc_ref.at[:, pl.ds(off + LANES, LANES)], y,
+                        mask=jnp.logical_not(low))
+
+    # the block is written once, at the last step that names it
+    @pl.when((t == steps - 1) | (blk_ref[jnp.minimum(t + 1, steps - 1)] != b))
+    def _():
+        obins_ref[:] = acc_ref[:Fp, :C].astype(obins_ref.dtype)
+        ogh_ref[:] = acc_ref[Fp:, :C].astype(ogh_ref.dtype)
 
 
 def compact_tables(inbag: jax.Array, Rp: int, capacity: int,
-                   C: int) -> Tuple[jax.Array, jax.Array]:
-    """(blk [T + E] int32, dest [1, Rp] int32) of ``compact_rows``: the
-    output block each grid step's window starts at, and every row's
-    column in the compact matrix (-1: out of bag). ``inbag``: [n] bool,
-    n <= Rp. After the tiles, ROW_ALIGN // C + 1 steps flush the window
-    and zero the blocks behind it: as many as a count within ROW_ALIGN of
-    ``capacity`` leaves (``compact_rows``' precondition)."""
+                   C: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(blk [T + E], dest [1, Rp], first [T, 1, C / 128]) int32 of
+    ``compact_rows``: the output block each grid step's window starts at,
+    every row's column in the compact matrix (-1: out of bag), and the
+    column each 128-row chunk's first in-bag row takes, counted from its
+    tile's window. ``inbag``: [n] bool, n <= Rp. After the tiles,
+    ceil(ROW_ALIGN / C) + 1 steps flush the window and zero the blocks
+    behind it: as many as a count within ROW_ALIGN of ``capacity`` leaves
+    (``compact_rows``' precondition)."""
     m = jnp.pad(inbag.astype(jnp.int32), (0, Rp - inbag.shape[0]))
-    csum = blocked_scan(m)
-    dest = jnp.where(m > 0, csum - 1, -1)[None, :]
-    ends = csum.reshape(-1, C)[:, -1]
-    before = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
-    blk = before // C
-    nblk = capacity // C
-    flush = jnp.minimum(blk[-1] + 1 + jnp.arange(ROW_ALIGN // C + 1,
+    dest = jnp.where(m > 0, blocked_scan(m) - 1, -1)[None, :]
+    cnt = jnp.sum(m.reshape(-1, LANES), axis=1)
+    first = (blocked_scan(cnt) - cnt).reshape(-1, C // LANES)
+    nblk = -(-capacity // C)
+    blk = jnp.minimum(first[:, 0] // C, nblk - 1)
+    flush = jnp.minimum(blk[-1] + 1 + jnp.arange(-(-ROW_ALIGN // C) + 1,
                                                  dtype=jnp.int32), nblk - 1)
-    return jnp.concatenate([jnp.minimum(blk, nblk - 1), flush]), dest
+    # (a chunk past the bag, under a clamped block, has nothing to move)
+    first = jnp.clip(first - blk[:, None] * C, 0, 2 * C - 1)
+    return jnp.concatenate([blk, flush]), dest, first[:, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "tile_rows",
                                              "interpret"))
 def compact_rows(bins_T: jax.Array, gh_T: jax.Array, inbag: jax.Array, *,
-                 capacity: int, tile_rows: int = COMPACT_TILE,
+                 capacity: int, tile_rows: Optional[int] = None,
                  interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """In-bag columns of ``bins_T`` [Fp, Rp] (int8 / int16, values <= 256)
     and ``gh_T`` [G, Rp] bfloat16 (G a multiple of 8), in row order, at the
@@ -224,27 +286,35 @@ def compact_rows(bins_T: jax.Array, gh_T: jax.Array, inbag: jax.Array, *,
     grid is not): the in-bag count is at most ``capacity`` and at least
     ``capacity - ROW_ALIGN``, as an exact count rounded up to the row tile
     is (``goss_plan``). With fewer rows in the bag the output blocks past
-    the flush steps are never written and hold whatever was there."""
+    the flush steps are never written and hold whatever was there.
+    ``tile_rows``: a power of two, the most rows of a tile (``compact_tile``
+    halves it to fit); None: ``compact_tile``'s own."""
     Fp, Rp = bins_T.shape
     G = gh_T.shape[0]
-    C = tile_rows
-    assert Rp % C == 0 and capacity % C == 0 and ROW_ALIGN % C == 0
+    C = compact_tile(Fp, bins_T.dtype.itemsize, G, Rp,
+                     tile_rows or COMPACT_TILE_MAX)
     T = Rp // C
-    blk, dest = compact_tables(inbag, Rp, capacity, C)
+    blk, dest, first = compact_tables(inbag, Rp, capacity, C)
     steps = blk.shape[0]
     row_in = lambda rows: pl.BlockSpec(
         (rows, C), lambda t, blk: (0, jnp.minimum(t, T - 1)))
     row_out = lambda rows: pl.BlockSpec((rows, C), lambda t, blk: (0, blk[t]))
     return pl.pallas_call(
-        functools.partial(_compact_kernel, C=C, T=T, Fp=Fp),
+        functools.partial(_compact_kernel, C=C, T=T, Fp=Fp, steps=steps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(steps,),
-            in_specs=[row_in(1), row_in(Fp), row_in(G)],
+            in_specs=[row_in(1),
+                      pl.BlockSpec((None, 1, C // LANES),
+                                   lambda t, blk: (jnp.minimum(t, T - 1), 0,
+                                                   0),
+                                   memory_space=pltpu.SMEM),
+                      row_in(Fp), row_in(G)],
             out_specs=[row_out(Fp), row_out(G)],
-            scratch_shapes=[pltpu.VMEM((Fp + G, 2 * C), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((Fp + G, 2 * C + LANES),
+                                       jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((Fp, capacity), bins_T.dtype),
                    jax.ShapeDtypeStruct((G, capacity), gh_T.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(blk, dest, bins_T, gh_T)
+    )(blk, dest, first, bins_T, gh_T)

@@ -108,35 +108,89 @@ def test_the_plan():
 
 
 # --------------------------------------------------------- the compaction
-@pytest.mark.parametrize("mask", ["empty", "full", "one_tile", "ragged",
-                                  "random"])
-def test_the_compaction_is_a_gather(mask):
+MASKS = ["empty", "full", "one_tile", "ragged", "random", "straddle",
+         "whole_tile", "odd_capacity", "rows_off_tile"]
+
+
+def _mask(kind, rng, tile):
+    """(rows, mask) of one geometry of the move."""
+    n = 9000 if kind == "rows_off_tile" else 16000
+    m = np.zeros(n, bool)
+    if kind == "full":
+        m[:] = True
+    elif kind == "one_tile":
+        m[600:900] = True
+    elif kind == "ragged":
+        m[1:n - 1:3] = True
+    elif kind == "random":
+        m = rng.random(n) < 0.3
+    elif kind == "straddle":          # runs across every 128-row boundary
+        m[(np.arange(n) % 128 >= 100) | (np.arange(n) % 128 < 29)] = True
+    elif kind == "whole_tile":        # one tile all in: two output blocks
+        m = rng.random(n) < 0.25
+        m[tile:2 * tile] = True
+    elif kind == "odd_capacity":      # 5,000 kept: capacity 3 x 2,048
+        m[rng.choice(n, 5000, replace=False)] = True
+    elif kind == "rows_off_tile":     # 9,000 rows: Rp 10,240, no 4,096
+        m = rng.random(n) < 0.6
+    return n, m
+
+
+@pytest.mark.parametrize("tile", [2048, 4096])
+@pytest.mark.parametrize("mask", MASKS)
+def test_the_compaction_is_a_gather(mask, tile):
     rng = np.random.default_rng(7)
-    n, Fp = 9000, 32                       # 9,000 is no multiple of a tile
+    n, m = _mask(mask, rng, tile)
+    Fp = 32
     Rp = -(-n // 2048) * 2048
     bins = rng.integers(0, 64, (Fp, Rp)).astype(np.int8)
     gh = jnp.asarray(rng.standard_normal((8, Rp)), jnp.bfloat16)
-    m = np.zeros(n, bool)
-    if mask == "full":
-        m[:] = True
-    elif mask == "one_tile":
-        m[600:900] = True
-    elif mask == "ragged":
-        m[1:8999:3] = True
-    elif mask == "random":
-        m = rng.random(n) < 0.3
     K = int(m.sum())
     cap = max(2048, -(-K // 2048) * 2048)
-    for tile in (256, 512):
-        cb, cg = goss.compact_rows(jnp.asarray(bins), gh, jnp.asarray(m),
-                                   capacity=cap, tile_rows=tile,
-                                   interpret=True)
-        idx = np.flatnonzero(m)
-        assert np.array_equal(np.asarray(cb)[:, :K], bins[:, idx])
-        assert np.array_equal(np.asarray(cg.astype(jnp.float32))[:, :K],
-                              np.asarray(gh.astype(jnp.float32))[:, idx])
-        assert not np.asarray(cb)[:, K:].any()
-        assert not np.asarray(cg.astype(jnp.float32))[:, K:].any()
+    if mask == "odd_capacity":
+        assert cap == 3 * 2048
+    cb, cg = goss.compact_rows(jnp.asarray(bins), gh, jnp.asarray(m),
+                               capacity=cap, tile_rows=tile, interpret=True)
+    idx = np.flatnonzero(m)
+    assert cb.shape == (Fp, cap) and cg.shape == (8, cap)
+    assert np.array_equal(np.asarray(cb)[:, :K], bins[:, idx])
+    assert np.array_equal(np.asarray(cg.astype(jnp.float32))[:, :K],
+                          np.asarray(gh.astype(jnp.float32))[:, idx])
+    assert not np.asarray(cb)[:, K:].any()
+    assert not np.asarray(cg.astype(jnp.float32))[:, K:].any()
+
+
+def test_the_compaction_at_the_widest_tile():
+    """The tile the Higgs cell's rows take (COMPACT_TILE_MAX), over two
+    tiles, the second wholly in the bag."""
+    rng = np.random.default_rng(9)
+    C = goss.COMPACT_TILE_MAX
+    bins = rng.integers(0, 64, (32, 2 * C)).astype(np.int8)
+    gh = jnp.asarray(rng.standard_normal((8, 2 * C)), jnp.bfloat16)
+    m = rng.random(2 * C) < 0.3
+    m[C:] = True
+    K = int(m.sum())
+    cap = -(-K // 2048) * 2048
+    cb, cg = goss.compact_rows(jnp.asarray(bins), gh, jnp.asarray(m),
+                               capacity=cap, interpret=True)
+    idx = np.flatnonzero(m)
+    assert np.array_equal(np.asarray(cb)[:, :K], bins[:, idx])
+    assert np.array_equal(np.asarray(cg.astype(jnp.float32))[:, :K],
+                          np.asarray(gh.astype(jnp.float32))[:, idx])
+    assert not np.asarray(cb)[:, K:].any()
+
+
+def test_the_compaction_tile_fits_the_rows():
+    """The tile is a power of two that divides the rows, from the shapes
+    alone: the Higgs cell's rows take the widest, rows that are a multiple
+    of 2,048 alone take 2,048, and a requested tile is halved to fit."""
+    assert goss.compact_tile(32, 1, 8, 28_000_256) == goss.COMPACT_TILE_MAX
+    assert goss.compact_tile(32, 1, 8, 10240) == 2048
+    assert goss.compact_tile(8, 2, 8, 16384) == goss.COMPACT_TILE_MAX
+    assert goss.compact_tile(32, 1, 8, 10240, 4096) == 2048
+    for fp, item in ((32, 1), (8, 2), (512, 1), (2000, 2)):
+        c = goss.compact_tile(fp, item, 8, 1 << 20)
+        assert c & (c - 1) == 0 and 256 <= c <= goss.COMPACT_TILE_MAX
 
 
 def test_the_compaction_keeps_int16_bins():
@@ -288,7 +342,10 @@ def test_goss_rides_the_megastep(mega):
     assert (layout["n"], layout["top_k"], layout["other_k"],
             layout["capacity"], layout["first_sampled_iteration"]) \
         == (n, plan.top_k, plan.other_k, plan.capacity, 4)
-    assert layout["compaction"] == "pallas_window"
+    assert layout["compaction"] == "staircase"
+    assert layout["tile_rows"] == goss.compact_tile(
+        b._gbdt.fused_bins_T.shape[0], b._gbdt.fused_bins_T.dtype.itemsize,
+        8, b._gbdt.fused_Rp)
     assert b._gbdt._fast_path_reason() is None
     assert len(curve["valid_0"]["auc"]) == 8
 

@@ -176,11 +176,16 @@ def test_bins_form_level_kernel_compiles_at_epsilon_width(one_chip):
 GOSS_ROWS, GOSS_CAPACITY = 28_000_256, 8_400_896   # the cell's Rp and K
 
 
-@pytest.mark.parametrize("tile", [256, 512, 1024])
+@pytest.mark.parametrize("tile", [4096, 8192, 16384])
 def test_the_row_compaction_compiles_at_the_cell_s_rows(one_chip, tile):
-    """``ops/goss.compact_rows`` at 28M rows -> 8.4M columns, Higgs width:
-    the scalar-prefetched block table (one int32 a tile: 437 KB at 256-row
-    tiles), the [40, 2C] float32 window and the [2C, C] permutation fit."""
+    """``ops/goss.compact_rows`` at 28M rows -> 8.4M columns, Higgs width,
+    in VMEM: the [40, 2C + 128] float32 window (5.3 MB at the cell's
+    16,384-row tile), the double-buffered bin, channel and int32
+    destination tiles and the two output blocks; per 128-row chunk a
+    [128, 128] bfloat16 rotation and the chunk's [40, 128] columns (C / 128
+    chunks unrolled); in SMEM the scalar-prefetched block table (one int32
+    a tile) and each tile's C / 128 chunk offsets. 8,400,896 is no
+    multiple of 16,384: the last output block is partial."""
     from lightgbm_tpu.ops import goss
     shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
     _compiles(functools.partial(goss.compact_rows, capacity=GOSS_CAPACITY,
